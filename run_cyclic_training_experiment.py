@@ -21,7 +21,9 @@ def main(argv=None) -> int:
     from turboprune_tpu.config.compose import compose
     from turboprune_tpu.driver import run_cyclic
     from turboprune_tpu.parallel import initialize_distributed, is_primary
+    from turboprune_tpu.utils.compile_cache import place_compile_cache
 
+    place_compile_cache()
     cfg = compose(args.config_name, args.overrides, args.config_path)
     initialize_distributed()
     expt_dir, summaries = run_cyclic(cfg)

@@ -41,7 +41,9 @@ def main(argv=None) -> int:
     from turboprune_tpu.config.compose import compose
     from turboprune_tpu.driver import run
     from turboprune_tpu.parallel import initialize_distributed, is_primary
+    from turboprune_tpu.utils.compile_cache import place_compile_cache
 
+    place_compile_cache()
     cfg = compose(args.config_name, args.overrides, args.config_path)
     initialize_distributed()
     expt_dir, summaries = run(cfg)

@@ -61,6 +61,9 @@ def main(argv=None) -> int:
 
     from turboprune_tpu.config.compose import compose
     from turboprune_tpu.serve import build_server
+    from turboprune_tpu.utils.compile_cache import place_compile_cache
+
+    place_compile_cache()
 
     cfg = compose(args.config_name, args.overrides, args.config_path)
     server = build_server(cfg, expt_dir=args.expt_dir)

@@ -40,9 +40,7 @@ os.environ["JAX_PROCESS_ID"] = str(pid)
 
 import jax  # noqa: E402
 
-jax.config.update("jax_platforms", "cpu")
-jax.config.update("jax_compilation_cache_dir", "/tmp/jax_test_cache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+jax.config.update("jax_enable_compilation_cache", False)  # as conftest.py
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 

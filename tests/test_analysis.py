@@ -2814,6 +2814,23 @@ class TestCompileAudit:
         with pytest.raises(AuditError, match="bogus"):
             run_compile_audit("bogus-target")
 
+    def test_ledger_records_a_real_compile(self):
+        """The ledger patches a jax-internal funnel. If jax moves it, the
+        audit must fail here, not report "0 compiles" for ever after."""
+        import jax
+        import jax.numpy as jnp
+
+        from turboprune_tpu.analysis.compile_audit import CompileLedger
+
+        def tiny_step(x):
+            return x * 2.0 + 1.0
+
+        x = jnp.zeros((3,), jnp.float32)  # created outside the window
+        ledger = CompileLedger()
+        with ledger:
+            jax.jit(tiny_step)(x).block_until_ready()
+        assert [r["name"] for r in ledger.records] == ["jit_tiny_step"]
+
     def test_ledger_attributes_by_name_and_site(self):
         from turboprune_tpu.analysis.compile_audit import _attribution
 

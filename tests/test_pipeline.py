@@ -7,7 +7,7 @@ the chunked iterator, BIT-EXACT parity of ``make_scan_chunk(K)`` with K
 sequential train steps, the end-to-end streamed chunked harness path on
 synthetic .tpk data (dispatch count reduced by K×), and the bench.py
 headline-honesty regression (a skipped headline stage must print
-``value: null`` + ``skipped``, never a fake measured 0.0 — BENCH_r05).
+``value: null`` + ``skipped``, never a fake measured 0.0).
 """
 
 import threading
@@ -432,13 +432,13 @@ def _load_bench_module():
 
 
 class TestBenchHeadlineHonesty:
-    """Regression for the r05 artifact: ``device_probe: unreachable`` with
-    no cached headline stage printed ``"value": 0.0, "vs_baseline": 0.0``
-    — a skipped stage must never look like a measured zero."""
+    """A headline stage that failed with nothing cached once printed
+    ``"value": 0.0, "vs_baseline": 0.0`` — a stage that did not run must
+    never look like a measured zero."""
 
     def test_unmeasured_headline_is_null_and_skipped(self):
         bench = _load_bench_module()
-        record = bench._headline_record(None, {"device_probe": "unreachable"})
+        record = bench._headline_record(None, {"resnet18_error": "boom"})
         assert record["value"] is None
         assert record["vs_baseline"] is None
         assert "skipped" in record

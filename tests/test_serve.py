@@ -518,7 +518,7 @@ class TestSatellites:
         bench = importlib.util.module_from_spec(spec)
         spec.loader.exec_module(bench)
 
-        rec = bench._headline_record(None, {"device_probe": "unreachable"})
+        rec = bench._headline_record(None, {"resnet18_error": "boom"})
         assert rec["value"] is None
         assert rec["vs_baseline"] is None
         assert "skipped" in rec

@@ -1,0 +1,261 @@
+"""What can be shown about the chip without one.
+
+Two kinds of test, kept in ONE file because only one process may hold the
+TPU compiler's library and pytest-xdist hands a file to one worker:
+
+* Compiles for a DESCRIBED TPU v5e (``jax.experimental.topologies``; the
+  compiler is installed here, no chip is attached): the Pallas flash kernel
+  forward and backward at the shapes the main path uses, ring attention on
+  a 2x2 mesh, and (slow) the BASELINE ResNet50 batch-512 train step with its
+  memory count. A compile that passes is not a run; it is what interpret
+  mode cannot show (tiling, VMEM, whether the program fits).
+* chip_smoke.py's phases at tiny size on the virtual CPU mesh, so the
+  script the chip runs is not first executed on the chip.
+
+The topology is described inside a fixture, never at import, in a skipif or
+in a parametrize argument: every xdist worker imports this file, and only
+the one that runs it may load the library.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+import chip_smoke
+from turboprune_tpu.ops.flash import flash_attention
+
+V5E_HBM_BYTES = 16 * 2**30
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else it logs to /tmp
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    # graftlint: disable=broad-except -- whatever keeps the TPU compiler from describing a topology here (no libtpu, lock held by another process) is a reason to skip, not to fail
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _placed(tree, sharding):
+    """The tree's shapes, each said to live under ``sharding`` — there is no
+    device to put an array on, so compiles take shapes."""
+    return jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding),
+        tree,
+    )
+
+
+@pytest.fixture()
+def no_persistent_cache():
+    """A compile for a described device can be written to the persistent
+    cache but not read back without a chip (the next one warns and compiles
+    again), so the cache is off around these compiles whatever the session
+    has set."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    cc.reset_cache()
+
+
+# DeiT-small on one chip (batch 64 x 6 heads of 64, 197 tokens padded to
+# 256) and the long-sequence shape bench.py times.
+@pytest.mark.parametrize("backward", [False, True], ids=["forward", "grad"])
+@pytest.mark.parametrize("shape", [(384, 256, 64), (48, 1024, 64)])
+def test_flash_kernel_compiles_for_v5e(
+    one_chip, no_persistent_cache, shape, backward
+):
+    qkv, valid = _placed(
+        (
+            jax.ShapeDtypeStruct(shape, jnp.bfloat16),
+            jax.ShapeDtypeStruct((1, shape[1]), jnp.float32),
+        ),
+        one_chip,
+    )
+
+    def forward(q, k, v, valid):
+        return flash_attention(
+            q, k, v, valid, shape[2] ** -0.5, interpret=False
+        )
+
+    def loss(q, k, v, valid):
+        return forward(q, k, v, valid).astype(jnp.float32).sum()
+
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if backward else forward
+    compiled = jax.jit(fn).lower(qkv, qkv, qkv, valid).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_ring_attention_compiles_for_a_2x2_mesh(topo, no_persistent_cache):
+    """The sequence-parallel path exists only across chips and the driver
+    runs one: this keeps it compiling for the four-chip host."""
+    import flax.linen as nn
+
+    from turboprune_tpu.models.vit import RingSelfAttention
+
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"))
+    ring = RingSelfAttention(num_heads=6, mesh=mesh, dtype=jnp.bfloat16)
+    dense = nn.MultiHeadDotProductAttention(num_heads=6, dtype=jnp.bfloat16)
+    x = jax.ShapeDtypeStruct((64, 197, 384), jnp.float32)
+    params = jax.eval_shape(
+        lambda: dense.init(jax.random.key(0), jnp.zeros(x.shape), jnp.zeros(x.shape))
+    )
+    def loss(params, x):
+        return ring.apply(params, x).astype(jnp.float32).sum()
+
+    compiled = (
+        jax.jit(jax.grad(loss, argnums=(0, 1)))
+        .lower(
+            _placed(params, NamedSharding(mesh, P())),
+            _placed(x, NamedSharding(mesh, P("data"))),
+        )
+        .compile()
+    )
+    assert "collective-permute" in compiled.as_text()
+
+
+@pytest.mark.slow
+def test_resnet50_batch512_train_step_fits_one_v5e(one_chip, no_persistent_cache):
+    """BASELINE.md's step: ResNet50, ImageNet-224, batch 512, bf16, donated
+    state — the program conf/imagenet_imp.yaml asks the harness for. The
+    compiler counts this one program, not what else the process keeps on
+    the device (chip_smoke.TRAIN_BATCH says what that came to)."""
+    from turboprune_tpu.models import create_model
+    from turboprune_tpu.train import (
+        create_optimizer,
+        create_schedule,
+        create_train_state,
+        make_train_step,
+    )
+
+    model = create_model(
+        "resnet50", num_classes=1000, dataset_name="ImageNet",
+        compute_dtype=jnp.bfloat16,
+    )
+    schedule = create_schedule(
+        "TriangularSchedule", base_lr=0.2, epochs=90, steps_per_epoch=1251
+    )
+    tx = create_optimizer("SGD", schedule, momentum=0.9, weight_decay=1e-4)
+    state = _placed(
+        jax.eval_shape(
+            lambda: create_train_state(
+                model, tx, jax.random.key(0), (1, 224, 224, 3)
+            )
+        ),
+        one_chip,
+    )
+    batch = _placed(
+        (
+            jax.ShapeDtypeStruct((512, 224, 224, 3), jnp.float32),
+            jax.ShapeDtypeStruct((512,), jnp.int32),
+        ),
+        one_chip,
+    )
+    step = jax.jit(make_train_step(model, tx, schedule), donate_argnums=0)
+    memory = step.lower(state, batch).compile().memory_analysis()
+    assert (
+        memory.temp_size_in_bytes + memory.argument_size_in_bytes
+        < V5E_HBM_BYTES
+    )
+
+
+# ------------------------------------------------- chip_smoke at tiny size
+TINY = dict(
+    config_name="cifar10_imp",  # ResNet18, 32x32
+    batch=32,
+    num_train=64,
+    num_test=32,
+    steps=2,
+)
+
+
+def test_chip_smoke_one_chip_phases_at_tiny_size(tmp_path):
+    with chip_smoke.CompileLog() as log:
+        run = chip_smoke.phase_train(
+            log,
+            base_dir=tmp_path,
+            platform="cpu",
+            target_sparsity=0.3,
+            num_devices=1,
+            **TINY,
+        )
+        assert [r["level"] for r in run["levels"]] == [0, 1, 2]
+        chip_smoke.phase_serve(
+            log,
+            expt_dir=run["expt_dir"],
+            platform="cpu",
+            request_sizes=(1, 3),
+            final_level=2,
+        )
+    assert "jit(train_step)" in log.modules
+
+
+def test_chip_smoke_data_parallel_phase_at_tiny_size(tmp_path):
+    with chip_smoke.CompileLog() as log:
+        chip_smoke.phase_data_parallel(
+            log,
+            devices=4,
+            mask_tol=5e-2,
+            base_dir=tmp_path,
+            platform="cpu",
+            target_sparsity=0.2,
+            **TINY,
+        )
+
+
+def test_chip_smoke_ring_phase_at_tiny_size():
+    with chip_smoke.CompileLog() as log:
+        chip_smoke.phase_ring(
+            log, data=2, model=2, batch=4, seq=197, dim=384, heads=6
+        )
+
+
+def test_chip_smoke_refuses_to_start_without_a_tpu(tmp_path, monkeypatch, capsys):
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    with pytest.raises(SystemExit) as e:
+        chip_smoke.main([])
+    assert e.value.code not in (0, None)
+    assert capsys.readouterr().out == ""  # no phase ran, no result line
+
+
+class TestCompileCachePlacement:
+    def test_environment_wins_and_nothing_is_set_in_code(
+        self, tmp_path, monkeypatch
+    ):
+        from turboprune_tpu.utils.compile_cache import place_compile_cache
+
+        before = jax.config.jax_compilation_cache_dir
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert place_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before
+
+    def test_default_is_a_fixed_path_in_the_checkout(self, monkeypatch):
+        from pathlib import Path
+
+        from turboprune_tpu.utils.compile_cache import place_compile_cache
+
+        before = jax.config.jax_compilation_cache_dir
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        try:
+            placed = place_compile_cache()
+            assert placed == str(Path(chip_smoke.__file__).parent / ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == placed
+        finally:
+            jax.config.update("jax_compilation_cache_dir", before)

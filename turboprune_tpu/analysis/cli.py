@@ -34,7 +34,7 @@ Modes:
   into analysis/exec_manifest.json; ``diff`` fails when the surface has
   drifted from the checked-in manifest (exec_manifest.py);
 * ``--compile-audit [TARGET]`` — the runtime mirror of the manifest
-  (compile_audit.py): patch jax's backend_compile, drive the serving /
+  (compile_audit.py): patch jax's compile funnel, drive the serving /
   train smokes, and fail on any XLA compile the manifest does not
   explain. Needs jax, like --jaxpr-audit;
 * ``--rule-docs`` — print the generated rule-catalog markdown table
@@ -175,7 +175,7 @@ def build_parser() -> argparse.ArgumentParser:
         metavar="TARGET",
         help=(
             "runtime mirror of the executable manifest "
-            "(compile_audit.py): patch jax's backend_compile, drive "
+            "(compile_audit.py): patch jax's compile funnel, drive "
             "TARGET ('serve', 'train', 'all', or 'file.py:builder' "
             "returning a callable), and fail on any XLA compile not "
             "attributed to a manifest entry/compile site, or any "

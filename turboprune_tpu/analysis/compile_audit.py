@@ -6,9 +6,9 @@ thread rules.
 The manifest claims the compile surface is finite and statically known.
 This mode checks the claim against what XLA actually does: it patches the
 one funnel every compile goes through (``jax._src.compiler
-.backend_compile``), drives the package's real compile-heavy subsystems
-(the serving engine's bucket warmup; the synthetic train step), and
-demands that every compile observed in the measured window is attributed
+.backend_compile_and_load``), drives the package's real compile-heavy
+subsystems (the serving engine's bucket warmup; the synthetic train step),
+and demands that every compile observed in the measured window is attributed
 to a manifest entry or compile site:
 
 * by NAME — a compiled module is named ``jit_<fn.__name__>`` (non-word
@@ -92,8 +92,8 @@ def _repo_site() -> Optional[tuple]:
 
 
 class CompileLedger:
-    """Context manager: patch ``backend_compile``, record every compile
-    in the window as ``{"name", "site"}`` (site = innermost repo frame).
+    """Context manager: patch ``backend_compile_and_load``, record every
+    compile in the window as ``{"name", "site"}`` (site = innermost repo frame).
     Thread-safe — the serving engine compiles under its own lock, and
     nothing stops a driver from compiling from several threads."""
 
@@ -101,25 +101,15 @@ class CompileLedger:
         self.records: list = []
         self._mu = threading.Lock()
         self._orig = None
-        self._host = None
-
-    def _patch_point(self):
-        import jax._src.compiler as compiler
-
-        if hasattr(compiler, "backend_compile"):
-            return compiler
-        import jax._src.dispatch as dispatch  # older jax
-
-        if hasattr(dispatch, "backend_compile"):
-            return dispatch
-        raise AuditError(
-            "cannot find jax's backend_compile to patch (jax internals "
-            "moved); --compile-audit needs updating for this jax version"
-        )
 
     def __enter__(self) -> "CompileLedger":
-        host = self._patch_point()
-        orig = host.backend_compile
+        import jax._src.compiler as host
+
+        # The one funnel every jit/AOT compile of a loaded executable goes
+        # through on the installed jax. If it moves, this raises
+        # AttributeError and the ledger test fails — an audit that patches
+        # nothing would report zero compiles, not an error.
+        orig = host.backend_compile_and_load
         ledger = self
 
         def patched(*args, **kwargs):
@@ -139,14 +129,16 @@ class CompileLedger:
                 ledger.records.append(rec)
             return orig(*args, **kwargs)
 
-        host.backend_compile = patched
-        self._host, self._orig = host, orig
+        host.backend_compile_and_load = patched
+        self._orig = orig
         return self
 
     def __exit__(self, *exc) -> None:
-        if self._host is not None:
-            self._host.backend_compile = self._orig
-            self._host = self._orig = None
+        if self._orig is not None:
+            import jax._src.compiler as host
+
+            host.backend_compile_and_load = self._orig
+            self._orig = None
 
 
 def _attribution(rec: dict, names: set, spans: list) -> Optional[str]:

@@ -100,18 +100,16 @@ def _iter_eqns(jaxpr) -> Iterator:
 
 
 def _source_site(eqn) -> Optional[tuple]:
-    """(file, line) of the first user frame behind an equation, if jax
-    exposes it (source_info_util is jax-internal; degrade to None)."""
-    try:
-        from jax._src import source_info_util
+    """(file, line) of the first user frame behind an equation, or None
+    when the traceback holds none. source_info_util is jax-internal: a
+    changed signature raises here and fails TestJaxprAudit, it does not
+    degrade to "no source frame" (which reports every upcast unexplained)."""
+    from jax._src import source_info_util
 
-        frame = source_info_util.user_frame(eqn.source_info)
-        if frame is None:
-            return None
-        return str(frame.file_name), int(frame.start_line)
-    # graftlint: disable=broad-except -- jax-internal API drift degrades to "no source frame", which the diff reports
-    except Exception:
+    frame = source_info_util.user_frame(eqn.source_info.traceback)
+    if frame is None:
         return None
+    return str(frame.file_name), int(frame.start_line)
 
 
 def _dtype_name(d) -> str:

@@ -38,11 +38,12 @@ _lib: Optional[ctypes.CDLL] = None
 
 
 def ensure_built() -> Path:
-    """Build libtpkdata.so on first use (make is idempotent)."""
-    if not _LIB_PATH.exists():
-        subprocess.run(
-            ["make", "-C", str(_NATIVE_DIR)], check=True, capture_output=True
-        )
+    """Bring libtpkdata.so up to date with tpkdata.cpp on first use. make
+    decides by timestamp: the library is ignored by git, so one left on
+    disk can be older than the source beside it."""
+    subprocess.run(
+        ["make", "-C", str(_NATIVE_DIR)], check=True, capture_output=True
+    )
     return _LIB_PATH
 
 

@@ -20,9 +20,11 @@ Relationship to the rest of the framework:
   - The reference has no analog: its DeiT path runs timm's dense attention
     (materialized scores) and was dead code anyway (SURVEY.md §2.1).
 
-On non-TPU backends the kernels run in Pallas interpret mode (exact same
-program, executed by XLA ops) — which is how the CPU test suite proves
-them, including gradients, against a dense jnp oracle.
+On the CPU backend, and only there, the kernels run in Pallas interpret
+mode (exact same program, executed by XLA ops) — which is how the CPU test
+suite proves them, including gradients, against a dense jnp oracle. On any
+other backend the kernels are compiled by Mosaic, and a lowering failure
+raises: nothing here re-routes to interpret mode or to dense attention.
 """
 
 from __future__ import annotations
@@ -39,8 +41,9 @@ NEG_BIG = -1e30
 
 
 def _use_interpret() -> bool:
-    """Mosaic lowering needs a real TPU; anything else runs interpreted."""
-    return jax.default_backend() not in ("tpu",)
+    """Interpret only where no Mosaic compiler exists at all: the CPU
+    backend of the test suite. Every other backend compiles or raises."""
+    return jax.default_backend() == "cpu"
 
 
 def _dot(a, b):  # [m, k] @ [k, n] with fp32 accumulation on the MXU

@@ -24,14 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-try:  # jax >= 0.8 top-level API; experimental path for older versions
-    from jax import shard_map
-
-    _CHECK_KW = {"check_vma": False}
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
-
-    _CHECK_KW = {"check_rep": False}  # legacy name of the same knob
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from .mesh import DATA_AXIS, MODEL_AXIS
@@ -126,6 +119,6 @@ def ring_attention(
         mesh=mesh,
         in_specs=(spec, spec, spec, P(seq_axis)),
         out_specs=spec,
-        **_CHECK_KW,
+        check_vma=False,
     )
     return fn(q, k, v, kv_valid)

@@ -1,25 +1,25 @@
 """Persistent on-disk cache of serialized AOT executables.
 
-The XLA persistent compilation cache is unusable in this environment — its
-read path segfaults the process (CHANGES PR 1), so it is force-disabled in
-tests/conftest.py and cold start has meant a full re-compile of every
-(model, bucket) pair on every restart. This module is our own, much
-narrower layer: after ``jit(...).lower(...).compile()`` the compiled
-executable is serialized with ``jax.experimental.serialize_executable``
-(payload + in/out pytree defs) and written to one file per key; a later
-process deserializes it and serves without ever invoking the compiler
-(verified cross-process: load is ~30 ms where the compile was seconds).
+A fleet restart would otherwise re-compile every (model, bucket) pair.
+After ``jit(...).lower(...).compile()`` the compiled executable is
+serialized with ``jax.experimental.serialize_executable`` (payload + in/out
+pytree defs + the ids of the devices it was compiled for) and written to
+one file per key; a later process deserializes it ONTO THOSE DEVICES and
+serves without invoking the compiler. It is separate from XLA's own
+persistent compilation cache (utils/compile_cache.py): its key is the
+serving vocabulary — plan signature x bucket — which the exec manifest
+audits.
 
 Keying: the filename hash covers the semantic identity of the computation —
 HLO fingerprint (sha256 of the lowered StableHLO text), the execution-plan
 signature (compacted widths / N:M plan digest / masked), and the batch
 bucket. The environment identity (jax, jaxlib, backend) is stored in the
-entry's metadata and CHECKED at load: a mismatch is a "bypass" (the entry
-is ignored and later overwritten by the current environment's store), never
-a crash and never a silent wrong-executable hit. Unreadable or truncated
-entries are quarantined (renamed ``*.quarantined``) and counted, so one
-corrupt file degrades to a single cold compile instead of taking the
-process down — the exact failure mode the XLA cache has here.
+entry's metadata and CHECKED at load: a mismatch — or an entry compiled for
+devices this process does not have — is a "bypass" (the entry is ignored
+and later overwritten by the current environment's store), never a crash
+and never a silent wrong-executable hit. Unreadable or truncated entries
+are quarantined (renamed ``*.quarantined``) and counted, so one corrupt
+file degrades to a single cold compile instead of taking the process down.
 
 Writes are atomic (tmp file + rename) so concurrent replicas sharing a
 cache directory never observe torn entries.
@@ -37,7 +37,7 @@ from typing import Any, Optional
 
 import jax
 
-_FORMAT_VERSION = 1
+_FORMAT_VERSION = 2  # 2: entries carry the executable's device ids
 _SUFFIX = ".aotx"
 
 # Load statuses (also the counter keys, exported via stats()).
@@ -123,7 +123,7 @@ class AOTExecutableCache:
         try:
             entry = pickle.loads(path.read_bytes())
             meta = entry["meta"]
-        # graftlint: disable=broad-except -- degrade-don't-die: any unreadable/truncated/hostile entry must quarantine to a cold compile, not crash the serving process (the XLA cache's failure mode here)
+        # graftlint: disable=broad-except -- degrade-don't-die: any unreadable/truncated/hostile entry must quarantine to a cold compile, not crash the serving process
         except Exception:
             self._quarantine(path)
             return None, self._count(CORRUPT)
@@ -133,14 +133,27 @@ class AOTExecutableCache:
             # portable across those, so ignore it; the caller compiles and
             # store() overwrites with the current environment's build.
             return None, self._count(BYPASS)
-        try:
-            from jax.experimental import serialize_executable
+        by_id = {d.id: d for d in jax.devices()}
+        if any(i not in by_id for i in entry["devices"]):
+            # Compiled for a device this process does not have (another
+            # topology sharing the directory): not ours to load.
+            return None, self._count(BYPASS)
+        from jax.experimental import serialize_executable
 
+        try:
+            # execution_devices defaults to EVERY device of the backend; a
+            # one-device executable loaded that way dies at its first call
+            # on any multi-device host.
             compiled = serialize_executable.deserialize_and_load(
-                entry["payload"], entry["in_tree"], entry["out_tree"]
+                entry["payload"],
+                entry["in_tree"],
+                entry["out_tree"],
+                execution_devices=[by_id[i] for i in entry["devices"]],
             )
-        # graftlint: disable=broad-except -- degrade-don't-die: deserialization failures (e.g. CPU-feature mismatch surfacing as XlaRuntimeError) must also degrade to a compile
-        except Exception:
+        except (jax.errors.JaxRuntimeError, pickle.UnpicklingError, EOFError):
+            # A payload the runtime refuses (truncated, built for other CPU
+            # features) degrades to a compile. Anything else — a changed
+            # signature, say — is this module's bug and must raise.
             self._quarantine(path)
             return None, self._count(CORRUPT)
         return compiled, self._count(HIT)
@@ -164,6 +177,9 @@ class AOTExecutableCache:
             return False
         entry = {
             "meta": _env_meta(),
+            "devices": [
+                d.id for d in compiled.runtime_executable().local_devices()
+            ],
             "payload": payload,
             "in_tree": in_tree,
             "out_tree": out_tree,
