@@ -12,20 +12,16 @@ from __future__ import annotations
 
 import sys
 
-from run_experiment import parse_args
+from run_experiment import parse_args, setup
 
 
 def main(argv=None) -> int:
     args = parse_args(argv if argv is not None else sys.argv[1:])
+    cfg = setup(args)
 
-    from turboprune_tpu.config.compose import compose
     from turboprune_tpu.driver import run_cyclic
-    from turboprune_tpu.parallel import initialize_distributed, is_primary
-    from turboprune_tpu.utils.compile_cache import place_compile_cache
+    from turboprune_tpu.parallel import is_primary
 
-    place_compile_cache()
-    cfg = compose(args.config_name, args.overrides, args.config_path)
-    initialize_distributed()
     expt_dir, summaries = run_cyclic(cfg)
     if is_primary():
         print(f"\nCyclic experiment complete: {expt_dir}")

@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import argparse
 import sys
+import time
 
 
 def parse_args(argv):
@@ -35,17 +36,35 @@ def parse_args(argv):
     return parser.parse_args(argv)
 
 
+def setup(args):
+    """What both entry points do before the level loop, each part under its
+    ``setup/`` span: the package imports, the compile cache's place and the
+    composed config, distributed initialisation. Returns the config."""
+    t0 = time.perf_counter()
+    from turboprune_tpu.utils import tracing
+
+    with tracing.span("setup/imports") as imports:
+        from turboprune_tpu import driver  # noqa: F401  (pulls in the rest)
+        from turboprune_tpu.config.compose import compose
+        from turboprune_tpu.parallel import initialize_distributed
+        from turboprune_tpu.utils.compile_cache import place_compile_cache
+    imports.start = t0  # the recorder's own import came first, and is most of it
+
+    with tracing.span("setup/config"):
+        place_compile_cache()
+        cfg = compose(args.config_name, args.overrides, args.config_path)
+    with tracing.span("setup/distributed"):
+        initialize_distributed()
+    return cfg
+
+
 def main(argv=None) -> int:
     args = parse_args(argv if argv is not None else sys.argv[1:])
+    cfg = setup(args)
 
-    from turboprune_tpu.config.compose import compose
     from turboprune_tpu.driver import run
-    from turboprune_tpu.parallel import initialize_distributed, is_primary
-    from turboprune_tpu.utils.compile_cache import place_compile_cache
+    from turboprune_tpu.parallel import is_primary
 
-    place_compile_cache()
-    cfg = compose(args.config_name, args.overrides, args.config_path)
-    initialize_distributed()
     expt_dir, summaries = run(cfg)
     if is_primary():
         print(f"\nExperiment complete: {expt_dir}")

@@ -48,6 +48,7 @@ from turboprune_tpu.train import (
     create_train_state,
     make_train_step,
 )
+from turboprune_tpu.utils import tracing
 
 # Reassociation noise ceiling for fp32 logits/losses (see tests/test_sparse).
 ATOL = 1e-5
@@ -366,7 +367,7 @@ class TestHarnessCompactTrainSmoke:
         assert post["test_acc"] == pytest.approx(s1["test_acc"])
 
         # Gauges export the size the level ACTUALLY compiled.
-        snap = h.compact_metrics.snapshot()
+        snap = tracing.gauges()
         assert snap["plan_params_compacted"] == rep["params_after"]
         assert snap["plan_step_cache_size"] == 1
 
@@ -376,6 +377,6 @@ class TestHarnessCompactTrainSmoke:
         self._kill(h, 0.75)
         h.train_one_level(1, 2)
         assert set(h._plan_step_cache).isdisjoint(keys_l1)
-        snap = h.compact_metrics.snapshot()
+        snap = tracing.gauges()
         assert snap["plan_step_cache_size"] == 1
         assert snap["plan_eval_cache_size"] == 0  # compact_eval off

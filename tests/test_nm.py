@@ -40,6 +40,7 @@ from turboprune_tpu.sparse import (
     project_masks,
 )
 from turboprune_tpu.sparse.nm import split_index
+from turboprune_tpu.utils import tracing
 from turboprune_tpu.sparse.nm_execute import (
     NMConv1x1,
     NMDense,
@@ -487,7 +488,7 @@ class TestHarnessNMSmoke:
         assert fc["kept_out_frac"] == 1.0
         assert len(h._plan_step_cache) == 1
         keys_l1 = set(h._plan_step_cache)
-        snap = h.compact_metrics.snapshot()
+        snap = tracing.gauges()
         assert snap["plan_step_cache_size"] == 1
         assert snap["plan_coverage_frac"] == pytest.approx(rep["coverage_frac"])
         assert s1["test_acc"] >= 0.0

@@ -46,6 +46,7 @@ from turboprune_tpu.sparse.compact import (
     compact_tree,
     expand_tree,
 )
+from turboprune_tpu.utils import tracing
 
 # Reassociation noise ceilings (see tests/test_sparse, tests/test_nm): the
 # sliced/gathered programs sum the same terms in a different order.
@@ -469,7 +470,7 @@ class TestHarnessMixedPlanSmoke:
         keys_l1 = set(h._plan_step_cache)
         # exited back to full coordinates
         assert jax.tree.map(lambda a: a.shape, h.state.params) == full_shapes
-        snap = h.compact_metrics.snapshot()
+        snap = tracing.gauges()
         assert snap["plan_layers_nm"] == rep["backend_counts"]["nm_layers"]
         assert snap["plan_spaces_compacted"] > 0
         assert snap["plan_coverage_frac"] == pytest.approx(

@@ -1,0 +1,275 @@
+"""utils/tracing.py: the recorder alone, then a tiny two-level ladder through
+``driver.run`` with ``profile_dir`` set, read back through the spans, the
+``[time]`` lines, ``level_timing.csv`` and the two profiler sessions. CPU
+only: nothing here is a speed."""
+
+import contextlib
+import io
+import threading
+from collections import deque
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import pandas as pd
+import pytest
+
+from turboprune_tpu.utils import tracing
+
+
+def _made(name, start, end, parent=None, **attrs):
+    s = tracing.Span(name, attrs)
+    s.start, s.end, s.parent = start, end, parent.id if parent else None
+    return s
+
+
+class TestRecorder:
+    def test_nesting_gives_parent_ids_and_threads_do_not_share_a_stack(self):
+        seen = {}
+
+        def worker():
+            with tracing.span("t/outer") as outer:
+                with tracing.span("t/inner") as inner:
+                    pass
+            seen["worker"] = (outer, inner)
+
+        with tracing.span("t/main") as main:
+            t = threading.Thread(target=worker)
+            t.start()
+            t.join(timeout=30)
+            assert not t.is_alive()
+            with tracing.span("t/child") as child:
+                pass
+        outer, inner = seen["worker"]
+        assert child.parent == main.id and main.parent is None
+        # The worker's spans opened while t/main was open on this thread.
+        assert outer.parent is None and inner.parent == outer.id
+        assert outer.thread != main.thread
+        assert len({main.id, child.id, outer.id, inner.id}) == 4
+        names = [s.name for s in tracing.recorded(t0=main.start, t1=main.end)]
+        assert names == ["t/inner", "t/outer", "t/child", "t/main"]  # closing order
+        assert main.start <= child.start <= child.end <= main.end
+
+    def test_level_and_epoch_are_inherited_and_nothing_else(self):
+        with tracing.span("level", level=3, density=0.5):
+            with tracing.span("epoch", epoch=7, cycle=1):
+                with tracing.span("epoch/log") as log:
+                    pass
+            with tracing.span("level/save", level=4) as save:
+                pass
+        assert log.attrs == {"level": 3, "epoch": 7}
+        assert save.attrs == {"level": 4}  # its own wins
+
+    def test_a_span_closed_by_an_exception_is_recorded_with_the_error(self):
+        class WindowClosed(Exception):
+            pass
+
+        with pytest.raises(WindowClosed):
+            with tracing.span("level", level=1) as level:
+                with tracing.span("level/save") as save:
+                    raise WindowClosed()
+        assert save.attrs["error"] == level.attrs["error"] == "WindowClosed"
+        assert tracing.recorded("level")[-1] is level and level.end >= save.end > 0
+        with tracing.span("after") as after:
+            pass
+        assert after.parent is None and "error" not in after.attrs  # the stack unwound
+
+    def test_breakdown_of_hand_made_spans_sums_to_the_root(self):
+        level = _made("level", 0.0, 10.0, level=2)
+        load = _made("level/load", 0.0, 1.0, level)
+        read = _made("ckpt/read", 0.1, 0.9, load)
+        train = _made("level/train", 1.5, 9.0, level)
+        epochs = [_made("epoch", 2.0 + 3 * i, 4.5 + 3 * i, train) for i in range(2)]
+        inner = [_made("epoch/train", e.start, e.start + 2.0, e) for e in epochs]
+        save = _made("level/save", 9.0, 10.0, level)
+        fetch = _made("ckpt/fetch", 9.0, 9.25, save)
+        write = _made("ckpt/write", 9.25, 9.75, save)
+        inner[1].compiles, inner[1].compile_s = 2, 0.5
+        spans = [read, load, *inner, *epochs, train, fetch, write, save, level]
+        b = tracing.breakdown([level], spans)
+        assert b["terms"] == {"level/load": 1.0, "epoch/train": 4.0, "level/save": 1.0}
+        assert b["inside"] == {
+            "level/load": {"ckpt/read": 0.8},
+            "level/save": {"ckpt/fetch": 0.25, "ckpt/write": 0.5},
+        }
+        # level 0.5 of its own, level/train 7.5 - 5.0, each epoch 0.5
+        assert b["other_s"] == pytest.approx(0.5 + 2.5 + 1.0)
+        assert sum(b["terms"].values()) + b["other_s"] == pytest.approx(b["total_s"]) == 10.0
+        assert (b["compiles"], b["compile_s"]) == (2, 0.5)
+        assert tracing.line("level 2", b) == (
+            "[time] level 2: 10.00 s = load 1.00 (read 0.80) + train 4.00 + "
+            "save 1.00 (fetch 0.25, write 0.50) + other 4.00; compiled 2 modules, 0.5 s"
+        )
+        row = tracing.timing_row(level, b)
+        assert list(row) == tracing.TIMING_COLUMNS
+        assert (row["level"], row["train_s"], row["ckpt_write_s"], row["prune_s"]) == (2, 4.0, 0.5, 0.0)
+
+    def test_the_recorder_is_bounded_and_keeps_the_newest(self, monkeypatch):
+        assert tracing._spans.maxlen == tracing.MAX_SPANS
+        monkeypatch.setattr(tracing, "_spans", deque(maxlen=8))
+        for i in range(20):
+            with tracing.span("t/bounded", i=i):
+                pass
+        assert [s.attrs["i"] for s in tracing.recorded("t/bounded")] == list(range(12, 20))
+
+    def test_a_compile_is_charged_to_the_innermost_open_span_only(self):
+        f = jax.jit(lambda x: x * 3 + 1)
+        x = jnp.ones(3)  # an eager op is a compilation of its own
+        with tracing.span("t/root") as root:
+            with tracing.span("t/before") as before:
+                pass
+            with tracing.span("t/compiles") as here:
+                f(x).block_until_ready()
+            with tracing.span("t/cached") as cached:
+                f(x).block_until_ready()
+        assert here.compiles == 1 and here.compile_s > 0
+        assert (before.compiles, cached.compiles, root.compiles) == (0, 0, 0)
+        assert tracing.breakdown([root])["compiles"] == 1
+
+    def test_gauges_hold_the_last_value_set(self):
+        tracing.gauge("t_gauge", 3)
+        tracing.gauge("t_gauge", 5)
+        assert tracing.gauges()["t_gauge"] == 5
+
+
+def test_the_lowered_train_step_names_its_layers():
+    from turboprune_tpu.models import create_model
+    from turboprune_tpu.train import create_optimizer, create_train_state, make_eval_step, make_train_step
+
+    model = create_model("resnet18", num_classes=10, dataset_name="CIFAR10")
+    tx = create_optimizer("SGD", lambda step: 0.1, momentum=0.9, weight_decay=5e-4)
+    # Shapes are enough to lower: no weights are made, nothing compiles.
+    state = jax.eval_shape(
+        lambda: create_train_state(model, tx, jax.random.PRNGKey(0), (1, 32, 32, 3))
+    )
+    batch = (jnp.zeros((4, 32, 32, 3)), jnp.zeros((4,), jnp.int32))
+    text = jax.jit(make_train_step(model, tx)).lower(state, batch).as_text(debug_info=True)
+    for scope in ("forward", "loss", "optimizer", "mask_apply", "transpose(jvp(forward))"):
+        assert scope in text, scope
+    text = jax.jit(make_eval_step(model)).lower(state, batch).as_text(debug_info=True)
+    assert "eval_forward" in text and "mask_apply" in text
+
+
+# What a two-level IMP ladder records (ISSUE 24's table), and whether a level
+# without a prune (level 0) records it too.
+LADDER_SPANS = {
+    "harness/init": None, "init/mesh_model": None, "init/loaders": None,
+    "init/state": None, "init/steps": None,
+    "level": True, "level/load": False, "level/prune": False, "level/rewind": False,
+    "level/train": True, "level/setup": True, "epoch": True, "epoch/feed": True,
+    "epoch/train": True, "epoch/eval": True, "epoch/log": True, "level/finish": True,
+    "level/save": True, "ckpt/fetch": True, "ckpt/write": True, "ckpt/barrier": True,
+    "ckpt/read": False,
+}  # fmt: skip
+
+
+@pytest.fixture(scope="module")
+def ladder(tmp_path_factory):
+    from turboprune_tpu.config.compose import compose
+    from turboprune_tpu.driver import run
+
+    tmp = tmp_path_factory.mktemp("traced")
+    cfg = compose(
+        "cifar10_imp",
+        overrides=[
+            f"experiment_params.base_dir={tmp / 'experiments'}",
+            f"experiment_params.profile_dir={tmp / 'profile'}",
+            "experiment_params.num_devices=1",
+            "experiment_params.epochs_per_level=2",
+            "dataset_params.dataloader_type=synthetic",
+            # One scanned step of batch 8 an epoch: the CPU runs ResNet18's
+            # scanned epoch at seconds a step.
+            "dataset_params.total_batch_size=8",
+            "dataset_params.synthetic_num_train=8",
+            "dataset_params.synthetic_num_test=8",
+            # Two levels; keeping a tenth is a top_k the CPU makes in 5 s,
+            # not 13.
+            "pruning_params.prune_rate=0.9",
+            "pruning_params.target_sparsity=0.9",
+        ],
+    )
+    out = io.StringIO()
+    with tracing.span("t/ladder") as whole, contextlib.redirect_stdout(out):
+        expt_dir, summaries = run(cfg)
+    assert len(summaries) == 2
+    spans = tracing.recorded(t0=whole.start, t1=whole.end)
+    return {"spans": spans, "out": out.getvalue(), "expt_dir": Path(expt_dir), "profile": tmp / "profile"}
+
+
+@pytest.mark.parametrize("name", sorted(LADDER_SPANS))
+def test_the_ladder_records_every_span_of_the_table(ladder, name):
+    found = [s for s in ladder["spans"] if s.name == name]
+    assert found, name
+    in_level_zero = LADDER_SPANS[name]
+    if in_level_zero is None:
+        assert all("level" not in s.attrs for s in found)
+    else:
+        want = {0, 1} if in_level_zero else {1}
+        assert {s.attrs["level"] for s in found} == want
+    if name.startswith("epoch"):
+        assert {s.attrs["epoch"] for s in found} == {0, 1}
+    assert not any("error" in s.attrs for s in found)
+
+
+def test_every_recorded_name_is_one_the_time_line_knows(ladder):
+    known = set(LADDER_SPANS) | {"t/ladder"}
+    assert {s.name for s in ladder["spans"]} <= known
+    terms_or_inside = set(tracing.TERMS) | {n for n in known if n.startswith(("ckpt/", "init/"))}
+    containers = {"level", "level/train", "epoch", "t/ladder"}
+    assert known - terms_or_inside == containers
+
+
+def test_level_self_times_sum_to_the_level_and_land_in_the_csv(ladder):
+    levels = [s for s in ladder["spans"] if s.name == "level"]
+    assert [s.attrs["level"] for s in levels] == [0, 1]
+    rows = pd.read_csv(ladder["expt_dir"] / "metrics" / "level_timing.csv")
+    assert list(rows.columns) == tracing.TIMING_COLUMNS and list(rows["level"]) == [0, 1]
+    for level, (_, row) in zip(levels, rows.iterrows()):
+        b = tracing.breakdown([level], ladder["spans"])
+        assert sum(b["terms"].values()) + b["other_s"] == pytest.approx(level.seconds)
+        assert 0 <= b["other_s"] < level.seconds
+        assert row["total_s"] == pytest.approx(level.seconds)
+        assert row["train_s"] == pytest.approx(b["terms"]["epoch/train"])
+        parts = [c for c in rows.columns if c.endswith("_s") and not c.startswith(("total", "ckpt_", "compile"))]
+        assert row[parts].sum() + row["ckpt_s"] == pytest.approx(row["total_s"])
+    assert rows["rewind_s"][0] == 0 and rows["rewind_s"][1] > 0
+    assert rows["ckpt_read_s"][1] > 0 and rows["ckpt_write_s"].min() > 0
+    # Level 0 compiles the epoch; level 1 reuses it and compiles its prune.
+    assert rows["compiles"][0] > 0
+
+
+def test_the_operator_gets_a_time_line_per_level_and_one_for_setup(ladder):
+    lines = [ln for ln in ladder["out"].splitlines() if ln.startswith("[time] ")]
+    assert [ln.split(":")[0] for ln in lines] == ["[time] set-up", "[time] level 0", "[time] level 1"]
+    assert "init " in lines[0] and "loaders " in lines[0] and "state " in lines[0]
+    for want in ("load ", "prune ", "rewind ", "setup ", "train ", "eval ", "log ", "save ", "other ", "compiled "):
+        assert want in lines[2], want
+    assert "prune " not in lines[1]
+
+
+def _host_span_names(session: Path) -> set[str]:
+    from jax.profiler import ProfileData
+
+    (xplane,) = session.glob("plugins/profile/*/*.xplane.pb")
+    data = ProfileData.from_file(str(xplane))
+    return {
+        ev.name
+        for plane in data.planes
+        if plane.name.startswith("/host:")
+        for ln in plane.lines
+        for ev in ln.events
+        if ev.name.startswith(tracing.PREFIX)
+    }
+
+
+@pytest.mark.parametrize(
+    "session, holds, lacks",
+    [
+        ("level0_epoch1", {"tp/epoch", "tp/epoch/train", "tp/epoch/eval", "tp/epoch/log"}, {"tp/level/rewind"}),
+        ("level0_to_1", {"tp/level/save", "tp/level/load", "tp/level/prune", "tp/level/rewind", "tp/level/setup", "tp/ckpt/read"}, {"tp/epoch/train"}),
+    ],
+)
+def test_profile_dir_leaves_two_sessions_that_hold_the_spans(ladder, session, holds, lacks):
+    assert sorted(p.name for p in ladder["profile"].iterdir()) == ["level0_epoch1", "level0_to_1"]
+    names = _host_span_names(ladder["profile"] / session)
+    assert holds <= names and not (lacks & names)

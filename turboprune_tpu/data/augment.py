@@ -114,17 +114,20 @@ def augment_epoch(
     k_crop, k_flip, k_cut = jax.random.split(key, 3)
     images = preflipped_padded
     if translate > 0:
-        images = batch_translate_crop(images, k_crop, crop_size)
+        with jax.named_scope("augment/crop"):
+            images = batch_translate_crop(images, k_crop, crop_size)
     if flip:
-        if altflip:
-            images = jax.lax.cond(
-                epoch % 2 == 1,
-                lambda x: x[:, :, ::-1, :],
-                lambda x: x,
-                images,
-            )
-        else:
-            images = batch_flip_lr(images, k_flip)
+        with jax.named_scope("augment/flip"):
+            if altflip:
+                images = jax.lax.cond(
+                    epoch % 2 == 1,
+                    lambda x: x[:, :, ::-1, :],
+                    lambda x: x,
+                    images,
+                )
+            else:
+                images = batch_flip_lr(images, k_flip)
     if cutout > 0:
-        images = batch_cutout(images, k_cut, cutout)
+        with jax.named_scope("augment/cutout"):
+            images = batch_cutout(images, k_cut, cutout)
     return images

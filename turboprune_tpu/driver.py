@@ -14,6 +14,7 @@ check_model_equality, distributed_utils.py:31-60, made real).
 
 from __future__ import annotations
 
+from pathlib import Path
 from typing import Optional, Type
 
 import jax
@@ -30,6 +31,7 @@ from .utils import (
     reset_weights,
     save_config,
     set_seed,
+    tracing,
 )
 
 
@@ -62,72 +64,86 @@ def prune_level(harness, density: float, level: int) -> None:
     (reference run_experiment.py:95-105 + reset_weights)."""
     cfg = harness.cfg
     method = cfg.pruning_params.prune_method
-    # Same key on every host => identical Bernoulli/normal draws (SURVEY.md
-    # §7 "Replicated pruning determinism").
-    rng = jax.random.fold_in(
-        jax.random.PRNGKey(cfg.experiment_params.seed), level
-    )
-    batch = None
-    if method in ("snip", "synflow"):
-        batch = _first_train_batch(harness)
-
-    nm_spec = None
-    if cfg.experiment_params.nm_sparsity:
-        from .config.schema import parse_nm
-
-        n, m = parse_nm(cfg.experiment_params.nm_sparsity)
-        nm_spec = (n, m, cfg.experiment_params.nm_transposable)
-
-    state = harness.state
-    before = masking.overall_sparsity(state.masks)
-    masks = prune_the_model(
-        method,
-        harness.model,
-        {"params": state.params, "batch_stats": state.batch_stats}
-        if state.batch_stats
-        else {"params": state.params},
-        state.masks,
-        density,
-        rng,
-        batch=batch,
-        nm=nm_spec if method == "nm" else None,
-    )
-    nm_note = ""
-    if nm_spec is not None and method not in ("nm", "just dont"):
-        # Projection post-pass on any other criterion: snap its mask to the
-        # N:M pattern (monotone — the ladder's no-resurrection invariant
-        # holds; the "nm" criterion projects inside prune_the_model).
-        from .sparse.nm import project_masks
-
-        masks, nm_report = project_masks(
-            state.params, masks, nm_spec[0], nm_spec[1], nm_spec[2]
+    # The span runs through the sparsity read that follows the prune, which
+    # is where the host waits for the prune's device work.
+    with tracing.span("level/prune"):
+        # Same key on every host => identical Bernoulli/normal draws
+        # (SURVEY.md §7 "Replicated pruning determinism").
+        rng = jax.random.fold_in(
+            jax.random.PRNGKey(cfg.experiment_params.seed), level
         )
-        nm_note = (
-            f", {cfg.experiment_params.nm_sparsity} projection kept "
-            f"{nm_report['preserved_magnitude_frac']:.3f} of magnitude"
+        batch = None
+        if method in ("snip", "synflow"):
+            batch = _first_train_batch(harness)
+
+        nm_spec = None
+        if cfg.experiment_params.nm_sparsity:
+            from .config.schema import parse_nm
+
+            n, m = parse_nm(cfg.experiment_params.nm_sparsity)
+            nm_spec = (n, m, cfg.experiment_params.nm_transposable)
+
+        state = harness.state
+        before = masking.overall_sparsity(state.masks)
+        masks = prune_the_model(
+            method,
+            harness.model,
+            {"params": state.params, "batch_stats": state.batch_stats}
+            if state.batch_stats
+            else {"params": state.params},
+            state.masks,
+            density,
+            rng,
+            batch=batch,
+            nm=nm_spec if method == "nm" else None,
         )
-    state = state.replace(masks=masks)
-    harness.state = state
-    after = masking.overall_sparsity(state.masks)
-    if is_primary():
-        print(
-            f"[prune] level {level}: {method} to density {density:.4f} "
-            f"(sparsity {before:.2f}% -> {after:.2f}%){nm_note}",
-            flush=True,
-        )
+        nm_note = ""
+        if nm_spec is not None and method not in ("nm", "just dont"):
+            # Projection post-pass on any other criterion: snap its mask to
+            # the N:M pattern (monotone — the ladder's no-resurrection
+            # invariant holds; the "nm" criterion projects inside
+            # prune_the_model).
+            from .sparse.nm import project_masks
+
+            masks, nm_report = project_masks(
+                state.params, masks, nm_spec[0], nm_spec[1], nm_spec[2]
+            )
+            nm_note = (
+                f", {cfg.experiment_params.nm_sparsity} projection kept "
+                f"{nm_report['preserved_magnitude_frac']:.3f} of magnitude"
+            )
+        state = state.replace(masks=masks)
+        harness.state = state
+        after = masking.overall_sparsity(state.masks)
+        if is_primary():
+            print(
+                f"[prune] level {level}: {method} to density {density:.4f} "
+                f"(sparsity {before:.2f}% -> {after:.2f}%){nm_note}",
+                flush=True,
+            )
     # Rewind AFTER pruning: masks survive, weights roll back per
     # training_type (custom_models.py:112-146 semantics).
-    harness.state = reset_weights(
-        cfg.pruning_params.training_type, harness.state, harness.ckpts
-    )
+    with tracing.span("level/rewind"):
+        harness.state = reset_weights(
+            cfg.pruning_params.training_type, harness.state, harness.ckpts
+        )
     if jax.process_count() > 1:
         # Once per level, so the exact digest allgather (full device->host
         # transfer; catches element-permuting divergence the cheap moments
         # check cannot) stays off the per-step path.
-        check_state_equality(
-            {"params": harness.state.params, "masks": harness.state.masks},
-            exact=True,
-        )
+        with tracing.span("level/agree"):
+            check_state_equality(
+                {"params": harness.state.params, "masks": harness.state.masks},
+                exact=True,
+            )
+
+
+def _say_time(title: str, roots: list) -> dict:
+    """The operator's ``[time]`` line for ``roots``; returns the breakdown."""
+    b = tracing.breakdown(roots)
+    if is_primary():
+        print(tracing.line(title, b), flush=True)
+    return b
 
 
 def run(cfg: MainConfig, harness_cls: Optional[Type[PruningHarness]] = None):
@@ -154,6 +170,7 @@ def run(cfg: MainConfig, harness_cls: Optional[Type[PruningHarness]] = None):
         save_config(expt_dir, cfg)
 
     harness = harness_cls(cfg, (prefix, expt_dir))
+    _say_time("set-up", tracing.setup_roots())
 
     pp = cfg.pruning_params
     densities = generate_densities(
@@ -169,26 +186,42 @@ def run(cfg: MainConfig, harness_cls: Optional[Type[PruningHarness]] = None):
         harness.state = harness.state.replace(**restored)
 
     summaries = []
-    for level in range(start_level, len(densities)):
-        density = densities[level]
-        if level == 0:
-            if pp.training_type == "at_init":
-                # PaI: prune the untrained network before any training
-                # (run_experiment.py:86-91). model_init is saved after, so
-                # it carries the pruned-at-init weights.
-                prune_level(harness, density, level)
-        else:
-            restored = harness.ckpts.load_level(level - 1, harness.state)
-            harness.state = harness.state.replace(**restored)
-            prune_level(harness, density, level)
+    try:
+        for level in range(start_level, len(densities)):
+            density = densities[level]
+            with tracing.span("level", level=level, density=density) as level_span:
+                if level == 0:
+                    if pp.training_type == "at_init":
+                        # PaI: prune the untrained network before any
+                        # training (run_experiment.py:86-91). model_init is
+                        # saved after, so it carries the pruned-at-init
+                        # weights.
+                        prune_level(harness, density, level)
+                else:
+                    with tracing.span("level/load"):
+                        restored = harness.ckpts.load_level(level - 1, harness.state)
+                        harness.state = harness.state.replace(**restored)
+                    prune_level(harness, density, level)
 
-        summary = harness.train_one_level(ep.epochs_per_level, level)
-        # Saves are primary-only with a cross-host barrier — state is
-        # replicated, so host 0 holds everything (utils/checkpoint.py).
-        harness.ckpts.save_level(level, harness.state)
-        achieved = masking.overall_density(harness.state.masks)
-        summary["achieved_density"] = achieved
-        summaries.append(summary)
+                with tracing.span("level/train"):
+                    summary = harness.train_one_level(ep.epochs_per_level, level)
+                if ep.profile_dir and level == 0 and len(densities) > 1:
+                    # The second session of profile_dir: the 0 -> 1 level
+                    # boundary, stopped by the harness where level 1's
+                    # set-up ends.
+                    tracing.start_profile(Path(ep.profile_dir) / "level0_to_1")
+                # Saves are primary-only with a cross-host barrier — state is
+                # replicated, so host 0 holds everything
+                # (utils/checkpoint.py).
+                with tracing.span("level/save"):
+                    harness.ckpts.save_level(level, harness.state)
+                achieved = masking.overall_density(harness.state.masks)
+                summary["achieved_density"] = achieved
+                summaries.append(summary)
+            timing = _say_time(f"level {level}", [level_span])
+            harness.metrics.log_level_timing(tracing.timing_row(level_span, timing))
+    finally:
+        tracing.stop_profile()  # a level that raised must not leave one running
     if ep.checkpoint_every_epochs:
         # Run complete: the final level's mid-level slot is stale — left
         # behind it would hijack a later resume of this dir after a config

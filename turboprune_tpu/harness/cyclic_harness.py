@@ -15,7 +15,7 @@ from __future__ import annotations
 from ..config.schema import ConfigError
 from ..ops import masking
 from ..pruning import generate_cyclical_schedule
-from ..utils import MODEL_INIT, MODEL_REWIND, OPTIMIZER_INIT, OPTIMIZER_REWIND
+from ..utils import MODEL_INIT, OPTIMIZER_INIT, tracing
 from ..utils.experiment import display_training_info
 from .pruning_harness import PruningHarness
 
@@ -43,18 +43,6 @@ class CyclicPruningHarness(PruningHarness):
         cycle_epochs = generate_cyclical_schedule(
             epochs_per_level, num_cycles, ct.strategy
         )
-        density = masking.overall_density(self.state.masks)
-        display_training_info(self.cfg, level, density)
-
-        if level == 0:
-            # Save BEFORE any training so cycle-0 state is the true init
-            # (reference saves inside the first cycle, cyclic_harness.py:
-            # 202-211; we need a fresh opt_state pytree for the artifact).
-            self.setup_level(cycle_epochs[0])
-            self.ckpts.save_model(MODEL_INIT, self.state)
-            self.ckpts.save_optimizer(OPTIMIZER_INIT, self.state.opt_state)
-
-        rewind_epoch = self.cfg.pruning_params.rewind_epoch
         max_test_acc = 0.0
         for cycle, epochs in enumerate(cycle_epochs):
             # Fresh optimizer + schedule per cycle: the LR re-warms from the
@@ -63,46 +51,45 @@ class CyclicPruningHarness(PruningHarness):
             # enters/exits per cycle — the planned step bundle is cached by
             # (total_steps, widths, nm signature) and cycles with equal
             # epoch budgets reuse one executable.
-            self.setup_level(epochs)
-            if cycle == 0:
-                self.maybe_rewind_optimizer(level)
-            self._enter_plan()
+            with tracing.span("level/setup", cycle=cycle):
+                self.setup_level(epochs)
+                if cycle == 0:
+                    density = masking.overall_density(self.state.masks)
+                    display_training_info(self.cfg, level, density)
+                    if level == 0:
+                        # Saved BEFORE any training so cycle-0 state is the
+                        # true init (reference saves inside the first cycle,
+                        # cyclic_harness.py:202-211), with the fresh
+                        # opt_state pytree setup_level just made.
+                        self.ckpts.save_model(MODEL_INIT, self.state)
+                        self.ckpts.save_optimizer(
+                            OPTIMIZER_INIT, self.state.opt_state
+                        )
+                    self.maybe_rewind_optimizer(level)
+                self._enter_plan()
+            if level == 1:
+                tracing.stop_profile()  # driver.run's session over the 0 -> 1 boundary
             try:
                 for epoch in range(epochs):
-                    row = {"level": level, "cycle": cycle, "epoch": epoch}
-                    row.update(self.train_epoch())
-                    row.update(self.evaluate())
-                    max_test_acc = max(max_test_acc, row["test_acc"])
-                    row["max_test_acc"] = max_test_acc
-                    row["sparsity"] = masking.overall_sparsity(
-                        self._full_masks()
-                    )
-                    self.metrics.log_epoch(row)
-                    self.wandb.log(row)
-                    self._log_console(row)
-
-                    if (
-                        level == 0
-                        and cycle == 0
-                        and rewind_epoch is not None
-                        and epoch == rewind_epoch
-                    ):
-                        full = self._full_state()
-                        self.ckpts.save_model(MODEL_REWIND, full)
-                        self.ckpts.save_optimizer(
-                            OPTIMIZER_REWIND, full.opt_state
+                    with self._epoch_scope(level, epoch, cycle=cycle):
+                        max_test_acc = self._train_eval_log(
+                            {"level": level, "cycle": cycle, "epoch": epoch},
+                            max_test_acc,
                         )
+                        if level == 0 and cycle == 0:
+                            self._maybe_save_rewind_point(epoch)
             finally:
                 self._exit_plan()
 
-        return self.metrics.finish_level(
-            level,
-            {
-                "density": density,
-                "final_sparsity": masking.overall_sparsity(self.state.masks),
-                "num_cycles": num_cycles,
-            },
-        )
+        with tracing.span("level/finish"):
+            return self.metrics.finish_level(
+                level,
+                {
+                    "density": density,
+                    "final_sparsity": masking.overall_sparsity(self.state.masks),
+                    "num_cycles": num_cycles,
+                },
+            )
 
     def _log_console(self, row: dict) -> None:
         cyc = row.get("cycle", 0)

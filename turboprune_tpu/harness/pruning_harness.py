@@ -27,6 +27,7 @@ subsequent levels (SURVEY.md §7 "Recompile hazards").
 from __future__ import annotations
 
 import time
+from contextlib import contextmanager
 from pathlib import Path
 from typing import Any, Optional
 
@@ -73,6 +74,7 @@ from ..utils import (
     MetricsLogger,
     config_fingerprint,
     display_training_info,
+    tracing,
 )
 from ..utils.wandb_logging import WandbRun
 
@@ -94,23 +96,29 @@ class PruningHarness:
         loaders: Optional[Any] = None,
         state: Optional[TrainState] = None,
     ):
+        with tracing.span("harness/init"):
+            self._build(cfg, expt_dir, loaders, state)
+
+    def _build(self, cfg, expt_dir, loaders, state) -> None:
         self.cfg = cfg
         self.prefix, self.expt_dir = expt_dir
         ep = cfg.experiment_params
         self.compute_dtype = PRECISION_DTYPES[ep.training_precision]
 
-        self.mesh = create_mesh(
-            num_devices=ep.num_devices, model_parallelism=ep.model_parallelism
-        )
-        self.model = create_model(
-            cfg.model_params.model_name,
-            num_classes=cfg.dataset_params.num_classes,
-            dataset_name=cfg.dataset_params.dataset_name,
-            compute_dtype=self.compute_dtype,
-            attention_impl=cfg.model_params.attention_impl,
-            mesh=self.mesh,
-        )
-        self.loaders = loaders if loaders is not None else create_loaders(cfg)
+        with tracing.span("init/mesh_model"):
+            self.mesh = create_mesh(
+                num_devices=ep.num_devices, model_parallelism=ep.model_parallelism
+            )
+            self.model = create_model(
+                cfg.model_params.model_name,
+                num_classes=cfg.dataset_params.num_classes,
+                dataset_name=cfg.dataset_params.dataset_name,
+                compute_dtype=self.compute_dtype,
+                attention_impl=cfg.model_params.attention_impl,
+                mesh=self.mesh,
+            )
+        with tracing.span("init/loaders"):  # synthetic data is made here
+            self.loaders = loaders if loaders is not None else create_loaders(cfg)
         data_size = self.mesh.shape["data"]
         per_host_batch = cfg.dataset_params.total_batch_size // max(
             jax.process_count(), 1
@@ -139,40 +147,17 @@ class PruningHarness:
         self.tx = None
         self.schedule = None
 
-        if state is None:
-            input_shape = (
-                1,
-                cfg.dataset_params.image_size,
-                cfg.dataset_params.image_size,
-                3,
-            )
-            # tx is rebuilt per level; init with a placeholder SGD so the
-            # opt_state pytree has the final structure.
-            tx0, _ = self._build_tx(epochs=ep.epochs_per_level)
-            state = create_train_state(
-                self.model,
-                tx0,
-                jax.random.PRNGKey(ep.seed),
-                input_shape,
-            )
-            if cfg.model_params.pretrained_path:
-                # Warm-start ViT weights from a local timm checkpoint
-                # (reference deit.py:82-89; models/pretrained.py). Applied to
-                # the fresh init only — resume/level restores keep their own
-                # weights — and before the level-0 MODEL_INIT save, so the
-                # imp rewind target carries the pretrained weights.
-                from ..models.pretrained import load_pretrained
+        with tracing.span("init/state"):
+            if state is None:
+                state = self._fresh_state()
+            self.state = replicate(state, self.mesh)
 
-                state = state.replace(
-                    params=load_pretrained(
-                        cfg.model_params.pretrained_path, self.model, state.params
-                    )
-                )
-        self.state = replicate(state, self.mesh)
-
-        raw_eval = make_eval_step(self.model)
-        self._eval_step = make_sharded_eval_step(raw_eval, self.mesh)
-        self._scan_eval = make_sharded_scan_eval(make_scan_eval(raw_eval), self.mesh)
+        with tracing.span("init/steps"):
+            raw_eval = make_eval_step(self.model)
+            self._eval_step = make_sharded_eval_step(raw_eval, self.mesh)
+            self._scan_eval = make_sharded_scan_eval(
+                make_scan_eval(raw_eval), self.mesh
+            )
         self._eval_batches = None  # device-cached stacked test set
         # Opt-in compacted eval (experiment_params.compact_eval): compiled
         # eval steps cached by the compacted width signature — widths only
@@ -190,7 +175,7 @@ class PruningHarness:
         # (total_steps, width signature, nm signature); _plan_ctx holds the
         # plan + the full-coordinate anchor (compaction only) while the
         # level runs on it (None <=> training masked-dense). Cache sizes and
-        # the last plan report are exported on compact_metrics so the
+        # the last plan report are exported as tracing gauges so the
         # bench/tests can read the shape the level ACTUALLY compiled.
         self._plan_step_cache: dict[tuple, tuple] = {}
         self._plan_ctx: Optional[dict] = None
@@ -205,9 +190,40 @@ class PruningHarness:
 
             _, m_block = parse_nm(ep.nm_sparsity)
             check_divisibility(self.state.masks, m_block)
-        from ..serve.metrics import ServeMetrics
 
-        self.compact_metrics = ServeMetrics()
+    def _fresh_state(self) -> TrainState:
+        """The seeded initial state (pretrained weights laid over it where
+        the config names a checkpoint)."""
+        cfg, ep = self.cfg, self.cfg.experiment_params
+        input_shape = (
+            1,
+            cfg.dataset_params.image_size,
+            cfg.dataset_params.image_size,
+            3,
+        )
+        # tx is rebuilt per level; init with a placeholder SGD so the
+        # opt_state pytree has the final structure.
+        tx0, _ = self._build_tx(epochs=ep.epochs_per_level)
+        state = create_train_state(
+            self.model,
+            tx0,
+            jax.random.PRNGKey(ep.seed),
+            input_shape,
+        )
+        if cfg.model_params.pretrained_path:
+            # Warm-start ViT weights from a local timm checkpoint
+            # (reference deit.py:82-89; models/pretrained.py). Applied to
+            # the fresh init only — resume/level restores keep their own
+            # weights — and before the level-0 MODEL_INIT save, so the
+            # imp rewind target carries the pretrained weights.
+            from ..models.pretrained import load_pretrained
+
+            state = state.replace(
+                params=load_pretrained(
+                    cfg.model_params.pretrained_path, self.model, state.params
+                )
+            )
+        return state
 
     # ------------------------------------------------------------------ tx
     def _build_tx(self, epochs: int):
@@ -284,28 +300,34 @@ class PruningHarness:
         the chunked-scan path when ``dataset_params.scan_chunk_steps > 1``
         (K batches per compiled dispatch) and the per-batch path
         otherwise."""
+        t0 = time.perf_counter()
         if (
             hasattr(self.loaders.train_loader, "epoch_arrays")
             and not self.cfg.experiment_params.max_steps_per_epoch
         ):
-            t0 = time.perf_counter()
-            batches = jax.device_put(
-                self.loaders.train_loader.epoch_arrays(),
-                epoch_sharding(self.mesh),
-            )
-            self.state, sums = self._scan_epoch(self.state, batches)
-            sums = jax.device_get(sums)
-            wall = time.perf_counter() - t0
-            n = float(sums["count"])
-            return {
-                "train_loss": float(sums["loss_sum"]) / n,
-                "train_acc": 100.0 * float(sums["correct"]) / n,
-                "epoch_seconds": wall,
-                "samples_per_sec": n / wall,
-            }
+            with tracing.span("epoch/feed"):
+                batches = jax.device_put(
+                    self.loaders.train_loader.epoch_arrays(),
+                    epoch_sharding(self.mesh),
+                )
+            with tracing.span("epoch/train"):
+                self.state, sums = self._scan_epoch(self.state, batches)
+                sums = jax.device_get(sums)
+        else:
+            with tracing.span("epoch/train"):  # one span, none per batch
+                sums = jax.device_get(self._stream_epoch())
+        wall = time.perf_counter() - t0
+        n = float(sums["count"])
+        return {
+            "train_loss": float(sums["loss_sum"]) / n,
+            "train_acc": 100.0 * float(sums["correct"]) / n,
+            "epoch_seconds": wall,
+            "samples_per_sec": n / wall,
+        }
 
+    def _stream_epoch(self):
+        """The streamed epoch's dispatch loop; returns the device-side sums."""
         sums = None
-        t0 = time.perf_counter()
         train_loader = self.loaders.train_loader
         train_scope = getattr(train_loader, "batch_scope", "global")
         chunk_steps = self.cfg.dataset_params.scan_chunk_steps
@@ -339,15 +361,7 @@ class PruningHarness:
                 "train loader yielded no batches — dataset smaller than "
                 "total_batch_size with drop_last?"
             )
-        sums = jax.device_get(sums)
-        wall = time.perf_counter() - t0
-        n = float(sums["count"])
-        return {
-            "train_loss": float(sums["loss_sum"]) / n,
-            "train_acc": 100.0 * float(sums["correct"]) / n,
-            "epoch_seconds": wall,
-            "samples_per_sec": n / wall,
-        }
+        return sums
 
     def evaluate(self) -> dict:
         """Full test pass (reference test, base_harness.py:204-245). For
@@ -500,6 +514,7 @@ class PruningHarness:
         if compact_mode == "off" and nm_mode == "off":
             return
         from ..sparse import plan_execution, width_signature
+        from ..sparse.plan import report_gauges
 
         pl = self.cfg.planner
         plan = plan_execution(
@@ -533,7 +548,8 @@ class PruningHarness:
         self.last_plan_report = plan.report
         if plan.report["nm"] is not None:
             self.last_nm_report = plan.report["nm"]
-        self.compact_metrics.record_plan(plan.report)
+        for name, value in report_gauges(plan.report).items():
+            tracing.gauge(name, value)
         if plan.kind == "masked":
             # Neither backend pays at this level: keep the dense bundle.
             return
@@ -670,173 +686,198 @@ class PruningHarness:
             del self._plan_eval_cache[k]
 
     def _export_cache_gauges(self) -> None:
-        self.compact_metrics.set_gauge(
-            "plan_step_cache_size", len(self._plan_step_cache)
-        )
-        self.compact_metrics.set_gauge(
-            "plan_eval_cache_size", len(self._plan_eval_cache)
-        )
+        tracing.gauge("plan_step_cache_size", len(self._plan_step_cache))
+        tracing.gauge("plan_eval_cache_size", len(self._plan_eval_cache))
 
     # --------------------------------------------------------------- level
     def train_one_level(self, epochs_per_level: int, level: int) -> dict:
         """Train one sparsity level (reference train_one_level,
         standard_pruning_harness.py:159-269)."""
-        self.setup_level(epochs_per_level)
-        self.maybe_rewind_optimizer(level)
-        density = masking.overall_density(self.state.masks)
-        display_training_info(self.cfg, level, density)
-
-        if level == 0:
-            # Level-0 artifacts: starting weights + optimizer (imp rewind
-            # target; standard_pruning_harness.py:190-199).
-            self.ckpts.save_model(MODEL_INIT, self.state)
-            self.ckpts.save_optimizer(OPTIMIZER_INIT, self.state.opt_state)
-
-        rewind_epoch = self.cfg.pruning_params.rewind_epoch
-        profile_dir = self.cfg.experiment_params.profile_dir
         ckpt_every = self.cfg.experiment_params.checkpoint_every_epochs
         max_test_acc = 0.0
         start_epoch = 0
-        mid = self.ckpts.peek_mid_level() if ckpt_every else None
-        if mid and mid.get("config_hash") != self.config_hash:
-            # Identity mismatch (or a pre-stamp slot of unknown provenance):
-            # the slot holds mid-trajectory state trained under a DIFFERENT
-            # config (lr, epoch budget, loader type, ...) — restoring it
-            # would silently continue the wrong trajectory. Refuse and
-            # replay the level from its start.
-            if is_primary():
-                print(
-                    "[resume] REFUSING mid-level restore: slot config hash "
-                    f"{mid.get('config_hash')!r} != current "
-                    f"{self.config_hash!r} (run {mid.get('run_id')!r}) — "
-                    "the config changed since the slot was written; "
-                    "replaying the level from its start",
-                    flush=True,
-                )
-            self.ckpts.clear_mid_level()
-        elif mid and mid["level"] != level:
-            # Levels run in ascending order, so a slot for a different level
-            # is always from an abandoned trajectory (e.g. resumed BELOW a
-            # preempted level) — drop it before it can hijack a later
-            # re-run of its level.
-            self.ckpts.clear_mid_level()
-        elif mid:
-            # Epoch-granular re-entry (beyond-reference; checkpoint.py
-            # MID_LEVEL): restore the FULL state — opt_state and step come
-            # back mid-schedule — and fast-forward the train loader's epoch
-            # counter so the per-epoch shuffle/augment PRNG stream continues
-            # exactly where the interrupted run left it (bit-identical to an
-            # uninterrupted run; asserted in tests/test_harness.py).
-            restored = self.ckpts.load_mid_level(
-                self.state, expect_level=level, expect_epoch=mid["epoch"]
-            )
-            if restored is None:
-                # Torn save (header and state tree from different saves):
-                # replay the level from its start instead of mixing them.
+        with tracing.span("level/setup"):
+            self.setup_level(epochs_per_level)
+            self.maybe_rewind_optimizer(level)
+            density = masking.overall_density(self.state.masks)
+            display_training_info(self.cfg, level, density)
+
+            if level == 0:
+                # Level-0 artifacts: starting weights + optimizer (imp rewind
+                # target; standard_pruning_harness.py:190-199).
+                self.ckpts.save_model(MODEL_INIT, self.state)
+                self.ckpts.save_optimizer(OPTIMIZER_INIT, self.state.opt_state)
+
+            mid = self.ckpts.peek_mid_level() if ckpt_every else None
+            if mid and mid.get("config_hash") != self.config_hash:
+                # Identity mismatch (or a pre-stamp slot of unknown provenance):
+                # the slot holds mid-trajectory state trained under a DIFFERENT
+                # config (lr, epoch budget, loader type, ...) — restoring it
+                # would silently continue the wrong trajectory. Refuse and
+                # replay the level from its start.
                 if is_primary():
                     print(
-                        "[resume] mid-level slot is torn (header/state "
-                        "disagree) — replaying the level",
+                        "[resume] REFUSING mid-level restore: slot config hash "
+                        f"{mid.get('config_hash')!r} != current "
+                        f"{self.config_hash!r} (run {mid.get('run_id')!r}) — "
+                        "the config changed since the slot was written; "
+                        "replaying the level from its start",
                         flush=True,
                     )
                 self.ckpts.clear_mid_level()
-            else:
-                self.state = replicate(
-                    self.state.replace(**restored), self.mesh
+            elif mid and mid["level"] != level:
+                # Levels run in ascending order, so a slot for a different level
+                # is always from an abandoned trajectory (e.g. resumed BELOW a
+                # preempted level) — drop it before it can hijack a later
+                # re-run of its level.
+                self.ckpts.clear_mid_level()
+            elif mid:
+                # Epoch-granular re-entry (beyond-reference; checkpoint.py
+                # MID_LEVEL): restore the FULL state — opt_state and step come
+                # back mid-schedule — and fast-forward the train loader's epoch
+                # counter so the per-epoch shuffle/augment PRNG stream continues
+                # exactly where the interrupted run left it (bit-identical to an
+                # uninterrupted run; asserted in tests/test_harness.py).
+                restored = self.ckpts.load_mid_level(
+                    self.state, expect_level=level, expect_epoch=mid["epoch"]
                 )
-                start_epoch = mid["epoch"] + 1
-                max_test_acc = mid.get("max_test_acc", 0.0)
-                # Pre-preemption epoch rows ride in the header so the level
-                # CSV and the summary's max_test_acc cover the WHOLE level,
-                # not just the post-resume epochs.
-                self.metrics.level_rows = [
-                    dict(r) for r in mid.get("level_rows", [])
-                ]
-                self._restore_train_stream(mid, level)
-                if is_primary():
-                    print(
-                        f"[resume] mid-level checkpoint: re-entering level "
-                        f"{level} at epoch {start_epoch}",
-                        flush=True,
+                if restored is None:
+                    # Torn save (header and state tree from different saves):
+                    # replay the level from its start instead of mixing them.
+                    if is_primary():
+                        print(
+                            "[resume] mid-level slot is torn (header/state "
+                            "disagree) — replaying the level",
+                            flush=True,
+                        )
+                    self.ckpts.clear_mid_level()
+                else:
+                    self.state = replicate(
+                        self.state.replace(**restored), self.mesh
                     )
-        # After any mid-level restore, so the anchor is the true level-start
-        # full state (post-rewind, post-resume) and a resumed level
-        # re-derives its ExecutionPlan from the restored full coordinates.
-        self._enter_plan()
+                    start_epoch = mid["epoch"] + 1
+                    max_test_acc = mid.get("max_test_acc", 0.0)
+                    # Pre-preemption epoch rows ride in the header so the level
+                    # CSV and the summary's max_test_acc cover the WHOLE level,
+                    # not just the post-resume epochs.
+                    self.metrics.level_rows = [
+                        dict(r) for r in mid.get("level_rows", [])
+                    ]
+                    self._restore_train_stream(mid, level)
+                    if is_primary():
+                        print(
+                            f"[resume] mid-level checkpoint: re-entering level "
+                            f"{level} at epoch {start_epoch}",
+                            flush=True,
+                        )
+            # After any mid-level restore, so the anchor is the true
+            # level-start full state (post-rewind, post-resume) and a resumed
+            # level re-derives its ExecutionPlan from the restored full
+            # coordinates.
+            self._enter_plan()
+        if level == 1:
+            tracing.stop_profile()  # driver.run's session over the 0 -> 1 boundary
         try:
             for epoch in range(start_epoch, epochs_per_level):
-                # Trace the second epoch of level 0 (first is
-                # compile-polluted).
-                tracing = bool(profile_dir) and level == 0 and epoch == 1
-                if tracing:
-                    jax.profiler.start_trace(profile_dir)
-                row = {"level": level, "epoch": epoch}
-                row.update(self.train_epoch())
-                if tracing:
-                    jax.profiler.stop_trace()
-                row.update(self.evaluate())
-                max_test_acc = max(max_test_acc, row["test_acc"])
-                row["max_test_acc"] = max_test_acc
-                row["sparsity"] = masking.overall_sparsity(self._full_masks())
-                self.metrics.log_epoch(row)
-                self.wandb.log(row)
-                self._log_console(row)
-
-                if level == 0 and rewind_epoch is not None and epoch == rewind_epoch:
-                    # Weight-rewinding snapshot (standard_pruning_harness.py:
-                    # 212-223). Full coordinates — the rewind target must
-                    # not depend on whether this level ran compacted.
-                    full = self._full_state()
-                    self.ckpts.save_model(MODEL_REWIND, full)
-                    self.ckpts.save_optimizer(OPTIMIZER_REWIND, full.opt_state)
-
-                if (
-                    ckpt_every
-                    and (epoch + 1) % ckpt_every == 0
-                    and epoch + 1 < epochs_per_level  # last epoch -> level ckpt
-                ):
-                    meta = {
-                        "max_test_acc": max_test_acc,
-                        # Slot identity (ADVICE r5): the restore path refuses
-                        # a slot whose config hash disagrees with the live
-                        # run.
-                        "config_hash": self.config_hash,
-                        "run_id": self.run_id,
-                        "train_loader_epoch": getattr(
-                            self.loaders.train_loader, "epoch", 0
-                        ),
-                        # So the level CSV / summary survive the preemption
-                        # (rows are plain float/int dicts — JSON-safe).
-                        "level_rows": self.metrics.level_rows,
-                    }
-                    get_stream = getattr(
-                        self.loaders.train_loader, "get_stream_state", None
+                with self._epoch_scope(level, epoch):
+                    max_test_acc = self._train_eval_log(
+                        {"level": level, "epoch": epoch}, max_test_acc
                     )
-                    if get_stream is not None:
-                        stream = get_stream()
-                        if stream is not None:
-                            # EVERY host writes its own blob (its own shard
-                            # position) — a shared primary-only header would
-                            # hand all hosts the primary's position.
-                            self.ckpts.save_mid_level_stream(
-                                level, epoch, stream, jax.process_index()
+                    if level == 0:
+                        self._maybe_save_rewind_point(epoch)
+                    if (
+                        ckpt_every
+                        and (epoch + 1) % ckpt_every == 0
+                        and epoch + 1 < epochs_per_level  # last epoch -> level ckpt
+                    ):
+                        with tracing.span("epoch/ckpt"):
+                            meta = {
+                                "max_test_acc": max_test_acc,
+                                # Slot identity (ADVICE r5): the restore path refuses
+                                # a slot whose config hash disagrees with the live
+                                # run.
+                                "config_hash": self.config_hash,
+                                "run_id": self.run_id,
+                                "train_loader_epoch": getattr(
+                                    self.loaders.train_loader, "epoch", 0
+                                ),
+                                # So the level CSV / summary survive the preemption
+                                # (rows are plain float/int dicts — JSON-safe).
+                                "level_rows": self.metrics.level_rows,
+                            }
+                            get_stream = getattr(
+                                self.loaders.train_loader, "get_stream_state", None
                             )
-                            meta["train_loader_stream_hosts"] = (
-                                jax.process_count()
+                            if get_stream is not None:
+                                stream = get_stream()
+                                if stream is not None:
+                                    # EVERY host writes its own blob (its own shard
+                                    # position) — a shared primary-only header would
+                                    # hand all hosts the primary's position.
+                                    self.ckpts.save_mid_level_stream(
+                                        level, epoch, stream, jax.process_index()
+                                    )
+                                    meta["train_loader_stream_hosts"] = (
+                                        jax.process_count()
+                                    )
+                            self.ckpts.save_mid_level(
+                                level, epoch, self._full_state(), meta=meta
                             )
-                    self.ckpts.save_mid_level(
-                        level, epoch, self._full_state(), meta=meta
-                    )
         finally:
             self._exit_plan()
 
-        return self.metrics.finish_level(
-            level,
-            {
-                "density": density,
-                "final_sparsity": masking.overall_sparsity(self.state.masks),
-            },
-        )
+        with tracing.span("level/finish"):
+            return self.metrics.finish_level(
+                level,
+                {
+                    "density": density,
+                    "final_sparsity": masking.overall_sparsity(self.state.masks),
+                },
+            )
+
+    @contextmanager
+    def _epoch_scope(self, level: int, epoch: int, **attrs):
+        """One iteration of the epoch loop as the ``epoch`` span. With
+        ``profile_dir`` set, the second epoch of level 0 (the first is
+        compile-polluted) runs under a profiler session that covers the
+        whole iteration: train, eval and logging."""
+        profile_dir = self.cfg.experiment_params.profile_dir
+        profiled = bool(profile_dir) and level == 0 and epoch == 1 and not attrs.get("cycle")
+        if profiled:
+            tracing.start_profile(Path(profile_dir) / "level0_epoch1")
+        try:
+            with tracing.span("epoch", epoch=epoch, **attrs):
+                yield
+        finally:
+            if profiled:
+                tracing.stop_profile()
+
+    def _train_eval_log(self, row: dict, max_test_acc: float) -> float:
+        """Train one epoch, evaluate, and log ``row`` (which already names
+        the level, the epoch and, cyclic, the cycle); returns the level's
+        best test accuracy so far."""
+        row.update(self.train_epoch())
+        with tracing.span("epoch/eval"):
+            row.update(self.evaluate())
+        with tracing.span("epoch/log"):
+            max_test_acc = max(max_test_acc, row["test_acc"])
+            row["max_test_acc"] = max_test_acc
+            row["sparsity"] = masking.overall_sparsity(self._full_masks())
+            self.metrics.log_epoch(row)
+            self.wandb.log(row)
+            self._log_console(row)
+        return max_test_acc
+
+    def _maybe_save_rewind_point(self, epoch: int) -> None:
+        """Weight-rewinding snapshot at ``rewind_epoch`` of level 0
+        (standard_pruning_harness.py:212-223). Full coordinates — the rewind
+        target must not depend on whether this level ran compacted."""
+        rewind_epoch = self.cfg.pruning_params.rewind_epoch
+        if rewind_epoch is not None and epoch == rewind_epoch:
+            with tracing.span("epoch/ckpt"):
+                full = self._full_state()
+                self.ckpts.save_model(MODEL_REWIND, full)
+                self.ckpts.save_optimizer(OPTIMIZER_REWIND, full.opt_state)
 
     def _restore_train_stream(self, mid: dict, level: int) -> None:
         """Restore the train loader's data-order state on mid-level resume.

@@ -72,7 +72,8 @@ def apply_masks(params: PyTree, masks: PyTree) -> PyTree:
             return p
         return p * m.astype(p.dtype)
 
-    return jax.tree.map(apply, masks, params, is_leaf=_is_none)
+    with jax.named_scope("mask_apply"):
+        return jax.tree.map(apply, masks, params, is_leaf=_is_none)
 
 
 def mask_where(masks: PyTree, fn: Callable[..., jax.Array], *trees: PyTree) -> PyTree:

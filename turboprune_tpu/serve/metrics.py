@@ -81,25 +81,12 @@ class ServeMetrics:
         self.inc("compile_cache_misses_total")
 
     def record_plan(self, report: dict) -> None:
-        """Export an ExecutionPlan report (sparse/plan.py) as the unified
-        ``plan_*`` gauge family: per-layer backend decision counts, N:M
-        coverage, and — when compaction was planned — the dense vs compacted
-        parameter/channel counts, so a scraper (or the bench) can read the
-        size and routing the process ACTUALLY compiled, not just the mask
-        density. Replaces the parallel ``compaction_*``/``nm_*`` families."""
-        counts = report.get("backend_counts", {})
-        self.set_gauge("plan_layers_nm", counts.get("nm_layers", 0))
-        self.set_gauge("plan_layers_dense", counts.get("dense_layers", 0))
-        self.set_gauge(
-            "plan_spaces_compacted", counts.get("compact_spaces", 0)
-        )
-        self.set_gauge("plan_coverage_frac", report.get("coverage_frac", 0.0))
-        comp = report.get("compaction") or {}
-        if "params_before" in comp:
-            self.set_gauge("plan_params_dense", comp["params_before"])
-            self.set_gauge("plan_params_compacted", comp["params_after"])
-            self.set_gauge("plan_channels_dense", comp["channels_before"])
-            self.set_gauge("plan_channels_compacted", comp["channels_after"])
+        """Export an ExecutionPlan report as the unified ``plan_*`` gauge
+        family (``sparse/plan.py::report_gauges``)."""
+        from ..sparse.plan import report_gauges
+
+        for name, value in report_gauges(report).items():
+            self.set_gauge(name, value)
 
     def observe_latency_ms(self, ms: float) -> None:
         with self._lock:

@@ -412,3 +412,28 @@ def plan_execution(
         "decisions": decisions,
     }
     return plan
+
+
+def report_gauges(report: dict) -> dict[str, float]:
+    """An ExecutionPlan report as the ``plan_*`` gauge family: per-layer
+    backend decision counts, N:M coverage, and — when compaction was
+    planned — the dense vs compacted parameter/channel counts, so a scraper,
+    a test or the bench reads the size and routing the process ACTUALLY
+    compiled, not just the mask density. One mapping for the trainer's
+    gauges (utils/tracing.py) and the server's (serve/metrics.py)."""
+    counts = report.get("backend_counts", {})
+    out = {
+        "plan_layers_nm": counts.get("nm_layers", 0),
+        "plan_layers_dense": counts.get("dense_layers", 0),
+        "plan_spaces_compacted": counts.get("compact_spaces", 0),
+        "plan_coverage_frac": report.get("coverage_frac", 0.0),
+    }
+    comp = report.get("compaction") or {}
+    if "params_before" in comp:
+        out.update(
+            plan_params_dense=comp["params_before"],
+            plan_params_compacted=comp["params_after"],
+            plan_channels_dense=comp["channels_before"],
+            plan_channels_compacted=comp["channels_after"],
+        )
+    return out

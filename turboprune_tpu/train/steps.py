@@ -70,18 +70,23 @@ def make_train_step(
         step_rng = jax.random.fold_in(state.rng, state.step)
 
         def loss_fn(params):
-            logits, new_batch_stats = _forward_train(
-                model, params, state.masks, state.batch_stats, images, step_rng
-            )
-            n = jnp.asarray(labels.shape[0], jnp.float32)
-            loss_sum = cross_entropy_sum(logits, labels)
+            # Named scopes label the device trace's operations by layer; the
+            # backward pass comes out as transpose(jvp(forward)).
+            with jax.named_scope("forward"):
+                logits, new_batch_stats = _forward_train(
+                    model, params, state.masks, state.batch_stats, images, step_rng
+                )
+            with jax.named_scope("loss"):
+                n = jnp.asarray(labels.shape[0], jnp.float32)
+                loss_sum = cross_entropy_sum(logits, labels)
             return loss_sum / n, (logits, new_batch_stats, loss_sum, n)
 
         grads, (logits, new_batch_stats, loss_sum, n) = jax.grad(
             loss_fn, has_aux=True
         )(state.params)
-        updates, new_opt_state = tx.update(grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("optimizer"):
+            updates, new_opt_state = tx.update(grads, state.opt_state, state.params)
+            new_params = optax.apply_updates(state.params, updates)
 
         correct = jnp.sum(jnp.argmax(logits, axis=-1) == labels).astype(jnp.float32)
         metrics = {"loss_sum": loss_sum, "correct": correct, "count": n}
@@ -181,7 +186,8 @@ def make_eval_step(model) -> Callable[[TrainState, Batch], dict]:
         variables = {"params": apply_masks(state.params, state.masks)}
         if state.batch_stats:
             variables["batch_stats"] = state.batch_stats
-        logits = model.apply(variables, images, train=False)
+        with jax.named_scope("eval_forward"):
+            logits = model.apply(variables, images, train=False)
         valid = labels >= 0
         safe_labels = jnp.maximum(labels, 0)
         logp = jax.nn.log_softmax(logits.astype(jnp.float32))

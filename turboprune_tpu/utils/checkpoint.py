@@ -31,6 +31,8 @@ import jax
 import numpy as np
 import orbax.checkpoint as ocp
 
+from . import tracing
+
 PyTree = Any
 
 MODEL_INIT = "model_init"
@@ -81,31 +83,35 @@ def save_pytree(path: str | Path, tree: PyTree) -> None:
     if is_primary():
         # device_get works per-host on replicated arrays; saving numpy keeps
         # the array leaves fully addressable for the single-process save.
-        host_tree = jax.tree.map(
-            lambda x: np.asarray(jax.device_get(x))
-            if isinstance(x, jax.Array)
-            else x,
-            tree,
-        )
-        ckptr = _primary_only_checkpointer()
-        if path.exists():
-            import shutil
+        with tracing.span("ckpt/fetch"):
+            host_tree = jax.tree.map(
+                lambda x: np.asarray(jax.device_get(x))
+                if isinstance(x, jax.Array)
+                else x,
+                tree,
+            )
+        with tracing.span("ckpt/write"):
+            ckptr = _primary_only_checkpointer()
+            if path.exists():
+                import shutil
 
-            shutil.rmtree(path)
-        ckptr.save(path, host_tree)
-        ckptr.wait_until_finished()
-    sync_hosts(f"save_pytree:{path.name}")
+                shutil.rmtree(path)
+            ckptr.save(path, host_tree)
+            ckptr.wait_until_finished()
+    with tracing.span("ckpt/barrier"):
+        sync_hosts(f"save_pytree:{path.name}")
 
 
 def restore_pytree(path: str | Path, like: Optional[PyTree] = None) -> PyTree:
     """Restore; pass ``like`` (a matching concrete/abstract pytree) to get
     exact container types back (optax namedtuples, custom nodes)."""
     path = Path(path).resolve()
-    ckptr = ocp.StandardCheckpointer()
-    if like is None:
-        return ckptr.restore(path)
-    abstract = jax.tree.map(ocp.utils.to_shape_dtype_struct, like)
-    return ckptr.restore(path, abstract)
+    with tracing.span("ckpt/read"):
+        ckptr = ocp.StandardCheckpointer()
+        if like is None:
+            return ckptr.restore(path)
+        abstract = jax.tree.map(ocp.utils.to_shape_dtype_struct, like)
+        return ckptr.restore(path, abstract)
 
 
 # --- bit-packed mask payloads --------------------------------------------
@@ -191,7 +197,8 @@ def save_model_tree(path: str | Path, tree: dict) -> None:
     """Save a model-role tree ({"params", "masks", ...extras}) with the
     mask payload bit-packed under ``masks_packed``."""
     out = dict(tree)
-    out[MASKS_PACKED_KEY] = pack_mask_tree(out.pop(MASKS_KEY))
+    with tracing.span("ckpt/fetch"):  # the masks come to the host to be packed
+        out[MASKS_PACKED_KEY] = pack_mask_tree(out.pop(MASKS_KEY))
     save_pytree(path, out)
 
 

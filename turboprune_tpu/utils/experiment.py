@@ -167,6 +167,25 @@ class MetricsLogger:
         self.level_rows = []
         return summary
 
+    def log_level_timing(self, row: dict) -> None:
+        """Append one level's ``[time]`` line as a row of
+        ``metrics/level_timing.csv`` (``tracing.timing_row``; the columns are
+        fixed, so a resumed run appends to the same file). Host 0 only."""
+        import csv
+
+        import jax
+
+        if jax.process_index() != 0:
+            return
+        path = self.expt_dir / "metrics" / "level_timing.csv"
+        path.parent.mkdir(parents=True, exist_ok=True)
+        new = not path.exists()
+        with open(path, "a", newline="") as fh:
+            writer = csv.DictWriter(fh, fieldnames=list(row))
+            if new:
+                writer.writeheader()
+            writer.writerow(row)
+
 
 def display_training_info(cfg: MainConfig, level: int, density: float) -> None:
     """Rich config/level panels (reference display_training_info,

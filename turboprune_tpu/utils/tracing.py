@@ -1,0 +1,230 @@
+"""Spans, gauges and the profiler sessions of the training path: the one
+mechanism for driver, harness, checkpoints and step.
+
+``span(name, **attrs)`` times a scope on ``time.perf_counter()`` into a
+process-wide bounded recorder and opens a
+``jax.profiler.TraceAnnotation("tp/<name>")``, so a running profiler session
+holds the same scope on the device trace's clock. ``level`` and ``epoch`` are
+inherited from the enclosing span: the spans of one level share its number.
+Every XLA compilation is charged to the innermost span open on the thread
+that compiled (``compiles``, ``compile_s``): "which step recompiled".
+``breakdown`` and ``line`` turn a level's or set-up's spans into the
+operator's ``[time]`` line and the ``level_timing.csv`` row.
+
+There is no off switch, so what recording costs is in every run: a few
+microseconds a span, with a budget of a few dozen spans per level or epoch
+and none per step or batch. A span ends where the host already is; nothing
+here waits for the device. PERF.md section 3 lists every span and its reader.
+"""
+
+from __future__ import annotations
+
+import itertools
+import threading
+import time
+from collections import defaultdict, deque
+from pathlib import Path
+from typing import Any, Optional, Sequence
+
+import jax
+
+PREFIX = "tp/"
+MAX_SPANS = 65536  # a 30-level ladder of 150 epochs records about 32,000
+INHERITED = ("level", "epoch")
+# What a ``[time]`` line names, in its order. The spans between these and the
+# root (``level``, ``level/train``, ``epoch``) are containers: their self
+# time, a span's duration less what its children cover, is the line's "other".
+TERMS = (
+    "setup/imports", "setup/config", "setup/distributed", "harness/init",
+    "level/load", "level/prune", "level/rewind", "level/agree", "level/setup",
+    "epoch/feed", "epoch/train", "epoch/eval", "epoch/log", "epoch/ckpt",
+    "level/finish", "level/save",
+)  # fmt: skip
+_CKPT = ("ckpt/read", "ckpt/fetch", "ckpt/write", "ckpt/barrier")
+
+_spans: deque = deque(maxlen=MAX_SPANS)  # closed spans, in closing order
+_ids = itertools.count(1)
+_local = threading.local()
+_mu = threading.Lock()
+_gauges: dict[str, float] = {}
+_profiling = False
+
+
+def _stack() -> list:
+    if not hasattr(_local, "stack"):
+        _local.stack = []
+    return _local.stack
+
+
+class Span:
+    """One timed scope, and its own context manager."""
+
+    def __init__(self, name: str, attrs: dict):
+        self.id = next(_ids)
+        self.parent: Optional[int] = None
+        self.name, self.attrs = name, attrs
+        self.start = self.end = 0.0
+        self.thread = threading.get_ident()
+        self.compiles, self.compile_s = 0, 0.0
+
+    @property
+    def seconds(self) -> float:
+        return self.end - self.start
+
+    def __enter__(self) -> "Span":
+        stack = _stack()
+        if stack:
+            self.parent = stack[-1].id
+            for key in INHERITED:
+                if key in stack[-1].attrs:
+                    self.attrs.setdefault(key, stack[-1].attrs[key])
+        self._annotation = jax.profiler.TraceAnnotation(PREFIX + self.name, **self.attrs)
+        self._annotation.__enter__()
+        stack.append(self)
+        self.start = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        self.end = time.perf_counter()
+        _stack().pop()
+        self._annotation.__exit__(exc_type, exc, tb)
+        if exc_type is not None:
+            self.attrs["error"] = exc_type.__name__
+        _spans.append(self)
+
+
+def span(name: str, **attrs: Any) -> Span:
+    """``with span("level/prune"): ...``; the ``with`` yields the Span."""
+    return Span(name, attrs)
+
+
+def recorded(
+    name: Optional[str] = None, t0: float = float("-inf"), t1: float = float("inf")
+) -> list[Span]:
+    """Closed spans (of that name) wholly inside [t0, t1], in closing order."""
+    return [
+        s
+        for s in list(_spans)
+        if (name is None or s.name == name) and s.start >= t0 and s.end <= t1
+    ]
+
+
+def _on_duration(event: str, duration: float, **_kw) -> None:
+    stack = _stack()
+    if event == "/jax/core/compile/backend_compile_duration" and stack:
+        stack[-1].compiles += 1
+        stack[-1].compile_s += duration
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_duration)
+
+
+def gauge(name: str, value: float) -> None:
+    """Set a named number of the process (the harness's plan gauges)."""
+    with _mu:
+        _gauges[name] = value
+
+
+def gauges() -> dict[str, float]:
+    with _mu:
+        return dict(_gauges)
+
+
+def breakdown(roots: Sequence[Span], spans: Optional[Sequence[Span]] = None) -> dict:
+    """What a ``[time]`` line says of ``roots`` and all recorded under them
+    (or, for hand-made spans, found in ``spans``): the seconds of each of
+    ``TERMS`` with its children, ``inside`` each term its direct children by
+    name, the containers' self time as ``other_s``, and the compilations
+    charged anywhere below. Terms and ``other_s`` sum to ``total_s``."""
+    terms: dict = defaultdict(float)
+    inside: dict = defaultdict(lambda: defaultdict(float))
+    out = {"total_s": sum(r.seconds for r in roots), "other_s": 0.0, "compiles": 0, "compile_s": 0.0}
+    children: dict = defaultdict(list)
+    for s in recorded(t0=min(r.start for r in roots)) if spans is None else spans:
+        children[s.parent].append(s)
+    todo = deque((root, None, 0) for root in roots)  # span, the term it lies in, depth in it
+    while todo:
+        s, term, depth = todo.popleft()
+        out["compiles"] += s.compiles
+        out["compile_s"] += s.compile_s
+        if term is not None:
+            if depth == 1:
+                inside[term][s.name] += s.seconds
+        elif s.name in TERMS:
+            term = s.name
+            terms[term] += s.seconds
+        else:
+            out["other_s"] += s.seconds - sum(c.seconds for c in children[s.id])
+        todo.extend((c, term, depth + 1 if term else 0) for c in children[s.id])
+    return {**out, "terms": dict(terms), "inside": {k: dict(v) for k, v in inside.items()}}
+
+
+def _short(name: str) -> str:
+    return name.rsplit("/", 1)[-1]
+
+
+def line(title: str, b: dict) -> str:
+    """``[time] level 3: 3.47 s = load 0.24 (read 0.22) + ... + other 0.01;
+    compiled 0 modules, 0.0 s``."""
+    parts = []
+    for name in (n for n in TERMS if n in b["terms"]):
+        part = f"{_short(name)} {b['terms'][name]:.2f}"
+        if name in b["inside"]:
+            split = ", ".join(f"{_short(k)} {v:.2f}" for k, v in b["inside"][name].items())
+            part += f" ({split})"
+        parts.append(part)
+    return (
+        f"[time] {title}: {b['total_s']:.2f} s = "
+        + " + ".join(parts + [f"other {b['other_s']:.2f}"])
+        + f"; compiled {b['compiles']} modules, {b['compile_s']:.1f} s"
+    )
+
+
+TIMING_COLUMNS = (
+    ["level", "density", "total_s"]
+    + [f"{_short(n)}_s" for n in TERMS if n.startswith(("level/", "epoch/"))]
+    + ["other_s"]
+    + [n.replace("/", "_") + "_s" for n in _CKPT]
+    + ["compiles", "compile_s"]
+)
+
+
+def timing_row(level: Span, b: dict) -> dict:
+    """The ``level_timing.csv`` row of one level, keyed by ``TIMING_COLUMNS``."""
+    row = dict.fromkeys(TIMING_COLUMNS, 0.0)
+    row.update({k: b[k] for k in ("total_s", "other_s", "compiles", "compile_s")})
+    row.update(level=level.attrs.get("level"), density=level.attrs.get("density"))
+    row.update({f"{_short(n)}_s": s for n, s in b["terms"].items()})
+    for split in b["inside"].values():
+        for name in set(split) & set(_CKPT):
+            row[name.replace("/", "_") + "_s"] += split[name]
+    return row
+
+
+def setup_roots() -> list[Span]:
+    """The newest ``harness/init`` and the ``setup/`` spans that closed
+    between the one before it and it."""
+    spans = recorded()
+    inits = [i for i, s in enumerate(spans) if s.name == "harness/init"]
+    lo = inits[-2] + 1 if len(inits) > 1 else 0
+    return [s for s in spans[lo : inits[-1]] if s.name.startswith("setup/")] + [spans[inits[-1]]]
+
+
+def start_profile(directory: str | Path) -> None:
+    """Start a profiler session that writes under ``directory``. Python
+    frames are left out: the ``tp/`` spans say where the host is, and the
+    Python tracer's own cost would fill the gaps it is there to show."""
+    global _profiling
+    if not _profiling:
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(str(directory), profiler_options=options)
+        _profiling = True
+
+
+def stop_profile() -> None:
+    """Stop the session ``start_profile`` started; nothing where none runs."""
+    global _profiling
+    if _profiling:
+        _profiling = False
+        jax.profiler.stop_trace()
