@@ -57,6 +57,48 @@ class TestAugment:
             )
             assert found
 
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    @pytest.mark.parametrize("translate", [1, 2, 4])
+    def test_translate_crop_equals_per_image_reference(self, translate, dtype):
+        """Bit for bit the per-image crop at the shifts the same key draws;
+        37 images, so no block or tile size divides N."""
+        n, c = 37, 8
+        x = normalize_uint8(_images(n=n, s=c, seed=translate), (0, 0, 0), (1, 1, 1))
+        padded = pad_reflect(x, translate).astype(dtype)
+        key = jax.random.PRNGKey(1)  # draws every shift of 1, 2 and 4 on both axes
+        out = batch_translate_crop(padded, key, c)
+        assert out.dtype == dtype and out.shape == (n, c, c, 3)
+
+        # graftlint: disable=rng-key-reuse -- deliberate: the reference redraws the crop's own shifts from the same key
+        ky, kx = jax.random.split(key)
+        sy = np.asarray(jax.random.randint(ky, (n,), 0, 2 * translate + 1))
+        sx = np.asarray(jax.random.randint(kx, (n,), 0, 2 * translate + 1))
+        assert len(set(sy)) == len(set(sx)) == 2 * translate + 1  # every shift drawn
+        host = np.asarray(padded.astype(jnp.float32))
+        want = np.stack(
+            [host[i, sy[i] : sy[i] + c, sx[i] : sx[i] + c] for i in range(n)]
+        )
+        np.testing.assert_array_equal(np.asarray(out.astype(jnp.float32)), want)
+
+    def test_augment_epoch_lowers_to_dense_ops_only(self):
+        """The guard that the dense crop is what runs: the program lowered
+        for the TPU (PRNG unrolled there; the CPU rolls threefry into a
+        ``while`` of its own) indexes nothing by data and loops over
+        nothing. The parent's vmap(dynamic_slice) lowered to a gather,
+        which the TPU ran as a ``while`` of N iterations (PERF.md, PR 25)."""
+        x = jnp.zeros((37, 12, 12, 3), jnp.float32)
+        text = (
+            augment_epoch.trace(
+                x, jax.random.PRNGKey(0), jnp.asarray(1),
+                crop_size=8, flip=True, translate=2, altflip=True,
+            )
+            .lower(lowering_platforms=("tpu",))
+            .as_text()
+        )
+        assert "stablehlo.select" in text and "stablehlo.slice" in text
+        for op in ("gather", "dynamic_slice", "dynamic_update_slice", "while", "scatter"):
+            assert f"stablehlo.{op}" not in text, op
+
     def test_cutout_zeroes_exactly_one_square(self):
         x = jnp.ones((4, 8, 8, 3), jnp.float32)
         out = batch_cutout(x, jax.random.PRNGKey(2), 3)
@@ -114,6 +156,41 @@ class TestDeviceLoader:
         assert not bool(jnp.array_equal(labels1, labels2))
         np.testing.assert_array_equal(
             np.sort(np.asarray(labels1)), np.sort(np.asarray(labels2))
+        )
+
+    @pytest.mark.parametrize("epoch", [0, 1])
+    def test_epoch_arrays_stream_is_pinned(self, epoch):
+        """The seeded stream of a train loader, epoch by epoch, against the
+        crop as it was before PR 25 (vmap of a per-image dynamic_slice, kept
+        here) under the loader's key discipline: fold_in(epoch), split into
+        augmentation and permutation, the crop's key first of three."""
+        loader = self._loader(n=70, bs=16)
+        loader.epoch = epoch  # the counter is the loader's whole RNG state
+        images, labels = loader.epoch_arrays()
+
+        def old_crop(padded, key, crop_size):
+            n, h, _, c = padded.shape
+            ky, kx = jax.random.split(key)
+            sy = jax.random.randint(ky, (n,), 0, h - crop_size + 1)
+            sx = jax.random.randint(kx, (n,), 0, h - crop_size + 1)
+            return jax.vmap(
+                lambda img, y, x: jax.lax.dynamic_slice(
+                    img, (y, x, 0), (crop_size, crop_size, c)
+                )
+            )(padded, sy, sx)
+
+        k_aug, k_perm = jax.random.split(
+            jax.random.fold_in(loader._epoch_key, epoch)
+        )
+        want = old_crop(loader._base, jax.random.split(k_aug, 3)[0], 8)
+        if epoch % 2 == 1:
+            want = want[:, :, ::-1, :]
+        perm = jax.random.permutation(k_perm, 70)
+        np.testing.assert_array_equal(
+            np.asarray(images), np.asarray(want[perm][:64]).reshape(4, 16, 8, 8, 3)
+        )
+        np.testing.assert_array_equal(
+            np.asarray(labels), np.asarray(loader.labels[perm][:64]).reshape(4, 16)
         )
 
     def test_unknown_aug_key_rejected(self):

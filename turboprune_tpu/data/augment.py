@@ -2,11 +2,21 @@
 
 Rebuilds the reference's airbench GPU-batched augmentation
 (/root/reference/utils/dataset.py:38-98) as pure JAX ops over the WHOLE
-training set: one jitted call at epoch start augments all N images in a
-single fused XLA program, and batches are then plain slices of device
-arrays — zero per-step host work, which is the TPU-shaped version of the
-reference's "keep the dataset on the accelerator" trick
-(dataset.py:149, SURVEY.md §7).
+training set: one jitted call at epoch start (``augment_epoch``, the
+compiled module ``jit_augment_epoch``) augments all N images, and batches
+are then plain slices of device arrays — zero per-step host work, which is
+the TPU-shaped version of the reference's "keep the dataset on the
+accelerator" trick (dataset.py:149, SURVEY.md §7).
+
+Every op here is dense over N: elementwise selects, static slices, a
+reverse. Nothing indexes by data. The crop was once a
+``vmap(lax.dynamic_slice)`` with a per-image offset; the TPU compiler made
+a gather of it and expanded the gather into a ``while`` of N iterations,
+each slicing one image out and writing one in: 1,013 ms a CIFAR epoch
+(50,000 images, a third of an IMP level of ResNet18) and 918 ms for 2,048
+images at 224x224, more than their eight ResNet50 steps. As selects over
+static shifts the whole call takes 12.5 and 25 ms (``augment_ms`` on a
+v5e; PERF.md section 6, PR 25).
 
 Semantics preserved (dataset.py:191-215):
   - normalize once with dataset mean/std
@@ -54,24 +64,40 @@ def pad_reflect(images: jax.Array, r: int) -> jax.Array:
     return jnp.pad(images, ((0, 0), (r, r), (r, r), (0, 0)), mode="reflect")
 
 
+def _select_shift(x: jax.Array, shift: jax.Array, axis: int, size: int) -> jax.Array:
+    """``x[i]`` cut to ``[shift[i], shift[i] + size)`` along ``axis``: a
+    select over every static slice of that length, elementwise over N."""
+    out = jax.lax.slice_in_dim(x, 0, size, axis=axis)
+    for s in range(1, x.shape[axis] - size + 1):
+        window = jax.lax.slice_in_dim(x, s, s + size, axis=axis)
+        out = jnp.where(shift == s, window, out)
+    return out
+
+
 @partial(jax.jit, static_argnames=("crop_size",))
 def batch_translate_crop(
     padded: jax.Array, key: jax.Array, crop_size: int
 ) -> jax.Array:
     """Random (sy, sx) crop of ``crop_size`` from padded images — one
     independent integer shift per image (reference batch_crop,
-    dataset.py:43-69, implemented as a vmapped dynamic_slice instead of the
-    reference's per-shift boolean-mask loop)."""
-    n, h, w, c = padded.shape
+    dataset.py:43-69).
+
+    A shift is one of ``2r + 1`` static values per axis, so the crop is a
+    select over static slices of the whole array, rows first and columns
+    second: ``2 (2r + 1)`` slices and two loop fusions on the TPU, no
+    gather. The price is the rows-only intermediate, ``crop_size /
+    (crop_size + 2r)`` of the input's bytes, alive for the call. A select
+    copies values, so the result equals
+    ``padded[i, sy[i]:sy[i]+c, sx[i]:sx[i]+c]`` in every bit, for any
+    dtype; ``sy`` and ``sx`` are the draws they always were, so a seeded or
+    resumed run keeps its augmentation stream."""
+    n, h = padded.shape[:2]
     r2 = h - crop_size  # == 2r
     ky, kx = jax.random.split(key)
-    sy = jax.random.randint(ky, (n,), 0, r2 + 1)
-    sx = jax.random.randint(kx, (n,), 0, r2 + 1)
-
-    def crop_one(img, y, x):
-        return jax.lax.dynamic_slice(img, (y, x, 0), (crop_size, crop_size, c))
-
-    return jax.vmap(crop_one)(padded, sy, sx)
+    sy = jax.random.randint(ky, (n,), 0, r2 + 1).reshape(n, 1, 1, 1)
+    sx = jax.random.randint(kx, (n,), 0, r2 + 1).reshape(n, 1, 1, 1)
+    rows = _select_shift(padded, sy, 1, crop_size)
+    return _select_shift(rows, sx, 2, crop_size)
 
 
 def batch_cutout(images: jax.Array, key: jax.Array, size: int) -> jax.Array:
@@ -104,7 +130,8 @@ def augment_epoch(
     cutout: int = 0,
     altflip: bool = True,
 ) -> jax.Array:
-    """Augment the ENTIRE training set for one epoch in one fused program.
+    """Augment the ENTIRE training set for one epoch in one compiled
+    program of dense ops (no loop over images: see the module docstring).
 
     Input is the epoch-0-preprocessed tensor: normalized, pre-flipped (if
     ``flip``), reflect-padded (if ``translate``) — the reference caches
