@@ -257,6 +257,141 @@ class TestRewindSemantics:
         assert set(back) == {"params", "masks", "batch_stats"}
 
 
+def _reads_during(fn):
+    """(what ``fn`` returned, how many Orbax restores ran inside it)."""
+    from turboprune_tpu.utils import tracing
+
+    with tracing.span("t/reads") as whole:
+        out = fn()
+    return out, len(tracing.recorded("ckpt/read", whole.start, whole.end))
+
+
+def _assert_trees_equal(got, want):
+    jax.tree.map(
+        lambda a, b: np.testing.assert_array_equal(np.asarray(a), np.asarray(b)), got, want
+    )
+
+
+def _source_of(fn):
+    """(what ``fn`` returned, the ``source`` the rewind noted on the open span)."""
+    from turboprune_tpu.utils import tracing
+
+    with tracing.span("t/rewind") as sp:
+        out = fn()
+    return out, sp.attrs.get("source")
+
+
+class TestResidentRewindTargets:
+    """The process that saves the target its run rewinds to keeps it and
+    rewinds from memory; a process that did not (a resume) reads it from disk
+    once. Either way what it keeps is a host tree."""
+
+    @pytest.mark.parametrize("ttype, role", [("imp", "model_init"), ("wr", "model_rewind")])
+    def test_the_writer_rewinds_without_a_read_and_a_resumed_process_reads_once(
+        self, small_state, tmp_path, ttype, role
+    ):
+        from turboprune_tpu.utils import rewind_roles
+
+        _, _, state = small_state
+        assert rewind_roles(ttype) == {role}
+        writer = ExperimentCheckpoints(tmp_path, keep=rewind_roles(ttype))
+        writer.save_model(role, state)
+        trained = state.replace(params=jax.tree.map(lambda x: x + 1.0, state.params))
+        for _ in range(2):
+            (back, source), reads = _reads_during(
+                lambda: _source_of(lambda: reset_weights(ttype, trained, writer))
+            )
+            assert (reads, source) == (0, "resident")
+            _assert_trees_equal(back.params, state.params)
+            _assert_trees_equal(back.batch_stats, state.batch_stats)
+
+        # Same directory, another process.
+        resumed = ExperimentCheckpoints(tmp_path, keep=rewind_roles(ttype))
+        for want in ((1, "disk"), (0, "resident")):
+            (back, source), reads = _reads_during(
+                lambda: _source_of(lambda: reset_weights(ttype, trained, resumed))
+            )
+            assert (reads, source) == want
+            _assert_trees_equal(back.params, state.params)
+
+    @pytest.mark.parametrize(
+        "ttype, rewind_optimizer, kept",
+        [
+            ("imp", False, {"model_init"}),
+            ("wr", False, {"model_rewind"}),
+            ("wr", True, {"model_rewind", "optimizer_rewind"}),
+            ("lrr", False, set()),
+            ("at_init", False, set()),
+        ],
+    )
+    def test_a_process_keeps_only_what_its_run_rewinds_to(
+        self, small_state, tmp_path, ttype, rewind_optimizer, kept
+    ):
+        """``lrr`` never rewinds, ``wr`` never to ``model_init``: a save of a
+        role the run does not rewind to fetches and holds nothing extra."""
+        from turboprune_tpu.utils import rewind_roles
+
+        _, _, state = small_state
+        assert rewind_roles(ttype, rewind_optimizer) == kept
+        ck = ExperimentCheckpoints(tmp_path, keep=rewind_roles(ttype, rewind_optimizer))
+        for role in ("model_init", "model_rewind"):
+            ck.save_model(role, state)
+        for role in ("optimizer_init", "optimizer_rewind"):
+            ck.save_optimizer(role, state.opt_state)
+        assert set(ck._resident) == kept
+        assert ExperimentCheckpoints(tmp_path)._keep == frozenset()  # the server's
+
+    def test_a_later_save_replaces_the_resident_target(self, small_state, tmp_path):
+        _, _, state = small_state
+        ck = ExperimentCheckpoints(tmp_path, keep={"model_init"})
+        ck.save_model("model_init", state)
+        second = state.replace(params=jax.tree.map(lambda x: x * 2.0, state.params))
+        ck.save_model("model_init", second)
+        _assert_trees_equal(reset_weights("imp", state, ck).params, second.params)
+        _assert_trees_equal(ck.load_model("model_init", state)["params"], second.params)
+
+    @pytest.mark.parametrize("from_disk", [False, True], ids=["writer", "resumed"])
+    def test_the_resident_target_is_a_host_tree_of_what_the_save_wrote(
+        self, small_state, tmp_path, from_disk
+    ):
+        """Params, packed masks and batch statistics on disk are the trees
+        the state held: keeping the fetched tree changed nothing written.
+        What a process keeps is numpy whether it wrote the target or read it:
+        Orbax restores onto the devices of the state it is shown, and a
+        device tree kept here would be aliased by ``replicate`` and deleted
+        by the donating step."""
+        _, _, state = small_state
+        ck = ExperimentCheckpoints(tmp_path, keep={"model_init"})
+        ck.save_model("model_init", state)
+        on_disk = ck.load_model("model_init", state)
+        _assert_trees_equal(on_disk, ck.model_state(state))
+        if from_disk:
+            assert any(isinstance(x, jax.Array) for x in jax.tree.leaves(on_disk["params"]))
+            ck = ExperimentCheckpoints(tmp_path)
+        held = ck.rewind_model("model_init", state)
+        assert set(held) == {"params", "batch_stats"}  # the masks are never rewound
+        _assert_trees_equal(held, {k: on_disk[k] for k in held})
+        assert all(isinstance(x, np.ndarray) for x in jax.tree.leaves(held))  # host, not HBM
+
+    def test_optimizer_rewind_is_resident_in_the_writer_and_read_once_elsewhere(
+        self, small_state, tmp_path
+    ):
+        _, _, state = small_state
+        warm = jax.tree.map(lambda x: x + 1, state.opt_state)
+        writer = ExperimentCheckpoints(tmp_path, keep={"optimizer_rewind"})
+        writer.save_optimizer("optimizer_init", state.opt_state)
+        writer.save_optimizer("optimizer_rewind", warm)
+        got, reads = _reads_during(lambda: writer.rewind_optimizer(state.opt_state))
+        assert reads == 0 and type(got) is type(state.opt_state)
+        _assert_trees_equal(got, warm)
+        resumed = ExperimentCheckpoints(tmp_path, keep={"optimizer_rewind"})
+        for want_reads in (1, 0):
+            got, reads = _reads_during(lambda: resumed.rewind_optimizer(state.opt_state))
+            assert reads == want_reads and type(got) is type(state.opt_state)
+            _assert_trees_equal(got, warm)
+            assert all(isinstance(x, np.ndarray) for x in jax.tree.leaves(got))
+
+
 class TestExperimentUtils:
     def _cfg(self, tmp_path):
         return compose(

@@ -30,13 +30,95 @@ def _cfg(tmp_path, *extra):
     )
 
 
+def _traced_run(cfg, harness_cls=None):
+    """``run(cfg)`` with its harness kept and its spans collected; a
+    KeyboardInterrupt (the tests' preemption) ends it early."""
+    from pathlib import Path
+
+    from turboprune_tpu.harness import PruningHarness
+    from turboprune_tpu.parallel.multihost import tree_fingerprint
+    from turboprune_tpu.utils import restore_pytree, tracing
+
+    held = {}
+
+    class Capturing(harness_cls or PruningHarness):
+        def __init__(self, *a, **k):
+            held["h"] = self  # before the base: a subclass may die in its own
+            super().__init__(*a, **k)
+
+    summaries = None
+    with tracing.span("t/run") as whole:
+        try:
+            _, summaries = run(cfg, harness_cls=Capturing)
+        except KeyboardInterrupt:
+            pass
+    h, s = held["h"], held["h"].state
+    d = Path(h.expt_dir)
+    return {
+        "cfg": cfg,
+        "harness": h,
+        "dir": d,
+        "summaries": summaries,
+        "spans": tracing.recorded(t0=whole.start, t1=whole.end),
+        "fingerprint": tree_fingerprint(
+            {"params": s.params, "masks": s.masks, "batch_stats": s.batch_stats}
+        ),
+        # As they are now: a later resume in this directory writes again.
+        "timing": pd.read_csv(t) if (t := d / "metrics" / "level_timing.csv").exists() else None,
+        "written": {
+            f"{sub}/{p.name}": tree_fingerprint(restore_pytree(p))
+            for sub in ("checkpoints", "artifacts")
+            for p in sorted((d / sub).iterdir())
+        },
+    }
+
+
+@pytest.fixture(scope="module")
+def whole_run(tmp_path_factory):
+    """The uninterrupted three-level IMP run both classes below read."""
+    return _traced_run(_cfg(tmp_path_factory.mktemp("imp")))
+
+
+def _killed_and_resumed(base, kill_level, *extra, harness_cls=None):
+    """Two runs in one directory: the first dies (the tests' preemption) once
+    ``model_level_{kill_level}`` is saved, the second takes it up at the next
+    level."""
+    from turboprune_tpu.harness import PruningHarness
+
+    class Killed(harness_cls or PruningHarness):
+        def __init__(self, *a, **k):
+            super().__init__(*a, **k)
+            save = self.ckpts.save_level
+
+            def dying(level, state):
+                save(level, state)
+                if level == kill_level:
+                    raise KeyboardInterrupt("simulated preemption")
+
+            self.ckpts.save_level = dying
+
+    killed = _traced_run(_cfg(base, *extra), Killed)
+    resumed = _traced_run(
+        _cfg(
+            base,
+            *extra,
+            "experiment_params.resume_experiment=true",
+            f"experiment_params.resume_experiment_stuff.resume_expt_name={killed['dir'].name}",
+            f"experiment_params.resume_experiment_stuff.resume_level={kill_level + 1}",
+        ),
+        harness_cls,
+    )
+    return killed, resumed
+
+
+def _named(run_, name):
+    return [s for s in run_["spans"] if s.name == name]
+
+
 class TestIterativeIMP:
     @pytest.fixture(scope="class")
-    def imp_run(self, tmp_path_factory):
-        tmp_path = tmp_path_factory.mktemp("imp")
-        cfg = _cfg(tmp_path)
-        expt_dir, summaries = run(cfg)
-        return cfg, expt_dir, summaries
+    def imp_run(self, whole_run):
+        return whole_run["cfg"], str(whole_run["dir"]), whole_run["summaries"]
 
     def test_ladder_lengths_and_densities(self, imp_run):
         _, _, summaries = imp_run
@@ -98,113 +180,99 @@ class TestIterativeIMP:
         np.testing.assert_allclose(summaries2[0]["density"], 0.64, atol=1e-6)
 
 
-class TestMidLevelResume:
-    """Epoch-granular checkpointing (beyond-reference): a run preempted
-    mid-level must resume at the saved epoch and finish BIT-IDENTICAL to an
-    uninterrupted run — params, masks, batch_stats and opt_state all match,
-    which also proves the loader's shuffle stream was restored."""
+class TestLevelHandOff:
+    """A continuous run hands its state from level to level in memory and
+    rewinds from the resident target; the directory is read only by a process
+    that does not hold the state. Three runs of one seed: ``whole`` is
+    uninterrupted, ``killed`` dies at level 0's save, ``resumed`` takes it up at
+    level 1, reading from disk once what every level used to read, and goes on
+    to level 2 as the process that wrote it would (``test_level_resume.py``
+    has the same for ``wr`` with its optimizer and for a cyclic run)."""
 
-    def _cfg(self, base, *extra):
-        return compose(
-            "cifar10_imp",
-            overrides=[
-                f"experiment_params.base_dir={base}",
-                "dataset_params.dataloader_type=synthetic",
-                "dataset_params.total_batch_size=16",
-                "dataset_params.synthetic_num_train=64",
-                "dataset_params.synthetic_num_test=32",
-                "experiment_params.epochs_per_level=5",
-                "experiment_params.checkpoint_every_epochs=2",
-                # target SPARSITY 0.2 -> density ladder [1.0, 0.8]: exactly
-                # two levels (0.8 would mean a density floor of 0.2 = EIGHT
-                # levels at prune_rate 0.2).
-                "pruning_params.target_sparsity=0.2",
-                "model_params.model_name=resnet18",
-                *extra,
-            ],
-        )
+    @pytest.fixture(scope="class")
+    def runs(self, whole_run, tmp_path_factory):
+        killed, resumed = _killed_and_resumed(tmp_path_factory.mktemp("handoff"), 0)
+        return {"whole": whole_run, "killed": killed, "resumed": resumed}
 
-    @staticmethod
-    def _fingerprint(harness):
-        from turboprune_tpu.parallel.multihost import tree_fingerprint
+    _named = staticmethod(_named)
 
-        s = harness.state
-        return tree_fingerprint(
-            {
-                "params": s.params,
-                "masks": s.masks,
-                "batch_stats": s.batch_stats,
-                "opt_state": s.opt_state,
-            }
-        )
+    def test_a_continuous_run_reads_nothing_back(self, runs):
+        whole = runs["whole"]
+        assert [s.attrs["level"] for s in self._named(whole, "level")] == [0, 1, 2]
+        assert not self._named(whole, "ckpt/read") and not self._named(whole, "level/load")
+        rewinds = self._named(whole, "level/rewind")
+        assert [(s.attrs["level"], s.attrs["source"]) for s in rewinds] == [
+            (1, "resident"),
+            (2, "resident"),
+        ]
+        timing = whole["timing"]
+        assert list(timing["level"]) == [0, 1, 2]
+        assert (timing["load_s"] == 0).all() and (timing["ckpt_read_s"] == 0).all()
 
-    def test_bit_identical_resume_after_preemption(self, tmp_path):
-        from pathlib import Path
+    def test_a_resumed_run_loads_once_and_reads_the_rewind_target_once(self, runs):
+        killed, resumed = runs["killed"], runs["resumed"]
+        assert [s.attrs["level"] for s in self._named(killed, "level")] == [0]
+        assert not self._named(killed, "level/rewind") and not self._named(killed, "ckpt/read")
+        assert [s.attrs["level"] for s in self._named(resumed, "level")] == [1, 2]
+        assert [s.attrs["level"] for s in self._named(resumed, "level/load")] == [1]
+        rewinds = self._named(resumed, "level/rewind")
+        assert [(s.attrs["level"], s.attrs["source"]) for s in rewinds] == [
+            (1, "disk"),
+            (2, "resident"),
+        ]
+        # model_level_0 and model_init, both in the resumed level: level 2
+        # reads nothing back.
+        assert [s.attrs["level"] for s in self._named(resumed, "ckpt/read")] == [1, 1]
 
-        from turboprune_tpu.harness import PruningHarness
+    def test_what_a_process_keeps_is_on_the_host(self, runs):
+        """The writer keeps the tree it fetched for the save. The resumed
+        process keeps what it read, and Orbax restores onto the devices of the
+        state it is shown: kept as that, ``replicate`` would alias it and the
+        donating step delete it before the second rewind."""
+        for run_ in (runs["whole"], runs["killed"], runs["resumed"]):
+            held = run_["harness"].ckpts._resident
+            assert set(held) == {"model_init"}
+            assert all(isinstance(x, np.ndarray) for x in jax.tree.leaves(held))
 
-        captured = {}
+    def test_killed_and_resumed_ends_where_the_continuous_run_ends(self, runs):
+        assert runs["resumed"]["fingerprint"] == runs["whole"]["fingerprint"]
+        assert runs["killed"]["fingerprint"] != runs["whole"]["fingerprint"]
 
-        class Capturing(PruningHarness):
-            def __init__(self, *a, **k):
-                super().__init__(*a, **k)
-                captured["h"] = self
+    def test_the_resident_rewind_target_survives_the_donating_steps(self, runs):
+        """Three trained levels donated their state to the step; a rewind
+        from the resident tree still gives model_init as it is on disk."""
+        from turboprune_tpu.utils import MODEL_INIT, reset_weights
 
-        # Uninterrupted reference run.
-        expt_a, _ = run(self._cfg(tmp_path / "a"), harness_cls=Capturing)
-        want = self._fingerprint(captured["h"])
+        h = runs["whole"]["harness"]
+        back = reset_weights("imp", h.state, h.ckpts)
+        on_disk = h.ckpts.load_model(MODEL_INIT, h.state)
+        for key in ("params", "batch_stats"):
+            jax.tree.map(
+                lambda a, b: np.testing.assert_array_equal(np.asarray(a), np.asarray(b)),
+                getattr(back, key),
+                on_disk[key],
+            )
+        trained = jax.tree.leaves(h.state.params)[0]
+        assert not np.array_equal(np.asarray(trained), np.asarray(jax.tree.leaves(back.params)[0]))
 
-        # Interrupted run: die right after the level-1 epoch-1 mid save.
-        class Preempted(Capturing):
-            def __init__(self, *a, **k):
-                super().__init__(*a, **k)
-                orig = self.ckpts.save_mid_level
-
-                def dying(level, epoch, state, meta):
-                    orig(level, epoch, state, meta)
-                    if (level, epoch) == (1, 1):
-                        raise KeyboardInterrupt("simulated preemption")
-
-                self.ckpts.save_mid_level = dying
-
-        cfg_b = self._cfg(tmp_path / "b")
-        with pytest.raises(KeyboardInterrupt):
-            run(cfg_b, harness_cls=Preempted)
-        expt_b = captured["h"].expt_dir
-        meta = captured["h"].ckpts.peek_mid_level()
-        assert meta["level"] == 1 and meta["epoch"] == 1
-
-        # Resume through the production path (resume_experiment config).
-        cfg_r = self._cfg(
-            tmp_path / "b",
-            "experiment_params.resume_experiment=true",
-            "experiment_params.resume_experiment_stuff.resume_expt_name="
-            + Path(expt_b).name,
-            "experiment_params.resume_experiment_stuff.resume_level=1",
-        )
-        expt_r, summaries = run(cfg_r, harness_cls=Capturing)
-        assert expt_r == expt_b
-        assert len(summaries) == 1
-        got = self._fingerprint(captured["h"])
-        assert got == want  # bit-identical to the uninterrupted run
-
-        # The level CSV and summary must cover the WHOLE level: the
-        # pre-preemption epoch rows ride in the mid-save header, so the
-        # resumed run's finish_level sees epochs 0..4, not just 2..4.
-        lv = pd.read_csv(
-            Path(expt_b) / "metrics" / "level_wise_metrics" / "level_1_metrics.csv"
-        )
-        assert list(lv["epoch"]) == [0, 1, 2, 3, 4]
-        assert summaries[0]["max_test_acc"] == pytest.approx(
-            float(lv["test_acc"].max())
-        )
-
-    def test_no_mid_checkpoint_when_disabled(self, tmp_path):
-        cfg = _cfg(tmp_path)  # checkpoint_every_epochs defaults to 0
-        from pathlib import Path
-
-        expt_dir, _ = run(cfg)
-        assert not (Path(expt_dir) / "checkpoints" / "mid_level").exists()
+    @pytest.mark.parametrize(
+        "role",
+        [
+            "checkpoints/model_init",
+            "checkpoints/model_level_0",
+            "checkpoints/model_level_1",
+            "checkpoints/model_level_2",
+            "artifacts/optimizer_init",
+        ],
+    )
+    def test_every_checkpoint_holds_what_a_run_that_read_from_disk_wrote(self, runs, role):
+        """``model_level_1`` of the continuous run came from memory, that of
+        the resumed run from ``model_level_0`` and ``model_init`` on disk, as
+        every level's did before: the restored trees are equal in every bit."""
+        whole, cut = runs["whole"]["written"], runs["resumed"]["written"]
+        assert sorted(whole) == sorted(cut) and len(whole) == 5
+        assert whole[role] == cut[role]
+        assert runs["killed"]["written"].get(role) in (whole[role], None)
 
 
 class TestPruneAtInit:
@@ -296,18 +364,27 @@ class TestOptimizerRewind:
         )
         h.ckpts.save_optimizer(OPTIMIZER_REWIND, h.state.opt_state)
 
-        h.setup_level(cfg.experiment_params.epochs_per_level)  # fresh level
-        assert int(optax.tree_utils.tree_get(h.state.opt_state, "count")) == 0
-        h.maybe_rewind_optimizer(level=1)
-        # momentum buffers came back ...
-        got_trace = optax.tree_utils.tree_get(h.state.opt_state, "trace")
-        jax.tree.map(
-            lambda a, b: np.testing.assert_array_equal(np.asarray(a), b),
-            got_trace,
-            saved_trace,
-        )
-        # ... but the schedule count did NOT
-        assert int(optax.tree_utils.tree_get(h.state.opt_state, "count")) == 0
+        # Twice: the second level trains on (and donates) what the first
+        # rewind handed out, and the resident copy must not have moved.
+        from turboprune_tpu.utils import tracing
+
+        with tracing.span("t/rewinds") as whole:
+            for _ in range(2):
+                h.setup_level(cfg.experiment_params.epochs_per_level)  # fresh level
+                assert int(optax.tree_utils.tree_get(h.state.opt_state, "count")) == 0
+                h.maybe_rewind_optimizer(level=1)
+                # momentum buffers came back ...
+                got_trace = optax.tree_utils.tree_get(h.state.opt_state, "trace")
+                jax.tree.map(
+                    lambda a, b: np.testing.assert_array_equal(np.asarray(a), b),
+                    got_trace,
+                    saved_trace,
+                )
+                # ... but the schedule count did NOT
+                assert int(optax.tree_utils.tree_get(h.state.opt_state, "count")) == 0
+                h.train_epoch()
+        # ... and from the copy this process kept at the save, not from disk.
+        assert not tracing.recorded("ckpt/read", whole.start, whole.end)
 
     def test_adamw_rewind_keeps_bias_correction_count(self, tmp_path):
         """Only the SCHEDULE state resets on rewind: AdamW's

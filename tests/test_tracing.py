@@ -151,15 +151,18 @@ def test_the_lowered_train_step_names_its_layers():
 
 
 # What a two-level IMP ladder records (ISSUE 24's table), and whether a level
-# without a prune (level 0) records it too.
+# without a prune (level 0) records it too. RESUMED: nowhere in a continuous
+# run, which hands its state on in memory; only in level 1 of a process that
+# starts from level 0's checkpoint.
+RESUMED = "resumed"
 LADDER_SPANS = {
     "harness/init": None, "init/mesh_model": None, "init/loaders": None,
     "init/state": None, "init/steps": None,
-    "level": True, "level/load": False, "level/prune": False, "level/rewind": False,
+    "level": True, "level/load": RESUMED, "level/prune": False, "level/rewind": False,
     "level/train": True, "level/setup": True, "epoch": True, "epoch/feed": True,
     "epoch/train": True, "epoch/eval": True, "epoch/log": True, "level/finish": True,
     "level/save": True, "ckpt/fetch": True, "ckpt/write": True, "ckpt/barrier": True,
-    "ckpt/read": False,
+    "ckpt/read": RESUMED,
 }  # fmt: skip
 
 
@@ -169,42 +172,66 @@ def ladder(tmp_path_factory):
     from turboprune_tpu.driver import run
 
     tmp = tmp_path_factory.mktemp("traced")
-    cfg = compose(
-        "cifar10_imp",
-        overrides=[
-            f"experiment_params.base_dir={tmp / 'experiments'}",
-            f"experiment_params.profile_dir={tmp / 'profile'}",
-            "experiment_params.num_devices=1",
-            "experiment_params.epochs_per_level=2",
-            "dataset_params.dataloader_type=synthetic",
-            # One scanned step of batch 8 an epoch: the CPU runs ResNet18's
-            # scanned epoch at seconds a step.
-            "dataset_params.total_batch_size=8",
-            "dataset_params.synthetic_num_train=8",
-            "dataset_params.synthetic_num_test=8",
-            # Two levels; keeping a tenth is a top_k the CPU makes in 5 s,
-            # not 13.
-            "pruning_params.prune_rate=0.9",
-            "pruning_params.target_sparsity=0.9",
-        ],
-    )
+    def cfg(*more):
+        return compose("cifar10_imp", overrides=[*common, *more])
+
+    common = [
+        f"experiment_params.base_dir={tmp / 'experiments'}",
+        "experiment_params.num_devices=1",
+        "experiment_params.epochs_per_level=2",
+        "dataset_params.dataloader_type=synthetic",
+        # One scanned step of batch 8 an epoch: the CPU runs ResNet18's
+        # scanned epoch at seconds a step.
+        "dataset_params.total_batch_size=8",
+        "dataset_params.synthetic_num_train=8",
+        "dataset_params.synthetic_num_test=8",
+        # Two levels; keeping a tenth is a top_k the CPU makes in 5 s,
+        # not 13.
+        "pruning_params.prune_rate=0.9",
+        "pruning_params.target_sparsity=0.9",
+    ]
     out = io.StringIO()
     with tracing.span("t/ladder") as whole, contextlib.redirect_stdout(out):
-        expt_dir, summaries = run(cfg)
+        expt_dir, summaries = run(cfg(f"experiment_params.profile_dir={tmp / 'profile'}"))
     assert len(summaries) == 2
     spans = tracing.recorded(t0=whole.start, t1=whole.end)
-    return {"spans": spans, "out": out.getvalue(), "expt_dir": Path(expt_dir), "profile": tmp / "profile"}
+    timing = pd.read_csv(Path(expt_dir) / "metrics" / "level_timing.csv")
+
+    # The same directory taken up at level 1 by a process that holds nothing,
+    # under a session of the test's own: ``profile_dir`` starts none past
+    # level 0, and the harness stops this one where level 1's set-up ends.
+    resumed = cfg(
+        "experiment_params.resume_experiment=true",
+        f"experiment_params.resume_experiment_stuff.resume_expt_name={Path(expt_dir).name}",
+        "experiment_params.resume_experiment_stuff.resume_level=1",
+    )
+    resumed_out = io.StringIO()
+    tracing.start_profile(tmp / "profile_resumed" / "level1_resumed")
+    try:
+        with tracing.span("t/ladder") as again, contextlib.redirect_stdout(resumed_out):
+            _, summaries = run(resumed)
+    finally:
+        tracing.stop_profile()
+    assert [s["level"] for s in summaries] == [1]
+    return {
+        "spans": spans, "out": out.getvalue(), "expt_dir": Path(expt_dir), "profile": tmp / "profile",
+        "timing": timing, "resumed_spans": tracing.recorded(t0=again.start, t1=again.end),
+        "resumed_out": resumed_out.getvalue(), "profile_resumed": tmp / "profile_resumed",
+    }  # fmt: skip
 
 
 @pytest.mark.parametrize("name", sorted(LADDER_SPANS))
 def test_the_ladder_records_every_span_of_the_table(ladder, name):
-    found = [s for s in ladder["spans"] if s.name == name]
-    assert found, name
     in_level_zero = LADDER_SPANS[name]
+    found = [s for s in ladder["spans"] if s.name == name]
+    if in_level_zero == RESUMED:
+        assert not found, name  # the continuous run read nothing back
+        found = [s for s in ladder["resumed_spans"] if s.name == name]
+    assert found, name
     if in_level_zero is None:
         assert all("level" not in s.attrs for s in found)
     else:
-        want = {0, 1} if in_level_zero else {1}
+        want = {0, 1} if in_level_zero is True else {1}
         assert {s.attrs["level"] for s in found} == want
     if name.startswith("epoch"):
         assert {s.attrs["epoch"] for s in found} == {0, 1}
@@ -213,7 +240,7 @@ def test_the_ladder_records_every_span_of_the_table(ladder, name):
 
 def test_every_recorded_name_is_one_the_time_line_knows(ladder):
     known = set(LADDER_SPANS) | {"t/ladder"}
-    assert {s.name for s in ladder["spans"]} <= known
+    assert {s.name for s in ladder["spans"] + ladder["resumed_spans"]} <= known
     terms_or_inside = set(tracing.TERMS) | {n for n in known if n.startswith(("ckpt/", "init/"))}
     containers = {"level", "level/train", "epoch", "t/ladder"}
     assert known - terms_or_inside == containers
@@ -222,7 +249,7 @@ def test_every_recorded_name_is_one_the_time_line_knows(ladder):
 def test_level_self_times_sum_to_the_level_and_land_in_the_csv(ladder):
     levels = [s for s in ladder["spans"] if s.name == "level"]
     assert [s.attrs["level"] for s in levels] == [0, 1]
-    rows = pd.read_csv(ladder["expt_dir"] / "metrics" / "level_timing.csv")
+    rows = ladder["timing"]
     assert list(rows.columns) == tracing.TIMING_COLUMNS and list(rows["level"]) == [0, 1]
     for level, (_, row) in zip(levels, rows.iterrows()):
         b = tracing.breakdown([level], ladder["spans"])
@@ -233,18 +260,48 @@ def test_level_self_times_sum_to_the_level_and_land_in_the_csv(ladder):
         parts = [c for c in rows.columns if c.endswith("_s") and not c.startswith(("total", "ckpt_", "compile"))]
         assert row[parts].sum() + row["ckpt_s"] == pytest.approx(row["total_s"])
     assert rows["rewind_s"][0] == 0 and rows["rewind_s"][1] > 0
-    assert rows["ckpt_read_s"][1] > 0 and rows["ckpt_write_s"].min() > 0
+    # A level of a continuous run reads nothing: the columns stay and say 0.
+    assert (rows["load_s"] == 0).all() and (rows["ckpt_read_s"] == 0).all()
+    assert rows["ckpt_write_s"].min() > 0
     # Level 0 compiles the epoch; level 1 reuses it and compiles its prune.
     assert rows["compiles"][0] > 0
 
 
-def test_the_operator_gets_a_time_line_per_level_and_one_for_setup(ladder):
-    lines = [ln for ln in ladder["out"].splitlines() if ln.startswith("[time] ")]
-    assert [ln.split(":")[0] for ln in lines] == ["[time] set-up", "[time] level 0", "[time] level 1"]
+def test_a_resumed_level_says_what_it_read_in_its_row_and_its_spans(ladder):
+    rows = pd.read_csv(ladder["expt_dir"] / "metrics" / "level_timing.csv")
+    assert list(rows["level"]) == [0, 1, 1]  # the resumed level's row follows
+    row = rows.iloc[2]
+    assert row["load_s"] > 0 and row["rewind_s"] > 0
+    (load,) = [s for s in ladder["resumed_spans"] if s.name == "level/load"]
+    (rewind,) = [s for s in ladder["resumed_spans"] if s.name == "level/rewind"]
+    reads = [s for s in ladder["resumed_spans"] if s.name == "ckpt/read"]
+    assert sorted(s.parent for s in reads) == sorted([load.id, rewind.id])
+    assert row["ckpt_read_s"] == pytest.approx(sum(s.seconds for s in reads))
+    assert row["ckpt_read_s"] < row["load_s"] + row["rewind_s"]
+    # Where the rewind target came from: the writer's memory, or the disk.
+    (continuous,) = [s for s in ladder["spans"] if s.name == "level/rewind"]
+    assert (continuous.attrs["source"], rewind.attrs["source"]) == ("resident", "disk")
+
+
+@pytest.mark.parametrize(
+    "out, titles, load",
+    [
+        ("out", ["[time] set-up", "[time] level 0", "[time] level 1"], False),
+        ("resumed_out", ["[time] set-up", "[time] level 1"], True),
+    ],
+)
+def test_the_operator_gets_a_time_line_per_level_and_one_for_setup(ladder, out, titles, load):
+    lines = [ln for ln in ladder[out].splitlines() if ln.startswith("[time] ")]
+    assert [ln.split(":")[0] for ln in lines] == titles
     assert "init " in lines[0] and "loaders " in lines[0] and "state " in lines[0]
-    for want in ("load ", "prune ", "rewind ", "setup ", "train ", "eval ", "log ", "save ", "other ", "compiled "):
-        assert want in lines[2], want
-    assert "prune " not in lines[1]
+    for want in ("prune ", "rewind ", "setup ", "train ", "eval ", "log ", "save ", "other ", "compiled "):
+        assert want in lines[-1], want
+    # Only a level that restored says so: "load 0.20 (read 0.19)", and its
+    # rewind "(read ...)" too.
+    assert ("load " in lines[-1]) == load and (lines[-1].count("(read ") == 2) == load
+    assert (lines[-1].count("(read ") == 0) != load
+    if not load:
+        assert "prune " not in lines[1]
 
 
 def _host_span_names(session: Path) -> set[str]:
@@ -263,13 +320,15 @@ def _host_span_names(session: Path) -> set[str]:
 
 
 @pytest.mark.parametrize(
-    "session, holds, lacks",
+    "where, session, holds, lacks",
     [
-        ("level0_epoch1", {"tp/epoch", "tp/epoch/train", "tp/epoch/eval", "tp/epoch/log"}, {"tp/level/rewind"}),
-        ("level0_to_1", {"tp/level/save", "tp/level/load", "tp/level/prune", "tp/level/rewind", "tp/level/setup", "tp/ckpt/read"}, {"tp/epoch/train"}),
+        ("profile", "level0_epoch1", {"tp/epoch", "tp/epoch/train", "tp/epoch/eval", "tp/epoch/log"}, {"tp/level/rewind"}),
+        ("profile", "level0_to_1", {"tp/level/save", "tp/level/prune", "tp/level/rewind", "tp/level/setup"}, {"tp/epoch/train", "tp/level/load", "tp/ckpt/read"}),
+        # The boundary as a resumed process crosses it: the session is the test's.
+        ("profile_resumed", "level1_resumed", {"tp/level/load", "tp/level/prune", "tp/level/rewind", "tp/level/setup", "tp/ckpt/read"}, {"tp/epoch/train", "tp/level/save"}),
     ],
-)
-def test_profile_dir_leaves_two_sessions_that_hold_the_spans(ladder, session, holds, lacks):
+)  # fmt: skip
+def test_profile_dir_leaves_two_sessions_that_hold_the_spans(ladder, where, session, holds, lacks):
     assert sorted(p.name for p in ladder["profile"].iterdir()) == ["level0_epoch1", "level0_to_1"]
-    names = _host_span_names(ladder["profile"] / session)
+    names = _host_span_names(ladder[where] / session)
     assert holds <= names and not (lacks & names)

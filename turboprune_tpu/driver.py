@@ -122,7 +122,9 @@ def prune_level(harness, density: float, level: int) -> None:
                 flush=True,
             )
     # Rewind AFTER pruning: masks survive, weights roll back per
-    # training_type (custom_models.py:112-146 semantics).
+    # training_type (custom_models.py:112-146 semantics). The rewind notes
+    # on this span the ``source`` of its target: ``resident`` in the process
+    # that holds it, ``disk`` at a resumed process's first rewind.
     with tracing.span("level/rewind"):
         harness.state = reset_weights(
             cfg.pruning_params.training_type, harness.state, harness.ckpts
@@ -176,14 +178,11 @@ def run(cfg: MainConfig, harness_cls: Optional[Type[PruningHarness]] = None):
     densities = generate_densities(
         pp.prune_method, pp.target_sparsity, pp.prune_rate
     )
-    if start_level:
-        if not harness.ckpts.has_level(start_level - 1):
-            raise FileNotFoundError(
-                f"resume_level={start_level} needs checkpoint "
-                f"model_level_{start_level - 1}"
-            )
-        restored = harness.ckpts.load_level(start_level - 1, harness.state)
-        harness.state = harness.state.replace(**restored)
+    if start_level and not harness.ckpts.has_level(start_level - 1):
+        raise FileNotFoundError(
+            f"resume_level={start_level} needs checkpoint "
+            f"model_level_{start_level - 1}"
+        )
 
     summaries = []
     try:
@@ -198,9 +197,16 @@ def run(cfg: MainConfig, harness_cls: Optional[Type[PruningHarness]] = None):
                         # weights.
                         prune_level(harness, density, level)
                 else:
-                    with tracing.span("level/load"):
-                        restored = harness.ckpts.load_level(level - 1, harness.state)
-                        harness.state = harness.state.replace(**restored)
+                    # A level starts from the state the last one left in
+                    # ``harness.state``: the very tree ``save_level`` wrote.
+                    # Only the first level of a resumed process holds no such
+                    # state and reads it back, and puts the train loader
+                    # where the levels before would have left it.
+                    if level == start_level:
+                        with tracing.span("level/load"):
+                            restored = harness.ckpts.load_level(level - 1, harness.state)
+                            harness.state = harness.state.replace(**restored)
+                            harness.resume_data_order(level)
                     prune_level(harness, density, level)
 
                 with tracing.span("level/train"):

@@ -35,6 +35,15 @@ class CyclicPruningHarness(PruningHarness):
                 "works)."
             )
 
+    def epochs_in_level(self) -> int:
+        """The cycles' epochs: the split may leave some of the budget out."""
+        ct = self.cfg.cyclic_training
+        return sum(
+            generate_cyclical_schedule(
+                self.cfg.experiment_params.epochs_per_level, ct.num_cycles, ct.strategy
+            )
+        )
+
     def train_one_level(
         self, epochs_per_level: int, level: int, num_cycles: int = 0
     ) -> dict:

@@ -74,6 +74,7 @@ from ..utils import (
     MetricsLogger,
     config_fingerprint,
     display_training_info,
+    rewind_roles,
     tracing,
 )
 from ..utils.wandb_logging import WandbRun
@@ -128,7 +129,10 @@ class PruningHarness:
                 f"per-host batch {per_host_batch} not divisible by local "
                 f"data-axis size — adjust total_batch_size or num_devices"
             )
-        self.ckpts = ExperimentCheckpoints(self.expt_dir)
+        pp = cfg.pruning_params
+        self.ckpts = ExperimentCheckpoints(
+            self.expt_dir, keep=rewind_roles(pp.training_type, pp.rewind_optimizer)
+        )
         # Identity stamps for the mid-level slot: a slot whose config hash
         # disagrees with the live config is never restored (it holds
         # mid-trajectory state trained under different knobs).
@@ -282,7 +286,7 @@ class PruningHarness:
         pp = self.cfg.pruning_params
         if level > 0 and pp.training_type == "wr" and pp.rewind_optimizer:
             fresh = self.state.opt_state
-            opt = self.ckpts.load_optimizer(OPTIMIZER_REWIND, fresh)
+            opt = self.ckpts.rewind_optimizer(fresh)  # the resident host tree
             is_sched = lambda x: isinstance(x, optax.ScaleByScheduleState)
             opt = jax.tree.map(
                 lambda r, f: f if is_sched(r) else r, opt, fresh, is_leaf=is_sched
@@ -878,6 +882,19 @@ class PruningHarness:
                 full = self._full_state()
                 self.ckpts.save_model(MODEL_REWIND, full)
                 self.ckpts.save_optimizer(OPTIMIZER_REWIND, full.opt_state)
+
+    def epochs_in_level(self) -> int:
+        """The passes over the train loader that one level makes."""
+        return self.cfg.experiment_params.epochs_per_level
+
+    def resume_data_order(self, level: int) -> None:
+        """Level-granular resume: the train loader goes on where the levels
+        before ``level`` of a continuous run would have left it, so the
+        resumed level sees that run's shuffle and augmentation (tier 2
+        below; tier 3's warning for loaders that cannot)."""
+        self._restore_train_stream(
+            {"train_loader_epoch": level * self.epochs_in_level()}, level
+        )
 
     def _restore_train_stream(self, mid: dict, level: int) -> None:
         """Restore the train loader's data-order state on mid-level resume.

@@ -12,6 +12,7 @@ from .checkpoint import (
     reset_weights,
     restore_model_tree,
     restore_pytree,
+    rewind_roles,
     save_model_tree,
     save_pytree,
     unpack_mask_tree,
@@ -30,6 +31,7 @@ from .experiment import (
 __all__ = [
     "ExperimentCheckpoints",
     "reset_weights",
+    "rewind_roles",
     "save_pytree",
     "restore_pytree",
     "save_model_tree",

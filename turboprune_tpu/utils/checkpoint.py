@@ -17,6 +17,15 @@ Rewind semantics (reference PruneModel.reset_weights,
 custom_models.py:112-146): imp -> restore params+batch_stats from init,
 wr -> from rewind, lrr / at_init -> keep trained weights; masks are NEVER
 restored — the freshly pruned masks always survive a rewind.
+
+The directory is for processes that do not hold the state: a resumed run and
+the server. A continuous run reads nothing back from it. The level loop hands
+its state on in memory (driver.run), and the rewind targets are RESIDENT: the
+process that saves the target its run rewinds to (``rewind_roles``) keeps the
+host tree it fetched for the save, and every later rewind takes it from there.
+A process that did not write it (a resume) reads it from disk once, at first
+use, and keeps it as a host tree too. A rewind's host-to-device copy is the
+``replicate`` of the level's set-up (``level/setup``), not the rewind's own.
 """
 
 from __future__ import annotations
@@ -40,6 +49,17 @@ MODEL_REWIND = "model_rewind"
 OPTIMIZER_INIT = "optimizer_init"
 OPTIMIZER_REWIND = "optimizer_rewind"
 MID_LEVEL = "mid_level"
+# training_type -> the model role a post-prune rewind restores, and what of
+# it: never the masks.
+_REWIND_ROLE = {"imp": MODEL_INIT, "wr": MODEL_REWIND}
+_REWOUND = ("params", "batch_stats")
+
+
+def rewind_roles(training_type: str, rewind_optimizer: bool = False) -> frozenset:
+    """The roles a run of this training type rewinds to: what the process
+    that saves them keeps (``ExperimentCheckpoints(keep=...)``)."""
+    roles = {_REWIND_ROLE.get(training_type), OPTIMIZER_REWIND if rewind_optimizer else None}
+    return frozenset(roles - {None})
 
 _LEVEL_RE = re.compile(r"^model_level_(\d+)$")
 
@@ -64,6 +84,17 @@ def _primary_only_checkpointer() -> ocp.StandardCheckpointer:
     )
 
 
+def fetch_to_host(tree: PyTree) -> PyTree:
+    """Every device leaf as numpy, under a ``ckpt/fetch`` span. device_get
+    works per host on replicated arrays; a leaf that is on the host already
+    passes through."""
+    with tracing.span("ckpt/fetch"):
+        return jax.tree.map(
+            lambda x: np.asarray(jax.device_get(x)) if isinstance(x, jax.Array) else x,
+            tree,
+        )
+
+
 def save_pytree(path: str | Path, tree: PyTree) -> None:
     """Atomic directory-style save (overwrites an existing checkpoint).
 
@@ -76,20 +107,15 @@ def save_pytree(path: str | Path, tree: PyTree) -> None:
 
     REQUIREMENT: on >1 process the experiment dir must be on storage every
     host can read (NFS/GCS/localhost-shared disk) — restore_pytree is called
-    by ALL hosts (reset_weights / optimizer rewind / level resume)."""
+    by ALL hosts of a RESUMED run (level resume, and its first rewind)."""
     from ..parallel.multihost import is_primary, sync_hosts
 
     path = Path(path).resolve()
     if is_primary():
-        # device_get works per-host on replicated arrays; saving numpy keeps
-        # the array leaves fully addressable for the single-process save.
-        with tracing.span("ckpt/fetch"):
-            host_tree = jax.tree.map(
-                lambda x: np.asarray(jax.device_get(x))
-                if isinstance(x, jax.Array)
-                else x,
-                tree,
-            )
+        # Saving numpy keeps the array leaves fully addressable for the
+        # single-process save. A rewind target comes fetched already, by the
+        # caller that keeps it.
+        host_tree = fetch_to_host(tree)
         with tracing.span("ckpt/write"):
             ckptr = _primary_only_checkpointer()
             if path.exists():
@@ -220,12 +246,20 @@ class ExperimentCheckpoints:
     """Role-addressed checkpoints under an experiment directory (the
     reference's checkpoints/ + artifacts/ split, harness_utils.py:90-93)."""
 
-    def __init__(self, expt_dir: str | Path):
+    def __init__(self, expt_dir: str | Path, keep: frozenset = frozenset()):
         self.expt_dir = Path(expt_dir)
         self.checkpoints_dir = self.expt_dir / "checkpoints"
         self.artifacts_dir = self.expt_dir / "artifacts"
         self.checkpoints_dir.mkdir(parents=True, exist_ok=True)
         self.artifacts_dir.mkdir(parents=True, exist_ok=True)
+        # The rewind targets this process holds, as host trees by role (module
+        # docstring): those of ``keep`` from the moment they are saved, any
+        # other from its first use. Host memory, not HBM, and never handed to
+        # a step: the step donates its state, and ``replicate`` would alias a
+        # device tree, so a rewind gives the state a fresh device copy of a
+        # numpy one (``replicate`` in ``setup_level``).
+        self._keep = frozenset(keep)
+        self._resident: dict[str, PyTree] = {}
 
     # --- path helpers -----------------------------------------------------
     def model_path(self, role: str) -> Path:
@@ -246,7 +280,13 @@ class ExperimentCheckpoints:
         }
 
     def save_model(self, role: str, state) -> None:
-        save_model_tree(self.model_path(role), self.model_state(state))
+        tree = self.model_state(state)
+        if role in self._keep:
+            # On EVERY process: each keeps its own copy of the replicated
+            # state. The primary's save below finds the fetch done.
+            tree = fetch_to_host(tree)
+            self._resident[role] = {k: tree[k] for k in _REWOUND}
+        save_model_tree(self.model_path(role), tree)
 
     def load_model(self, role: str, like_state) -> dict:
         return restore_model_tree(
@@ -407,26 +447,54 @@ class ExperimentCheckpoints:
 
     # --- optimizer roles --------------------------------------------------
     def save_optimizer(self, role: str, opt_state) -> None:
+        if role in self._keep:  # optimizer_rewind, like a model rewind target
+            opt_state = self._resident[role] = fetch_to_host(opt_state)
         save_pytree(self.optimizer_path(role), opt_state)
 
     def load_optimizer(self, role: str, like_opt_state):
         return restore_pytree(self.optimizer_path(role), like_opt_state)
 
+    # --- resident rewind targets -------------------------------------------
+    def _held(self, role: str, read) -> PyTree:
+        """``role``'s rewind target as a host tree: resident, or ``read`` from
+        disk this once, fetched (Orbax restores onto the devices of the state
+        it is shown) and kept."""
+        if role not in self._resident:
+            self._resident[role] = fetch_to_host(read())
+        return self._resident[role]
+
+    def rewind_model(self, role: str, like_state) -> dict:
+        """``{"params", "batch_stats"}`` of ``model_init`` / ``model_rewind``.
+        The open span (``level/rewind``) learns where they came from."""
+        tracing.note(source="resident" if role in self._resident else "disk")
+
+        def read():
+            restored = self.load_model(role, like_state)
+            return {k: restored[k] for k in _REWOUND}
+
+        return self._held(role, read)
+
+    def rewind_optimizer(self, like_opt_state):
+        """``optimizer_rewind``, likewise."""
+        return self._held(
+            OPTIMIZER_REWIND, lambda: self.load_optimizer(OPTIMIZER_REWIND, like_opt_state)
+        )
+
 
 def reset_weights(training_type: str, state, ckpts: ExperimentCheckpoints):
     """Post-prune rewind (reference reset_weights semantics,
     custom_models.py:112-146): restores params + batch_stats from the role'd
-    checkpoint, KEEPS the current (just-pruned) masks.
+    rewind target (the resident host tree, ``ExperimentCheckpoints.rewind_model``;
+    the leaves reach the device with the level's ``replicate``, in
+    ``level/setup``), KEEPS the current (just-pruned) masks.
 
       imp      -> model_init
       wr       -> model_rewind
       lrr      -> no-op (learning-rate rewinding keeps trained weights)
       at_init  -> no-op (PaI never rewinds)
     """
-    role = {"imp": MODEL_INIT, "wr": MODEL_REWIND}.get(training_type)
+    role = _REWIND_ROLE.get(training_type)
     if role is None:
         return state
-    restored = ckpts.load_model(role, state)
-    return state.replace(
-        params=restored["params"], batch_stats=restored["batch_stats"]
-    )
+    target = ckpts.rewind_model(role, state)
+    return state.replace(params=target["params"], batch_stats=target["batch_stats"])

@@ -98,6 +98,15 @@ def span(name: str, **attrs: Any) -> Span:
     return Span(name, attrs)
 
 
+def note(**attrs: Any) -> None:
+    """Add attributes to the innermost span open on this thread, for what is
+    known only inside it; nothing where none is open. The recorder's span gets
+    them, not its TraceAnnotation, which the profiler took at entry."""
+    stack = _stack()
+    if stack:
+        stack[-1].attrs.update(attrs)
+
+
 def recorded(
     name: Optional[str] = None, t0: float = float("-inf"), t1: float = float("inf")
 ) -> list[Span]:
@@ -164,8 +173,9 @@ def _short(name: str) -> str:
 
 
 def line(title: str, b: dict) -> str:
-    """``[time] level 3: 3.47 s = load 0.24 (read 0.22) + ... + other 0.01;
-    compiled 0 modules, 0.0 s``."""
+    """``[time] level 3: 2.07 s = prune 0.11 + ... + save 0.34 (fetch 0.07,
+    write 0.27, barrier 0.00) + other 0.03; compiled 0 modules, 0.0 s``. A
+    term is named only where its span ran: ``load`` in a resumed level."""
     parts = []
     for name in (n for n in TERMS if n in b["terms"]):
         part = f"{_short(name)} {b['terms'][name]:.2f}"
