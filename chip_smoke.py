@@ -215,7 +215,7 @@ def _observed_harness(levels: list, densities: list):
                         (d.platform, d.id) for d in leaf.devices()
                     ),
                     "step_cache_entries": len(self._step_cache),
-                    "step_executables": self._train_step._cache_size(),
+                    "step_executables": self._steps.train_step._cache_size(),
                     "expt_dir": self.expt_dir,
                 }
             )

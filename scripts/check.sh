@@ -59,9 +59,11 @@
 #      predict) and the jitted train step, and fail on any XLA compile
 #      not attributed to a manifest entry, or any compiled (plan,
 #      bucket) outside the declared surface.
-#  11. tier-1 fast tests        — the same command ROADMAP.md pins,
-#      including its plugin surface (-p no:xdist -p no:randomly), so the
-#      gate and tier-1 agree on what "the suite" is.
+#  11. tier-1 fast tests        — the suite as the driver runs it (the
+#      command of /root/TESTS_LAST_RUN.json: six xdist workers, one file
+#      a worker at a time; its ALLOW_MULTIPLE_LIBTPU_LOAD is the
+#      driver's to set, not a repository file's), under the driver's
+#      limit of 1,470 s. One process cannot finish inside that limit.
 # Each stage prints its wall time (even when it fails, so slow-AND-broken
 # is visible as both). Exits nonzero if any stage fails. Run from
 # anywhere: paths resolve relative to the repo root.
@@ -117,9 +119,10 @@ run_stage "exec-manifest round-trip (static compile surface vs checked-in)" \
 run_stage "compile audit (runtime compiles attributed to the manifest)" \
     env JAX_PLATFORMS=cpu python -m turboprune_tpu.analysis --compile-audit all
 
-run_stage "tier-1 tests (fast tier, CPU)" \
-    env JAX_PLATFORMS=cpu python -m pytest tests/ -q -m 'not slow' \
+run_stage "tier-1 tests (fast tier, CPU, six workers)" \
+    timeout -k 10 1470 env JAX_PLATFORMS=cpu \
+    python -m pytest tests/ -q -m 'not slow' \
     --continue-on-collection-errors -p no:cacheprovider \
-    -p no:xdist -p no:randomly
+    -p xdist -n 6 --dist loadfile -p no:randomly
 
 echo "check.sh: all gates passed"

@@ -354,3 +354,37 @@ def test_nm_sparsity_composes_with_compact_train():
     assert cfg.experiment_params.nm_sparsity == "4:8"
     assert cfg.experiment_params.nm_transposable is False
     assert cfg.experiment_params.compact_train is True
+
+
+@pytest.mark.parametrize("doc", ["README.md", "PARITY.md"])
+def test_a_document_names_only_files_that_exist(doc):
+    """Every source file, document or directory the README and the parity map
+    name in backticks, and every ``python <file>`` of a command block, is in
+    the tree: a README that tells its reader to run a deleted program, or a
+    parity row whose evidence is gone, fails here."""
+    import re
+    from pathlib import Path
+
+    root = Path(__file__).resolve().parents[1]
+    text = (root / doc).read_text(encoding="utf-8")
+    source = r"[\w./-]*\w\.(?:py|md|json|jsonl|sh|cpp|ini|toml)"
+    named = set(re.findall(rf"python3? ({source})", text))
+    for token in re.findall(r"`([^`\n]+)`", text):
+        # `path`, `path:line`, `path::test`, `python path`, `conf/x.yaml`, `dir/`
+        m = re.fullmatch(
+            rf"(?:python3? )?({source}|conf/[\w./-]+|(?:\w+/)+)(?:::?[\w:.-]+)?", token.strip()
+        )
+        if m:
+            named.add(m.group(1))
+    assert len(named) > 40  # the pattern still finds what the document names
+
+    def exists(name: str) -> bool:
+        if any((base / name).exists() for base in (root, root / "turboprune_tpu")):
+            return True
+        return "/" not in name and any((root / "turboprune_tpu").rglob(name))
+
+    # experiments/<dir> is made at run time; path/to/ is the linter's example;
+    # tp/ is the prefix of the program's spans.
+    made_up = ("experiments/", "path/to/", "tp/")
+    missing = sorted(n for n in named if not exists(n) and not n.startswith(made_up))
+    assert not missing, f"{doc} names files that are not in the tree: {missing}"

@@ -68,8 +68,8 @@ BUCKETS = (2,)  # one bucket: every compile in this module is deliberate
 
 # --------------------------------------------------------------- fixtures
 def _channel_structured_masks(params, graph, kill_frac):
-    """Kill the smallest-L2 fan-out slices per compactable space (the bench
-    helper's logic) — the structure dead-channel compaction rewards."""
+    """Kill the smallest-L2 fan-out slices per compactable space — the
+    structure dead-channel compaction rewards."""
     from turboprune_tpu.ops import masking
 
     masks = jax.tree.map(

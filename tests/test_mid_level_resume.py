@@ -22,9 +22,13 @@ class TestMidLevelResume:
             overrides=[
                 f"experiment_params.base_dir={base}",
                 "dataset_params.dataloader_type=synthetic",
-                "dataset_params.total_batch_size=16",
-                "dataset_params.synthetic_num_train=64",
-                "dataset_params.synthetic_num_test=32",
+                # Two scanned steps of batch 8 an epoch on two devices: a
+                # scanned ResNet18 step of batch 16 takes XLA's CPU backend
+                # 4-5 s, and this test trains 45 of them over its three runs.
+                "dataset_params.total_batch_size=8",
+                "dataset_params.synthetic_num_train=16",
+                "dataset_params.synthetic_num_test=8",
+                "experiment_params.num_devices=2",
                 "experiment_params.epochs_per_level=5",
                 "experiment_params.checkpoint_every_epochs=2",
                 # target SPARSITY 0.2 -> density ladder [1.0, 0.8]: exactly

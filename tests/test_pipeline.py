@@ -4,10 +4,8 @@ Covers the engine contract the loaders now depend on (ordering, bounded
 depth, exception propagation with the worker's traceback, deterministic
 shutdown, no deadlock on early consumer exit), loader-level equivalence of
 the chunked iterator, BIT-EXACT parity of ``make_scan_chunk(K)`` with K
-sequential train steps, the end-to-end streamed chunked harness path on
-synthetic .tpk data (dispatch count reduced by K×), and the bench.py
-headline-honesty regression (a skipped headline stage must print
-``value: null`` + ``skipped``, never a fake measured 0.0).
+sequential train steps, and the end-to-end streamed chunked harness path
+on synthetic .tpk data (dispatch count reduced by K×).
 """
 
 import threading
@@ -398,8 +396,8 @@ class TestStreamedChunkedHarness:
         harness = PruningHarness(cfg, ("smoke", str(tmp_path / "expt")))
         harness.setup_level(1)
         calls = {"scan": 0, "step": 0}
-        orig_scan = harness._scan_chunk
-        orig_step = harness._train_step
+        orig_scan = harness._steps.scan_chunk
+        orig_step = harness._steps.train_step
 
         def counting_scan(*a):
             calls["scan"] += 1
@@ -409,8 +407,9 @@ class TestStreamedChunkedHarness:
             calls["step"] += 1
             return orig_step(*a)
 
-        harness._scan_chunk = counting_scan
-        harness._train_step = counting_step
+        harness._steps = harness._steps._replace(
+            scan_chunk=counting_scan, train_step=counting_step
+        )
         row = harness.train_epoch()
         # 48 samples / batch 8 = 6 batches; K=3 -> 2 scans, no tail steps.
         assert calls == {"scan": 2, "step": 0}
@@ -418,42 +417,3 @@ class TestStreamedChunkedHarness:
         stats = harness.loaders.train_loader.last_pipeline_stats
         assert stats["batches_decoded"] == 6
         assert stats["items_emitted"] == 2  # K batches per emitted chunk
-
-
-def _load_bench_module():
-    import importlib.util
-    from pathlib import Path
-
-    path = Path(__file__).resolve().parents[1] / "bench.py"
-    spec = importlib.util.spec_from_file_location("_bench_under_test", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-class TestBenchHeadlineHonesty:
-    """A headline stage that failed with nothing cached once printed
-    ``"value": 0.0, "vs_baseline": 0.0`` — a stage that did not run must
-    never look like a measured zero."""
-
-    def test_unmeasured_headline_is_null_and_skipped(self):
-        bench = _load_bench_module()
-        record = bench._headline_record(None, {"resnet18_error": "boom"})
-        assert record["value"] is None
-        assert record["vs_baseline"] is None
-        assert "skipped" in record
-
-    def test_legacy_cached_zero_is_scrubbed(self):
-        # A stages.json written by the pre-fix bench can hold a fake 0.0;
-        # replaying it must also come out null+skipped, not measured-zero.
-        bench = _load_bench_module()
-        record = bench._headline_record(0.0, {})
-        assert record["value"] is None
-        assert "skipped" in record
-
-    def test_measured_headline_round_trips(self):
-        bench = _load_bench_module()
-        record = bench._headline_record(4642.0, {})
-        assert record["value"] == 4642.0
-        assert record["vs_baseline"] == pytest.approx(1.0, rel=1e-2)
-        assert "skipped" not in record

@@ -11,18 +11,24 @@ amplify that noise chaotically over steps (measured: 3e-7 after 1 step,
 asserts a TIGHT bound after 2 steps — where any semantic bug (wrong fold,
 stale batch_stats, skipped step) shows up as O(1) divergence — and an
 amplification-aware bound after the full epoch.
+
+``TestStepRecord`` holds the harness to the one record of executables a
+level runs, and the scanned epoch and the loader's augmentation to the
+module names the benchmark reads from the device trace.
 """
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
+from test_harness import _cfg
 
 from turboprune_tpu.data.synthetic import SyntheticLoaders
 from turboprune_tpu.models import create_model
 from turboprune_tpu.parallel import (
     create_mesh,
     epoch_sharding,
-    make_sharded_scan_epoch,
+    make_sharded_scan_chunk,
     make_sharded_train_step,
     replicate,
     shard_batch,
@@ -30,7 +36,7 @@ from turboprune_tpu.parallel import (
 from turboprune_tpu.train import (
     create_optimizer,
     create_train_state,
-    make_scan_epoch,
+    make_scan_chunk,
     make_train_step,
 )
 
@@ -77,8 +83,8 @@ def test_scan_epoch_matches_per_step_loop():
         "CIFAR10", batch_size=16, image_size=8, num_classes=4,
         num_train=64, num_test=16, seed=0,
     )
-    scan = make_sharded_scan_epoch(
-        make_scan_epoch(raw), mesh, donate_state=False
+    scan = make_sharded_scan_chunk(
+        make_scan_chunk(raw), mesh, donate_state=False
     )
     batches = loaders2.train_loader.epoch_arrays()
 
@@ -128,8 +134,6 @@ def test_scan_epoch_matches_per_step_loop():
 
 
 def test_epoch_arrays_shapes_and_train_only():
-    import pytest
-
     loaders = SyntheticLoaders(
         "CIFAR10", batch_size=16, image_size=8, num_classes=4,
         num_train=70, num_test=16, seed=0,
@@ -179,3 +183,83 @@ def test_scan_eval_matches_per_batch_eval():
     np.testing.assert_allclose(
         float(scan_sums["correct"]), float(loop_sums["correct"])
     )
+
+
+class TestStepRecord:
+    """One builder makes what a level runs (``PruningHarness._build_steps``),
+    one record of it is live, and the two programs the benchmark reads from
+    the device trace keep the module names it looks for."""
+
+    @pytest.fixture(scope="class")
+    def harness(self, tmp_path_factory):
+        from turboprune_tpu.harness import PruningHarness
+
+        base = tmp_path_factory.mktemp("steps")
+        cfg = _cfg(base, "experiment_params.nm_sparsity='2:4'")
+        return PruningHarness(cfg, ("steps", str(base / "expt")))
+
+    @pytest.mark.parametrize(
+        "key, name",
+        [("step_program", "jit_scan_chunk"), ("augment_program", "jit_augment_epoch")],
+    )
+    def test_the_benchmark_finds_the_program_by_its_module_name(
+        self, harness, key, name, monkeypatch
+    ):
+        """``benchmarks/workloads/*.json`` name the resident epoch's program
+        and the device loader's augmentation by their traced module names;
+        renamed, ``step_ms``, ``step_mfu_pct`` and ``augment_ms`` read
+        nothing and say nothing."""
+        import json
+        import re
+        from pathlib import Path
+
+        from turboprune_tpu.data import cifar
+        from turboprune_tpu.parallel import epoch_sharding
+
+        train_loader = harness.loaders.train_loader
+        if key == "step_program":  # what train_epoch runs on a resident loader
+            batches = jax.device_put(train_loader.epoch_arrays(), epoch_sharding(harness.mesh))
+            text = harness._steps.scan_chunk.lower(harness.state, batches).as_text()
+        else:  # as the loader calls it at the start of an epoch
+            real, lowered = cifar.augment_epoch, []
+
+            def lowering(*a, **k):
+                lowered.append(real.lower(*a, **k).as_text())
+                return real(*a, **k)
+
+            monkeypatch.setattr(cifar, "augment_epoch", lowering)
+            train_loader.epoch_arrays()
+            (text,) = lowered
+        assert re.match(r"module @(\w+)", text).group(1) == name
+        cells = sorted((Path(__file__).parents[1] / "benchmarks" / "workloads").glob("*.json"))
+        assert cells and all(json.loads(c.read_text())["params"][key] == name for c in cells)
+
+    def test_one_record_a_budget_and_the_dense_one_back_after_a_plan(self, harness):
+        h = harness
+        h.setup_level(2)
+        dense = h._steps
+        h.setup_level(2)
+        assert h._steps is dense
+        h.setup_level(3)
+        other = h._steps
+        assert other is not dense and other.scan_chunk is not dense.scan_chunk
+        # Eval does not depend on the budget: one executable for all of them.
+        assert (other.eval_step, other.scan_eval) == (dense.eval_step, dense.scan_eval)
+        assert h._scan_eval is other.scan_eval  # the name the benchmark's job reads
+
+        # A planned level: half of the head's input rows dead routes it gathered.
+        h.setup_level(2)
+        masks = jax.tree.map(
+            lambda m: None if m is None else np.array(m),
+            h.state.masks,
+            is_leaf=lambda x: x is None,
+        )
+        masks["fc"]["kernel"][1::2, :] = False
+        h.state = h.state.replace(masks=masks)
+        h._enter_plan()
+        assert h._plan_ctx is not None and h._plan_ctx["plan"].kind == "nm"
+        (planned,) = h._plan_step_cache.values()
+        assert h._steps is planned and h._scan_eval is planned.scan_eval
+        assert planned.scan_eval is not dense.scan_eval
+        h._exit_plan()
+        assert h._plan_ctx is None and h._steps is dense

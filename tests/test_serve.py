@@ -505,28 +505,3 @@ class TestSatellites:
         )
         with pytest.raises(ConfigError, match="cyclic"):
             run_cyclic(cfg)
-
-    def test_bench_headline_record_honesty(self):
-        """ADVICE r5 medium: a skipped headline stage must publish null +
-        a top-level marker, never a measured-looking 0.0."""
-        import importlib.util
-        from pathlib import Path
-
-        spec = importlib.util.spec_from_file_location(
-            "bench", Path(__file__).resolve().parents[1] / "bench.py"
-        )
-        bench = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(bench)
-
-        rec = bench._headline_record(None, {"resnet18_error": "boom"})
-        assert rec["value"] is None
-        assert rec["vs_baseline"] is None
-        assert "skipped" in rec
-
-        rec = bench._headline_record(4642.0, {})
-        assert rec["value"] == 4642.0
-        assert rec["vs_baseline"] == 1.0
-        assert "skipped" not in rec
-
-        rec = bench._headline_record(None, {}, error="watchdog: stalled")
-        assert rec["value"] is None and rec["error"].startswith("watchdog")

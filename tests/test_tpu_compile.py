@@ -76,7 +76,7 @@ def no_persistent_cache():
 
 
 # DeiT-small on one chip (batch 64 x 6 heads of 64, 197 tokens padded to
-# 256) and the long-sequence shape bench.py times.
+# 256) and a long-sequence shape.
 @pytest.mark.parametrize("backward", [False, True], ids=["forward", "grad"])
 @pytest.mark.parametrize("shape", [(384, 256, 64), (48, 1024, 64)])
 def test_flash_kernel_compiles_for_v5e(

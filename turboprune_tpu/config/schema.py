@@ -364,7 +364,7 @@ PLANNER_AUTOTUNE_MODES = ("off", "cost", "measure")
 class PlannerConfig:
     """Execution-planner routing knobs (sparse/plan.py): ONE config surface
     for the thresholds that decide which sparse backend each level/layer
-    runs, shared by the harness, serving, and the bench."""
+    runs, shared by the harness and serving."""
 
     # Minimum fraction of parameters channel-slicing must remove before a
     # level is re-instantiated physically smaller (compile + state-slice

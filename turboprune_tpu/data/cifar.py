@@ -224,7 +224,7 @@ class DeviceCifarLoader:
     def epoch_arrays(self) -> Batch:
         """The whole epoch stacked on a step axis: images [S, B, H, W, C],
         labels [S, B] — input for the lax.scan epoch runner
-        (train/steps.py make_scan_epoch): one dispatch per EPOCH instead of
+        (train/steps.py make_scan_chunk): one dispatch per EPOCH instead of
         per step. Train-mode only (needs drop_last's uniform batches)."""
         if not self.drop_last:
             raise ValueError("epoch_arrays requires drop_last (train mode)")

@@ -27,8 +27,9 @@ Contract:
   * ``close()`` is idempotent, joins the transfer thread, cancels pending
     decode tasks, and never deadlocks — even when the consumer abandons
     the iterator mid-epoch
-  * ``stats()`` reports per-stage wall time so a bench round can say
-    whether an epoch was decode-bound (``decode_wait_s``), transfer-bound
+  * ``stats()`` reports per-stage wall time so a reader of the loader's
+    ``last_pipeline_stats`` (tests/test_pipeline.py today) can say whether
+    an epoch was decode-bound (``decode_wait_s``), transfer-bound
     (``transfer_wait_s``) or compute-bound (``consumer_wait_s``)
 
 Bounded-memory guarantee: decoded-but-unconsumed batches never exceed

@@ -29,7 +29,7 @@ from __future__ import annotations
 import time
 from contextlib import contextmanager
 from pathlib import Path
-from typing import Any, Optional
+from typing import Any, Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -47,7 +47,6 @@ from ..parallel import (
     epoch_sharding,
     make_sharded_eval_step,
     make_sharded_scan_chunk,
-    make_sharded_scan_epoch,
     make_sharded_scan_eval,
     make_sharded_train_step,
     replicate,
@@ -60,7 +59,6 @@ from ..train import (
     eval_params,
     make_eval_step,
     make_scan_chunk,
-    make_scan_epoch,
     make_scan_eval,
     make_train_step,
 )
@@ -84,6 +82,17 @@ PRECISION_DTYPES = {
     "float16": jnp.float16,
     "float32": jnp.float32,
 }
+
+
+class _LevelSteps(NamedTuple):
+    """Everything a level runs for one (model, epoch budget) pair."""
+
+    tx: Any
+    schedule: Callable
+    train_step: Callable  # one batch: streamed loaders, a chunk's tail
+    scan_chunk: Callable  # K stacked batches; a resident loader's whole epoch
+    eval_step: Callable
+    scan_eval: Callable  # the whole stacked test set
 
 
 class PruningHarness:
@@ -145,23 +154,19 @@ class PruningHarness:
         if ep.max_steps_per_epoch:
             self.steps_per_epoch = min(self.steps_per_epoch, ep.max_steps_per_epoch)
 
-        # Built per level (fresh optimizer semantics); cached by total_steps
-        # so identical level budgets reuse one executable.
-        self._step_cache: dict[int, tuple] = {}
-        self.tx = None
-        self.schedule = None
-
         with tracing.span("init/state"):
             if state is None:
                 state = self._fresh_state()
             self.state = replicate(state, self.mesh)
 
         with tracing.span("init/steps"):
-            raw_eval = make_eval_step(self.model)
-            self._eval_step = make_sharded_eval_step(raw_eval, self.mesh)
-            self._scan_eval = make_sharded_scan_eval(
-                make_scan_eval(raw_eval), self.mesh
-            )
+            # The dense model's records, cached by total_steps so identical
+            # level budgets reuse one executable; _steps is the live one
+            # (a plan's while a planned level runs).
+            self._steps = self._build_steps(self.model, ep.epochs_per_level)
+            self._step_cache: dict[int, _LevelSteps] = {
+                ep.epochs_per_level * self.steps_per_epoch: self._steps
+            }
         self._eval_batches = None  # device-cached stacked test set
         # Opt-in compacted eval (experiment_params.compact_eval): compiled
         # eval steps cached by the compacted width signature — widths only
@@ -175,13 +180,13 @@ class PruningHarness:
         # live masks — slice the whole train state onto a physically smaller
         # model where dead channels clear the savings threshold, gather the
         # surviving N:M-patterned contractions, and stay masked-dense where
-        # neither pays. The per-plan step bundle is cached by
+        # neither pays. The per-plan step record is cached by
         # (total_steps, width signature, nm signature); _plan_ctx holds the
-        # plan + the full-coordinate anchor (compaction only) while the
-        # level runs on it (None <=> training masked-dense). Cache sizes and
-        # the last plan report are exported as tracing gauges so the
-        # bench/tests can read the shape the level ACTUALLY compiled.
-        self._plan_step_cache: dict[tuple, tuple] = {}
+        # plan, the dense record + the full-coordinate anchor (compaction
+        # only) while the level runs on it (None <=> training masked-dense).
+        # Cache sizes and the last plan report are exported as tracing
+        # gauges so the tests can read the shape the level ACTUALLY compiled.
+        self._plan_step_cache: dict[tuple, _LevelSteps] = {}
         self._plan_ctx: Optional[dict] = None
         self.last_plan_report: Optional[dict] = None
         self.last_nm_report: Optional[dict] = None
@@ -247,6 +252,31 @@ class PruningHarness:
         )
         return tx, schedule
 
+    def _build_steps(self, model, epochs: int, evals=None) -> _LevelSteps:
+        """The one place a level's executables are made (jitted, so compiled
+        only when first called). ``evals`` hands on the eval pair of another
+        record of the same model: eval does not depend on the budget."""
+        tx, schedule = self._build_tx(epochs)
+        raw_step = make_train_step(model, tx, schedule)
+        if evals is None:
+            raw_eval = make_eval_step(model)
+            evals = (
+                make_sharded_eval_step(raw_eval, self.mesh),
+                make_sharded_scan_eval(make_scan_eval(raw_eval), self.mesh),
+            )
+        return _LevelSteps(
+            tx,
+            schedule,
+            make_sharded_train_step(raw_step, self.mesh),
+            make_sharded_scan_chunk(make_scan_chunk(raw_step), self.mesh),
+            *evals,
+        )
+
+    @property
+    def _scan_eval(self) -> Callable:
+        """The live scanned eval, under the name the benchmark's job reads."""
+        return self._steps.scan_eval
+
     def setup_level(self, epochs: int) -> None:
         """Fresh optimizer + schedule for a level/cycle (reference
         _setup_optimizer/_setup_scheduler per level,
@@ -255,18 +285,17 @@ class PruningHarness:
         total_steps = epochs * self.steps_per_epoch
         self._current_epochs = epochs  # compact path rebuilds the same tx
         if total_steps not in self._step_cache:
-            tx, schedule = self._build_tx(epochs)
-            raw_step = make_train_step(self.model, tx, schedule)
-            step = make_sharded_train_step(raw_step, self.mesh)
-            scan = make_sharded_scan_epoch(make_scan_epoch(raw_step), self.mesh)
-            chunk = make_sharded_scan_chunk(make_scan_chunk(raw_step), self.mesh)
-            self._step_cache[total_steps] = (tx, schedule, step, scan, chunk)
-        self.tx, self.schedule, self._train_step, self._scan_epoch, self._scan_chunk = (
-            self._step_cache[total_steps]
-        )
+            # No plan is live here (_exit_plan runs in the level's finally).
+            self._step_cache[total_steps] = self._build_steps(
+                self.model,
+                epochs,
+                evals=(self._steps.eval_step, self._steps.scan_eval),
+            )
+        self._steps = self._step_cache[total_steps]
         self.state = replicate(
             self.state.replace(
-                step=jnp.zeros((), jnp.int32), opt_state=self.tx.init(self.state.params)
+                step=jnp.zeros((), jnp.int32),
+                opt_state=self._steps.tx.init(self.state.params),
             ),
             self.mesh,
         )
@@ -299,11 +328,11 @@ class PruningHarness:
         base_harness.py:151-202). Returns host-side epoch means.
 
         Fast path: device-resident loaders expose ``epoch_arrays`` and the
-        whole epoch runs as ONE lax.scan program (make_scan_epoch) — no
-        per-step host dispatch at all. Streaming loaders (grain/tpk) take
-        the chunked-scan path when ``dataset_params.scan_chunk_steps > 1``
-        (K batches per compiled dispatch) and the per-batch path
-        otherwise."""
+        whole epoch runs as ONE lax.scan program (make_scan_chunk over all
+        of its steps) — no per-step host dispatch at all. Streaming loaders
+        (grain/tpk) take the chunked-scan path when
+        ``dataset_params.scan_chunk_steps > 1`` (K batches per compiled
+        dispatch) and the per-batch path otherwise."""
         t0 = time.perf_counter()
         if (
             hasattr(self.loaders.train_loader, "epoch_arrays")
@@ -315,7 +344,7 @@ class PruningHarness:
                     epoch_sharding(self.mesh),
                 )
             with tracing.span("epoch/train"):
-                self.state, sums = self._scan_epoch(self.state, batches)
+                self.state, sums = self._steps.scan_chunk(self.state, batches)
                 sums = jax.device_get(sums)
         else:
             with tracing.span("epoch/train"):  # one span, none per batch
@@ -346,10 +375,10 @@ class PruningHarness:
             ):
                 if batch[0].ndim == 5:
                     cb = assemble_chunk(batch, self.mesh, train_scope)
-                    self.state, m = self._scan_chunk(self.state, cb)
+                    self.state, m = self._steps.scan_chunk(self.state, cb)
                 else:
                     b = assemble_batch(batch, self.mesh, train_scope)
-                    self.state, m = self._train_step(self.state, b)
+                    self.state, m = self._steps.train_step(self.state, b)
                     m = {k: v for k, v in m.items() if k != "lr"}
                 sums = m if sums is None else jax.tree.map(jnp.add, sums, m)
         else:
@@ -357,7 +386,7 @@ class PruningHarness:
                 if i >= self.steps_per_epoch:
                     break
                 batch = assemble_batch(batch, self.mesh, train_scope)
-                self.state, m = self._train_step(self.state, batch)
+                self.state, m = self._steps.train_step(self.state, batch)
                 m = {k: v for k, v in m.items() if k != "lr"}
                 sums = m if sums is None else jax.tree.map(jnp.add, sums, m)
         if sums is None:
@@ -382,9 +411,9 @@ class PruningHarness:
             )
         if self.cfg.experiment_params.compact_eval and self._plan_ctx is None:
             # With an ExecutionPlan live the state/step functions already run
-            # the planned shape — compact: the state is small and _eval_step
+            # the planned shape — compact: the state is small and eval_step
             # is the small model's (re-compacting sliced params against the
-            # full model's graph would be wrong); N:M: _eval_step already
+            # full model's graph would be wrong); N:M: eval_step already
             # runs the gathered reduced-width path. Either way that IS the
             # level's compact eval.
             return self._evaluate_compacted(ev_state)
@@ -397,13 +426,13 @@ class PruningHarness:
                 self._eval_batches = jax.device_put(
                     test_loader.eval_epoch_arrays(), epoch_sharding(self.mesh)
                 )
-            sums = jax.device_get(self._scan_eval(ev_state, self._eval_batches))
+            sums = jax.device_get(self._steps.scan_eval(ev_state, self._eval_batches))
         else:
             sums = None
             test_scope = getattr(test_loader, "batch_scope", "global")
             for batch in test_loader:
                 batch = assemble_batch(batch, self.mesh, test_scope)
-                m = self._eval_step(ev_state, batch)
+                m = self._steps.eval_step(ev_state, batch)
                 sums = m if sums is None else jax.tree.map(jnp.add, sums, m)
             if sums is None:
                 raise RuntimeError("test loader yielded no batches")
@@ -485,7 +514,7 @@ class PruningHarness:
 
     def _enter_plan(self) -> None:
         """Derive this level's ExecutionPlan from the live masks and swap
-        the step bundle onto it (sparse/plan.py plan_execution — the ONE
+        the step record onto it (sparse/plan.py plan_execution — the ONE
         producer of backend decisions).
 
         The planner decides everything the old compact-then-nm enter pair
@@ -555,7 +584,7 @@ class PruningHarness:
         for name, value in report_gauges(plan.report).items():
             tracing.gauge(name, value)
         if plan.kind == "masked":
-            # Neither backend pays at this level: keep the dense bundle.
+            # Neither backend pays at this level: keep the dense record.
             return
         if plan.compaction is not None:
             self.last_compaction_report = plan.compaction.report
@@ -568,35 +597,16 @@ class PruningHarness:
             exec_model = self._small_model(
                 plan.width_overrides, nm_overrides=plan.nm_overrides
             )
-            tx, schedule = self._build_tx(self._current_epochs)
-            raw_step = make_train_step(exec_model, tx, schedule)
-            raw_eval = make_eval_step(exec_model)
-            self._plan_step_cache[key] = (
-                make_sharded_train_step(raw_step, self.mesh),
-                make_sharded_scan_epoch(make_scan_epoch(raw_step), self.mesh),
-                make_sharded_scan_chunk(make_scan_chunk(raw_step), self.mesh),
-                make_sharded_eval_step(raw_eval, self.mesh),
-                make_sharded_scan_eval(make_scan_eval(raw_eval), self.mesh),
+            self._plan_step_cache[key] = self._build_steps(
+                exec_model, self._current_epochs
             )
         self._export_cache_gauges()
         self._plan_ctx = {
             "plan": plan,
             "anchor": self.state if plan.compaction is not None else None,
-            "dense_fns": (
-                self._train_step,
-                self._scan_epoch,
-                self._scan_chunk,
-                self._eval_step,
-                self._scan_eval,
-            ),
+            "dense_steps": self._steps,
         }
-        (
-            self._train_step,
-            self._scan_epoch,
-            self._scan_chunk,
-            self._eval_step,
-            self._scan_eval,
-        ) = self._plan_step_cache[key]
+        self._steps = self._plan_step_cache[key]
         if plan.compaction is not None:
             from ..sparse import compact_train_state
 
@@ -625,7 +635,7 @@ class PruningHarness:
 
     def _exit_plan(self) -> None:
         """Expand back to full coordinates (when the plan compacted) and
-        restore the masked-dense step functions. Idempotent; called in a
+        restore the masked-dense step record. Idempotent; called in a
         finally so a raising epoch can't leave the harness stuck on a
         plan's shapes (the driver's save_level/prune always see full
         coordinates)."""
@@ -633,13 +643,7 @@ class PruningHarness:
             return
         ctx = self._plan_ctx
         self._plan_ctx = None
-        (
-            self._train_step,
-            self._scan_epoch,
-            self._scan_chunk,
-            self._eval_step,
-            self._scan_eval,
-        ) = ctx["dense_fns"]
+        self._steps = ctx["dense_steps"]
         plan = ctx["plan"]
         if plan.compaction is not None:
             from ..sparse import expand_train_state

@@ -134,30 +134,22 @@ def make_sharded_scan_eval(scan_eval: Callable, mesh: Mesh) -> Callable:
     )
 
 
-def make_sharded_scan_epoch(
-    scan_epoch: Callable, mesh: Mesh, donate_state: bool = True
+def make_sharded_scan_chunk(
+    scan_chunk: Callable, mesh: Mesh, donate_state: bool = True
 ) -> Callable:
-    """jit the lax.scan epoch runner (train/steps.py make_scan_epoch): the
-    whole epoch executes as ONE XLA program with the per-step psum still
-    inserted by the partitioner — zero host dispatches in the hot loop."""
+    """jit the lax.scan runner (train/steps.py make_scan_chunk): K stacked
+    batches [K, B, ...] execute as ONE XLA program (state replicated +
+    donated, batch axis sharded on ``data``) with the per-step psum still
+    inserted by the partitioner. K is a whole epoch for a device-resident
+    loader (zero host dispatches in the hot loop) and
+    ``scan_chunk_steps`` prefetched batches on the STREAMED train path, so
+    data that doesn't fit in HBM still amortizes dispatch."""
     return jax.jit(
-        scan_epoch,
+        scan_chunk,
         in_shardings=(replicated(mesh), epoch_sharding(mesh)),
         out_shardings=(replicated(mesh), replicated(mesh)),
         donate_argnums=(0,) if donate_state else (),
     )
-
-
-def make_sharded_scan_chunk(
-    scan_chunk: Callable, mesh: Mesh, donate_state: bool = True
-) -> Callable:
-    """jit the chunked-scan runner (train/steps.py make_scan_chunk) for the
-    STREAMED train path: K stacked prefetched batches [K, B, ...] execute
-    as one XLA program (state replicated + donated, batch axis sharded on
-    ``data``) — the same compilation contract as the whole-epoch scan, at
-    chunk granularity so data that doesn't fit in HBM still amortizes
-    dispatch."""
-    return make_sharded_scan_epoch(scan_chunk, mesh, donate_state)
 
 
 def assemble_chunk(batch: PyTree, mesh: Mesh, scope: str = "global") -> PyTree:

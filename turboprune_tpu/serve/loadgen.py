@@ -16,7 +16,7 @@ queue sheds), and sampled queue depth per point, then locates the
 SATURATION KNEE: the first offered load where goodput falls measurably
 short of offered or tail latency explodes relative to the lightest point.
 Everything is in-process against a submit callable (fleet engine or
-batcher), so the bench measures the serving stack, not HTTP parsing.
+batcher), so a sweep measures the serving stack, not HTTP parsing.
 """
 
 from __future__ import annotations

@@ -17,7 +17,7 @@ tests/test_fleet.py).
 
 Quantiles (p50/p99) are computed from a bounded sliding window of recent
 latencies rather than from the histogram buckets: the window gives exact
-recent-traffic quantiles for the JSON snapshot/bench, while the cumulative
+recent-traffic quantiles for the JSON snapshot, while the cumulative
 buckets remain the long-horizon Prometheus view (scrapers compute their own
 quantiles via histogram_quantile).
 """

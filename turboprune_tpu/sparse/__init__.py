@@ -26,12 +26,11 @@ plan.py           ``plan_execution`` — the ONE planner that turns live masks
                   into an ``ExecutionPlan`` (compact the dead channels, N:M
                   the scattered survivors, dense where neither pays, with an
                   optional cost-model/micro-bench autotune pass) consumed by
-                  the harness, the serving engine, and the bench alike
+                  the harness and the serving engine alike
 
-Consumed by serve/engine.py (planner-driven backend selection), the
-harness's compact eval and plan-execution paths, and bench.py's
-``compaction`` / ``compact_train`` / ``nm_frontier`` / ``mixed_plan``
-stages.
+Consumed by serve/engine.py (planner-driven backend selection) and the
+harness's compact eval and plan-execution paths. No benchmark cell runs a
+sparse backend yet (ROADMAP A1).
 """
 
 from .compact import (

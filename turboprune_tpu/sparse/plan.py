@@ -4,10 +4,10 @@ Before this module the repo had three separately-wired execution paths —
 masked-dense, channel compaction (compact.py/train_compact.py) and gathered
 N:M (nm_execute.py) — each with its own enter/exit logic in the harness and
 its own probe branch in serve/engine.py, each globally on or off per run.
-The N:M frontier bench showed the winner is workload-dependent (scattered
-masks favor gathering, dead channels favor compaction), so any
-single-backend run leaves speed on the floor for the layers where the other
-backend wins.
+Which backend applies depends on the masks (scattered zeros can only be
+gathered, dead channels can be sliced out), so a single-backend run has
+nothing to offer the layers that only the other backend fits. Whether
+either beats masked-dense on the chip has no measurement yet (ROADMAP A1).
 
 ``plan_execution`` derives ONE ``ExecutionPlan`` from the live masks:
 
@@ -78,8 +78,8 @@ AUTOTUNE_MODES = ("off", "cost", "measure")
 
 # Analytic gather overhead as a fraction of the dense layer cost: two
 # static takes on the operands plus (transposable only) the output
-# scatter. Calibrated loosely from the nm_frontier bench's small-layer
-# floor; autotune="measure" replaces it with a real timing.
+# scatter. A constant set on a CPU, owed to the sparse-level cell (ROADMAP
+# A1); autotune="measure" replaces it with a real timing.
 _GATHER_OVERHEAD = 0.15
 
 
@@ -239,8 +239,8 @@ def _nm_layer_estimates(
             }
             continue
         # measure: time the two executables on a synthetic batch. Runs on
-        # whatever platform the caller is pinned to (the bench and the
-        # harness both plan on CPU); index maps are compile-time metadata.
+        # whatever platform the caller is pinned to; index maps are
+        # compile-time metadata.
         x = jnp.ones((32, i), jnp.float32)
         w = jnp.ones((i, o), jnp.float32)
         b = jnp.zeros((o,), jnp.float32)
@@ -417,8 +417,8 @@ def plan_execution(
 def report_gauges(report: dict) -> dict[str, float]:
     """An ExecutionPlan report as the ``plan_*`` gauge family: per-layer
     backend decision counts, N:M coverage, and — when compaction was
-    planned — the dense vs compacted parameter/channel counts, so a scraper,
-    a test or the bench reads the size and routing the process ACTUALLY
+    planned — the dense vs compacted parameter/channel counts, so a scraper
+    or a test reads the size and routing the process ACTUALLY
     compiled, not just the mask density. One mapping for the trainer's
     gauges (utils/tracing.py) and the server's (serve/metrics.py)."""
     counts = report.get("backend_counts", {})

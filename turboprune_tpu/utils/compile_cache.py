@@ -3,7 +3,7 @@
 A cold ResNet50 train step is tens of seconds of compile per executable and
 an IMP run builds several; the cache turns a restart's compiles into reads.
 Placed by the entry points (``main()`` of run_experiment.py,
-run_cyclic_training_experiment.py, run_server.py, chip_smoke.py, bench.py),
+run_cyclic_training_experiment.py, run_server.py, chip_smoke.py),
 never at import of the package, so a library user keeps their own setting.
 """
 
