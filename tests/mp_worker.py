@@ -192,6 +192,13 @@ def check_driver_imp():
         {"params": state.params, "masks": state.masks}
     )
     result["imp_sparsity"] = summaries[-1]["achieved_density"]
+    # Level saves write behind, on the primary's writer alone; run() waited
+    # (the write, then the cross-host barrier) before it returned, so both
+    # hosts list both levels.
+    ckpts = captured["h"].ckpts
+    result["imp_has_writer"] = ckpts._behind._pool is not None
+    result["imp_unsettled_after_run"] = ckpts._unsettled
+    result["imp_saved_levels"] = ckpts.saved_levels()
 
 
 class _HostScopeLoader:

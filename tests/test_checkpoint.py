@@ -257,6 +257,249 @@ class TestRewindSemantics:
         assert set(back) == {"params", "masks", "batch_stats"}
 
 
+def _tiny_state(scale=1.0):
+    """A state of three small leaves: what ``save_level`` takes of one."""
+    import types
+
+    return types.SimpleNamespace(
+        params={"w": scale * jnp.arange(12.0).reshape(3, 4)},
+        masks={"w": jnp.arange(12).reshape(3, 4) % 3 > 0},
+        batch_stats={"mean": jnp.full(4, scale)},
+    )
+
+
+class _HeldWriter:
+    """``checkpoint._write_tree`` held at a gate: the writer's thread stands
+    in it until ``release()``; ``entered`` says the write has been handed over
+    (``start_write()``) and begun."""
+
+    def __init__(self, monkeypatch):
+        import threading
+
+        from turboprune_tpu.utils import checkpoint
+
+        self.gate, self.entered, real = threading.Event(), threading.Event(), checkpoint._write_tree
+
+        def held(path, tree, **attrs):
+            self.entered.set()
+            assert self.gate.wait(60), "the test never opened the gate"
+            real(path, tree, **attrs)
+
+        monkeypatch.setattr(checkpoint, "_write_tree", held)
+
+    def release(self):
+        self.gate.set()
+
+
+def _blocked_until_released(held, fn):
+    """``fn``, on a thread of the test's own, stands still while the writer is
+    held and ends once it is let go; returns what ``fn`` returned."""
+    from concurrent.futures import ThreadPoolExecutor, TimeoutError
+
+    with ThreadPoolExecutor(1) as pool:
+        call = pool.submit(fn)
+        try:
+            with pytest.raises(TimeoutError):  # it waits for the write in flight
+                call.result(0.3)
+        finally:
+            held.release()
+        return call.result(60)
+
+
+class TestWriteBehind:
+    """A level save returns after the fetch; one writer holds the one write in
+    flight; every reader, the next save and ``wait()`` stand until it is
+    committed (ISSUE 30)."""
+
+    READERS = {
+        "has_level": lambda ck: ck.has_level(0),
+        "saved_levels": lambda ck: ck.saved_levels() == [0],
+        "has_model": lambda ck: not ck.has_model("model_init") and ck.level_path(0).exists(),
+        "load_level": lambda ck: bool(
+            np.array_equal(ck.load_level(0, _tiny_state())["params"]["w"], _tiny_state().params["w"])
+        ),
+    }
+
+    @pytest.mark.parametrize("reader", sorted(READERS))
+    def test_save_level_returns_with_nothing_on_disk_and_a_reader_waits_for_it(
+        self, tmp_path, monkeypatch, reader
+    ):
+        held = _HeldWriter(monkeypatch)
+        ck = ExperimentCheckpoints(tmp_path)
+        ck.save_level(0, _tiny_state())  # returned, with the tree fetched and kept
+        assert ck._behind._pool is None and not held.entered.is_set()
+        ck.start_write()  # the epoch loop's, once the next epoch is dispatched
+        assert held.entered.wait(60)
+        assert not ck.level_path(0).exists()
+        assert [p.name for p in ck.checkpoints_dir.iterdir()] == []  # not even a temporary name
+        assert _blocked_until_released(held, lambda: self.READERS[reader](ck)) is True
+        assert ck.level_path(0).exists()
+
+    SAVES = {
+        "save_level": lambda ck, st: ck.save_level(1, st),
+        "save_model": lambda ck, st: ck.save_model("model_rewind", st),
+        "save_optimizer": lambda ck, st: ck.save_optimizer("optimizer_rewind", {"mu": st.params}),
+        "save_mid_level": lambda ck, st: ck.save_mid_level(
+            1, 0, type(st)(**vars(st), opt_state={"mu": st.params}, step=jnp.int32(3)), {}
+        ),
+    }
+
+    @pytest.mark.parametrize("save", sorted(SAVES))
+    def test_the_next_save_waits_for_the_write_in_flight(self, tmp_path, monkeypatch, save):
+        """At most one write and one host tree alive, and the order on disk
+        kept: no save of this class starts before the one before it is
+        committed."""
+        held = _HeldWriter(monkeypatch)
+        ck = ExperimentCheckpoints(tmp_path)
+        ck.save_level(0, _tiny_state())
+        ck.start_write()
+        assert held.entered.wait(60)
+        _blocked_until_released(held, lambda: self.SAVES[save](ck, _tiny_state(2.0)))
+        ck.wait()
+        assert ck.saved_levels() == ([0, 1] if save == "save_level" else [0])
+        assert len(list(ck.checkpoints_dir.iterdir()) + list(ck.artifacts_dir.iterdir())) >= 2
+
+    @pytest.mark.parametrize("at", ["wait", "save_level", "has_level", "load_level"])
+    def test_what_the_writer_raised_is_raised_by_the_next_wait(self, tmp_path, monkeypatch, at):
+        from turboprune_tpu.utils import checkpoint
+
+        def full(path, tree, **attrs):
+            raise OSError(28, "No space left on device", str(path))
+
+        monkeypatch.setattr(checkpoint, "_write_tree", full)
+        ck = ExperimentCheckpoints(tmp_path)
+        ck.save_level(0, _tiny_state())  # the hand-over itself says nothing
+        calls = {
+            "wait": ck.wait,
+            "save_level": lambda: ck.save_level(1, _tiny_state()),
+            "has_level": lambda: ck.has_level(0),
+            "load_level": lambda: ck.load_level(0, _tiny_state()),
+        }
+        with pytest.raises(OSError, match="No space left"):
+            calls[at]()
+        ck.wait()  # said once: nothing is in flight any more
+        assert not ck.level_path(0).exists()
+
+    @pytest.mark.parametrize("handed_over", [True, False])
+    def test_wait_writes_what_nobody_handed_over(self, tmp_path, handed_over):
+        """A caller that is no epoch loop never says ``start_write()``: the
+        first reader, the next save or ``wait()`` starts the write it then
+        waits for. Said twice, it is one write."""
+        from turboprune_tpu.utils import tracing
+
+        ck = ExperimentCheckpoints(tmp_path)
+        with tracing.span("t/handed") as whole:
+            ck.save_level(0, _tiny_state())
+            if handed_over:
+                ck.start_write()
+                ck.start_write()
+            assert ck.saved_levels() == [0]
+            ck.start_write()  # nothing is held any more
+            ck.wait()
+        assert len(tracing.recorded("ckpt/write", whole.start, whole.end)) == 1
+        assert len(tracing.recorded("ckpt/wait", whole.start, whole.end)) == 1
+
+    def test_a_directory_written_behind_is_the_one_written_in_line(self, tmp_path):
+        """Orbax's b-tree is not reproducible from one in-line write of a tree
+        to the next: it names its data files at random, cuts them in a number
+        that varies, and stamps two times. Everything else is equal byte for
+        byte: the names outside the data directories, the tree's manifest
+        (``_METADATA``), the stamped metadata but for its times, and every
+        leaf read back."""
+        import json
+
+        from turboprune_tpu.utils.checkpoint import save_model_tree
+
+        state = _tiny_state()
+        ck = ExperimentCheckpoints(tmp_path)
+        ck.save_level(0, state)
+        ck.wait()
+        behind, inline = ck.level_path(0), tmp_path / "inline"
+        save_model_tree(inline, ck.model_state(state))
+
+        def names(root):  # files and directories, but for the data files' own names
+            return sorted(
+                str(p.relative_to(root)) for p in root.rglob("*") if p.parent.name != "d"
+            )
+
+        def stamped(root):
+            meta = json.loads((root / "_CHECKPOINT_METADATA").read_text())
+            return {k: v for k, v in meta.items() if not k.endswith("_timestamp_nsecs")}
+
+        assert names(behind) == names(inline)
+        assert {"_METADATA", "manifest.ocdbt", "d", "ocdbt.process_0/d"} <= set(names(behind))
+        assert (behind / "_METADATA").read_bytes() == (inline / "_METADATA").read_bytes()
+        assert stamped(behind) == stamped(inline)
+        got, want = restore_pytree(behind), restore_pytree(inline)
+        assert jax.tree.structure(got) == jax.tree.structure(want)
+        for x, y in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            x, y = np.asarray(x), np.asarray(y)
+            assert (x.dtype, x.shape, x.tobytes()) == (y.dtype, y.shape, y.tobytes())
+
+    def test_the_write_is_recorded_on_the_writers_thread_with_the_askers_level(self, tmp_path):
+        import threading
+
+        from turboprune_tpu.utils import tracing
+
+        ck = ExperimentCheckpoints(tmp_path)
+        with tracing.span("t/behind") as whole:
+            with tracing.span("level", level=7, density=0.5):
+                with tracing.span("level/save"):
+                    ck.save_level(7, _tiny_state())
+            with tracing.span("level", level=8):
+                with tracing.span("level/save") as second:
+                    ck.save_level(8, _tiny_state())
+            ck.wait()
+        me = threading.get_ident()
+        writes = tracing.recorded("ckpt/write", whole.start, whole.end)
+        assert [s.attrs for s in writes] == [{"level": 7}, {"level": 8}]
+        assert all(s.thread != me and s.parent is None for s in writes)
+        assert len({s.thread for s in writes}) == 1  # the one writer
+        waits = tracing.recorded("ckpt/wait", whole.start, whole.end)
+        # One where level 8's save found level 7's write, one at the end: each
+        # on the caller's thread, under the span that stood waiting.
+        assert [(s.thread, s.parent, s.attrs) for s in waits] == [
+            (me, second.id, {"level": 8}),
+            (me, whole.id, {}),
+        ]
+        fetches = tracing.recorded("ckpt/fetch", whole.start, whole.end)
+        assert {s.thread for s in fetches} == {me} and {s.attrs["level"] for s in fetches} == {7, 8}
+        # The write that ended during level 8 is level 7's, and the level's
+        # breakdown names it beside the level's own time.
+        level8 = tracing.recorded("level", whole.start, whole.end)[1]
+        assert tracing.breakdown([level8])["behind"] == [(7, writes[0].seconds)]
+
+    def test_a_process_that_never_saved_a_level_has_no_writer_and_waits_on_nothing(
+        self, small_state, tmp_path
+    ):
+        from turboprune_tpu.utils import tracing
+
+        _, _, state = small_state
+        writer = ExperimentCheckpoints(tmp_path)
+        writer.save_level(0, _tiny_state())
+        writer.wait()
+        assert writer._behind._pool is not None
+        with tracing.span("t/reader") as whole:
+            reader = ExperimentCheckpoints(tmp_path)  # the server, a resumed process
+            assert reader.saved_levels() == [0] and reader.has_level(0)
+            reader.load_level(0, _tiny_state())
+            reader.save_model("model_init", state)  # set-up's saves stay in line
+            reader.wait()
+        assert reader._behind._pool is None and reader._unsettled is None
+        assert not tracing.recorded("ckpt/wait", whole.start, whole.end)
+        (write,) = tracing.recorded("ckpt/write", whole.start, whole.end)
+        assert write.thread == whole.thread
+
+    def test_twenty_saves_back_to_back_leave_twenty_levels(self, tmp_path):
+        ck = ExperimentCheckpoints(tmp_path)
+        for level in range(20):
+            ck.save_level(level, _tiny_state(float(level)))
+        assert ck.saved_levels() == list(range(20))
+        for level in (0, 19):
+            back = ck.load_level(level, _tiny_state())
+            np.testing.assert_array_equal(back["batch_stats"]["mean"], np.full(4, float(level)))
+
+
 def _reads_during(fn):
     """(what ``fn`` returned, how many Orbax restores ran inside it)."""
     from turboprune_tpu.utils import tracing

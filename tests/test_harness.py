@@ -79,11 +79,24 @@ def whole_run(tmp_path_factory):
     return _traced_run(_cfg(tmp_path_factory.mktemp("imp")))
 
 
-def _killed_and_resumed(base, kill_level, *extra, harness_cls=None):
+def _killed_and_resumed(base, kill_level, *extra, harness_cls=None, in_flight=False):
     """Two runs in one directory: the first dies (the tests' preemption) once
     ``model_level_{kill_level}`` is saved, the second takes it up at the next
-    level."""
+    level. ``in_flight``: it dies one ``save_level`` later, when that save has
+    returned and its write is still held in the writer, never to be committed:
+    what a kill in the first moments of the level after leaves behind."""
+    import threading
+
     from turboprune_tpu.harness import PruningHarness
+    from turboprune_tpu.utils import checkpoint
+
+    dead, real_write = threading.Event(), checkpoint._write_tree
+
+    def write_until_killed(path, tree, **attrs):
+        if path.name == f"model_level_{kill_level + 1}":
+            assert dead.wait(60)  # held until save_level has returned
+            raise KeyboardInterrupt("killed with the write in flight")
+        real_write(path, tree, **attrs)
 
     class Killed(harness_cls or PruningHarness):
         def __init__(self, *a, **k):
@@ -92,12 +105,17 @@ def _killed_and_resumed(base, kill_level, *extra, harness_cls=None):
 
             def dying(level, state):
                 save(level, state)
-                if level == kill_level:
+                if level == kill_level + in_flight:
+                    assert not self.ckpts.level_path(level).exists()  # behind: not yet
+                    dead.set()
                     raise KeyboardInterrupt("simulated preemption")
 
             self.ckpts.save_level = dying
 
-    killed = _traced_run(_cfg(base, *extra), Killed)
+    with pytest.MonkeyPatch.context() as patch:
+        if in_flight:
+            patch.setattr(checkpoint, "_write_tree", write_until_killed)
+        killed = _traced_run(_cfg(base, *extra), Killed)
     resumed = _traced_run(
         _cfg(
             base,
@@ -184,14 +202,17 @@ class TestLevelHandOff:
     """A continuous run hands its state from level to level in memory and
     rewinds from the resident target; the directory is read only by a process
     that does not hold the state. Three runs of one seed: ``whole`` is
-    uninterrupted, ``killed`` dies at level 0's save, ``resumed`` takes it up at
-    level 1, reading from disk once what every level used to read, and goes on
-    to level 2 as the process that wrote it would (``test_level_resume.py``
-    has the same for ``wr`` with its optimizer and for a cyclic run)."""
+    uninterrupted; ``killed`` dies in the first moments of level 2, when level
+    1's save has returned and its write is still in flight, so that
+    ``model_level_0`` is the last level on disk; ``resumed`` takes it up at
+    level 1, reading from disk once what every level used to read, repeats
+    that level and goes on to level 2 as the process that wrote it would
+    (``test_level_resume.py`` has a kill at a committed save, for ``wr`` with
+    its optimizer and for a cyclic run)."""
 
     @pytest.fixture(scope="class")
     def runs(self, whole_run, tmp_path_factory):
-        killed, resumed = _killed_and_resumed(tmp_path_factory.mktemp("handoff"), 0)
+        killed, resumed = _killed_and_resumed(tmp_path_factory.mktemp("handoff"), 0, in_flight=True)
         return {"whole": whole_run, "killed": killed, "resumed": resumed}
 
     _named = staticmethod(_named)
@@ -211,8 +232,8 @@ class TestLevelHandOff:
 
     def test_a_resumed_run_loads_once_and_reads_the_rewind_target_once(self, runs):
         killed, resumed = runs["killed"], runs["resumed"]
-        assert [s.attrs["level"] for s in self._named(killed, "level")] == [0]
-        assert not self._named(killed, "level/rewind") and not self._named(killed, "ckpt/read")
+        assert [s.attrs["level"] for s in self._named(killed, "level")] == [0, 1]
+        assert not self._named(killed, "ckpt/read")
         assert [s.attrs["level"] for s in self._named(resumed, "level")] == [1, 2]
         assert [s.attrs["level"] for s in self._named(resumed, "level/load")] == [1]
         rewinds = self._named(resumed, "level/rewind")
@@ -223,6 +244,23 @@ class TestLevelHandOff:
         # model_level_0 and model_init, both in the resumed level: level 2
         # reads nothing back.
         assert [s.attrs["level"] for s in self._named(resumed, "ckpt/read")] == [1, 1]
+
+    def test_a_kill_with_a_write_in_flight_costs_that_level_and_no_more(self, runs):
+        """Level 1's save had returned when the process died; its write was
+        never committed, so the directory holds no ``model_level_1``, under
+        no name: a resumed process and the server list committed levels only.
+        The wait at the run's end raised what the writer raised."""
+        killed, resumed = runs["killed"], runs["resumed"]
+        assert [s.attrs["level"] for s in self._named(killed, "level/save")] == [0, 1]
+        assert "error" not in self._named(killed, "level/save")[0].attrs
+        assert sorted(k for k in killed["written"] if "level" in k) == ["checkpoints/model_level_0"]
+        loop = self._named(killed, "level")[0].thread
+        behind = [s for s in self._named(killed, "ckpt/write") if s.thread != loop]
+        assert [s.attrs["level"] for s in behind] == [0]  # level 1's never ran to its end
+        assert self._named(killed, "ckpt/wait")[-1].attrs["error"] == "KeyboardInterrupt"
+        # The resumed run repeats level 1 from model_level_0 and writes it.
+        assert [s.attrs["level"] for s in self._named(resumed, "ckpt/write")] == [1, 2]
+        assert resumed["harness"].ckpts.saved_levels() == [0, 1, 2]
 
     def test_what_a_process_keeps_is_on_the_host(self, runs):
         """The writer keeps the tree it fetched for the save. The resumed

@@ -102,6 +102,15 @@ class TestTwoProcessWorld:
             mp_results[0]["imp_fingerprint"] == mp_results[1]["imp_fingerprint"]
         )
 
+    def test_only_the_primary_has_a_writer_and_every_host_sees_every_level(self, mp_results):
+        # save_level writes behind on process 0's one writer thread; the
+        # other process fetches nothing, has no writer, and meets the primary
+        # at wait()'s barrier, after which it may read what was written.
+        assert [r["imp_has_writer"] for r in mp_results] == [True, False]
+        for r in mp_results:
+            assert r["imp_unsettled_after_run"] is None
+            assert r["imp_saved_levels"] == [0, 1]
+
     def test_ring_attention_cross_host_identical(self, mp_results):
         # shard_map ring attention over a mesh spanning both processes:
         # the ppermute ring crosses the process boundary and the replicated

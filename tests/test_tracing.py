@@ -81,28 +81,58 @@ class TestRecorder:
         train = _made("level/train", 1.5, 9.0, level)
         epochs = [_made("epoch", 2.0 + 3 * i, 4.5 + 3 * i, train) for i in range(2)]
         inner = [_made("epoch/train", e.start, e.start + 2.0, e) for e in epochs]
+        # A level's save as it runs since PR 30: it waits for the write of the
+        # level before (and holds that save's barrier), fetches, and returns.
+        # That write ended behind ``epoch/train``, on the writer's thread.
         save = _made("level/save", 9.0, 10.0, level)
-        fetch = _made("ckpt/fetch", 9.0, 9.25, save)
-        write = _made("ckpt/write", 9.25, 9.75, save)
+        wait = _made("ckpt/wait", 9.0, 9.125, save)
+        barrier = _made("ckpt/barrier", 9.125, 9.25, save)
+        fetch = _made("ckpt/fetch", 9.25, 9.75, save)
+        behind = _made("ckpt/write", -0.25, 2.5, level=1)
+        behind.thread += 1
         inner[1].compiles, inner[1].compile_s = 2, 0.5
-        spans = [read, load, *inner, *epochs, train, fetch, write, save, level]
+        spans = [read, load, behind, *inner, *epochs, train, wait, barrier, fetch, save, level]
         b = tracing.breakdown([level], spans)
         assert b["terms"] == {"level/load": 1.0, "epoch/train": 4.0, "level/save": 1.0}
         assert b["inside"] == {
             "level/load": {"ckpt/read": 0.8},
-            "level/save": {"ckpt/fetch": 0.25, "ckpt/write": 0.5},
+            "level/save": {"ckpt/wait": 0.125, "ckpt/barrier": 0.125, "ckpt/fetch": 0.5},
         }
+        assert b["behind"] == [(1, 2.75)]
         # level 0.5 of its own, level/train 7.5 - 5.0, each epoch 0.5
         assert b["other_s"] == pytest.approx(0.5 + 2.5 + 1.0)
         assert sum(b["terms"].values()) + b["other_s"] == pytest.approx(b["total_s"]) == 10.0
         assert (b["compiles"], b["compile_s"]) == (2, 0.5)
         assert tracing.line("level 2", b) == (
             "[time] level 2: 10.00 s = load 1.00 (read 0.80) + train 4.00 + "
-            "save 1.00 (fetch 0.25, write 0.50) + other 4.00; compiled 2 modules, 0.5 s"
+            "save 1.00 (wait 0.12, barrier 0.12, fetch 0.50) + other 4.00; "
+            "wrote level 1 behind, 2.75 s; compiled 2 modules, 0.5 s"
         )
         row = tracing.timing_row(level, b)
         assert list(row) == tracing.TIMING_COLUMNS
-        assert (row["level"], row["train_s"], row["ckpt_write_s"], row["prune_s"]) == (2, 4.0, 0.5, 0.0)
+        assert (row["level"], row["train_s"], row["prune_s"]) == (2, 4.0, 0.0)
+        # The stall on the loop, and the work off it that ended in this level.
+        assert (row["ckpt_wait_s"], row["ckpt_fetch_s"], row["ckpt_write_s"]) == (0.125, 0.5, 2.75)
+
+    @pytest.mark.parametrize(
+        "case, start, end, other_thread, said",
+        [
+            ("ended in the level", -1.0, 1.0, True, [(1, 2.0)]),
+            ("ended before it opened", -2.0, -0.5, True, []),  # the level before names it
+            ("still running at its end", 9.0, 11.0, True, []),  # the next level's, or the exit's
+            ("in line, on the level's thread", 1.0, 2.0, False, []),  # a child, named inside its term
+        ],
+    )
+    def test_a_level_names_the_write_that_ended_behind_it(self, case, start, end, other_thread, said):
+        level = _made("level", 0.0, 10.0, level=2)
+        ckpt = _made("epoch/ckpt", 1.0, 2.0, level)
+        write = _made("ckpt/write", start, end, None if other_thread else ckpt, level=1)
+        write.thread += other_thread
+        b = tracing.breakdown([level], [write, ckpt, level])
+        assert b["behind"] == said, case
+        assert ("wrote level 1 behind, 2.00 s" in tracing.line("level 2", b)) == bool(said)
+        in_line = 0.0 if other_thread else 1.0
+        assert tracing.timing_row(level, b)["ckpt_write_s"] == in_line + sum(s for _, s in said)
 
     def test_the_recorder_is_bounded_and_keeps_the_newest(self, monkeypatch):
         assert tracing._spans.maxlen == tracing.MAX_SPANS
@@ -153,7 +183,10 @@ def test_the_lowered_train_step_names_its_layers():
 # What a two-level IMP ladder records (ISSUE 24's table), and whether a level
 # without a prune (level 0) records it too. RESUMED: nowhere in a continuous
 # run, which hands its state on in memory; only in level 1 of a process that
-# starts from level 0's checkpoint.
+# starts from level 0's checkpoint. A level's write runs behind the next
+# level (``ckpt/write``, on the writer's thread, with the level that asked for
+# it); ``ckpt/wait`` is recorded where a write was in flight to wait for:
+# in level 1's save and, outside every level, at the run's end.
 RESUMED = "resumed"
 LADDER_SPANS = {
     "harness/init": None, "init/mesh_model": None, "init/loaders": None,
@@ -162,7 +195,7 @@ LADDER_SPANS = {
     "level/train": True, "level/setup": True, "epoch": True, "epoch/feed": True,
     "epoch/train": True, "epoch/eval": True, "epoch/log": True, "level/finish": True,
     "level/save": True, "ckpt/fetch": True, "ckpt/write": True, "ckpt/barrier": True,
-    "ckpt/read": RESUMED,
+    "ckpt/wait": False, "ckpt/read": RESUMED,
 }  # fmt: skip
 
 
@@ -228,6 +261,10 @@ def test_the_ladder_records_every_span_of_the_table(ladder, name):
         assert not found, name  # the continuous run read nothing back
         found = [s for s in ladder["resumed_spans"] if s.name == name]
     assert found, name
+    if name in ("ckpt/wait", "ckpt/barrier"):
+        at_exit = [s for s in found if "level" not in s.attrs]  # driver.run's last wait()
+        assert len(at_exit) == 1
+        found.remove(at_exit[0])
     if in_level_zero is None:
         assert all("level" not in s.attrs for s in found)
     else:
@@ -263,6 +300,12 @@ def test_level_self_times_sum_to_the_level_and_land_in_the_csv(ladder):
     # A level of a continuous run reads nothing: the columns stay and say 0.
     assert (rows["load_s"] == 0).all() and (rows["ckpt_read_s"] == 0).all()
     assert rows["ckpt_write_s"].min() > 0
+    # The stall on the loop beside the work off it: level 0 had no write to
+    # wait for; level 1's row holds its wait and level 0's write, whole.
+    (waited,) = [s for s in ladder["spans"] if s.name == "ckpt/wait" and "level" in s.attrs]
+    (wrote,) = [s for s in ladder["spans"] if s.name == "ckpt/write" and s.attrs["level"] == 0 and s.thread != waited.thread]
+    assert rows["ckpt_wait_s"][0] == 0 and rows["ckpt_wait_s"][1] == pytest.approx(waited.seconds)
+    assert rows["ckpt_write_s"][1] == pytest.approx(wrote.seconds)
     # Level 0 compiles the epoch; level 1 reuses it and compiles its prune.
     assert rows["compiles"][0] > 0
 
@@ -302,6 +345,13 @@ def test_the_operator_gets_a_time_line_per_level_and_one_for_setup(ladder, out, 
     assert (lines[-1].count("(read ") == 0) != load
     if not load:
         assert "prune " not in lines[1]
+    # A level's save waits for the write of the level before and says so, and
+    # the level says which write ended behind it. The first level a process
+    # saves has neither: level 0, and a resumed process's level 1.
+    behind = not load
+    assert ("save " in lines[-1] and "(wait " in lines[-1]) == behind
+    assert ("; wrote level 0 behind, " in lines[-1]) == behind
+    assert "wait " not in lines[1] and "behind" not in lines[1]
 
 
 def _host_span_names(session: Path) -> set[str]:

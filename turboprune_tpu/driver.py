@@ -198,7 +198,7 @@ def run(cfg: MainConfig, harness_cls: Optional[Type[PruningHarness]] = None):
                         prune_level(harness, density, level)
                 else:
                     # A level starts from the state the last one left in
-                    # ``harness.state``: the very tree ``save_level`` wrote.
+                    # ``harness.state``: the very tree ``save_level`` fetched.
                     # Only the first level of a resumed process holds no such
                     # state and reads it back, and puts the train loader
                     # where the levels before would have left it.
@@ -216,8 +216,10 @@ def run(cfg: MainConfig, harness_cls: Optional[Type[PruningHarness]] = None):
                     # boundary, stopped by the harness where level 1's
                     # set-up ends.
                     tracing.start_profile(Path(ep.profile_dir) / "level0_to_1")
-                # Saves are primary-only with a cross-host barrier — state is
-                # replicated, so host 0 holds everything
+                # Saves are primary-only — state is replicated, so host 0
+                # holds everything — and a level's is written behind the next
+                # level: this returns once the tree is on the host, and the
+                # directory and its cross-host barrier are the next wait()'s
                 # (utils/checkpoint.py).
                 with tracing.span("level/save"):
                     harness.ckpts.save_level(level, harness.state)
@@ -228,6 +230,9 @@ def run(cfg: MainConfig, harness_cls: Optional[Type[PruningHarness]] = None):
             harness.metrics.log_level_timing(tracing.timing_row(level_span, timing))
     finally:
         tracing.stop_profile()  # a level that raised must not leave one running
+        # Returning or raising, every level this run reported is on disk
+        # before anyone is told the run is over.
+        harness.ckpts.wait()
     if ep.checkpoint_every_epochs:
         # Run complete: the final level's mid-level slot is stale — left
         # behind it would hijack a later resume of this dir after a config

@@ -345,10 +345,15 @@ class PruningHarness:
                 )
             with tracing.span("epoch/train"):
                 self.state, sums = self._steps.scan_chunk(self.state, batches)
+                # The epoch is dispatched and the host waits for it: room
+                # for the write of the level before (utils/checkpoint.py).
+                self.ckpts.start_write()
                 sums = jax.device_get(sums)
         else:
             with tracing.span("epoch/train"):  # one span, none per batch
-                sums = jax.device_get(self._stream_epoch())
+                sums = self._stream_epoch()
+                self.ckpts.start_write()
+                sums = jax.device_get(sums)
         wall = time.perf_counter() - t0
         n = float(sums["count"])
         return {

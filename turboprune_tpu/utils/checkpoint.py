@@ -26,6 +26,14 @@ host tree it fetched for the save, and every later rewind takes it from there.
 A process that did not write it (a resume) reads it from disk once, at first
 use, and keeps it as a host tree too. A rewind's host-to-device copy is the
 ``replicate`` of the level's set-up (``level/setup``), not the rewind's own.
+
+Because the loop reads nothing back, a LEVEL save writes behind the next
+level's training (``ExperimentCheckpoints``): ``save_level`` brings the tree
+to the host and returns, the epoch loop hands it to the one writer thread once
+the next epoch is dispatched, and ``wait()`` stands before every read of the
+directory, the next save and the run's end. A level is on disk at the next
+``wait()``, not when ``save_level`` returns; Orbax commits a directory by
+rename, so until then it is not there at all.
 """
 
 from __future__ import annotations
@@ -33,6 +41,8 @@ from __future__ import annotations
 import json
 import os
 import re
+import shutil
+from concurrent.futures import Future, ThreadPoolExecutor
 from pathlib import Path
 from typing import Any, Optional
 
@@ -95,6 +105,19 @@ def fetch_to_host(tree: PyTree) -> PyTree:
         )
 
 
+def _write_tree(path: Path, host_tree: PyTree, **attrs) -> None:
+    """The primary's write of a tree that is on the host already, under a
+    ``ckpt/write`` span: the old directory goes, Orbax writes a temporary one
+    and commits it by rename. ``attrs`` are the span's where the caller's
+    thread is not this one (the writer of ``ExperimentCheckpoints``)."""
+    with tracing.span("ckpt/write", **attrs):
+        ckptr = _primary_only_checkpointer()
+        if path.exists():
+            shutil.rmtree(path)
+        ckptr.save(path, host_tree)
+        ckptr.wait_until_finished()
+
+
 def save_pytree(path: str | Path, tree: PyTree) -> None:
     """Atomic directory-style save (overwrites an existing checkpoint).
 
@@ -115,15 +138,7 @@ def save_pytree(path: str | Path, tree: PyTree) -> None:
         # Saving numpy keeps the array leaves fully addressable for the
         # single-process save. A rewind target comes fetched already, by the
         # caller that keeps it.
-        host_tree = fetch_to_host(tree)
-        with tracing.span("ckpt/write"):
-            ckptr = _primary_only_checkpointer()
-            if path.exists():
-                import shutil
-
-                shutil.rmtree(path)
-            ckptr.save(path, host_tree)
-            ckptr.wait_until_finished()
+        _write_tree(path, fetch_to_host(tree))
     with tracing.span("ckpt/barrier"):
         sync_hosts(f"save_pytree:{path.name}")
 
@@ -219,13 +234,18 @@ def _has_packed_masks(path: Path) -> bool:
     return any(f"'{MASKS_PACKED_KEY}'" in k for k in keys)
 
 
-def save_model_tree(path: str | Path, tree: dict) -> None:
-    """Save a model-role tree ({"params", "masks", ...extras}) with the
-    mask payload bit-packed under ``masks_packed``."""
+def _packed(tree: dict) -> dict:
+    """A model-role tree ({"params", "masks", ...extras}) as it is written:
+    the mask payload bit-packed under ``masks_packed``."""
     out = dict(tree)
     with tracing.span("ckpt/fetch"):  # the masks come to the host to be packed
         out[MASKS_PACKED_KEY] = pack_mask_tree(out.pop(MASKS_KEY))
-    save_pytree(path, out)
+    return out
+
+
+def save_model_tree(path: str | Path, tree: dict) -> None:
+    """Save a model-role tree with its masks packed (``_packed``)."""
+    save_pytree(path, _packed(tree))
 
 
 def restore_model_tree(path: str | Path, like: dict) -> dict:
@@ -242,9 +262,50 @@ def restore_model_tree(path: str | Path, like: dict) -> dict:
     return restored
 
 
+class _WriteBehind:
+    """The one writer thread of a process and the one tree it is given: held
+    from ``hold()`` until ``start()`` hands it over, in flight from then until
+    ``settle()`` has its outcome. Only the owner's thread touches this object;
+    the writer gets its path and its tree as arguments and gives its outcome
+    back in a Future. The thread is made by the first hand-over."""
+
+    def __init__(self):
+        self._pool: Optional[ThreadPoolExecutor] = None
+        self._held: Optional[tuple] = None
+        self._in_flight: Optional[Future] = None
+
+    def hold(self, path: Path, host_tree: PyTree, attrs: dict) -> None:
+        self._held = (path, host_tree, attrs)
+
+    def start(self) -> None:
+        if self._held is None:
+            return
+        (path, host_tree, attrs), self._held = self._held, None
+        if self._pool is None:
+            self._pool = ThreadPoolExecutor(1, thread_name_prefix="ckpt-writer")
+        self._in_flight = self._pool.submit(_write_tree, path, host_tree, **attrs)
+
+    def settle(self) -> None:
+        """Block until the write in flight is committed, under ``ckpt/wait``,
+        and raise here what the writer raised; nothing where none is."""
+        self.start()
+        write, self._in_flight = self._in_flight, None
+        if write is not None:
+            with tracing.span("ckpt/wait"):
+                write.result()
+
+
 class ExperimentCheckpoints:
     """Role-addressed checkpoints under an experiment directory (the
-    reference's checkpoints/ + artifacts/ split, harness_utils.py:90-93)."""
+    reference's checkpoints/ + artifacts/ split, harness_utils.py:90-93).
+
+    A level save writes BEHIND the caller (module docstring): at most one
+    level is held or in flight (``_WriteBehind``), on the primary's one
+    writer thread from ``start_write()`` on, and ``wait()`` settles it. Every
+    method here that reads or lists the directory, and every save, waits
+    first; ``driver.run`` waits before it returns or raises. Every process of
+    a multi-host run reaches those points in the same order, which is what
+    lets ``wait()`` hold the save's cross-host barrier."""
 
     def __init__(self, expt_dir: str | Path, keep: frozenset = frozenset()):
         self.expt_dir = Path(expt_dir)
@@ -260,6 +321,12 @@ class ExperimentCheckpoints:
         # numpy one (``replicate`` in ``setup_level``).
         self._keep = frozenset(keep)
         self._resident: dict[str, PyTree] = {}
+        # The level save that ``wait()`` has yet to settle: its directory's
+        # name, on every process (the barrier's key). Its tree and its write
+        # are the primary's alone: a process that never saved a level (the
+        # server) or is not the primary never starts the writer's thread.
+        self._unsettled: Optional[str] = None
+        self._behind = _WriteBehind()
 
     # --- path helpers -----------------------------------------------------
     def model_path(self, role: str) -> Path:
@@ -279,7 +346,32 @@ class ExperimentCheckpoints:
             "batch_stats": state.batch_stats,
         }
 
+    # --- the write behind -------------------------------------------------
+    def start_write(self) -> None:
+        """Hand the level that ``save_level`` fetched to the writer; nothing
+        where none is held. The epoch loop calls this once an epoch is
+        dispatched and the host is about to stand waiting for the device: a
+        write begun at once would share the host with the next level's prune
+        and set-up and stretch both (PERF.md, PR 30)."""
+        self._behind.start()
+
+    def wait(self) -> None:
+        """Settle the level save in flight, if there is one: start its write
+        if nobody has, block until its directory is committed (``ckpt/wait``:
+        the seconds a caller stood waiting for a write, about 0 where the
+        write was hidden), re-raise here what the writer raised, then hold
+        the barrier after which every host may read it."""
+        from ..parallel.multihost import sync_hosts
+
+        if self._unsettled is None:
+            return
+        name, self._unsettled = self._unsettled, None
+        self._behind.settle()
+        with tracing.span("ckpt/barrier"):
+            sync_hosts(f"save_pytree:{name}")
+
     def save_model(self, role: str, state) -> None:
+        self.wait()
         tree = self.model_state(state)
         if role in self._keep:
             # On EVERY process: each keeps its own copy of the replicated
@@ -289,25 +381,44 @@ class ExperimentCheckpoints:
         save_model_tree(self.model_path(role), tree)
 
     def load_model(self, role: str, like_state) -> dict:
+        self.wait()
         return restore_model_tree(
             self.model_path(role), self.model_state(like_state)
         )
 
     def save_level(self, level: int, state) -> None:
-        save_model_tree(self.level_path(level), self.model_state(state))
+        """Bring the level's tree to the host and return: the write runs
+        behind the caller, from ``start_write()`` to ``wait()``. The fetch
+        cannot: the next level's first step donates the state's buffers."""
+        from ..parallel.multihost import is_primary
+
+        self.wait()
+        path = self.level_path(level).resolve()
+        tree = _packed(self.model_state(state))
+        self._unsettled = path.name
+        if is_primary():
+            # The writer's span stack is its own thread's, so the span there
+            # is told the level (and epoch) it would have inherited here.
+            self._behind.hold(path, fetch_to_host(tree), tracing.inherited())
 
     def load_level(self, level: int, like_state) -> dict:
+        self.wait()
         return restore_model_tree(
             self.level_path(level), self.model_state(like_state)
         )
 
     def has_model(self, role: str) -> bool:
+        self.wait()
         return self.model_path(role).exists()
 
     def has_level(self, level: int) -> bool:
+        self.wait()
         return self.level_path(level).exists()
 
     def saved_levels(self) -> list[int]:
+        """The committed levels: one whose write has not been renamed into
+        place has no ``model_level_<n>`` directory yet."""
+        self.wait()
         out = []
         for p in self.checkpoints_dir.iterdir():
             m = _LEVEL_RE.match(p.name)
@@ -340,6 +451,9 @@ class ExperimentCheckpoints:
         # the harness falls back to replaying the level — never a mixed
         # old-header/new-state restore.
         tag = level * 1_000_000 + epoch  # int: Orbax round-trips it exactly
+        # In line, unlike a level save: the header below may only follow a
+        # committed tree.
+        self.wait()
         save_model_tree(
             self.mid_level_path(),
             {
@@ -376,6 +490,7 @@ class ExperimentCheckpoints:
         """Restore the slot; returns the state dict, or None when the slot's
         embedded tag disagrees with the header-derived expectation (a torn
         save — the caller must replay the level from its start)."""
+        self.wait()
         restored = restore_model_tree(
             self.mid_level_path(),
             {
@@ -433,8 +548,6 @@ class ExperimentCheckpoints:
         otherwise hijack a later re-run of its level (e.g. resume at level 2
         after a preemption at level 3 — the recomputed level-3 entry must
         not restore the old trajectory's state)."""
-        import shutil
-
         from ..parallel.multihost import is_primary, sync_hosts
 
         if is_primary():
@@ -447,11 +560,13 @@ class ExperimentCheckpoints:
 
     # --- optimizer roles --------------------------------------------------
     def save_optimizer(self, role: str, opt_state) -> None:
+        self.wait()
         if role in self._keep:  # optimizer_rewind, like a model rewind target
             opt_state = self._resident[role] = fetch_to_host(opt_state)
         save_pytree(self.optimizer_path(role), opt_state)
 
     def load_optimizer(self, role: str, like_opt_state):
+        self.wait()
         return restore_pytree(self.optimizer_path(role), like_opt_state)
 
     # --- resident rewind targets -------------------------------------------

@@ -40,7 +40,11 @@ TERMS = (
     "epoch/feed", "epoch/train", "epoch/eval", "epoch/log", "epoch/ckpt",
     "level/finish", "level/save",
 )  # fmt: skip
-_CKPT = ("ckpt/read", "ckpt/fetch", "ckpt/write", "ckpt/barrier")
+_CKPT = ("ckpt/read", "ckpt/fetch", "ckpt/wait", "ckpt/write", "ckpt/barrier")
+# A level save's ``ckpt/write`` runs behind the next level, on the writer's
+# thread (utils/checkpoint.py), so it is no span's child: a level's line and
+# row name the write that ENDED during the level, beside the level's own time.
+BEHIND = "ckpt/write"
 
 _spans: deque = deque(maxlen=MAX_SPANS)  # closed spans, in closing order
 _ids = itertools.count(1)
@@ -98,6 +102,13 @@ def span(name: str, **attrs: Any) -> Span:
     return Span(name, attrs)
 
 
+def inherited() -> dict:
+    """What a span opened here would inherit (``INHERITED``): for a span that
+    opens on another thread on this one's behalf, whose stack holds nothing."""
+    stack = _stack()
+    return {k: stack[-1].attrs[k] for k in INHERITED if k in stack[-1].attrs} if stack else {}
+
+
 def note(**attrs: Any) -> None:
     """Add attributes to the innermost span open on this thread, for what is
     known only inside it; nothing where none is open. The recorder's span gets
@@ -144,7 +155,9 @@ def breakdown(roots: Sequence[Span], spans: Optional[Sequence[Span]] = None) -> 
     (or, for hand-made spans, found in ``spans``): the seconds of each of
     ``TERMS`` with its children, ``inside`` each term its direct children by
     name, the containers' self time as ``other_s``, and the compilations
-    charged anywhere below. Terms and ``other_s`` sum to ``total_s``."""
+    charged anywhere below. Terms and ``other_s`` sum to ``total_s``. Beside
+    them ``behind``: the ``(level, seconds)`` of each write that ended on
+    another thread while a root was open."""
     terms: dict = defaultdict(float)
     inside: dict = defaultdict(lambda: defaultdict(float))
     out = {"total_s": sum(r.seconds for r in roots), "other_s": 0.0, "compiles": 0, "compile_s": 0.0}
@@ -165,6 +178,11 @@ def breakdown(roots: Sequence[Span], spans: Optional[Sequence[Span]] = None) -> 
         else:
             out["other_s"] += s.seconds - sum(c.seconds for c in children[s.id])
         todo.extend((c, term, depth + 1 if term else 0) for c in children[s.id])
+    out["behind"] = [
+        (s.attrs.get("level"), s.seconds)
+        for s in (recorded() if spans is None else spans)
+        if s.name == BEHIND and any(s.thread != r.thread and r.start < s.end <= r.end for r in roots)
+    ]
     return {**out, "terms": dict(terms), "inside": {k: dict(v) for k, v in inside.items()}}
 
 
@@ -173,9 +191,10 @@ def _short(name: str) -> str:
 
 
 def line(title: str, b: dict) -> str:
-    """``[time] level 3: 2.07 s = prune 0.11 + ... + save 0.34 (fetch 0.07,
-    write 0.27, barrier 0.00) + other 0.03; compiled 0 modules, 0.0 s``. A
-    term is named only where its span ran: ``load`` in a resumed level."""
+    """``[time] level 3: 1.80 s = prune 0.11 + ... + save 0.08 (wait 0.00,
+    barrier 0.00, fetch 0.07) + other 0.03; wrote level 2 behind, 0.27 s;
+    compiled 0 modules, 0.0 s``. A term is named only where its span ran:
+    ``load`` in a resumed level."""
     parts = []
     for name in (n for n in TERMS if n in b["terms"]):
         part = f"{_short(name)} {b['terms'][name]:.2f}"
@@ -186,6 +205,7 @@ def line(title: str, b: dict) -> str:
     return (
         f"[time] {title}: {b['total_s']:.2f} s = "
         + " + ".join(parts + [f"other {b['other_s']:.2f}"])
+        + "".join(f"; wrote level {level} behind, {s:.2f} s" for level, s in b["behind"])
         + f"; compiled {b['compiles']} modules, {b['compile_s']:.1f} s"
     )
 
@@ -208,6 +228,7 @@ def timing_row(level: Span, b: dict) -> dict:
     for split in b["inside"].values():
         for name in set(split) & set(_CKPT):
             row[name.replace("/", "_") + "_s"] += split[name]
+    row[BEHIND.replace("/", "_") + "_s"] += sum(s for _, s in b["behind"])
     return row
 
 
