@@ -60,13 +60,17 @@ def main(argv=None) -> int:
     args = parse_args(argv if argv is not None else sys.argv[1:])
 
     from turboprune_tpu.config.compose import compose
-    from turboprune_tpu.serve import build_server
+    from turboprune_tpu.serve import NotServable, build_server
     from turboprune_tpu.utils.compile_cache import place_compile_cache
 
     place_compile_cache()
 
     cfg = compose(args.config_name, args.overrides, args.config_path)
-    server = build_server(cfg, expt_dir=args.expt_dir)
+    try:
+        server = build_server(cfg, expt_dir=args.expt_dir)
+    except NotServable as e:
+        print(f"run_server: {e}", file=sys.stderr, flush=True)
+        return 2
     host, port = server.server_address[:2]
     if server.fleet is not None:
         info = server.fleet.info()

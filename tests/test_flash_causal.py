@@ -1,0 +1,118 @@
+"""The causal, grouped, packed path of the Pallas flash kernel
+(ops/flash.py::flash_attention_causal) against a plain masked softmax,
+forward and gradient, in interpret mode; and the bidirectional call, which
+the language model's path may not have changed by a bit."""
+
+import hashlib
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from turboprune_tpu.ops.flash import _block_ranges, flash_attention, flash_attention_causal
+
+BATCH, HEADS, KV_HEADS, T, D = 2, 4, 2, 64, 8
+SCALE = 0.3
+
+
+def plain(q, k, v, seg, scale, heads=HEADS, kv_heads=KV_HEADS):
+    bsz, t, d = seg.shape[0], q.shape[1], q.shape[2]
+    q = q.reshape(bsz, kv_heads, heads // kv_heads, t, d)
+    k, v = k.reshape(bsz, kv_heads, t, d), v.reshape(bsz, kv_heads, t, d)
+    s = jnp.einsum("bkgqd,bksd->bkgqs", q, k) * scale
+    pos = jnp.arange(t)
+    keep = (seg[:, :, None] == seg[:, None, :]) & (pos[None, :, None] >= pos[None, None, :])
+    w = jax.nn.softmax(jnp.where(keep[:, None, None], s, -jnp.inf), axis=-1)
+    return jnp.einsum("bkgqs,bksd->bkgqd", w, v).reshape(bsz * heads, t, d)
+
+
+def inputs(seed=0, dtype=jnp.float32):
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(seed), 3)
+    q = jax.random.normal(kq, (BATCH * HEADS, T, D), dtype)
+    k = jax.random.normal(kk, (BATCH * KV_HEADS, T, D), dtype)
+    v = jax.random.normal(kv, (BATCH * KV_HEADS, T, D), dtype)
+    flags = np.zeros((BATCH, T), np.int32)
+    flags[0, [5, 16, 17, 40]] = 1  # starts inside blocks and on a block's border
+    flags[1, [32]] = 1
+    return q, k, v, jnp.asarray(np.cumsum(flags, axis=1))
+
+
+@pytest.mark.parametrize("blocks", [(16, 16), (32, 32), (64, 64), (16, 32), (32, 16)])
+def test_forward_equals_a_plain_masked_softmax(blocks):
+    q, k, v, seg = inputs()
+    with jax.default_matmul_precision("highest"):
+        got = flash_attention_causal(q, k, v, seg, SCALE, *blocks)
+        want = plain(q, k, v, seg, SCALE)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-6)
+
+
+@pytest.mark.parametrize("blocks", [(16, 16), (64, 64), (16, 32)])
+def test_gradients_equal_a_plain_masked_softmax(blocks):
+    q, k, v, seg = inputs(seed=1)
+    weigh = lambda fn: jax.grad(lambda q, k, v: jnp.sum(jnp.sin(fn(q, k, v))), argnums=(0, 1, 2))
+    with jax.default_matmul_precision("highest"):
+        got = weigh(lambda q, k, v: flash_attention_causal(q, k, v, seg, SCALE, *blocks))(q, k, v)
+        want = weigh(lambda q, k, v: plain(q, k, v, seg, SCALE))(q, k, v)
+    for name, g, w in zip("qkv", got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=1e-5, err_msg=name)
+
+
+def test_one_key_value_head_a_query_head_is_plain_multi_head():
+    q, k, v, seg = inputs()
+    k = jnp.repeat(k.reshape(BATCH, KV_HEADS, T, D), HEADS // KV_HEADS, axis=1).reshape(q.shape)
+    v = jnp.repeat(v.reshape(BATCH, KV_HEADS, T, D), HEADS // KV_HEADS, axis=1).reshape(q.shape)
+    got = flash_attention_causal(q, k, v, seg, SCALE, 16, 16)
+    want = plain(q, k, v, seg, SCALE, kv_heads=HEADS)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-6)
+
+
+def test_blocks_of_other_documents_are_skipped_and_change_nothing():
+    """Poison (NaN) in the keys and values of the first document's blocks
+    reaches no query of a later document: those blocks are skipped, not
+    multiplied by zero."""
+    q, k, v, _ = inputs()
+    seg = jnp.asarray(np.repeat(np.arange(4), 16)[None].repeat(BATCH, 0), jnp.int32)
+    clean = flash_attention_causal(q, k, v, seg, SCALE, 16, 16)
+    k, v = k.at[:, :16].set(jnp.nan), v.at[:, :16].set(jnp.nan)
+    got = flash_attention_causal(q, k, v, seg, SCALE, 16, 16)
+    np.testing.assert_array_equal(np.asarray(got[:, 16:]), np.asarray(clean[:, 16:]))
+    lo, hi = _block_ranges(seg, 16)
+    assert lo.tolist() == hi.tolist() == [0, 1, 2, 3] * BATCH
+
+
+def test_bf16_operands():
+    q, k, v, seg = inputs(dtype=jnp.bfloat16)
+    got = flash_attention_causal(q, k, v, seg, SCALE, 16, 16)
+    want = plain(*(t.astype(jnp.float32) for t in (q, k, v)), seg, SCALE)
+    assert got.dtype == jnp.bfloat16
+    np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want), atol=0.05)
+
+
+@pytest.mark.parametrize(
+    "change, match",
+    [
+        (lambda q, k, v, seg: (q, k, v, seg[:, :48]), "share a batch"),
+        (lambda q, k, v, seg: (q[:6], k[:4], v[:4], seg), "not a multiple"),
+        (lambda q, k, v, seg: (q[:, :40], k[:, :40], v[:, :40], seg[:, :40]), "multiple of"),
+    ],
+)
+def test_shapes_that_do_not_fit_are_refused(change, match):
+    with pytest.raises(ValueError, match=match):
+        flash_attention_causal(*change(*inputs()), SCALE, 16, 16)
+
+
+def test_the_bidirectional_call_is_the_program_it_was():
+    """The lowered program of ``flash_attention`` (forward and its three
+    gradients, interpret mode) hashes to what the commit before the causal
+    path gave: the same program gives the same bits. Its values are held to
+    the dense oracle in test_flash.py."""
+    rng = np.random.default_rng(20260929)
+    q, k, v = (jnp.asarray(rng.normal(size=(6, 32, 8)), jnp.float32) for _ in range(3))
+    valid = jnp.asarray([[1.0] * 27 + [0.0] * 5])
+    f = lambda q, k, v: flash_attention(q, k, v, valid, 0.35, 16, 8)
+    g = jax.jit(lambda q, k, v: jax.grad(lambda q, k, v: jnp.sum(jnp.sin(f(q, k, v))), argnums=(0, 1, 2))(q, k, v))
+    text = g.lower(q, k, v).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == (
+        "23c63effc2c0d248fc9f1d0bbe75e7d8842bbe9fdf9f5f4e9e7e3af35340d7e8"
+    )

@@ -232,7 +232,11 @@ class TestStepRecord:
             (text,) = lowered
         assert re.match(r"module @(\w+)", text).group(1) == name
         cells = sorted((Path(__file__).parents[1] / "benchmarks" / "workloads").glob("*.json"))
-        assert cells and all(json.loads(c.read_text())["params"][key] == name for c in cells)
+        # A cell whose loader has no such program (packed tokens are not
+        # augmented) does not name one; every cell names its step program.
+        named = [json.loads(c.read_text())["params"].get(key) for c in cells]
+        assert named.count(name) >= 2 and set(named) <= {name, None}
+        assert key != "step_program" or None not in named
 
     def test_one_record_a_budget_and_the_dense_one_back_after_a_plan(self, harness):
         h = harness

@@ -26,7 +26,8 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 import chip_smoke
-from turboprune_tpu.ops.flash import flash_attention
+from turboprune_tpu.ops.flash import flash_attention, flash_attention_causal
+from turboprune_tpu.ops.ssd import ssd_chunked
 
 V5E_HBM_BYTES = 16 * 2**30
 
@@ -101,6 +102,53 @@ def test_flash_kernel_compiles_for_v5e(
     fn = jax.grad(loss, argnums=(0, 1, 2)) if backward else forward
     compiled = jax.jit(fn).lower(qkv, qkv, qkv, valid).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# The language-model cell's attention layer: 32 query heads over 8 key/value
+# heads of 64, one packed sequence of 8,192 tokens, blocks of 512.
+@pytest.mark.parametrize("backward", [False, True], ids=["forward", "grad"])
+def test_causal_flash_kernel_compiles_for_v5e(one_chip, no_persistent_cache, backward):
+    q, kv, seg = _placed(
+        (
+            jax.ShapeDtypeStruct((32, 8192, 64), jnp.bfloat16),
+            jax.ShapeDtypeStruct((8, 8192, 64), jnp.bfloat16),
+            jax.ShapeDtypeStruct((1, 8192), jnp.int32),
+        ),
+        one_chip,
+    )
+
+    def forward(q, k, v, seg):
+        return flash_attention_causal(q, k, v, seg, 0.015625, 512, 512, interpret=False)
+
+    def loss(q, k, v, seg):
+        return forward(q, k, v, seg).astype(jnp.float32).sum()
+
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if backward else forward
+    text = jax.jit(fn).lower(q, kv, kv, seg).compile().as_text()
+    names = ("flash_causal_fwd", "flash_causal_dq", "flash_causal_dkv") if backward else ("flash_causal_fwd",)
+    assert "tpu_custom_call" in text and all(name in text for name in names)
+
+
+def test_chunked_scan_compiles_for_v5e_and_fits(one_chip, no_persistent_cache):
+    """One Mamba-2 layer's scan at published widths over 8,192 tokens,
+    forward and backward: the [Q, Q] decay tensors of its 32 chunks are the
+    layer's largest temporaries, and must leave the chip room."""
+    x, dt, a, bc, seg = _placed(
+        (
+            jax.ShapeDtypeStruct((1, 8192, 64, 64), jnp.bfloat16),
+            jax.ShapeDtypeStruct((1, 8192, 64), jnp.float32),
+            jax.ShapeDtypeStruct((64,), jnp.float32),
+            jax.ShapeDtypeStruct((1, 8192, 128), jnp.bfloat16),
+            jax.ShapeDtypeStruct((1, 8192), jnp.int32),
+        ),
+        one_chip,
+    )
+
+    def loss(x, dt, a, b, c, seg):
+        return ssd_chunked(x, dt, a, b, c, seg, 256).astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(x, dt, a, bc, bc, seg).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2**30
 
 
 def test_ring_attention_compiles_for_a_2x2_mesh(topo, no_persistent_cache):

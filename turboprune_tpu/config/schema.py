@@ -14,7 +14,10 @@ from dataclasses import dataclass, field, fields
 from typing import Any, Optional
 
 # Choice sets (reference: harness_params.py Literals).
-DATASETS = ("CIFAR10", "CIFAR100", "ImageNet")
+DATASETS = ("CIFAR10", "CIFAR100", "ImageNet", "SyntheticTokens")
+# Datasets whose samples are packed token sequences (data/tokens.py), not
+# images: the loader, the model's input and the console's unit follow.
+TOKEN_DATASETS = ("SyntheticTokens",)
 DATALOADER_TYPES = ("device", "grain", "tpk", "synthetic")
 MASK_LAYER_TYPES = ("ConvMask", "LinearMask")
 PRUNE_METHODS = (
@@ -145,6 +148,29 @@ class DatasetConfig:
     # compiled lax.scan dispatch (1 = per-step dispatch). Device-resident
     # loaders already scan whole epochs and ignore this knob.
     scan_chunk_steps: int = 1
+    # Token datasets (dataset_name in TOKEN_DATASETS; data/tokens.py): a
+    # sample is one packed sequence of seq_len tokens, num_classes is the
+    # vocabulary held, total_batch_size counts sequences. Document lengths
+    # are exp(N(mu, sigma)) clipped to [doc_len_min, seq_len], drawn from
+    # layout_seed and NOT from the experiment's seed: every seed packs the
+    # same documents and so does the same work.
+    seq_len: int = 0
+    doc_len_mu: float = 6.5
+    doc_len_sigma: float = 1.2
+    doc_len_min: int = 16
+    layout_seed: int = 0
+
+    @property
+    def is_tokens(self) -> bool:
+        return self.dataset_name in TOKEN_DATASETS
+
+    def input_spec(self) -> tuple[tuple, str]:
+        """(shape, dtype) of a one-sample batch that initialises a model. A
+        token model's parameters do not depend on the length, so its dummy
+        is short and its init program small."""
+        if self.is_tokens:
+            return (1, 2, min(self.seq_len, 512)), "int32"
+        return (1, self.image_size, self.image_size, 3), "float32"
 
     def validate(self) -> None:
         _check_choice("dataset_params.dataset_name", self.dataset_name, DATASETS)
@@ -174,6 +200,23 @@ class DatasetConfig:
             raise ConfigError("decode_workers must be >= 1")
         if self.scan_chunk_steps < 1:
             raise ConfigError("scan_chunk_steps must be >= 1")
+        if self.is_tokens:
+            if self.dataloader_type != "synthetic":
+                raise ConfigError(
+                    f"dataset_name={self.dataset_name} is generated: it needs "
+                    "dataloader_type=synthetic"
+                )
+            if self.seq_len < 2 or self.num_classes < 2:
+                raise ConfigError(
+                    f"dataset_name={self.dataset_name} needs seq_len >= 2 and "
+                    "num_classes (the vocabulary held) >= 2"
+                )
+            if not (1 <= self.doc_len_min <= self.seq_len) or self.doc_len_sigma < 0:
+                raise ConfigError(
+                    "doc_len_min must lie in [1, seq_len] and doc_len_sigma "
+                    "must not be negative"
+                )
+            return
         if self.image_size == 0:
             self.image_size = 224 if self.dataset_name == "ImageNet" else 32
         if self.num_classes == 0:
@@ -205,11 +248,17 @@ class ModelConfig:
     # "flash" = single-device blockwise Pallas kernel (ops/flash.py).
     # ViT models only; params/checkpoints identical across all three.
     attention_impl: str = "dense"
+    # Depth of a language model that repeats a period of layer kinds
+    # (models/granite.py): the first N layers of the published order. 0 = as
+    # published. Image models have one depth and reject the knob.
+    num_hidden_layers: int = 0
 
     def validate(self) -> None:
         _check_choice(
             "model_params.mask_layer_type", self.mask_layer_type, MASK_LAYER_TYPES
         )
+        if self.num_hidden_layers < 0:
+            raise ConfigError("model_params.num_hidden_layers must be >= 0")
         _check_choice(
             "model_params.attention_impl", self.attention_impl, ATTENTION_IMPLS
         )
@@ -558,6 +607,23 @@ class MainConfig:
                 "model_parallelism > 1 requires model_params.attention_impl="
                 "ring (nothing else uses the model axis; dense attention "
                 "would silently duplicate compute across it)"
+            )
+        # Cross-group: a token model reads token batches and nothing else
+        # does (models.LANGUAGE_MODELS is the registry's list).
+        from ..models import LANGUAGE_MODELS
+
+        is_lm = self.model_params.model_name in LANGUAGE_MODELS
+        if is_lm != self.dataset_params.is_tokens:
+            raise ConfigError(
+                f"model_name={self.model_params.model_name!r} and "
+                f"dataset_name={self.dataset_params.dataset_name!r} do not go "
+                "together: a language model trains on a token dataset "
+                f"({TOKEN_DATASETS}), an image model on images"
+            )
+        if self.model_params.num_hidden_layers and not is_lm:
+            raise ConfigError(
+                "model_params.num_hidden_layers is a language model's depth "
+                f"(got model_name={self.model_params.model_name!r})"
             )
         # Cross-group: prune_method "nm" is magnitude pruning + N:M
         # projection — without a pattern there is nothing to project onto.

@@ -11,11 +11,15 @@ standard_pruning_harness.py:145-157):
   tpk       first-party native loader: mmap'd packed file + multithreaded
             C++ decode/crop (native/tpkdata.cpp) — FFCV's actual
             architecture (compiled pipeline + os_cache mmap)
-  synthetic deterministic generated data (zero-egress tests/benches)
+  synthetic deterministic generated data (zero-egress tests/benches);
+            for a token dataset (config TOKEN_DATASETS) packed token
+            sequences, resident like ``device`` (data/tokens.py)
 
 All loaders share one contract: ``.train_loader`` / ``.test_loader``
 iterables yielding device-resident ``(images NHWC float, labels int32)``,
-``len(loader)`` = batches per epoch, ``.num_classes``.
+``len(loader)`` = batches per epoch, ``.num_classes``. A token dataset's
+batch is ``(tokens [B, 2, T] int32, targets [B, T] int32)`` under the same
+contract.
 """
 
 from __future__ import annotations
@@ -40,6 +44,7 @@ from .cifar import CifarLoaders, DeviceCifarLoader, cache_cifar_npz, load_cifar_
 from .imagenet import GrainImageLoader, ImageFolderSource, ImageNetLoaders
 from .pipeline import PrefetchEngine, stream_batches
 from .synthetic import SyntheticLoaders, synthetic_arrays
+from .tokens import PackedTokenLoader, SyntheticTokenLoaders
 
 
 def create_loaders(cfg) -> Any:
@@ -47,6 +52,19 @@ def create_loaders(cfg) -> Any:
     standard_pruning_harness.py:145-157)."""
     dp = cfg.dataset_params
     seed = cfg.experiment_params.seed
+    if dp.is_tokens:
+        return SyntheticTokenLoaders(
+            vocab_size=dp.num_classes,
+            seq_len=dp.seq_len,
+            batch_size=dp.total_batch_size,
+            num_train=dp.synthetic_num_train,
+            num_test=dp.synthetic_num_test,
+            doc_len_mu=dp.doc_len_mu,
+            doc_len_sigma=dp.doc_len_sigma,
+            doc_len_min=dp.doc_len_min,
+            layout_seed=dp.layout_seed,
+            seed=seed,
+        )
     if dp.dataloader_type == "synthetic":
         return SyntheticLoaders(
             dataset_name=dp.dataset_name,
@@ -103,6 +121,8 @@ __all__ = [
     "CifarLoaders",
     "DeviceCifarLoader",
     "SyntheticLoaders",
+    "SyntheticTokenLoaders",
+    "PackedTokenLoader",
     "ImageNetLoaders",
     "GrainImageLoader",
     "ImageFolderSource",

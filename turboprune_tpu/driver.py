@@ -140,11 +140,13 @@ def prune_level(harness, density: float, level: int) -> None:
             )
 
 
-def _say_time(title: str, roots: list) -> dict:
-    """The operator's ``[time]`` line for ``roots``; returns the breakdown."""
+def _say_time(title: str, roots: list, gauges: Optional[dict] = None) -> dict:
+    """The operator's ``[time]`` line for ``roots``, with ``gauges`` after it
+    where there are any; returns the breakdown."""
     b = tracing.breakdown(roots)
     if is_primary():
-        print(tracing.line(title, b), flush=True)
+        said = "".join(f"; {k} {v:g}" for k, v in (gauges or {}).items())
+        print(tracing.line(title, b) + said, flush=True)
     return b
 
 
@@ -172,7 +174,7 @@ def run(cfg: MainConfig, harness_cls: Optional[Type[PruningHarness]] = None):
         save_config(expt_dir, cfg)
 
     harness = harness_cls(cfg, (prefix, expt_dir))
-    _say_time("set-up", tracing.setup_roots())
+    _say_time("set-up", tracing.setup_roots(), harness.data_gauges)
 
     pp = cfg.pruning_params
     densities = generate_densities(
@@ -227,7 +229,9 @@ def run(cfg: MainConfig, harness_cls: Optional[Type[PruningHarness]] = None):
                 summary["achieved_density"] = achieved
                 summaries.append(summary)
             timing = _say_time(f"level {level}", [level_span])
-            harness.metrics.log_level_timing(tracing.timing_row(level_span, timing))
+            harness.metrics.log_level_timing(
+                {**tracing.timing_row(level_span, timing), **harness.data_gauges}
+            )
     finally:
         tracing.stop_profile()  # a level that raised must not leave one running
         # Returning or raising, every level this run reported is on disk

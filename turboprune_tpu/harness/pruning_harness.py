@@ -126,9 +126,15 @@ class PruningHarness:
                 compute_dtype=self.compute_dtype,
                 attention_impl=cfg.model_params.attention_impl,
                 mesh=self.mesh,
+                num_layers=cfg.model_params.num_hidden_layers,
             )
         with tracing.span("init/loaders"):  # synthetic data is made here
             self.loaders = loaders if loaders is not None else create_loaders(cfg)
+            # What a step holds, where the loader says (data/tokens.py):
+            # gauges, the set-up line and level_timing.csv (driver.run).
+            self.data_gauges: dict[str, float] = dict(getattr(self.loaders, "gauges", {}))
+            for name, value in self.data_gauges.items():
+                tracing.gauge(name, value)
         data_size = self.mesh.shape["data"]
         per_host_batch = cfg.dataset_params.total_batch_size // max(
             jax.process_count(), 1
@@ -204,12 +210,7 @@ class PruningHarness:
         """The seeded initial state (pretrained weights laid over it where
         the config names a checkpoint)."""
         cfg, ep = self.cfg, self.cfg.experiment_params
-        input_shape = (
-            1,
-            cfg.dataset_params.image_size,
-            cfg.dataset_params.image_size,
-            3,
-        )
+        input_shape, input_dtype = cfg.dataset_params.input_spec()
         # tx is rebuilt per level; init with a placeholder SGD so the
         # opt_state pytree has the final structure.
         tx0, _ = self._build_tx(epochs=ep.epochs_per_level)
@@ -218,6 +219,11 @@ class PruningHarness:
             tx0,
             jax.random.PRNGKey(ep.seed),
             input_shape,
+            input_dtype=input_dtype,
+            # The image models initialise op by op (PERF.md: ``init/state``);
+            # making theirs one program moves their cells' set-up and is not
+            # this model's change to make.
+            init_as_one_program=cfg.dataset_params.is_tokens,
         )
         if cfg.model_params.pretrained_path:
             # Warm-start ViT weights from a local timm checkpoint
@@ -515,6 +521,7 @@ class PruningHarness:
             mesh=self.mesh,
             width_overrides=width_overrides,
             nm_overrides=nm_overrides,
+            num_layers=self.cfg.model_params.num_hidden_layers,
         )
 
     def _enter_plan(self) -> None:
@@ -961,12 +968,15 @@ class PruningHarness:
             )
 
     def _log_console(self, row: dict) -> None:
+        # ``samples_per_sec`` counts what the step's ``count`` counts: images,
+        # or a token dataset's target tokens.
+        unit = "tok/s" if self.cfg.dataset_params.is_tokens else "img/s"
         print(
             f"[L{row['level']:>2} E{row['epoch']:>3}] "
             f"train {row['train_loss']:.4f}/{row['train_acc']:5.2f}% "
             f"test {row['test_loss']:.4f}/{row['test_acc']:5.2f}% "
             f"(best {row['max_test_acc']:5.2f}%) "
             f"sparsity {row['sparsity']:5.2f}% "
-            f"{row['samples_per_sec']:,.0f} img/s",
+            f"{row['samples_per_sec']:,.0f} {unit}",
             flush=True,
         )

@@ -13,8 +13,9 @@ from typing import Any, Callable
 
 import jax.numpy as jnp
 
-from . import densenet, resnet, vgg, vit
+from . import densenet, granite, resnet, vgg, vit
 from .densenet import DenseNet
+from .granite import HybridLM
 from .resnet import ResNet, resnet18, resnet34, resnet50, resnet101, resnet152
 from .vgg import VGG
 from .vit import VisionTransformer
@@ -45,7 +46,12 @@ MODEL_REGISTRY: dict[str, Callable] = {
     "deit_small_distilled_patch16_224": vit.deit_small_distilled_patch16_224,
     "deit_base_distilled_patch16_224": vit.deit_base_distilled_patch16_224,
     "deit_base_distilled_patch16_384": vit.deit_base_distilled_patch16_384,
+    "granite_4_0_h_micro": granite.granite_4_0_h_micro,
+    "hybrid_lm_tiny": granite.hybrid_lm_tiny,
 }
+# Models that read packed token batches (data/tokens.py) and return
+# next-token logits; ``num_classes`` is their vocabulary.
+LANGUAGE_MODELS = ("granite_4_0_h_micro", "hybrid_lm_tiny")
 
 
 def create_model(
@@ -57,6 +63,7 @@ def create_model(
     mesh: Any = None,
     width_overrides: Any = None,
     nm_overrides: Any = None,
+    num_layers: int = 0,
 ):
     """Build a model module with dataset-appropriate stem.
 
@@ -71,13 +78,24 @@ def create_model(
     ``nm_overrides`` (hook key -> (kept_in, kept_out) index tuples, from
     ``sparse.nm_execute.build_nm_plan``) routes matmul-heavy layers through
     the gathered N:M path; same normalization, composes with
-    ``width_overrides``."""
+    ``width_overrides``. ``num_layers`` is a language model's depth (0 = as
+    published); it always runs its causal flash kernel, whatever
+    ``attention_impl`` says of the ViTs."""
     if model_name not in MODEL_REGISTRY:
         raise ValueError(
             f"Model {model_name!r} not in registry: {sorted(MODEL_REGISTRY)}"
         )
     cifar_stem = dataset_name.lower() in ("cifar10", "cifar100")
     kwargs = {}
+    if model_name in LANGUAGE_MODELS:
+        if width_overrides or nm_overrides:
+            raise ValueError(
+                f"{model_name!r} has no compacted or gathered form "
+                "(sparse/graph.py): it runs masked"
+            )
+        return MODEL_REGISTRY[model_name](
+            num_classes, num_layers=num_layers, dtype=compute_dtype
+        )
     if model_name.startswith("deit"):
         kwargs = {"attention_impl": attention_impl, "mesh": mesh}
     elif attention_impl != "dense":
@@ -98,6 +116,8 @@ __all__ = [
     "MODEL_REGISTRY",
     "create_model",
     "DenseNet",
+    "HybridLM",
+    "LANGUAGE_MODELS",
     "ResNet",
     "VGG",
     "VisionTransformer",

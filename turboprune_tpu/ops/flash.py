@@ -1,7 +1,12 @@
 """First-party Pallas TPU flash attention (forward + backward kernels).
 
-Non-causal multi-head attention with a key-validity mask, computed
-blockwise so the S x S score matrix never materializes in HBM: for each
+Two entry points over the same recurrence. ``flash_attention``: non-causal
+multi-head attention with one key-validity mask shared by the batch (the ViT
+path; described first). ``flash_attention_causal``: causal attention inside
+packed documents with grouped key/value heads (the language-model path; its
+kernels are the second half of this file and say what differs).
+
+Attention is computed blockwise so the S x S score matrix never materializes in HBM: for each
 query block the kernel streams key/value blocks through VMEM, carrying the
 online-softmax running max/sum in VMEM scratch across the (sequential)
 innermost grid dimension — the flash-attention recurrence on the hardware
@@ -281,3 +286,295 @@ def _fa_bwd(scale, block_q, block_k, interpret, residuals, g):
 
 
 flash_attention.defvjp(_fa_fwd, _fa_bwd)
+
+
+# ------------------------------------------------- causal, packed, grouped
+# Attention of packed documents: a query sees the keys of its own document
+# at or before its own position. Query head ``h`` of ``Hq`` reads key/value
+# head ``h // (Hq / Hkv)``; the kernels never materialise the repeated heads.
+#
+# A block of scores that the causal order or the documents' extents mask
+# whole is skipped: ``_block_ranges`` gives each block of tokens the smallest
+# and largest segment id it holds, the kernels get them as scalars before the
+# body runs (scalar prefetch), and a (query block, key block) pair runs only
+# where the key block starts at or before the query block's last row and the
+# two ranges of ids overlap. The test is exact for documents stored one after
+# the other, and conservative (never wrong) for any other ids. Every query
+# sees itself, so the diagonal block always runs and no row's sum is empty.
+#
+# Operands go to the MXU in the dtype they come in (bf16 on the chip) and
+# accumulate in float32.
+
+
+def _block_ranges(segment_ids, block):
+    """(min, max) of the segment ids in each block of ``block`` tokens, each
+    flattened to [B * T / block] int32."""
+    blocks = segment_ids.astype(jnp.int32).reshape(segment_ids.shape[0], -1, block)
+    return blocks.min(axis=2).reshape(-1), blocks.max(axis=2).reshape(-1)
+
+
+def _runs(qmin, qmax, kmin, kmax, batch, qi, ki, nq, nk, block_q, block_k):
+    q, k = batch * nq + qi, batch * nk + ki
+    causal = ki * block_k <= qi * block_q + (block_q - 1)
+    return causal & (kmax[k] >= qmin[q]) & (kmin[k] <= qmax[q])
+
+
+def _keep(qseg_ref, kseg_ref, qi, ki, block_q, block_k):
+    """[Bq, Bk]: same document, key at or before the query."""
+    shape = (block_q, block_k)
+    qpos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    kpos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    return (qseg_ref[0] == kseg_ref[0]) & (qpos >= kpos)
+
+
+def _causal_fwd_kernel(qmin, qmax, kmin, kmax, q_ref, k_ref, v_ref, qseg_ref,
+                       kseg_ref, o_ref, lse_ref, acc, m, l, *, scale, heads,
+                       block_q, block_k):
+    bh, qi, ki = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    nq, nk = pl.num_programs(1), pl.num_programs(2)
+
+    @pl.when(ki == 0)
+    def _():
+        acc[:] = jnp.zeros_like(acc)
+        m[:] = jnp.full_like(m, -jnp.inf)
+        l[:] = jnp.zeros_like(l)
+
+    @pl.when(_runs(qmin, qmax, kmin, kmax, bh // heads, qi, ki, nq, nk,
+                   block_q, block_k))
+    def _():
+        keep = _keep(qseg_ref, kseg_ref, qi, ki, block_q, block_k)
+        s = jnp.where(keep, _dot_t1(q_ref[0], k_ref[0]) * scale, NEG_BIG)
+        m_old = m[:]
+        m_new = jnp.maximum(m_old, s.max(axis=1, keepdims=True))
+        p = jnp.where(keep, jnp.exp(s - m_new), 0.0)
+        corr = jnp.exp(m_old - m_new)
+        l[:] = l[:] * corr + p.sum(axis=1, keepdims=True)
+        acc[:] = acc[:] * corr + _dot(p.astype(v_ref.dtype), v_ref[0])
+        m[:] = m_new
+
+    @pl.when(ki == nk - 1)
+    def _():
+        o_ref[0] = (acc[:] / l[:]).astype(o_ref.dtype)
+        lse_ref[0] = m[:] + jnp.log(l[:])
+
+
+def _causal_dq_kernel(qmin, qmax, kmin, kmax, q_ref, k_ref, v_ref, qseg_ref,
+                      kseg_ref, do_ref, lse_ref, drow_ref, dq_ref, dq_acc, *,
+                      scale, heads, block_q, block_k):
+    bh, qi, ki = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    nq, nk = pl.num_programs(1), pl.num_programs(2)
+
+    @pl.when(ki == 0)
+    def _():
+        dq_acc[:] = jnp.zeros_like(dq_acc)
+
+    @pl.when(_runs(qmin, qmax, kmin, kmax, bh // heads, qi, ki, nq, nk,
+                   block_q, block_k))
+    def _():
+        keep = _keep(qseg_ref, kseg_ref, qi, ki, block_q, block_k)
+        k = k_ref[0]
+        s = _dot_t1(q_ref[0], k) * scale
+        p = jnp.where(keep, jnp.exp(s - lse_ref[0]), 0.0)
+        dp = _dot_t1(do_ref[0], v_ref[0])
+        ds = p * (dp - drow_ref[0]) * scale
+        dq_acc[:] = dq_acc[:] + _dot(ds.astype(k.dtype), k)
+
+    @pl.when(ki == nk - 1)
+    def _():
+        dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
+
+
+def _causal_dkv_kernel(qmin, qmax, kmin, kmax, q_ref, k_ref, v_ref, qseg_ref,
+                       kseg_ref, do_ref, lse_ref, drow_ref, dk_ref, dv_ref,
+                       dk_acc, dv_acc, *, scale, kv_heads, nq, block_q,
+                       block_k):
+    # The innermost axis walks the group's query heads, and each head's
+    # query blocks: they all add into this key block's dk and dv.
+    bh, ki, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    nk, last = pl.num_programs(1), pl.num_programs(2) - 1
+    qi = j % nq
+
+    @pl.when(j == 0)
+    def _():
+        dk_acc[:] = jnp.zeros_like(dk_acc)
+        dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    @pl.when(_runs(qmin, qmax, kmin, kmax, bh // kv_heads, qi, ki, nq, nk,
+                   block_q, block_k))
+    def _():
+        keep = _keep(qseg_ref, kseg_ref, qi, ki, block_q, block_k)
+        q, do = q_ref[0], do_ref[0]
+        s = _dot_t1(q, k_ref[0]) * scale
+        p = jnp.where(keep, jnp.exp(s - lse_ref[0]), 0.0)
+        dv_acc[:] = dv_acc[:] + _dot_t0(p.astype(do.dtype), do)
+        dp = _dot_t1(do, v_ref[0])
+        ds = p * (dp - drow_ref[0]) * scale
+        dk_acc[:] = dk_acc[:] + _dot_t0(ds.astype(q.dtype), q)
+
+    @pl.when(j == last)
+    def _():
+        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+
+
+def _causal_setup(q, k, segment_ids, block_q, block_k):
+    """Checks the shapes; returns (batch, heads, kv_heads, group, nq, nk),
+    the segment ids as the kernels' two tiles take them, and the blocks'
+    ranges for the scalar prefetch."""
+    (bh, s_len, _), bsz = q.shape, segment_ids.shape[0]
+    if segment_ids.shape != (bsz, s_len) or bh % bsz or k.shape[0] % bsz:
+        raise ValueError(
+            f"flash_attention_causal: q {q.shape}, k {k.shape} and "
+            f"segment_ids {segment_ids.shape} do not share a batch and a length"
+        )
+    heads, kv_heads = bh // bsz, k.shape[0] // bsz
+    if heads % kv_heads:
+        raise ValueError(
+            f"flash_attention_causal: {heads} query heads are not a multiple "
+            f"of {kv_heads} key/value heads"
+        )
+    if s_len % block_q or s_len % block_k:
+        raise ValueError(
+            f"flash_attention_causal: seq {s_len} must be a multiple of "
+            f"block_q={block_q} and block_k={block_k}"
+        )
+    seg = segment_ids.astype(jnp.int32)
+    ranges = _block_ranges(seg, block_q) + _block_ranges(seg, block_k)
+    dims = (bsz, heads, kv_heads, heads // kv_heads, s_len // block_q, s_len // block_k)
+    return dims, seg[:, :, None], seg[:, None, :], ranges
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def flash_attention_causal(
+    q: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    segment_ids: jax.Array,
+    scale: float,
+    block_q: int = 512,
+    block_k: int = 512,
+    interpret: Optional[bool] = None,
+) -> jax.Array:
+    """Causal blockwise attention inside packed documents. q: [batch *
+    heads, seq, head_dim]; k, v: [batch * kv_heads, seq, head_dim], heads a
+    multiple of kv_heads (batch-major both: row ``b * heads + h``);
+    segment_ids: [batch, seq] ints, constant along a document.
+    ``block_q``/``block_k`` must divide ``seq``. Returns the shape of q."""
+    o, _ = _fac_fwd(q, k, v, segment_ids, scale, block_q, block_k, interpret)
+    return o
+
+
+def _fac_fwd(q, k, v, segment_ids, scale, block_q, block_k, interpret):
+    if interpret is None:
+        interpret = _use_interpret()
+    (_, heads, _, group, nq, nk), qseg, kseg, ranges = _causal_setup(
+        q, k, segment_ids, block_q, block_k
+    )
+    bh, s_len, d = q.shape
+    o, lse = pl.pallas_call(
+        functools.partial(_causal_fwd_kernel, scale=scale, heads=heads,
+                          block_q=block_q, block_k=block_k),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(bh, nq, nk),
+            in_specs=[
+                pl.BlockSpec((1, block_q, d), lambda b, qi, ki, *_: (b, qi, 0)),
+                pl.BlockSpec((1, block_k, d), lambda b, qi, ki, *_: (b // group, ki, 0)),
+                pl.BlockSpec((1, block_k, d), lambda b, qi, ki, *_: (b // group, ki, 0)),
+                pl.BlockSpec((1, block_q, 1), lambda b, qi, ki, *_: (b // heads, qi, 0)),
+                pl.BlockSpec((1, 1, block_k), lambda b, qi, ki, *_: (b // heads, 0, ki)),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, block_q, d), lambda b, qi, ki, *_: (b, qi, 0)),
+                pl.BlockSpec((1, block_q, 1), lambda b, qi, ki, *_: (b, qi, 0)),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_q, d), jnp.float32),
+                pltpu.VMEM((block_q, 1), jnp.float32),
+                pltpu.VMEM((block_q, 1), jnp.float32),
+            ],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((bh, s_len, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, s_len, 1), jnp.float32),
+        ],
+        interpret=interpret,
+        name="flash_causal_fwd",
+    )(*ranges, q, k, v, qseg, kseg)
+    return o, (q, k, v, segment_ids, o, lse)
+
+
+def _fac_bwd(scale, block_q, block_k, interpret, residuals, g):
+    if interpret is None:
+        interpret = _use_interpret()
+    q, k, v, segment_ids, o, lse = residuals
+    (_, heads, kv_heads, group, nq, nk), qseg, kseg, ranges = _causal_setup(
+        q, k, segment_ids, block_q, block_k
+    )
+    bh, _, d = q.shape
+    drow = jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32), axis=-1,
+                   keepdims=True)
+
+    dq = pl.pallas_call(
+        functools.partial(_causal_dq_kernel, scale=scale, heads=heads,
+                          block_q=block_q, block_k=block_k),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(bh, nq, nk),
+            in_specs=[
+                pl.BlockSpec((1, block_q, d), lambda b, qi, ki, *_: (b, qi, 0)),
+                pl.BlockSpec((1, block_k, d), lambda b, qi, ki, *_: (b // group, ki, 0)),
+                pl.BlockSpec((1, block_k, d), lambda b, qi, ki, *_: (b // group, ki, 0)),
+                pl.BlockSpec((1, block_q, 1), lambda b, qi, ki, *_: (b // heads, qi, 0)),
+                pl.BlockSpec((1, 1, block_k), lambda b, qi, ki, *_: (b // heads, 0, ki)),
+                pl.BlockSpec((1, block_q, d), lambda b, qi, ki, *_: (b, qi, 0)),
+                pl.BlockSpec((1, block_q, 1), lambda b, qi, ki, *_: (b, qi, 0)),
+                pl.BlockSpec((1, block_q, 1), lambda b, qi, ki, *_: (b, qi, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, block_q, d), lambda b, qi, ki, *_: (b, qi, 0)),
+            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        interpret=interpret,
+        name="flash_causal_dq",
+    )(*ranges, q, k, v, qseg, kseg, g, lse, drow)
+
+    # Rows of q for key/value row ``b``: head ``b * group + j // nq`` (the
+    # batch-major layouts make that the right batch too), block ``j % nq``.
+    q_row = lambda b, ki, j, *_: (b * group + j // nq, j % nq, 0)
+    dk, dv = pl.pallas_call(
+        functools.partial(_causal_dkv_kernel, scale=scale, kv_heads=kv_heads,
+                          nq=nq, block_q=block_q, block_k=block_k),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(k.shape[0], nk, group * nq),
+            in_specs=[
+                pl.BlockSpec((1, block_q, d), q_row),
+                pl.BlockSpec((1, block_k, d), lambda b, ki, j, *_: (b, ki, 0)),
+                pl.BlockSpec((1, block_k, d), lambda b, ki, j, *_: (b, ki, 0)),
+                pl.BlockSpec((1, block_q, 1), lambda b, ki, j, *_: (b // kv_heads, j % nq, 0)),
+                pl.BlockSpec((1, 1, block_k), lambda b, ki, j, *_: (b // kv_heads, 0, ki)),
+                pl.BlockSpec((1, block_q, d), q_row),
+                pl.BlockSpec((1, block_q, 1), q_row),
+                pl.BlockSpec((1, block_q, 1), q_row),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, block_k, d), lambda b, ki, j, *_: (b, ki, 0)),
+                pl.BlockSpec((1, block_k, d), lambda b, ki, j, *_: (b, ki, 0)),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_k, d), jnp.float32),
+                pltpu.VMEM((block_k, d), jnp.float32),
+            ],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct(k.shape, k.dtype),
+            jax.ShapeDtypeStruct(v.shape, v.dtype),
+        ],
+        interpret=interpret,
+        name="flash_causal_dkv",
+    )(*ranges, q, k, v, qseg, kseg, g, lse, drow)
+    return dq, dk, dv, None
+
+
+flash_attention_causal.defvjp(_fac_fwd, _fac_bwd)

@@ -20,7 +20,7 @@ group composed through config/compose.py.
 """
 
 from .batcher import DynamicBatcher, QueueFullError
-from .engine import DEFAULT_BUCKETS, InferenceEngine
+from .engine import DEFAULT_BUCKETS, InferenceEngine, NotServable
 from .fleet import (
     AOTExecutableCache,
     FleetEngine,
@@ -43,6 +43,7 @@ __all__ = [
     "DynamicBatcher",
     "FleetEngine",
     "InferenceEngine",
+    "NotServable",
     "InferenceServer",
     "LATENCY_BUCKETS_MS",
     "MetricsHub",

@@ -89,6 +89,10 @@ def _clone_factory(model):
     return factory
 
 
+class NotServable(ValueError):
+    """The experiment's model is of a kind the server has no path for."""
+
+
 class InferenceEngine:
     """Bucketed, mask-folded forward over a loaded checkpoint.
 
@@ -365,6 +369,13 @@ class InferenceEngine:
             )
         cfg = config_from_dict(yaml.safe_load(cfg_path.read_text()))
         dp = cfg.dataset_params
+        if dp.is_tokens:
+            raise NotServable(
+                f"{expt_dir} holds a language model "
+                f"({cfg.model_params.model_name} on {dp.dataset_name}): the "
+                "server classifies images and has no generation path (no "
+                "cache, no sampling), so it does not serve this checkpoint"
+            )
         dtype = PRECISION_DTYPES[
             precision or cfg.experiment_params.training_precision
         ]

@@ -11,6 +11,7 @@ pytree math between levels.
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Optional
 
 import jax
@@ -38,11 +39,22 @@ class TrainState:
         return out
 
 
-def init_variables(model, rng: jax.Array, input_shape: tuple) -> dict:
-    """Initialize model variables with a dummy batch (shape-only trace)."""
+def init_variables(
+    model,
+    rng: jax.Array,
+    input_shape: tuple,
+    input_dtype: str = "float32",
+    as_one_program: bool = False,
+) -> dict:
+    """Initialize model variables with a dummy batch (shape-only trace).
+    ``as_one_program`` jits the init: op by op, the init pass of a model of
+    some hundred operations is some hundred compilations."""
     p_rng, d_rng = jax.random.split(rng)
-    dummy = jnp.zeros(input_shape, jnp.float32)
-    return model.init({"params": p_rng, "dropout": d_rng}, dummy, train=False)
+    dummy = jnp.zeros(input_shape, input_dtype)
+    init = functools.partial(model.init, train=False)
+    if as_one_program:
+        init = jax.jit(init)
+    return init({"params": p_rng, "dropout": d_rng}, dummy)
 
 
 def create_train_state(
@@ -52,6 +64,8 @@ def create_train_state(
     input_shape: tuple,
     variables: Optional[dict] = None,
     masks: Optional[PyTree] = None,
+    input_dtype: str = "float32",
+    init_as_one_program: bool = False,
 ) -> TrainState:
     """Fresh state: init variables (unless given), all-ones masks (unless
     given), fresh optimizer state — the reference's per-level optimizer
@@ -59,7 +73,9 @@ def create_train_state(
     (standard_pruning_harness.py:174 semantics without object rebuild)."""
     init_rng, state_rng = jax.random.split(rng)
     if variables is None:
-        variables = init_variables(model, init_rng, input_shape)
+        variables = init_variables(
+            model, init_rng, input_shape, input_dtype, init_as_one_program
+        )
     params = variables["params"]
     if masks is None:
         masks = make_masks(params)

@@ -28,13 +28,31 @@ import optax
 from ..ops.masking import PyTree, apply_masks
 from .state import TrainState
 
-Batch = tuple[jax.Array, jax.Array]  # (images NHWC, integer labels)
+# (images NHWC, integer labels [B]) or, for a language model, (tokens
+# [B, 2, T], next-token targets [B, T] with the padding label where a token
+# has none): which one a step has it reads off the labels' rank.
+Batch = tuple[jax.Array, jax.Array]
 
 
 def cross_entropy_sum(logits: jax.Array, labels: jax.Array) -> jax.Array:
     """Summed CE in fp32 (mean is taken on the host over exact counts)."""
     logp = jax.nn.log_softmax(logits.astype(jnp.float32))
     return -jnp.take_along_axis(logp, labels[:, None], axis=1).sum()
+
+
+def masked_cross_entropy(logits: jax.Array, labels: jax.Array):
+    """(summed CE, hits, count) over the labels that are not the padding
+    label (< 0), in fp32; any rank, the classes last."""
+    valid = labels >= 0
+    safe = jnp.maximum(labels, 0)
+    logp = jax.nn.log_softmax(logits.astype(jnp.float32))
+    per_row = -jnp.take_along_axis(logp, safe[..., None], axis=-1)[..., 0]
+    hit = jnp.argmax(logits, axis=-1) == safe
+    return (
+        jnp.sum(jnp.where(valid, per_row, 0.0)),
+        jnp.sum(valid & hit).astype(jnp.float32),
+        jnp.sum(valid).astype(jnp.float32),
+    )
 
 
 def _forward_train(model, params, masks, batch_stats, images, rng):
@@ -77,18 +95,26 @@ def make_train_step(
                     model, params, state.masks, state.batch_stats, images, step_rng
                 )
             with jax.named_scope("loss"):
+                if labels.ndim > 1:
+                    # Token targets: the mean is over the valid ones, and the
+                    # logits [B, T, V] stay inside the gradient's scope. The
+                    # image path below keeps its arithmetic, and with it the
+                    # compiled program its cells have cached.
+                    loss_sum, correct, n = masked_cross_entropy(logits, labels)
+                    return loss_sum / n, (None, new_batch_stats, loss_sum, n, correct)
                 n = jnp.asarray(labels.shape[0], jnp.float32)
                 loss_sum = cross_entropy_sum(logits, labels)
-            return loss_sum / n, (logits, new_batch_stats, loss_sum, n)
+            return loss_sum / n, (logits, new_batch_stats, loss_sum, n, None)
 
-        grads, (logits, new_batch_stats, loss_sum, n) = jax.grad(
+        grads, (logits, new_batch_stats, loss_sum, n, correct) = jax.grad(
             loss_fn, has_aux=True
         )(state.params)
         with jax.named_scope("optimizer"):
             updates, new_opt_state = tx.update(grads, state.opt_state, state.params)
             new_params = optax.apply_updates(state.params, updates)
 
-        correct = jnp.sum(jnp.argmax(logits, axis=-1) == labels).astype(jnp.float32)
+        if correct is None:
+            correct = jnp.sum(jnp.argmax(logits, axis=-1) == labels).astype(jnp.float32)
         metrics = {"loss_sum": loss_sum, "correct": correct, "count": n}
         if schedule is not None:
             metrics["lr"] = jnp.asarray(schedule(state.step), jnp.float32)
@@ -185,15 +211,7 @@ def make_eval_step(model) -> Callable[[TrainState, Batch], dict]:
             variables["batch_stats"] = state.batch_stats
         with jax.named_scope("eval_forward"):
             logits = model.apply(variables, images, train=False)
-        valid = labels >= 0
-        safe_labels = jnp.maximum(labels, 0)
-        logp = jax.nn.log_softmax(logits.astype(jnp.float32))
-        per_row = -jnp.take_along_axis(logp, safe_labels[:, None], axis=1)[:, 0]
-        hit = jnp.argmax(logits, axis=-1) == safe_labels
-        return {
-            "loss_sum": jnp.sum(jnp.where(valid, per_row, 0.0)),
-            "correct": jnp.sum(valid & hit).astype(jnp.float32),
-            "count": jnp.sum(valid).astype(jnp.float32),
-        }
+        loss_sum, correct, count = masked_cross_entropy(logits, labels)
+        return {"loss_sum": loss_sum, "correct": correct, "count": count}
 
     return eval_step
