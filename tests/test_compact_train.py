@@ -325,6 +325,7 @@ class TestHarnessCompactTrainSmoke:
         harness.state = harness.state.replace(
             masks=_kill_channels(harness.state.masks, graph, frac)
         )
+        harness.masks_written()
 
     def test_levels_reinstantiate_and_roundtrip(self, tmp_path):
         h = self._harness(tmp_path)
@@ -341,7 +342,12 @@ class TestHarnessCompactTrainSmoke:
             is_leaf=lambda x: x is None,
         )
         sparsity_before = masking.overall_sparsity(h.state.masks)
+        reads = tracing.gauges()["mask_reads"]
         s1 = h.train_one_level(1, 1)
+        # ``_kill`` wrote masks: they are read once, in set-up, in full
+        # coordinates before the plan compacts them; the compacted epochs
+        # and the summary carry that count.
+        assert tracing.gauges()["mask_reads"] == reads + 1
 
         # Re-instantiated smaller, and exited back to full coordinates.
         assert h._plan_ctx is None
@@ -357,7 +363,7 @@ class TestHarnessCompactTrainSmoke:
                 np.testing.assert_array_equal(
                     np.asarray(a), np.asarray(jax.device_get(b))
                 )
-        assert s1["sparsity"] == pytest.approx(sparsity_before)
+        assert s1["sparsity"] == s1["final_sparsity"] == sparsity_before
 
         # Eval parity across the exit expansion: the level's logged test
         # metrics came from the SMALL model; re-evaluating the expanded

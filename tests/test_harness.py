@@ -34,24 +34,52 @@ def _traced_run(cfg, harness_cls=None):
     """``run(cfg)`` with its harness kept and its spans collected; a
     KeyboardInterrupt (the tests' preemption) ends it early."""
     from pathlib import Path
+    from unittest import mock
 
+    from turboprune_tpu import driver
     from turboprune_tpu.harness import PruningHarness
     from turboprune_tpu.parallel.multihost import tree_fingerprint
     from turboprune_tpu.utils import restore_pytree, tracing
 
     held = {}
+    rows, reads = [], []  # every epoch's row; (event, level, ``mask_reads`` so far)
+
+    def mark(event, level):
+        reads.append((event, level, tracing.gauges().get("mask_reads", 0)))
 
     class Capturing(harness_cls or PruningHarness):
         def __init__(self, *a, **k):
             held["h"] = self  # before the base: a subclass may die in its own
             super().__init__(*a, **k)
 
+        def train_one_level(self, epochs_per_level, level, **k):
+            mark("train>", level)
+            try:
+                return super().train_one_level(epochs_per_level, level, **k)
+            finally:
+                mark("train<", level)
+
+        def _train_eval_log(self, row, max_test_acc):
+            mark("epoch>", row["level"])
+            best = super()._train_eval_log(row, max_test_acc)
+            mark("epoch<", row["level"])
+            rows.append(dict(row))
+            return best
+
+    real_prune = driver.prune_level
+
+    def prune_level(harness, density, level):
+        mark("prune>", level)
+        real_prune(harness, density, level)
+        mark("prune<", level)
+
     summaries = None
-    with tracing.span("t/run") as whole:
+    with tracing.span("t/run") as whole, mock.patch.object(driver, "prune_level", prune_level):
         try:
             _, summaries = run(cfg, harness_cls=Capturing)
         except KeyboardInterrupt:
             pass
+    mark("run<", None)
     h, s = held["h"], held["h"].state
     d = Path(h.expt_dir)
     return {
@@ -59,6 +87,8 @@ def _traced_run(cfg, harness_cls=None):
         "harness": h,
         "dir": d,
         "summaries": summaries,
+        "rows": rows,
+        "reads": reads,
         "spans": tracing.recorded(t0=whole.start, t1=whole.end),
         "fingerprint": tree_fingerprint(
             {"params": s.params, "masks": s.masks, "batch_stats": s.batch_stats}
@@ -69,6 +99,7 @@ def _traced_run(cfg, harness_cls=None):
             f"{sub}/{p.name}": tree_fingerprint(restore_pytree(p))
             for sub in ("checkpoints", "artifacts")
             for p in sorted((d / sub).iterdir())
+            if not p.name.startswith("mid_level")  # a slot is three files of its own
         },
     }
 
@@ -131,6 +162,47 @@ def _killed_and_resumed(base, kill_level, *extra, harness_cls=None, in_flight=Fa
 
 def _named(run_, name):
     return [s for s in run_["spans"] if s.name == name]
+
+
+def _counted_on_disk(run_, level):
+    """Sparsity (%) and density of ``model_level_<level>``'s masks, counted
+    in numpy by the formula ``ops/masking.py`` had."""
+    h = run_["harness"]
+    masks = h.ckpts.load_level(level, h.state)["masks"]
+    total = zeros = 0
+    for m in jax.tree.leaves(masks):
+        total += int(m.size)
+        zeros += int(m.size - np.sum(np.asarray(m)))
+    sparsity = (zeros / total) * 100.0
+    return sparsity, 1.0 - sparsity / 100.0
+
+
+def _reports_the_masks_on_disk(run_, level):
+    """Every epoch row's ``sparsity``, the summary's ``final_sparsity`` and
+    ``achieved_density`` of ``level`` against the checkpointed masks: the
+    carried numbers are the read ones, float for float."""
+    sparsity, density = _counted_on_disk(run_, level)
+    rows = [r for r in run_["rows"] if r["level"] == level]
+    assert rows and [r["sparsity"] for r in rows] == [sparsity] * len(rows)
+    (summary,) = [s for s in run_["summaries"] if s["level"] == level]
+    assert summary["final_sparsity"] == sparsity
+    assert summary["achieved_density"] == density
+    return sparsity
+
+
+def _mask_reads(run_, level):
+    """What ``level`` added to the ``mask_reads`` gauge: in all (from its
+    first event to the next level's, or the run's end), inside
+    ``train_one_level``, and across its ``_train_eval_log`` calls."""
+    log = run_["reads"]
+    first = next(i for i, (_, lv, _) in enumerate(log) if lv == level)
+    after = next(i for i, (_, lv, _) in enumerate(log) if i > first and lv != level)
+    at = {}
+    for event, lv, n in log[first:after]:
+        at.setdefault(event, []).append(n)
+    (begun,), (done,) = at["train>"], at["train<"]
+    in_epochs = sum(b - a for a, b in zip(at["epoch>"], at["epoch<"]))
+    return log[after][2] - log[first][2], done - begun, in_epochs
 
 
 class TestIterativeIMP:
@@ -196,6 +268,34 @@ class TestIterativeIMP:
         assert len(summaries2) == 1
         assert summaries2[0]["level"] == 2
         np.testing.assert_allclose(summaries2[0]["density"], 0.64, atol=1e-6)
+
+
+class TestCarriedMaskCount:
+    """The sparsity of the masks is read by one compiled reduction where the
+    masks change and carried (``PruningHarness.mask_count``): between two
+    writes of ``state.masks`` it runs at most once, and never in the epoch
+    loop. Level 0 reads the masks it was built with, in its set-up; a steady
+    level reads once, after its prune, and its ``before`` is the level
+    before's ``after``."""
+
+    @pytest.mark.parametrize("level", [0, 1, 2])
+    def test_every_reported_sparsity_is_that_of_the_levels_checkpointed_masks(self, whole_run, level):
+        sparsity = _reports_the_masks_on_disk(whole_run, level)
+        assert sparsity == pytest.approx([0.0, 20.0, 36.0][level], abs=0.05)
+        assert len([r for r in whole_run["rows"] if r["level"] == level]) == 2
+
+    @pytest.mark.parametrize("level, in_set_up", [(0, 1), (1, 0), (2, 0)])
+    def test_a_level_reads_the_masks_once_and_no_epoch_reads_them(self, whole_run, level, in_set_up):
+        in_all, in_train_one_level, in_epochs = _mask_reads(whole_run, level)
+        assert (in_all, in_train_one_level, in_epochs) == (1, in_set_up, 0)
+
+    def test_the_level_csv_holds_the_carried_sparsity(self, whole_run):
+        for level in range(3):
+            lv = pd.read_csv(
+                whole_run["dir"] / "metrics" / "level_wise_metrics" / f"level_{level}_metrics.csv",
+                float_precision="round_trip",
+            )
+            assert list(lv["sparsity"]) == [_counted_on_disk(whole_run, level)[0]] * 2
 
 
 class TestLevelHandOff:
@@ -276,6 +376,15 @@ class TestLevelHandOff:
         assert runs["resumed"]["fingerprint"] == runs["whole"]["fingerprint"]
         assert runs["killed"]["fingerprint"] != runs["whole"]["fingerprint"]
 
+    @pytest.mark.parametrize("level, reads", [(1, 2), (2, 1)])
+    def test_a_resumed_process_reads_the_masks_it_loaded_and_then_carries(self, runs, level, reads):
+        """Its first level holds no carried count: ``level/load`` wrote the
+        masks, so the prune's ``before`` is a read and its ``after`` another;
+        from then on it is the process that wrote the directory."""
+        resumed = runs["resumed"]
+        assert _reports_the_masks_on_disk(resumed, level) == _counted_on_disk(runs["whole"], level)[0]
+        assert _mask_reads(resumed, level) == (reads, 0, 0)
+
     def test_the_resident_rewind_target_survives_the_donating_steps(self, runs):
         """Three trained levels donated their state to the step; a rewind
         from the resident tree still gives model_init as it is on disk."""
@@ -345,16 +454,28 @@ class TestPruneAtInit:
         expected = sum(alloc[n] * sizes[n] for n in sizes) / sum(sizes.values())
         assert abs(summaries[0]["achieved_density"] - expected) < 0.02
 
-    def test_snip_single_level(self, tmp_path):
-        cfg = _cfg(
-            tmp_path,
-            "pruning_params.prune_method=snip",
-            "pruning_params.training_type=at_init",
-            "pruning_params.target_sparsity=0.5",
+    @pytest.fixture(scope="class")
+    def snip_run(self, tmp_path_factory):
+        return _traced_run(
+            _cfg(
+                tmp_path_factory.mktemp("snip"),
+                "pruning_params.prune_method=snip",
+                "pruning_params.training_type=at_init",
+                "pruning_params.target_sparsity=0.5",
+            )
         )
-        _, summaries = run(cfg)
+
+    def test_snip_single_level(self, snip_run):
+        summaries = snip_run["summaries"]
         assert len(summaries) == 1
         assert abs(summaries[0]["achieved_density"] - 0.5) < 5e-3
+
+    def test_the_level_zero_prune_reads_before_and_after_and_the_level_carries(self, snip_run):
+        """Level 0 of pruning at init: the masks the harness was built with
+        are read for the prune's ``before``, the pruned ones for its
+        ``after``; set-up, both epochs, the summary and the driver carry."""
+        assert _reports_the_masks_on_disk(snip_run, 0) == pytest.approx(50.0, abs=0.5)
+        assert _mask_reads(snip_run, 0) == (2, 0, 0)
 
 
 class TestWeightRewinding:

@@ -7,7 +7,15 @@ two workers of the tier-1 run."""
 import jax
 import numpy as np
 import pytest
-from test_harness import _cfg, _killed_and_resumed, _named, _traced_run
+from test_harness import (
+    _cfg,
+    _counted_on_disk,
+    _killed_and_resumed,
+    _mask_reads,
+    _named,
+    _reports_the_masks_on_disk,
+    _traced_run,
+)
 
 from turboprune_tpu.harness import CyclicPruningHarness
 
@@ -80,3 +88,29 @@ class TestResumedRunRewindsAgain:
         held = runs["resumed"]["harness"].ckpts._resident
         assert len(held) == runs["reads"] - 1  # all it read but model_level_0
         assert all(isinstance(x, np.ndarray) for x in jax.tree.leaves(held))
+
+    @pytest.mark.parametrize("level", [0, 1, 2])
+    def test_every_reported_sparsity_is_that_of_the_checkpointed_masks(self, runs, level):
+        """The carried count (``PruningHarness.mask_count``) in every epoch
+        row, ``final_sparsity`` and ``achieved_density``: of the continuous
+        run, cyclic or not, and of the resumed one, whose first level holds
+        no carried count and reads the masks it loaded."""
+        sparsity = _reports_the_masks_on_disk(runs["whole"], level)
+        if level:
+            assert _reports_the_masks_on_disk(runs["resumed"], level) == sparsity
+        else:  # the killed run returned no summaries
+            assert {r["sparsity"] for r in runs["killed"]["rows"]} == {sparsity} == {0.0}
+
+    @pytest.mark.parametrize(
+        "which, level, reads",
+        [
+            ("whole", 0, (1, 1, 0)),  # the masks it was built with, in level 0's set-up
+            ("whole", 1, (1, 0, 0)),  # the prune's ``after``; ``before`` is carried
+            ("whole", 2, (1, 0, 0)),
+            ("resumed", 1, (2, 0, 0)),  # ``level/load`` wrote masks: ``before`` is a read
+            ("resumed", 2, (1, 0, 0)),
+        ],
+    )
+    def test_a_level_reads_the_masks_once_and_no_epoch_or_cycle_reads_them(self, runs, which, level, reads):
+        assert _mask_reads(runs[which], level) == reads
+        assert _counted_on_disk(runs[which], level)[0] == pytest.approx([0.0, 20.0, 36.0][level], abs=0.05)

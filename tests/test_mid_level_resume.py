@@ -4,7 +4,7 @@ run's longest file is not its longest pole: the two spread over two workers."""
 
 import pandas as pd
 import pytest
-from test_harness import _cfg
+from test_harness import _cfg, _mask_reads, _reports_the_masks_on_disk, _traced_run
 
 from turboprune_tpu.config.compose import compose
 from turboprune_tpu.driver import run
@@ -54,24 +54,16 @@ class TestMidLevelResume:
             }
         )
 
-    def test_bit_identical_resume_after_preemption(self, tmp_path):
-        from pathlib import Path
-
+    @pytest.fixture(scope="class")
+    def runs(self, tmp_path_factory):
+        """Three runs of one seed: uninterrupted; preempted right after the
+        level-1 epoch-1 mid save; resumed through the production path."""
         from turboprune_tpu.harness import PruningHarness
 
-        captured = {}
+        tmp = tmp_path_factory.mktemp("mid")
+        whole = _traced_run(self._cfg(tmp / "a"))
 
-        class Capturing(PruningHarness):
-            def __init__(self, *a, **k):
-                super().__init__(*a, **k)
-                captured["h"] = self
-
-        # Uninterrupted reference run.
-        expt_a, _ = run(self._cfg(tmp_path / "a"), harness_cls=Capturing)
-        want = self._fingerprint(captured["h"])
-
-        # Interrupted run: die right after the level-1 epoch-1 mid save.
-        class Preempted(Capturing):
+        class Preempted(PruningHarness):
             def __init__(self, *a, **k):
                 super().__init__(*a, **k)
                 orig = self.ckpts.save_mid_level
@@ -83,37 +75,67 @@ class TestMidLevelResume:
 
                 self.ckpts.save_mid_level = dying
 
-        cfg_b = self._cfg(tmp_path / "b")
-        with pytest.raises(KeyboardInterrupt):
-            run(cfg_b, harness_cls=Preempted)
-        expt_b = captured["h"].expt_dir
-        meta = captured["h"].ckpts.peek_mid_level()
-        assert meta["level"] == 1 and meta["epoch"] == 1
-
-        # Resume through the production path (resume_experiment config).
-        cfg_r = self._cfg(
-            tmp_path / "b",
-            "experiment_params.resume_experiment=true",
-            "experiment_params.resume_experiment_stuff.resume_expt_name="
-            + Path(expt_b).name,
-            "experiment_params.resume_experiment_stuff.resume_level=1",
+        killed = _traced_run(self._cfg(tmp / "b"), Preempted)
+        assert killed["summaries"] is None  # the interrupt ended it
+        slot = killed["harness"].ckpts.peek_mid_level()
+        resumed = _traced_run(
+            self._cfg(
+                tmp / "b",
+                "experiment_params.resume_experiment=true",
+                "experiment_params.resume_experiment_stuff.resume_expt_name="
+                + killed["dir"].name,
+                "experiment_params.resume_experiment_stuff.resume_level=1",
+            )
         )
-        expt_r, summaries = run(cfg_r, harness_cls=Capturing)
-        assert expt_r == expt_b
+        return {"whole": whole, "killed": killed, "slot": slot, "resumed": resumed}
+
+    def test_bit_identical_resume_after_preemption(self, runs):
+        want = self._fingerprint(runs["whole"]["harness"])
+        assert (runs["slot"]["level"], runs["slot"]["epoch"]) == (1, 1)
+        resumed = runs["resumed"]
+        assert resumed["dir"] == runs["killed"]["dir"]
+        summaries = resumed["summaries"]
         assert len(summaries) == 1
-        got = self._fingerprint(captured["h"])
+        got = self._fingerprint(resumed["harness"])
         assert got == want  # bit-identical to the uninterrupted run
 
         # The level CSV and summary must cover the WHOLE level: the
         # pre-preemption epoch rows ride in the mid-save header, so the
         # resumed run's finish_level sees epochs 0..4, not just 2..4.
         lv = pd.read_csv(
-            Path(expt_b) / "metrics" / "level_wise_metrics" / "level_1_metrics.csv"
+            resumed["dir"] / "metrics" / "level_wise_metrics" / "level_1_metrics.csv"
         )
         assert list(lv["epoch"]) == [0, 1, 2, 3, 4]
         assert summaries[0]["max_test_acc"] == pytest.approx(
             float(lv["test_acc"].max())
         )
+
+    def test_a_re_entered_level_reports_the_sparsity_of_its_checkpointed_masks(self, runs):
+        """Rows of before the preemption ride in the slot's header, those
+        after it take the count the re-entry read: all five are the count of
+        ``model_level_1``'s masks, as the uninterrupted run's are."""
+        resumed = runs["resumed"]
+        sparsity = _reports_the_masks_on_disk(resumed, 1)
+        assert sparsity == _reports_the_masks_on_disk(runs["whole"], 1)
+        assert [r["epoch"] for r in resumed["rows"]] == [2, 3, 4]
+        lv = pd.read_csv(
+            resumed["dir"] / "metrics" / "level_wise_metrics" / "level_1_metrics.csv",
+            float_precision="round_trip",
+        )
+        assert list(lv["sparsity"]) == [sparsity] * 5
+
+    @pytest.mark.parametrize(
+        "which, level, reads",
+        [
+            ("whole", 0, (1, 1, 0)),
+            ("whole", 1, (1, 0, 0)),
+            # ``level/load``, the prune and the slot's restore each wrote
+            # masks: one read after each, the last in ``level/setup``.
+            ("resumed", 1, (3, 1, 0)),
+        ],
+    )
+    def test_each_write_of_the_masks_is_read_once_and_no_epoch_reads_them(self, runs, which, level, reads):
+        assert _mask_reads(runs[which], level) == reads
 
     def test_no_mid_checkpoint_when_disabled(self, tmp_path):
         cfg = _cfg(tmp_path)  # checkpoint_every_epochs defaults to 0

@@ -1,5 +1,6 @@
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from turboprune_tpu.models import create_model
@@ -10,11 +11,13 @@ from turboprune_tpu.ops import (
     make_masks,
     mask_leaves,
     mask_where,
+    masking,
     num_prunable,
     overall_density,
     overall_sparsity,
     reset_masks,
 )
+from turboprune_tpu.utils import tracing
 
 
 @pytest.fixture(scope="module")
@@ -100,6 +103,107 @@ def test_mask_monotone_across_levels(tiny_resnet):
             assert int(resurrected.sum()) == 0
         masks = new_masks
     assert abs(overall_density(masks) - 0.64) < 0.001
+
+
+@pytest.fixture(scope="module")
+def pruned_resnet_masks(tiny_resnet):
+    """The tiny ResNet after a real magnitude prune to a tenth (the top_k the
+    CPU makes quickest)."""
+    _, variables = tiny_resnet
+    params = variables["params"]
+    masks = make_masks(params)
+    scores = mask_where(masks, lambda m, p: jnp.abs(p) * m.astype(p.dtype), params)
+    return global_threshold_mask(scores, masks, density=0.1)
+
+
+def _drawn(shape, keep, seed):
+    return np.random.default_rng(seed).random(shape) < keep
+
+
+# Trees the one reduction (masking.kept_counts) has to count as numpy does.
+MASK_TREES = {
+    "none_leaves": lambda: {
+        "conv": {"kernel": jnp.asarray(_drawn((3, 3, 4, 8), 0.7, 0)), "bias": None},
+        "bn": {"scale": None, "bias": None},
+        "fc": {"kernel": _drawn((8, 5), 0.2, 1)},  # a host array, as a restore leaves
+    },
+    "all_ones": lambda: {"a": {"kernel": jnp.ones((4, 6), bool)}, "b": {"kernel": jnp.ones((2, 2, 3, 3), bool)}},
+    "all_zeros_leaf": lambda: {
+        "a": {"kernel": jnp.zeros((5, 7), bool)},
+        "b": {"kernel": jnp.asarray(_drawn((9, 2), 0.5, 2))},
+    },
+    "odd_sizes": lambda: {
+        f"l{i}": {"kernel": jnp.asarray(_drawn(shape, 0.37, 3 + i))}
+        for i, shape in enumerate([(1,), (7, 13), (3, 3, 5, 17), (1, 1, 1, 1), (257, 3), (1031,)])
+    },
+}
+
+
+@pytest.fixture(params=[*MASK_TREES, "pruned_resnet"])
+def mask_tree(request):
+    if request.param == "pruned_resnet":
+        return request.getfixturevalue("pruned_resnet_masks")
+    return MASK_TREES[request.param]()
+
+
+def _numpy_leaves(masks):
+    return [np.asarray(m) for m in jax.tree.leaves(masks)]  # tree.leaves drops None
+
+
+def test_the_reduction_counts_each_leaf_as_numpy_does(mask_tree):
+    leaves = _numpy_leaves(mask_tree)
+    kept = masking.kept_counts(mask_tree)
+    assert kept == [int(np.count_nonzero(m)) for m in leaves]
+    assert all(type(k) is int for k in kept)
+    count = masking.count_masks(mask_tree)
+    assert count == (sum(m.size - np.count_nonzero(m) for m in leaves), sum(m.size for m in leaves))
+    assert count.total == num_prunable(mask_tree)
+
+
+def test_the_three_readers_return_the_floats_of_the_formula_they_replace(mask_tree):
+    """``zeros / total * 100.0`` on the same integers: equal with ``==``."""
+    leaves = _numpy_leaves(mask_tree)
+    total = zeros = 0
+    for m in leaves:  # masking.py's loop as it stood, in numpy
+        total += int(m.size)
+        zeros += int(m.size - np.sum(m))
+    sparsity = (zeros / total) * 100.0
+    assert overall_sparsity(mask_tree) == sparsity
+    assert overall_density(mask_tree) == 1.0 - sparsity / 100.0
+    table = layerwise_sparsity(mask_tree)
+    assert list(table.values()) == [(int(m.size - np.sum(m)) / m.size) * 100.0 for m in leaves]
+    assert len(table) == len(leaves) and all("kernel" in k for k in table)
+
+
+def test_the_pruned_resnet_is_what_the_fixture_says(pruned_resnet_masks):
+    assert len(mask_leaves(pruned_resnet_masks)) == 21
+    assert abs(overall_density(pruned_resnet_masks) - 0.1) < 0.001
+
+
+def test_a_tree_with_nothing_prunable_reads_zero_and_dispatches_nothing():
+    before = tracing.gauges().get("mask_reads", 0)
+    assert overall_sparsity({"bn": {"scale": None}}) == 0.0
+    assert layerwise_sparsity({"bn": {"scale": None}}) == {}
+    assert tracing.gauges().get("mask_reads", 0) == before
+
+
+@pytest.mark.parametrize("reader", [masking.kept_counts, overall_sparsity, overall_density, layerwise_sparsity])
+def test_one_call_is_one_read_and_a_second_tree_of_the_shapes_compiles_nothing(reader):
+    shapes = [(3, 3, 2, 5), (11, 4), (6,)]
+    trees = [
+        {f"l{i}": {"kernel": jnp.asarray(_drawn(s, keep, i)), "bias": None} for i, s in enumerate(shapes)}
+        for keep in (0.9, 0.4)
+    ]
+    jax.block_until_ready(trees)
+    reads = tracing.gauges().get("mask_reads", 0)
+    with tracing.span("t/first"):
+        first = reader(trees[0])
+    assert tracing.gauges()["mask_reads"] == reads + 1
+    with tracing.span("t/second") as second:
+        again = reader(trees[1])
+    assert tracing.gauges()["mask_reads"] == reads + 2
+    assert second.compiles == 0  # jax.monitoring's compile events, by span
+    assert first != again
 
 
 def test_reset_masks(tiny_resnet):

@@ -508,6 +508,7 @@ class TestHarnessNMSmoke:
         blk = int(np.flatnonzero(fc_mask.any(axis=1))[0]) // 4
         fc_mask[blk * 4 : blk * 4 + 4, :] = False
         h.state = h.state.replace(masks=masks)
+        h.masks_written()
         h.train_one_level(1, 2)
         assert len(h._plan_step_cache) == 1
         assert set(h._plan_step_cache).isdisjoint(keys_l1)
@@ -541,6 +542,7 @@ class TestHarnessNMSmoke:
             m[..., : int(m.shape[-1] * 0.5)] = False
         masks, _ = project_masks(h.state.params, masks, 2, 4)
         h.state = h.state.replace(masks=masks)
+        h.masks_written()
 
         h.train_one_level(1, 1)
         assert h._plan_ctx is None

@@ -445,6 +445,7 @@ class TestHarnessMixedPlanSmoke:
         masks = _kill_channels(h.state.masks, graph, frac)
         masks, _ = project_masks(h.state.params, masks, 2, 4)
         h.state = h.state.replace(masks=masks)
+        h.masks_written()
 
     def test_three_level_lifecycle_and_eviction(self, tmp_path):
         h = self._harness(tmp_path)

@@ -22,7 +22,6 @@ import numpy as np
 
 from .config.schema import MainConfig
 from .harness import CyclicPruningHarness, PruningHarness
-from .ops import masking
 from .parallel import broadcast_object, check_state_equality, is_primary
 from .pruning import generate_densities, prune_the_model
 from .utils import (
@@ -84,7 +83,7 @@ def prune_level(harness, density: float, level: int) -> None:
             nm_spec = (n, m, cfg.experiment_params.nm_transposable)
 
         state = harness.state
-        before = masking.overall_sparsity(state.masks)
+        before = harness.mask_count().sparsity
         masks = prune_the_model(
             method,
             harness.model,
@@ -112,9 +111,9 @@ def prune_level(harness, density: float, level: int) -> None:
                 f", {cfg.experiment_params.nm_sparsity} projection kept "
                 f"{nm_report['preserved_magnitude_frac']:.3f} of magnitude"
             )
-        state = state.replace(masks=masks)
-        harness.state = state
-        after = masking.overall_sparsity(state.masks)
+        harness.state = state.replace(masks=masks)
+        harness.masks_written()
+        after = harness.mask_count().sparsity
         if is_primary():
             print(
                 f"[prune] level {level}: {method} to density {density:.4f} "
@@ -208,6 +207,7 @@ def run(cfg: MainConfig, harness_cls: Optional[Type[PruningHarness]] = None):
                         with tracing.span("level/load"):
                             restored = harness.ckpts.load_level(level - 1, harness.state)
                             harness.state = harness.state.replace(**restored)
+                            harness.masks_written()
                             harness.resume_data_order(level)
                     prune_level(harness, density, level)
 
@@ -225,8 +225,7 @@ def run(cfg: MainConfig, harness_cls: Optional[Type[PruningHarness]] = None):
                 # (utils/checkpoint.py).
                 with tracing.span("level/save"):
                     harness.ckpts.save_level(level, harness.state)
-                achieved = masking.overall_density(harness.state.masks)
-                summary["achieved_density"] = achieved
+                summary["achieved_density"] = harness.mask_count().density
                 summaries.append(summary)
             timing = _say_time(f"level {level}", [level_span])
             harness.metrics.log_level_timing(

@@ -13,7 +13,6 @@ call into its schedule generator is broken for num_cycles>1
 from __future__ import annotations
 
 from ..config.schema import ConfigError
-from ..ops import masking
 from ..pruning import generate_cyclical_schedule
 from ..utils import MODEL_INIT, OPTIMIZER_INIT, tracing
 from ..utils.experiment import display_training_info
@@ -63,7 +62,7 @@ class CyclicPruningHarness(PruningHarness):
             with tracing.span("level/setup", cycle=cycle):
                 self.setup_level(epochs)
                 if cycle == 0:
-                    density = masking.overall_density(self.state.masks)
+                    density = self.mask_count().density
                     display_training_info(self.cfg, level, density)
                     if level == 0:
                         # Saved BEFORE any training so cycle-0 state is the
@@ -95,7 +94,7 @@ class CyclicPruningHarness(PruningHarness):
                 level,
                 {
                     "density": density,
-                    "final_sparsity": masking.overall_sparsity(self.state.masks),
+                    "final_sparsity": self.mask_count().sparsity,
                     "num_cycles": num_cycles,
                 },
             )

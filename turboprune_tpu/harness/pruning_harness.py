@@ -164,6 +164,8 @@ class PruningHarness:
             if state is None:
                 state = self._fresh_state()
             self.state = replicate(state, self.mesh)
+        # What ``mask_count`` carries between two writes of ``state.masks``.
+        self._mask_count: Optional[masking.MaskCount] = None
 
         with tracing.span("init/steps"):
             # The dense model's records, cached by total_steps so identical
@@ -688,6 +690,22 @@ class PruningHarness:
             return self.state.masks
         return ctx["anchor"].masks
 
+    def mask_count(self) -> masking.MaskCount:
+        """The zeros and size of the current masks, full coordinates. The
+        device is read once after a write of ``state.masks`` (the prune, a
+        restore, construction) and the numbers are carried until the next:
+        set-up, every epoch's row, the level's summary and the driver take
+        them from here and reach no device. The state is donated to every
+        epoch's program, so no array's identity could say the masks are the
+        same ones: their writers say so, through ``masks_written``."""
+        if self._mask_count is None:
+            self._mask_count = masking.count_masks(self._full_masks())
+        return self._mask_count
+
+    def masks_written(self) -> None:
+        """For whoever has just put other masks into ``state``."""
+        self._mask_count = None
+
     def _evict_stale_plan_caches(
         self, width_key: tuple, nm_key: Optional[tuple] = None
     ) -> None:
@@ -719,7 +737,7 @@ class PruningHarness:
         with tracing.span("level/setup"):
             self.setup_level(epochs_per_level)
             self.maybe_rewind_optimizer(level)
-            density = masking.overall_density(self.state.masks)
+            density = self.mask_count().density
             display_training_info(self.cfg, level, density)
 
             if level == 0:
@@ -775,6 +793,7 @@ class PruningHarness:
                     self.state = replicate(
                         self.state.replace(**restored), self.mesh
                     )
+                    self.masks_written()
                     start_epoch = mid["epoch"] + 1
                     max_test_acc = mid.get("max_test_acc", 0.0)
                     # Pre-preemption epoch rows ride in the header so the level
@@ -793,7 +812,9 @@ class PruningHarness:
             # After any mid-level restore, so the anchor is the true
             # level-start full state (post-rewind, post-resume) and a resumed
             # level re-derives its ExecutionPlan from the restored full
-            # coordinates.
+            # coordinates. The epoch loop reads no masks: a slot's are counted
+            # here, before a plan compacts them.
+            self.mask_count()
             self._enter_plan()
         if level == 1:
             tracing.stop_profile()  # driver.run's session over the 0 -> 1 boundary
@@ -851,7 +872,7 @@ class PruningHarness:
                 level,
                 {
                     "density": density,
-                    "final_sparsity": masking.overall_sparsity(self.state.masks),
+                    "final_sparsity": self.mask_count().sparsity,
                 },
             )
 
@@ -882,7 +903,7 @@ class PruningHarness:
         with tracing.span("epoch/log"):
             max_test_acc = max(max_test_acc, row["test_acc"])
             row["max_test_acc"] = max_test_acc
-            row["sparsity"] = masking.overall_sparsity(self._full_masks())
+            row["sparsity"] = self.mask_count().sparsity
             self.metrics.log_epoch(row)
             self.wandb.log(row)
             self._log_console(row)

@@ -15,10 +15,13 @@ momentum / weight decay — a semantic we preserve, SURVEY.md §3.3).
 
 from __future__ import annotations
 
-from typing import Any, Callable, Iterator, Optional
+from typing import Any, Callable, Iterator, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+
+from ..utils import tracing
 
 PyTree = Any
 
@@ -105,28 +108,65 @@ def num_prunable(masks: PyTree) -> int:
     return sum(int(m.size) for m in mask_leaves(masks))
 
 
+@jax.jit
+def _kept_counts(leaves: list[jax.Array]) -> jax.Array:
+    return jnp.stack([jnp.sum(m, dtype=jnp.int32) for m in leaves])
+
+
+def kept_counts(masks: PyTree) -> list[int]:
+    """The kept count of every prunable leaf, in ``mask_leaves`` order: the
+    one way to count a mask tree. One compiled program over the whole tree
+    (its executable keyed, as ``jax.jit`` keys it, by the tree's structure
+    and shapes), one dispatch and one fetch of an int32 vector (a leaf holds
+    under 2^31 elements); the host goes on in Python ints, so every count is
+    exact. Each dispatch adds one to the ``mask_reads`` gauge: the host waits
+    here for whatever wrote the masks, so callers that can carry the result
+    do (``MaskCount``, ``PruningHarness.mask_count``)."""
+    leaves = mask_leaves(masks)
+    if not leaves:
+        return []
+    tracing.count("mask_reads")
+    return np.asarray(_kept_counts(leaves)).tolist()
+
+
+class MaskCount(NamedTuple):
+    """The zeros and the size of a mask tree, as Python ints."""
+
+    zeros: int
+    total: int
+
+    @property
+    def sparsity(self) -> float:
+        """Percent of prunable weights masked out (reference
+        PruneModel.get_overall_sparsity, custom_models.py:51-62 — returns %)."""
+        return (self.zeros / self.total) * 100.0 if self.total else 0.0
+
+    @property
+    def density(self) -> float:
+        return 1.0 - self.sparsity / 100.0
+
+
+def count_masks(masks: PyTree) -> MaskCount:
+    """One read of the device (``kept_counts``)."""
+    total = num_prunable(masks)
+    return MaskCount(total - sum(kept_counts(masks)), total)
+
+
 def overall_sparsity(masks: PyTree) -> float:
-    """Percent of prunable weights masked out (reference
-    PruneModel.get_overall_sparsity, custom_models.py:51-62 — returns %)."""
-    total = 0
-    zeros = 0
-    for m in mask_leaves(masks):
-        total += int(m.size)
-        zeros += int(m.size - jnp.sum(m))
-    return (zeros / total) * 100.0 if total else 0.0
+    """Percent of prunable weights masked out; one read of the device."""
+    return count_masks(masks).sparsity
 
 
 def overall_density(masks: PyTree) -> float:
-    return 1.0 - overall_sparsity(masks) / 100.0
+    return count_masks(masks).density
 
 
 def layerwise_sparsity(masks: PyTree) -> dict[str, float]:
     """Per-layer sparsity %, keyed by param path (reference
     print_layer_sparsity, custom_models.py:29-49)."""
     out = {}
-    for path, m in mask_leaves_with_path(masks):
-        zeros = int(m.size - jnp.sum(m))
-        out[path_name(path)] = (zeros / m.size) * 100.0
+    for (path, m), kept in zip(mask_leaves_with_path(masks), kept_counts(masks)):
+        out[path_name(path)] = ((int(m.size) - kept) / m.size) * 100.0
     return out
 
 

@@ -145,6 +145,12 @@ def gauge(name: str, value: float) -> None:
         _gauges[name] = value
 
 
+def count(name: str) -> None:
+    """Add one to a gauge that counts what the process did (``mask_reads``)."""
+    with _mu:
+        _gauges[name] = _gauges.get(name, 0) + 1
+
+
 def gauges() -> dict[str, float]:
     with _mu:
         return dict(_gauges)
