@@ -199,7 +199,7 @@ def test_the_config_keeps_models_and_datasets_together():
     assert (cfg.model_params.model_name, cfg.model_params.num_hidden_layers) == ("granite_4_0_h_micro", 10)
     assert cfg.dataset_params.input_spec() == ((1, 2, 512), "int32")
     assert compose("cifar10_imp", []).dataset_params.input_spec() == ((1, 32, 32, 3), "float32")
-    assert set(LANGUAGE_MODELS) == {"granite_4_0_h_micro", "hybrid_lm_tiny"}
+    assert {"granite_4_0_h_micro", "hybrid_lm_tiny"} <= set(LANGUAGE_MODELS)
     for bad in (
         ["model_params.model_name=resnet18"],
         ["dataset_params.dataset_name=CIFAR10"],

@@ -19,6 +19,8 @@ the one that runs it may load the library.
 
 import os
 
+from unittest import mock
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -149,6 +151,44 @@ def test_chunked_scan_compiles_for_v5e_and_fits(one_chip, no_persistent_cache):
 
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(x, dt, a, bc, bc, seg).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2**30
+
+
+def test_routed_experts_compile_for_v5e_as_grouped_kernels(one_chip, no_persistent_cache):
+    """One LatentMoE layer's routed part at published widths over 8,192
+    tokens, forward and backward: top-22 of 512, the 16 experts held, the
+    10,496-row pair buffer in tiles of 128. The two products and their
+    transposes must come out as the grouped Pallas kernels (a tile of rows
+    against its expert's kernel), not as a dense product over every expert,
+    their blocks must fit the chip's fast memory, and the further rounds (a
+    loop as long as the routing makes it) must not cost the chip gigabytes
+    they never use."""
+    from turboprune_tpu.ops import moe
+
+    tokens, k, experts, held, latent, width = 8192, 22, 512, 16, 1024, 2688
+    capacity, tile = moe.pair_capacity(tokens, k, experts, held), moe.pair_tile(tokens, k, experts)
+    z, logits, up, down = _placed(
+        (
+            jax.ShapeDtypeStruct((tokens, latent), jnp.bfloat16),
+            jax.ShapeDtypeStruct((tokens, experts), jnp.float32),
+            jax.ShapeDtypeStruct((held, latent, width), jnp.bfloat16),
+            jax.ShapeDtypeStruct((held, width, latent), jnp.bfloat16),
+        ),
+        one_chip,
+    )
+
+    def loss(z, logits, up, down):
+        top, weights = moe.route(logits, jnp.zeros(experts), k, 5.0)
+        out, counters = moe.routed_experts(z, top, weights, up, down, 0, capacity, tile)
+        return out.sum(), counters
+
+    # On the CPU backend the kernels would be interpreted: compile the chip's.
+    with mock.patch.object(moe, "_use_interpret", lambda: False):
+        lowered = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3), has_aux=True)).lower(z, logits, up, down)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert (capacity, tile) == (10496, 128)
+    assert text.count("tpu_custom_call") >= 6  # two forward, four backward, and the rounds' own
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2**30
 
 
 def test_ring_attention_compiles_for_a_2x2_mesh(topo, no_persistent_cache):

@@ -18,6 +18,8 @@ DATASETS = ("CIFAR10", "CIFAR100", "ImageNet", "SyntheticTokens")
 # Datasets whose samples are packed token sequences (data/tokens.py), not
 # images: the loader, the model's input and the console's unit follow.
 TOKEN_DATASETS = ("SyntheticTokens",)
+# How a token dataset's seed draws ids (data/tokens.py::token_ids).
+TOKEN_SKEWS = ("log_uniform", "uniform")
 DATALOADER_TYPES = ("device", "grain", "tpk", "synthetic")
 MASK_LAYER_TYPES = ("ConvMask", "LinearMask")
 PRUNE_METHODS = (
@@ -159,6 +161,8 @@ class DatasetConfig:
     doc_len_sigma: float = 1.2
     doc_len_min: int = 16
     layout_seed: int = 0
+    # How the seed draws ids (TOKEN_SKEWS): p(i) about 1 / i, or all alike.
+    token_skew: str = "log_uniform"
 
     @property
     def is_tokens(self) -> bool:
@@ -211,6 +215,7 @@ class DatasetConfig:
                     f"dataset_name={self.dataset_name} needs seq_len >= 2 and "
                     "num_classes (the vocabulary held) >= 2"
                 )
+            _check_choice("dataset_params.token_skew", self.token_skew, TOKEN_SKEWS)
             if not (1 <= self.doc_len_min <= self.seq_len) or self.doc_len_sigma < 0:
                 raise ConfigError(
                     "doc_len_min must lie in [1, seq_len] and doc_len_sigma "
@@ -252,6 +257,21 @@ class ModelConfig:
     # (models/granite.py): the first N layers of the published order. 0 = as
     # published. Image models have one depth and reject the knob.
     num_hidden_layers: int = 0
+    # A model built as one chip's share of a deployment
+    # (models/nemotron_h.py): the stretch of the published layer pattern that
+    # is run ("" = all of it), over how many chips each layer's heads and the
+    # shared expert's columns (tensor_parallel) and its routed experts
+    # (expert_parallel) are divided, and which of the latter this chip is.
+    layer_pattern: str = ""
+    tensor_parallel: int = 1
+    expert_parallel: int = 1
+    expert_rank: int = 0
+
+    @property
+    def share(self) -> tuple:
+        """What ``models.create_model`` takes as ``share``; () = whole."""
+        share = (self.tensor_parallel, self.expert_parallel, self.expert_rank)
+        return () if share == (1, 1, 0) else share
 
     def validate(self) -> None:
         _check_choice(
@@ -259,6 +279,11 @@ class ModelConfig:
         )
         if self.num_hidden_layers < 0:
             raise ConfigError("model_params.num_hidden_layers must be >= 0")
+        if self.tensor_parallel < 1 or not 0 <= self.expert_rank < self.expert_parallel:
+            raise ConfigError(
+                "model_params: tensor_parallel >= 1 and 0 <= expert_rank < expert_parallel "
+                f"(got {self.tensor_parallel}, {self.expert_rank}, {self.expert_parallel})"
+            )
         _check_choice(
             "model_params.attention_impl", self.attention_impl, ATTENTION_IMPLS
         )
@@ -624,6 +649,15 @@ class MainConfig:
             raise ConfigError(
                 "model_params.num_hidden_layers is a language model's depth "
                 f"(got model_name={self.model_params.model_name!r})"
+            )
+        from ..models import SHARED_MODELS
+
+        mp = self.model_params
+        if (mp.layer_pattern or mp.share) and mp.model_name not in SHARED_MODELS:
+            raise ConfigError(
+                "model_params.layer_pattern, tensor_parallel, expert_parallel and "
+                f"expert_rank describe a chip's share of one of {SHARED_MODELS} "
+                f"(got model_name={mp.model_name!r})"
             )
         # Cross-group: prune_method "nm" is magnitude pruning + N:M
         # projection — without a pattern there is nothing to project onto.

@@ -64,6 +64,7 @@ def create_loaders(cfg) -> Any:
             doc_len_min=dp.doc_len_min,
             layout_seed=dp.layout_seed,
             seed=seed,
+            token_skew=dp.token_skew,
         )
     if dp.dataloader_type == "synthetic":
         return SyntheticLoaders(

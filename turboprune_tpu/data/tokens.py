@@ -21,7 +21,11 @@ loss counts the same targets, and two runs that differ in their seed do the
 same work. The seed draws the ids (and the weights).
 
 Ids are log-uniform over the vocabulary (p(i) about 1 / i): a unigram skew a
-model can learn, so a training loss falls from ln V.
+model can learn, so a training loss falls from ln V. ``token_skew="uniform"``
+draws every id alike instead: under the skew 7 % of a sequence is one token,
+and a router that tells tokens apart by what they are sends all of it to the
+same few experts, so that how much work a chip's experts get is the
+seed's (models/nemotron_h.py's entry config asks for uniform ids).
 """
 
 from __future__ import annotations
@@ -58,8 +62,10 @@ def document_layout(
     return (document - document[:, :1]).astype(np.int32)
 
 
-def token_ids(shape: tuple, vocab_size: int, seed: int) -> np.ndarray:
+def token_ids(shape: tuple, vocab_size: int, seed: int, skew: str = "log_uniform") -> np.ndarray:
     u = np.random.default_rng(seed).random(shape)
+    if skew == "uniform":
+        return np.minimum((u * vocab_size).astype(np.int32), vocab_size - 1)
     return np.minimum(np.exp(u * np.log(vocab_size)).astype(np.int32) - 1, vocab_size - 1)
 
 
@@ -141,12 +147,13 @@ class SyntheticTokenLoaders:
         doc_len_min: int,
         layout_seed: int,
         seed: int = 0,
+        token_skew: str = "log_uniform",
     ):
         self.num_classes = vocab_size
         segment_ids = document_layout(
             num_train + num_test, seq_len, doc_len_mu, doc_len_sigma, doc_len_min, seq_len, layout_seed
         )
-        ids = token_ids(segment_ids.shape, vocab_size, seed)
+        ids = token_ids(segment_ids.shape, vocab_size, seed, token_skew)
         self.train_loader = PackedTokenLoader(
             ids[:num_train], segment_ids[:num_train], batch_size, train=True, seed=seed
         )

@@ -127,6 +127,8 @@ class PruningHarness:
                 attention_impl=cfg.model_params.attention_impl,
                 mesh=self.mesh,
                 num_layers=cfg.model_params.num_hidden_layers,
+                layer_pattern=cfg.model_params.layer_pattern,
+                share=cfg.model_params.share,
             )
         with tracing.span("init/loaders"):  # synthetic data is made here
             self.loaders = loaders if loaders is not None else create_loaders(cfg)
@@ -369,6 +371,9 @@ class PruningHarness:
             "train_acc": 100.0 * float(sums["correct"]) / n,
             "epoch_seconds": wall,
             "samples_per_sec": n / wall,
+            # What the model's layers counted over the epoch (train/steps.py),
+            # come with the same fetch; a model without counters adds nothing.
+            **{k: int(sums[k]) for k in getattr(self.model, "counters", ())},
         }
 
     def _stream_epoch(self):
@@ -524,6 +529,8 @@ class PruningHarness:
             width_overrides=width_overrides,
             nm_overrides=nm_overrides,
             num_layers=self.cfg.model_params.num_hidden_layers,
+            layer_pattern=self.cfg.model_params.layer_pattern,
+            share=self.cfg.model_params.share,
         )
 
     def _enter_plan(self) -> None:
@@ -998,6 +1005,7 @@ class PruningHarness:
             f"test {row['test_loss']:.4f}/{row['test_acc']:5.2f}% "
             f"(best {row['max_test_acc']:5.2f}%) "
             f"sparsity {row['sparsity']:5.2f}% "
-            f"{row['samples_per_sec']:,.0f} {unit}",
+            f"{row['samples_per_sec']:,.0f} {unit}"
+            + "".join(f" {k} {row[k]:,}" for k in getattr(self.model, "counters", ()) if k in row),
             flush=True,
         )

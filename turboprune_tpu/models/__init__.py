@@ -13,9 +13,10 @@ from typing import Any, Callable
 
 import jax.numpy as jnp
 
-from . import densenet, granite, resnet, vgg, vit
+from . import densenet, granite, nemotron_h, resnet, vgg, vit
 from .densenet import DenseNet
 from .granite import HybridLM
+from .nemotron_h import NemotronH
 from .resnet import ResNet, resnet18, resnet34, resnet50, resnet101, resnet152
 from .vgg import VGG
 from .vit import VisionTransformer
@@ -48,10 +49,17 @@ MODEL_REGISTRY: dict[str, Callable] = {
     "deit_base_distilled_patch16_384": vit.deit_base_distilled_patch16_384,
     "granite_4_0_h_micro": granite.granite_4_0_h_micro,
     "hybrid_lm_tiny": granite.hybrid_lm_tiny,
+    "nemotron_3_super_120b_a12b": nemotron_h.nemotron_3_super_120b_a12b,
+    "nemotron_h_tiny": nemotron_h.nemotron_h_tiny,
 }
 # Models that read packed token batches (data/tokens.py) and return
 # next-token logits; ``num_classes`` is their vocabulary.
-LANGUAGE_MODELS = ("granite_4_0_h_micro", "hybrid_lm_tiny")
+LANGUAGE_MODELS = (
+    "granite_4_0_h_micro", "hybrid_lm_tiny", "nemotron_3_super_120b_a12b", "nemotron_h_tiny",
+)  # fmt: skip
+# Of those, the ones built as one chip's share of a deployment
+# (models/nemotron_h.py): they take ``layer_pattern`` and ``share``.
+SHARED_MODELS = ("nemotron_3_super_120b_a12b", "nemotron_h_tiny")
 
 
 def create_model(
@@ -64,6 +72,8 @@ def create_model(
     width_overrides: Any = None,
     nm_overrides: Any = None,
     num_layers: int = 0,
+    layer_pattern: str = "",
+    share: tuple = (),
 ):
     """Build a model module with dataset-appropriate stem.
 
@@ -80,7 +90,10 @@ def create_model(
     the gathered N:M path; same normalization, composes with
     ``width_overrides``. ``num_layers`` is a language model's depth (0 = as
     published); it always runs its causal flash kernel, whatever
-    ``attention_impl`` says of the ViTs."""
+    ``attention_impl`` says of the ViTs. ``layer_pattern`` and ``share`` are
+    models/nemotron_h.py's: the stretch of the published pattern that is run,
+    and (tensor_parallel, expert_parallel, expert_rank) of the deployment
+    whose one chip this is."""
     if model_name not in MODEL_REGISTRY:
         raise ValueError(
             f"Model {model_name!r} not in registry: {sorted(MODEL_REGISTRY)}"
@@ -93,8 +106,12 @@ def create_model(
                 f"{model_name!r} has no compacted or gathered form "
                 "(sparse/graph.py): it runs masked"
             )
+        if model_name in SHARED_MODELS:
+            kwargs = {"layer_pattern": layer_pattern, "share": tuple(share)}
+        elif layer_pattern or tuple(share):
+            raise ValueError(f"{model_name!r} has no layer_pattern and no share")
         return MODEL_REGISTRY[model_name](
-            num_classes, num_layers=num_layers, dtype=compute_dtype
+            num_classes, num_layers=num_layers, dtype=compute_dtype, **kwargs
         )
     if model_name.startswith("deit"):
         kwargs = {"attention_impl": attention_impl, "mesh": mesh}
@@ -118,6 +135,7 @@ __all__ = [
     "DenseNet",
     "HybridLM",
     "LANGUAGE_MODELS",
+    "NemotronH",
     "ResNet",
     "VGG",
     "VisionTransformer",

@@ -27,6 +27,12 @@ mixer and of the MLP, ``q_proj``, ``k_proj``, ``v_proj``, ``o_proj``. The tied
 Named scopes label the device trace: ``mamba/in_proj``, ``mamba/conv``,
 ``ssd``, ``mamba/gate_norm``, ``mamba/out_proj``, ``attn/qkv``, ``attn/flash``,
 ``attn/out_proj``, ``mlp``, ``lm_head``.
+
+models/nemotron_h.py imports ``RMSNorm``, ``MambaMixer`` (there with
+``n_groups`` groups of heads and its own ``out_std``), ``AttentionMixer`` and
+``_dense`` from here: a change to one of them is a change to both models, and
+at one group and the default ``out_std`` ``MambaMixer`` is the program this
+model has always run (tests/test_granite.py).
 """
 
 from __future__ import annotations
@@ -48,26 +54,33 @@ PERIOD = ("mamba",) * 5 + ("attention",) + ("mamba",) * 4
 FLASH_BLOCK = 512
 
 
-def _dense(features: int, dtype, name: str) -> nn.Dense:
+def _dense(features: int, dtype, name: str, std: float = 0.02) -> nn.Dense:
     return nn.Dense(
         features,
         use_bias=False,
         dtype=dtype,
-        kernel_init=nn.initializers.normal(0.02),
+        kernel_init=nn.initializers.normal(std),
         name=name,
     )
 
 
 class RMSNorm(nn.Module):
+    """``groups`` > 1 normalises each of that many equal runs of channels on
+    its own (Mamba-2's gated norm under ``n_groups``); the scale stays one
+    vector over all channels."""
+
     eps: float
     dtype: Any = jnp.float32
+    groups: int = 1
 
     @nn.compact
     def __call__(self, x):
         scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
         x32 = x.astype(jnp.float32)
+        if self.groups > 1:
+            x32 = x32.reshape(x.shape[:-1] + (self.groups, -1))
         y = x32 * jax.lax.rsqrt(jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + self.eps)
-        return (y * scale).astype(self.dtype)
+        return (y.reshape(x.shape) * scale).astype(self.dtype)
 
 
 def _same_document(seg, shift: int):
@@ -85,6 +98,10 @@ def _dt_bias_init(key, shape, dtype=jnp.float32):
 
 
 class MambaMixer(nn.Module):
+    """``n_groups`` groups of heads, each with its own ``B`` and ``C`` and its
+    own run of the gated norm (``in_proj`` columns [z | x | B_0.. | C_0.. | dt]);
+    at one group the program is what it was before there were groups."""
+
     heads: int
     head_dim: int
     state: int
@@ -92,11 +109,14 @@ class MambaMixer(nn.Module):
     chunk: int
     eps: float
     dtype: Any = jnp.float32
+    n_groups: int = 1
+    out_std: float = 0.02  # of ``out_proj``'s initial values
 
     @nn.compact
     def __call__(self, u, seg):
         inner = self.heads * self.head_dim
-        conv_dim = inner + 2 * self.state
+        bc_dim = self.n_groups * self.state
+        conv_dim = inner + 2 * bc_dim
         with jax.named_scope("mamba/in_proj"):
             zxbcdt = _dense(inner + conv_dim + self.heads, self.dtype, "in_proj")(u)
             z, xbc, dt = jnp.split(zxbcdt, [inner, inner + conv_dim], axis=-1)
@@ -120,8 +140,10 @@ class MambaMixer(nn.Module):
                 conv = conv + taps[k] * jnp.where(keep, earlier, 0)
             xbc = nn.silu(conv + conv_bias.astype(self.dtype))
 
-        x, b, c = jnp.split(xbc, [inner, inner + self.state], axis=-1)
+        x, b, c = jnp.split(xbc, [inner, inner + bc_dim], axis=-1)
         x = x.reshape(x.shape[:2] + (self.heads, self.head_dim))
+        if self.n_groups > 1:
+            b, c = (v.reshape(v.shape[:2] + (self.n_groups, self.state)) for v in (b, c))
         dt_bias = self.param("dt_bias", _dt_bias_init, (self.heads,))
         a_log = self.param(
             "A_log",
@@ -135,9 +157,9 @@ class MambaMixer(nn.Module):
             y = y + skip.astype(self.dtype)[:, None] * x
         with jax.named_scope("mamba/gate_norm"):
             y = y.reshape(z.shape) * nn.silu(z)
-            y = RMSNorm(self.eps, self.dtype, name="gate_norm")(y)
+            y = RMSNorm(self.eps, self.dtype, self.n_groups, name="gate_norm")(y)
         with jax.named_scope("mamba/out_proj"):
-            return _dense(u.shape[-1], self.dtype, "out_proj")(y)
+            return _dense(u.shape[-1], self.dtype, "out_proj", self.out_std)(y)
 
 
 class AttentionMixer(nn.Module):

@@ -15,6 +15,7 @@ momentum / weight decay — a semantic we preserve, SURVEY.md §3.3).
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable, Iterator, NamedTuple, Optional
 
 import jax
@@ -31,15 +32,36 @@ def _is_none(x) -> bool:
     return x is None
 
 
+def _leaf_key(path: tuple) -> str:
+    last = path[-1]
+    return str(getattr(last, "key", getattr(last, "name", str(last))))
+
+
 def is_prunable_path(path: tuple) -> bool:
-    """A param leaf is prunable iff it is a conv/dense kernel.
+    """A param leaf is prunable iff it is a conv/dense kernel, or a stack of
+    them.
 
     Flax linen names conv and dense weights 'kernel'; biases are 'bias' and
     norm params 'scale'/'bias' — matching the reference's rule of masking
-    exactly the Conv2d/Linear weights (custom_models.py:217-220)."""
-    last = path[-1]
-    key = getattr(last, "key", getattr(last, "name", str(last)))
-    return str(key) == "kernel"
+    exactly the Conv2d/Linear weights (custom_models.py:217-220).
+
+    A *stacked kernel* is one leaf ``kernel_<role>`` of shape
+    ``[layers, in, out]`` that holds one dense kernel per layer along its
+    first axis: the routed experts of models/nemotron_h.py
+    (``.../experts/kernel_up``, ``.../experts/kernel_down``), which one
+    grouped product reads whole. Its mask has its shape; the global criteria
+    see its elements like any other's, and everything that works per layer
+    (``mask_layers``: the ERK and balanced allocators, the per-layer
+    thresholds, ``kept_counts``, ``layerwise_sparsity``) sees ``layers``
+    kernels of ``[in, out]`` named ``.../experts/kernel_up[e]``. Any other
+    matrix (a router's ``weight``, an ``embedding``) is not a kernel and is
+    never masked."""
+    key = _leaf_key(path)
+    return key == "kernel" or key.startswith("kernel_")
+
+
+def is_stacked_path(path: tuple) -> bool:
+    return _leaf_key(path).startswith("kernel_")
 
 
 def tree_paths(tree: PyTree) -> Iterator[tuple]:
@@ -108,13 +130,34 @@ def num_prunable(masks: PyTree) -> int:
     return sum(int(m.size) for m in mask_leaves(masks))
 
 
-@jax.jit
-def _kept_counts(leaves: list[jax.Array]) -> jax.Array:
-    return jnp.stack([jnp.sum(m, dtype=jnp.int32) for m in leaves])
+def mask_layers(masks: PyTree) -> list[tuple[str, tuple, int]]:
+    """[(name, shape, numel)] of every prunable layer, in traversal order: a
+    leaf is one layer under its path's name, a stacked kernel
+    (``is_prunable_path``) one layer ``name[e]`` of shape ``[in, out]`` for
+    each index ``e`` of its first axis."""
+    out = []
+    for path, m in mask_leaves_with_path(masks):
+        name, shape = path_name(path), tuple(m.shape)
+        if is_stacked_path(path):
+            out += [(f"{name}[{e}]", shape[1:], int(m.size) // shape[0]) for e in range(shape[0])]
+        else:
+            out.append((name, shape, int(m.size)))
+    return out
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _kept_counts(leaves: list[jax.Array], stacked: tuple[bool, ...]) -> jax.Array:
+    return jnp.concatenate(
+        [
+            jnp.sum(m.reshape(m.shape[0] if s else 1, -1), axis=1, dtype=jnp.int32)
+            for m, s in zip(leaves, stacked)
+        ]
+    )
 
 
 def kept_counts(masks: PyTree) -> list[int]:
-    """The kept count of every prunable leaf, in ``mask_leaves`` order: the
+    """The kept count of every prunable layer, in ``mask_layers`` order (a
+    stacked kernel counts once for each kernel it holds): the
     one way to count a mask tree. One compiled program over the whole tree
     (its executable keyed, as ``jax.jit`` keys it, by the tree's structure
     and shapes), one dispatch and one fetch of an int32 vector (a leaf holds
@@ -122,11 +165,12 @@ def kept_counts(masks: PyTree) -> list[int]:
     exact. Each dispatch adds one to the ``mask_reads`` gauge: the host waits
     here for whatever wrote the masks, so callers that can carry the result
     do (``MaskCount``, ``PruningHarness.mask_count``)."""
-    leaves = mask_leaves(masks)
-    if not leaves:
+    with_path = mask_leaves_with_path(masks)
+    if not with_path:
         return []
     tracing.count("mask_reads")
-    return np.asarray(_kept_counts(leaves)).tolist()
+    stacked = tuple(is_stacked_path(path) for path, _ in with_path)
+    return np.asarray(_kept_counts([m for _, m in with_path], stacked)).tolist()
 
 
 class MaskCount(NamedTuple):
@@ -162,11 +206,12 @@ def overall_density(masks: PyTree) -> float:
 
 
 def layerwise_sparsity(masks: PyTree) -> dict[str, float]:
-    """Per-layer sparsity %, keyed by param path (reference
+    """Per-layer sparsity %, keyed by the layer's name (``mask_layers``: the
+    param path, with ``[e]`` for each kernel of a stacked one; reference
     print_layer_sparsity, custom_models.py:29-49)."""
     out = {}
-    for (path, m), kept in zip(mask_leaves_with_path(masks), kept_counts(masks)):
-        out[path_name(path)] = ((int(m.size) - kept) / m.size) * 100.0
+    for (name, _, numel), kept in zip(mask_layers(masks), kept_counts(masks)):
+        out[name] = ((numel - kept) / numel) * 100.0
     return out
 
 
@@ -230,10 +275,17 @@ def _kth_smallest(flat: jax.Array, k: int) -> jax.Array:
 
 def per_layer_threshold_mask(scores: PyTree, densities: dict[str, float]) -> PyTree:
     """Per-layer kthvalue masking used by random_erk / random_balanced
-    (reference pruning_utils.py:126-146, 326-347)."""
+    (reference pruning_utils.py:126-146, 326-347); ``densities`` is keyed as
+    ``mask_layers`` names the layers, so each kernel of a stacked one has its
+    own threshold."""
 
     def one(path, s):
-        d = densities[path_name(path)]
+        if is_stacked_path(path):
+            name = path_name(path)
+            return jnp.stack([layer(s[e], densities[f"{name}[{e}]"]) for e in range(s.shape[0])])
+        return layer(s, densities[path_name(path)])
+
+    def layer(s, d):
         n = s.size
         k = int((1.0 - d) * n)
         if k <= 0:

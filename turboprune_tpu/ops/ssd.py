@@ -51,11 +51,30 @@ def ssd_chunked(
     """``y_t = h_t C_t`` of the recurrence above.
 
     x [B, T, H, P]; dt [B, T, H] (after softplus, float32); a [H] (negative,
-    float32); b, c [B, T, N] (one group, shared by the heads); segment_ids
-    [B, T] non-negative ints, constant along a document. Returns [B, T, H, P]
-    in ``x``'s dtype. T need not be a multiple of ``chunk``: the tail is
-    filled with tokens of no document that change nothing before them."""
+    float32); b, c [B, T, N] (one group, shared by the heads) or [B, T, G, N]
+    (head ``i`` reads group ``i // (H / G)``: the groups are independent scans
+    of H / G heads each); segment_ids [B, T] non-negative ints, constant along
+    a document. Returns [B, T, H, P] in ``x``'s dtype. T need not be a
+    multiple of ``chunk``: the tail is filled with tokens of no document that
+    change nothing before them."""
     bsz, t, heads, p = x.shape
+    if b.ndim == 4:
+        groups = b.shape[2]
+        if groups == 1:
+            return ssd_chunked(x, dt, a, b[:, :, 0], c[:, :, 0], segment_ids, chunk)
+        per_group = jax.vmap(
+            lambda x, dt, a, b, c: ssd_chunked(x, dt, a, b, c, segment_ids, chunk),
+            in_axes=(2, 2, 0, 2, 2),
+            out_axes=2,
+        )
+        y = per_group(
+            x.reshape(bsz, t, groups, heads // groups, p),
+            dt.reshape(bsz, t, groups, heads // groups),
+            a.reshape(groups, heads // groups),
+            b,
+            c,
+        )
+        return y.reshape(x.shape)
     dtype = x.dtype
     pad = (-t) % chunk
     if pad:

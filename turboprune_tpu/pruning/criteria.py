@@ -32,8 +32,8 @@ import jax.numpy as jnp
 from ..ops.masking import (
     PyTree,
     global_threshold_mask,
+    is_stacked_path,
     mask_leaves,
-    mask_leaves_with_path,
     mask_where,
     path_name,
     per_layer_threshold_mask,
@@ -70,12 +70,15 @@ def _bernoulli_masks(
     mask_layers.py:36-43)."""
     names = [name for name, _, _ in _layer_sizes(masks)]
     keys = dict(zip(names, jax.random.split(rng, len(names))))
+    draw = lambda name, shape: jax.random.bernoulli(keys[name], densities[name], shape)
 
     def go(path, m):
         if m is None:
             return None
         name = path_name(path)
-        return jax.random.bernoulli(keys[name], densities[name], m.shape)
+        if is_stacked_path(path):  # a layer for each kernel it holds
+            return jnp.stack([draw(f"{name}[{e}]", m.shape[1:]) for e in range(m.shape[0])])
+        return draw(name, m.shape)
 
     return jax.tree_util.tree_map_with_path(
         go, masks, is_leaf=lambda x: x is None

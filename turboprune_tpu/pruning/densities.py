@@ -14,7 +14,7 @@ here too — they are pure budget math over layer shapes, not criteria.
 
 from __future__ import annotations
 
-from ..ops.masking import PyTree, mask_leaves_with_path, path_name
+from ..ops.masking import PyTree, mask_layers
 
 # "nm" is magnitude IMP + N:M projection (criteria.prune_nm): same
 # geometric ladder as "mag".
@@ -23,11 +23,13 @@ PAI_METHODS = ("er_erk", "er_balanced", "synflow", "snip")
 
 
 def _layer_sizes(masks: PyTree) -> list[tuple[str, tuple, int]]:
-    """[(path_name, shape, numel)] per prunable layer, in traversal order."""
-    out = []
-    for path, m in mask_leaves_with_path(masks):
-        out.append((path_name(path), tuple(m.shape), int(m.size)))
-    return out
+    """[(name, shape, numel)] per prunable layer, in traversal order. A
+    stacked kernel ``[layers, in, out]`` (ops/masking.py::is_prunable_path:
+    the routed experts) is ``layers`` layers of shape ``[in, out]`` named
+    ``.../experts/kernel_up[e]``: ERK's ``sum(shape) / numel`` and the
+    balanced budget are per expert, as they would be for that many Dense
+    layers, and a level's per-layer sparsity log reads per expert."""
+    return mask_layers(masks)
 
 
 def erk_densities(masks: PyTree, density: float) -> dict[str, float]:

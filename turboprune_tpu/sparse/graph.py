@@ -350,6 +350,7 @@ def build_graph(model, params: Any) -> PropagationGraph:
     correctly too). Raises CompactionError for unsupported architectures."""
     from ..models.densenet import DenseNet
     from ..models.granite import HybridLM
+    from ..models.nemotron_h import NemotronH
     from ..models.resnet import ResNet
     from ..models.vgg import VGG
     from ..models.vit import VisionTransformer
@@ -362,15 +363,17 @@ def build_graph(model, params: Any) -> PropagationGraph:
         return _densenet_graph(model, params)
     if isinstance(model, VisionTransformer):
         return _vit_graph(model, params)
-    if isinstance(model, HybridLM):
-        # Its compactable axes are known (the SwiGLU hidden axis, a scan
+    if isinstance(model, (HybridLM, NemotronH)):
+        # Their compactable axes are known (the SwiGLU hidden axis, a scan
         # head with its slices of in_proj, conv, gate norm and out_proj, a
-        # key/value head with its group of query heads) and have no Space
-        # yet: the planner's answer for this family is ``masked``.
+        # key/value head with its group of query heads; an expert's hidden
+        # axis, one Space for each expert of a stacked kernel, and the latent
+        # axis its experts share) and have no Space yet: the planner's answer
+        # for these families is ``masked``.
         raise CompactionError(
-            "the hybrid language model has no propagation graph yet: the "
-            "SwiGLU hidden axis, scan heads and grouped attention heads need "
-            "Spaces of their own; it runs masked"
+            "the hybrid language models have no propagation graph yet: the "
+            "SwiGLU and expert hidden axes, scan heads and grouped attention "
+            "heads need Spaces of their own; it runs masked"
         )
     raise CompactionError(
         f"no propagation graph for model type {type(model).__name__} — "

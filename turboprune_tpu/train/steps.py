@@ -56,6 +56,10 @@ def masked_cross_entropy(logits: jax.Array, labels: jax.Array):
 
 
 def _forward_train(model, params, masks, batch_stats, images, rng):
+    """(logits, new batch statistics, the layers' counters). A model that
+    names ``counters`` (models/nemotron_h.py) sows int32 scalars under those
+    names into the ``counters`` collection, a layer at a time; they come back
+    summed over the layers. Every other model returns {} and runs as before."""
     variables = {"params": apply_masks(params, masks)}
     if batch_stats:
         variables["batch_stats"] = batch_stats
@@ -66,11 +70,21 @@ def _forward_train(model, params, masks, batch_stats, images, rng):
             mutable=["batch_stats"],
             rngs={"dropout": rng},
         )
-        return logits, new_model_state.get("batch_stats", {})
+        return logits, new_model_state.get("batch_stats", {}), {}
+    names = getattr(model, "counters", ())
+    if names:
+        logits, sown = model.apply(
+            variables, images, train=True, mutable=["counters"], rngs={"dropout": rng}
+        )
+        leaves = jax.tree_util.tree_leaves_with_path(sown["counters"])
+        return logits, batch_stats, {
+            name: sum(v for path, v in leaves if any(getattr(p, "key", None) == name for p in path))
+            for name in names
+        }
     # No mutable collections (plain VGG, ViT): mutable=[] would make flax
     # return a (logits, state) tuple — don't pass it at all.
     logits = model.apply(variables, images, train=True, rngs={"dropout": rng})
-    return logits, batch_stats
+    return logits, batch_stats, {}
 
 
 def make_train_step(
@@ -91,7 +105,7 @@ def make_train_step(
             # Named scopes label the device trace's operations by layer; the
             # backward pass comes out as transpose(jvp(forward)).
             with jax.named_scope("forward"):
-                logits, new_batch_stats = _forward_train(
+                logits, new_batch_stats, counters = _forward_train(
                     model, params, state.masks, state.batch_stats, images, step_rng
                 )
             with jax.named_scope("loss"):
@@ -101,12 +115,12 @@ def make_train_step(
                     # image path below keeps its arithmetic, and with it the
                     # compiled program its cells have cached.
                     loss_sum, correct, n = masked_cross_entropy(logits, labels)
-                    return loss_sum / n, (None, new_batch_stats, loss_sum, n, correct)
+                    return loss_sum / n, (None, new_batch_stats, loss_sum, n, correct, counters)
                 n = jnp.asarray(labels.shape[0], jnp.float32)
                 loss_sum = cross_entropy_sum(logits, labels)
-            return loss_sum / n, (logits, new_batch_stats, loss_sum, n, None)
+            return loss_sum / n, (logits, new_batch_stats, loss_sum, n, None, counters)
 
-        grads, (logits, new_batch_stats, loss_sum, n, correct) = jax.grad(
+        grads, (logits, new_batch_stats, loss_sum, n, correct, counters) = jax.grad(
             loss_fn, has_aux=True
         )(state.params)
         with jax.named_scope("optimizer"):
@@ -115,7 +129,7 @@ def make_train_step(
 
         if correct is None:
             correct = jnp.sum(jnp.argmax(logits, axis=-1) == labels).astype(jnp.float32)
-        metrics = {"loss_sum": loss_sum, "correct": correct, "count": n}
+        metrics = {"loss_sum": loss_sum, "correct": correct, "count": n, **counters}
         if schedule is not None:
             metrics["lr"] = jnp.asarray(schedule(state.step), jnp.float32)
 
