@@ -29,6 +29,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSh
 
 import chip_smoke
 from turboprune_tpu.ops.flash import flash_attention, flash_attention_causal
+from turboprune_tpu.ops import ssd
 from turboprune_tpu.ops.ssd import ssd_chunked
 
 V5E_HBM_BYTES = 16 * 2**30
@@ -131,26 +132,66 @@ def test_causal_flash_kernel_compiles_for_v5e(one_chip, no_persistent_cache, bac
     assert "tpu_custom_call" in text and all(name in text for name in names)
 
 
-def test_chunked_scan_compiles_for_v5e_and_fits(one_chip, no_persistent_cache):
-    """One Mamba-2 layer's scan at published widths over 8,192 tokens,
-    forward and backward: the [Q, Q] decay tensors of its 32 chunks are the
-    layer's largest temporaries, and must leave the chip room."""
+# One Mamba-2 layer's scan at published widths over 8,192 tokens: granite's
+# 64 heads at chunks of 256, and one chip's group of Nemotron-3-Super, 16
+# heads at chunks of 128.
+@pytest.mark.parametrize("backward", [False, True], ids=["forward", "grad"])
+@pytest.mark.parametrize("heads, chunk, temp_mib", [(64, 256, 256), (16, 128, 48)])
+def test_chunked_scan_compiles_for_v5e_and_fits(one_chip, no_persistent_cache, heads, chunk, temp_mib, backward):
+    """The scan must come out as its Pallas kernels, whose blocks must fit the
+    chip's fast memory, and no [Q, Q] tensor may be among the program's
+    temporaries: the float32 states entering the chunks (64 MiB at 64 heads),
+    the gradients and the per-head factors are all it holds (192.4 MiB as
+    compiled for the gradient at 64 heads, 32.2 for the forward at 16; XLA's
+    form held 2 GiB of decays and scores under a limit of 4)."""
     x, dt, a, bc, seg = _placed(
         (
-            jax.ShapeDtypeStruct((1, 8192, 64, 64), jnp.bfloat16),
-            jax.ShapeDtypeStruct((1, 8192, 64), jnp.float32),
-            jax.ShapeDtypeStruct((64,), jnp.float32),
+            jax.ShapeDtypeStruct((1, 8192, heads, 64), jnp.bfloat16),
+            jax.ShapeDtypeStruct((1, 8192, heads), jnp.float32),
+            jax.ShapeDtypeStruct((heads,), jnp.float32),
             jax.ShapeDtypeStruct((1, 8192, 128), jnp.bfloat16),
             jax.ShapeDtypeStruct((1, 8192), jnp.int32),
         ),
         one_chip,
     )
 
-    def loss(x, dt, a, b, c, seg):
-        return ssd_chunked(x, dt, a, b, c, seg, 256).astype(jnp.float32).sum()
+    def forward(x, dt, a, b, c, seg):
+        return ssd_chunked(x, dt, a, b, c, seg, chunk)
 
-    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(x, dt, a, bc, bc, seg).compile()
-    assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2**30
+    def loss(x, dt, a, b, c, seg):
+        return jnp.square(forward(x, dt, a, b, c, seg).astype(jnp.float32)).sum()
+
+    fn = jax.grad(loss, argnums=(0, 1, 2, 3, 4)) if backward else forward
+    with mock.patch.object(ssd, "_use_interpret", return_value=False):  # the CPU backend would interpret
+        compiled = jax.jit(fn).lower(x, dt, a, bc, bc, seg).compile()
+    text = compiled.as_text()
+    names = ("ssd_scan_fwd", "ssd_scan_bwd") if backward else ("ssd_scan_fwd",)
+    assert "tpu_custom_call" in text and all(name in text for name in names)
+    assert compiled.memory_analysis().temp_size_in_bytes < temp_mib * 2**20
+
+
+def test_grouped_scan_compiles_for_v5e(one_chip, no_persistent_cache):
+    """Two groups of 16 heads: the kernels under ``jax.vmap`` must still be
+    handed blocks whose last two axes are tokens and lanes (with the group as
+    the third axis of ``B`` and ``C`` the chip's compiler refuses them; the
+    interpreter does not)."""
+    x, dt, a, bc, seg = _placed(
+        (
+            jax.ShapeDtypeStruct((1, 2048, 32, 64), jnp.bfloat16),
+            jax.ShapeDtypeStruct((1, 2048, 32), jnp.float32),
+            jax.ShapeDtypeStruct((32,), jnp.float32),
+            jax.ShapeDtypeStruct((1, 2048, 2, 128), jnp.bfloat16),
+            jax.ShapeDtypeStruct((1, 2048), jnp.int32),
+        ),
+        one_chip,
+    )
+
+    def loss(x, dt, a, b, c, seg):
+        return jnp.square(ssd_chunked(x, dt, a, b, c, seg, 128).astype(jnp.float32)).sum()
+
+    with mock.patch.object(ssd, "_use_interpret", return_value=False):
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(x, dt, a, bc, bc, seg).compile().as_text()
+    assert "ssd_scan_fwd" in text and "ssd_scan_bwd" in text
 
 
 def test_routed_experts_compile_for_v5e_as_grouped_kernels(one_chip, no_persistent_cache):
