@@ -71,69 +71,38 @@ BF16_TOL = 3e-2
 # "the same run", not "the same rounding".
 SAME_RUN_TOL = 0.1
 
-# Lowering and XLA compilation (or the read from the persistent cache) of
-# one module. Tracing is left out: its events nest, jit inside jit, and a
-# sum of them can exceed the wall clock.
-_XLA_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-_COMPILE_EVENTS = (
-    "/jax/core/compile/jaxpr_to_mlir_module_duration",
-    _XLA_COMPILE_EVENT,
-)
-
 
 def say(msg: str) -> None:
     print(msg, flush=True)
 
 
-class CompileLog:
-    """What JAX reports about compilation while the block runs: seconds
-    spent lowering and compiling (or reading the persistent cache), the
-    name of every module that reached XLA, and cache hits and misses."""
+def compiled_since(t0: float) -> tuple[float, list, int, int]:
+    """What the program's recorder (utils/tracing.py::modules) holds of the
+    modules that left the backend since ``t0``, on any thread (the serve
+    phase compiles off this one): seconds lowering and compiling (or reading
+    the persistent cache), their names, cache hits and misses."""
+    from turboprune_tpu.utils import tracing
 
-    def __init__(self):
-        self._mu = threading.Lock()  # the serve phase compiles off-thread
-        self.seconds = 0.0
-        self.modules: list[str] = []
-        self.cache_hits = 0
-        self.cache_misses = 0
-
-    def _on_duration(self, event: str, duration: float, **kw) -> None:
-        if event not in _COMPILE_EVENTS:
-            return
-        with self._mu:
-            self.seconds += duration
-            if event == _XLA_COMPILE_EVENT:
-                self.modules.append(str(kw.get("fun_name")))
-
-    def _on_event(self, event: str, **kw) -> None:
-        with self._mu:
-            if event == "/jax/compilation_cache/cache_hits":
-                self.cache_hits += 1
-            elif event == "/jax/compilation_cache/cache_misses":
-                self.cache_misses += 1
-
-    def __enter__(self) -> "CompileLog":
-        jax.monitoring.register_event_duration_secs_listener(self._on_duration)
-        jax.monitoring.register_event_listener(self._on_event)
-        return self
-
-    def __exit__(self, *exc) -> None:
-        jax.monitoring.unregister_event_duration_listener(self._on_duration)
-        jax.monitoring.unregister_event_listener(self._on_event)
+    modules = tracing.modules(t0)
+    return (
+        sum(m.lower_s + m.compile_s for m in modules),
+        [m.name for m in modules],
+        sum(m.cache == "hit" for m in modules),
+        sum(m.cache == "miss" for m in modules),
+    )
 
 
-def timed(log: CompileLog, name: str, phase, **sizes):
+def timed(name: str, phase, **sizes):
     """Run one phase and print its wall time split into compile and run."""
     say(f"[{name}] start {sizes}")
-    t0, c0, n0 = time.perf_counter(), log.seconds, len(log.modules)
-    h0, m0 = log.cache_hits, log.cache_misses
-    out = phase(log, **sizes)
-    wall, compile_s = time.perf_counter() - t0, log.seconds - c0
+    t0 = time.perf_counter()
+    out = phase(**sizes)
+    wall = time.perf_counter() - t0
+    compile_s, modules, hits, misses = compiled_since(t0)
     say(
         f"[{name}] ok: wall {wall:.1f}s = compile {compile_s:.1f}s + trace "
-        f"and run {wall - compile_s:.1f}s; {len(log.modules) - n0} modules reached "
-        f"XLA, persistent cache {log.cache_hits - h0} hits / "
-        f"{log.cache_misses - m0} misses"
+        f"and run {wall - compile_s:.1f}s; {len(modules)} modules reached "
+        f"XLA, persistent cache {hits} hits / {misses} misses"
     )
     return out
 
@@ -249,7 +218,6 @@ def _recording_assemble(real, layout: list):
 
 
 def phase_train(
-    log: CompileLog,
     *,
     base_dir: Path,
     platform: str,
@@ -289,7 +257,7 @@ def phase_train(
     )
     levels: list = []
     layout: list = []
-    n0 = len(log.modules)
+    began = time.perf_counter()
     with mock.patch.object(
         driver, "PruningHarness", _observed_harness(levels, densities)
     ), mock.patch.object(
@@ -335,7 +303,7 @@ def phase_train(
             f"level {r['level']}: one train step, compiled for one signature",
         )
     check(
-        log.modules[n0:].count("jit(train_step)") == 1,
+        compiled_since(began)[1].count("jit(train_step)") == 1,
         "jit(train_step) reached XLA once in the whole run (not again at "
         "levels 1 and 2)",
     )
@@ -401,7 +369,6 @@ def _http(url: str, body: dict | None = None) -> tuple[int, bytes]:
 
 
 def phase_serve(
-    log: CompileLog,
     *,
     expt_dir: str,
     platform: str,
@@ -506,7 +473,7 @@ def _close(a, b, what: str) -> None:
     )
 
 
-def phase_flash(log: CompileLog, *, bh: int, seq: int, valid_len: int, d: int):
+def phase_flash(*, bh: int, seq: int, valid_len: int, d: int):
     """ops/flash.py compiled by Mosaic — interpret=False is passed, so this
     phase cannot run interpreted — forward and jax.grad, bf16."""
     from turboprune_tpu.ops.flash import flash_attention
@@ -543,12 +510,12 @@ def phase_flash(log: CompileLog, *, bh: int, seq: int, valid_len: int, d: int):
 
 # ------------------------------------------------------------- four chips
 def phase_data_parallel(
-    log: CompileLog, *, devices: int, mask_tol: float, **train_sizes
+    *, devices: int, mask_tol: float, **train_sizes
 ) -> None:
     """The IMP run sharded over ``devices`` against the same global batch
     and seed on one device."""
-    many = phase_train(log, num_devices=devices, **train_sizes)
-    one = phase_train(log, num_devices=1, **train_sizes)
+    many = phase_train(num_devices=devices, **train_sizes)
+    one = phase_train(num_devices=1, **train_sizes)
 
     layout = sorted(many["layout"], key=lambda s: s["rows"])
     say(f"  first train batch: {layout}")
@@ -601,7 +568,6 @@ def phase_data_parallel(
 
 
 def phase_ring(
-    log: CompileLog,
     *,
     data: int,
     model: int,
@@ -712,58 +678,55 @@ def main(argv=None) -> int:
 
     t0 = time.perf_counter()
     try:
-        with CompileLog() as log:
-            if args.chips == 4:
-                timed(
-                    log,
-                    "data-parallel",
-                    phase_data_parallel,
-                    devices=4,
-                    mask_tol=5e-2,
-                    base_dir=OUT_DIR,
-                    platform="tpu",
-                    target_sparsity=0.2,
-                    **_RESNET50,
-                )
-                # DeiT-small attention: 6 heads of 64, 196 patches + cls.
-                timed(
-                    log, "ring", phase_ring,
-                    data=2, model=2, batch=64, seq=197, dim=384, heads=6,
-                )
-            else:
-                run = timed(
-                    log,
-                    "train",
-                    phase_train,
-                    base_dir=OUT_DIR,
-                    platform="tpu",
-                    target_sparsity=0.3,
-                    **_RESNET50,
-                )
-                say(
-                    f"  batch {TRAIN_BATCH} (BASELINE.md asks 512: see "
-                    f"TRAIN_BATCH); HBM after the train phase: {hbm()}"
-                )
-                timed(
-                    log,
-                    "serve",
-                    phase_serve,
-                    expt_dir=run["expt_dir"],
-                    platform="tpu",
-                    request_sizes=(1, 3, 8),
-                    final_level=2,
-                )
-                # DeiT-small on one chip: batch 64 x 6 heads, 197 -> 256.
-                timed(
-                    log, "flash", phase_flash,
-                    bh=384, seq=256, valid_len=197, d=64,
-                )
-            say(
-                f"total {time.perf_counter() - t0:.1f}s, of it compile "
-                f"{log.seconds:.1f}s; compile cache {entries} -> "
-                f"{cache_entries(cache_dir)} entries, {log.cache_hits} hits / "
-                f"{log.cache_misses} misses; HBM: {hbm()}"
+        if args.chips == 4:
+            timed(
+                "data-parallel",
+                phase_data_parallel,
+                devices=4,
+                mask_tol=5e-2,
+                base_dir=OUT_DIR,
+                platform="tpu",
+                target_sparsity=0.2,
+                **_RESNET50,
             )
+            # DeiT-small attention: 6 heads of 64, 196 patches + cls.
+            timed(
+                "ring", phase_ring,
+                data=2, model=2, batch=64, seq=197, dim=384, heads=6,
+            )
+        else:
+            run = timed(
+                "train",
+                phase_train,
+                base_dir=OUT_DIR,
+                platform="tpu",
+                target_sparsity=0.3,
+                **_RESNET50,
+            )
+            say(
+                f"  batch {TRAIN_BATCH} (BASELINE.md asks 512: see "
+                f"TRAIN_BATCH); HBM after the train phase: {hbm()}"
+            )
+            timed(
+                "serve",
+                phase_serve,
+                expt_dir=run["expt_dir"],
+                platform="tpu",
+                request_sizes=(1, 3, 8),
+                final_level=2,
+            )
+            # DeiT-small on one chip: batch 64 x 6 heads, 197 -> 256.
+            timed(
+                "flash", phase_flash,
+                bh=384, seq=256, valid_len=197, d=64,
+            )
+        compile_s, _, hits, misses = compiled_since(t0)
+        say(
+            f"total {time.perf_counter() - t0:.1f}s, of it compile "
+            f"{compile_s:.1f}s; compile cache {entries} -> "
+            f"{cache_entries(cache_dir)} entries, {hits} hits / "
+            f"{misses} misses; HBM: {hbm()}"
+        )
     finally:
         # Checkpoints are ~100 MB each; the tool brings back 64 MiB. Metrics
         # CSVs and the config snapshot stay for a post-mortem.

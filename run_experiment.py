@@ -39,7 +39,9 @@ def parse_args(argv):
 def setup(args):
     """What both entry points do before the level loop, each part under its
     ``setup/`` span: the package imports, the compile cache's place and the
-    composed config, distributed initialisation. Returns the config."""
+    composed config, distributed initialisation, the start of the backend's
+    runtime (seconds on a TPU, which would otherwise fall into whichever
+    span first asks for a device). Returns the config."""
     t0 = time.perf_counter()
     from turboprune_tpu.utils import tracing
 
@@ -55,6 +57,10 @@ def setup(args):
         cfg = compose(args.config_name, args.overrides, args.config_path)
     with tracing.span("setup/distributed"):
         initialize_distributed()
+    with tracing.span("setup/backend"):
+        import jax
+
+        jax.devices()
     return cfg
 
 

@@ -1,8 +1,10 @@
 #!/usr/bin/env python
 """What utils/tracing.py costs on this host, with no profiler session: the
 microseconds of one span (bare, and nested three deep with attributes, as an
-epoch's are) and the milliseconds of one level's ``[time]`` report with the
-recorder nearly empty and full. Touches no device.
+epoch's are), the microseconds of one call of a ``jax.monitoring`` listener
+(a module's eight: three stages begun and ended, the cache's answer and its
+read, with a span open) and the milliseconds of one level's ``[time]`` report
+with the recorder nearly empty and full. Touches no device.
 
     python scripts/span_cost.py
 """
@@ -33,6 +35,20 @@ def nested():
                     pass
 
 
+def listeners():
+    """What JAX tells the recorder of one module the cache served, N // 8
+    modules over: eight calls each, none of them JAX's own work."""
+    with tracing.span("epoch/train"):
+        for _ in range(N // 8):
+            for stage in (tracing._TRACE, tracing._LOWER):
+                tracing._on_start(stage, 0.0, fun_name="jit(f)")
+                tracing._on_duration(stage, 1e-3, fun_name="jit(f)")
+            tracing._on_start(tracing._COMPILE, 0.0, fun_name="jit(f)")
+            tracing._on_event("/jax/compilation_cache/cache_hits")
+            tracing._on_duration(tracing._CACHE_READ, 1e-3)
+            tracing._on_duration(tracing._COMPILE, 2e-3, fun_name="jit(f)")
+
+
 def per_span_us(fn, spans: int) -> float:
     times = []
     for _ in range(REPS):
@@ -59,4 +75,5 @@ if __name__ == "__main__":
     print(f"[span_cost] a level's report, {len(tracing._spans)} spans recorded: {report_ms():.3f} ms")
     print(f"[span_cost] bare span: {per_span_us(bare, N):.3f} us (median of {REPS} x {N})")
     print(f"[span_cost] nested span with attributes: {per_span_us(nested, N // 3 * 3):.3f} us")
+    print(f"[span_cost] a listener's call: {per_span_us(listeners, N // 8 * 8):.3f} us (a module is eight)")
     print(f"[span_cost] a level's report, {len(tracing._spans)} spans recorded: {report_ms():.3f} ms")

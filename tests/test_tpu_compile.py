@@ -18,6 +18,7 @@ the one that runs it may load the library.
 """
 
 import os
+import time
 
 from unittest import mock
 
@@ -316,44 +317,40 @@ TINY = dict(
 
 
 def test_chip_smoke_one_chip_phases_at_tiny_size(tmp_path):
-    with chip_smoke.CompileLog() as log:
-        run = chip_smoke.phase_train(
-            log,
-            base_dir=tmp_path,
-            platform="cpu",
-            target_sparsity=0.3,
-            num_devices=1,
-            **TINY,
-        )
-        assert [r["level"] for r in run["levels"]] == [0, 1, 2]
-        chip_smoke.phase_serve(
-            log,
-            expt_dir=run["expt_dir"],
-            platform="cpu",
-            request_sizes=(1, 3),
-            final_level=2,
-        )
-    assert "jit(train_step)" in log.modules
+    began = time.perf_counter()
+    run = chip_smoke.phase_train(
+        base_dir=tmp_path,
+        platform="cpu",
+        target_sparsity=0.3,
+        num_devices=1,
+        **TINY,
+    )
+    assert [r["level"] for r in run["levels"]] == [0, 1, 2]
+    chip_smoke.phase_serve(
+        expt_dir=run["expt_dir"],
+        platform="cpu",
+        request_sizes=(1, 3),
+        final_level=2,
+    )
+    # What the smoke prints of a phase comes from the program's own record
+    # of the modules that reached XLA (utils/tracing.py), on any thread.
+    compile_s, modules, hits, misses = chip_smoke.compiled_since(began)
+    assert "jit(train_step)" in modules and compile_s > 0 and (hits, misses) == (0, 0)
 
 
 def test_chip_smoke_data_parallel_phase_at_tiny_size(tmp_path):
-    with chip_smoke.CompileLog() as log:
-        chip_smoke.phase_data_parallel(
-            log,
-            devices=4,
-            mask_tol=5e-2,
-            base_dir=tmp_path,
-            platform="cpu",
-            target_sparsity=0.2,
-            **TINY,
-        )
+    chip_smoke.phase_data_parallel(
+        devices=4,
+        mask_tol=5e-2,
+        base_dir=tmp_path,
+        platform="cpu",
+        target_sparsity=0.2,
+        **TINY,
+    )
 
 
 def test_chip_smoke_ring_phase_at_tiny_size():
-    with chip_smoke.CompileLog() as log:
-        chip_smoke.phase_ring(
-            log, data=2, model=2, batch=4, seq=197, dim=384, heads=6
-        )
+    chip_smoke.phase_ring(data=2, model=2, batch=4, seq=197, dim=384, heads=6)
 
 
 def test_chip_smoke_refuses_to_start_without_a_tpu(tmp_path, monkeypatch, capsys):

@@ -1,13 +1,15 @@
 """utils/tracing.py: the recorder alone, then a tiny two-level ladder through
-``driver.run`` with ``profile_dir`` set, read back through the spans, the
-``[time]`` lines, ``level_timing.csv`` and the two profiler sessions. CPU
+``run_experiment.main`` with ``profile_dir`` set, read back through the spans,
+the ``[time]`` lines, ``level_timing.csv`` and the two profiler sessions. CPU
 only: nothing here is a speed."""
 
 import contextlib
 import io
+import re
 import threading
 from collections import deque
 from pathlib import Path
+from types import SimpleNamespace
 
 import jax
 import jax.numpy as jnp
@@ -91,6 +93,7 @@ class TestRecorder:
         behind = _made("ckpt/write", -0.25, 2.5, level=1)
         behind.thread += 1
         inner[1].compiles, inner[1].compile_s = 2, 0.5
+        inner[0].trace_s, inner[1].trace_s, epochs[0].lower_s = 0.25, 0.5, 0.125
         spans = [read, load, behind, *inner, *epochs, train, wait, barrier, fetch, save, level]
         b = tracing.breakdown([level], spans)
         assert b["terms"] == {"level/load": 1.0, "epoch/train": 4.0, "level/save": 1.0}
@@ -103,10 +106,12 @@ class TestRecorder:
         assert b["other_s"] == pytest.approx(0.5 + 2.5 + 1.0)
         assert sum(b["terms"].values()) + b["other_s"] == pytest.approx(b["total_s"]) == 10.0
         assert (b["compiles"], b["compile_s"]) == (2, 0.5)
+        assert (b["trace_s"], b["lower_s"]) == (0.75, 0.125)  # summed over terms and containers
         assert tracing.line("level 2", b) == (
             "[time] level 2: 10.00 s = load 1.00 (read 0.80) + train 4.00 + "
             "save 1.00 (wait 0.12, barrier 0.12, fetch 0.50) + other 4.00; "
-            "wrote level 1 behind, 2.75 s; compiled 2 modules, 0.5 s"
+            "wrote level 1 behind, 2.75 s; traced 0.8 s, lowered 0.1 s, compiled 2 modules "
+            "in 0.5 s (0 read from the cache in 0.0 s; 0 missed)"
         )
         row = tracing.timing_row(level, b)
         assert list(row) == tracing.TIMING_COLUMNS
@@ -156,6 +161,119 @@ class TestRecorder:
         assert (before.compiles, cached.compiles, root.compiles) == (0, 0, 0)
         assert tracing.breakdown([root])["compiles"] == 1
 
+    def test_the_tail_names_the_cache_and_the_longest_misses_and_the_row_has_them(self, monkeypatch):
+        level = _made("level", 0.0, 100.0, level=0)
+        train = _made("epoch/train", 1.0, 90.0, level)
+        other = _made("epoch/train", 0.0, 1.0)  # not under the level
+        train.trace_s, train.lower_s, train.compiles, train.compile_s = 12.25, 3.5, 7, 60.0
+        train.cache_hits, train.cache_read_s, train.cache_misses, train.miss_compile_s = 2, 1.25, 4, 57.5
+        level.compiles, level.compile_s = 1, 0.25  # a container's own count too
+        missed = [("jit(scan_chunk)", 34.5), ("jit(a)", 0.5), ("jit(top_k)", 20.0), ("jit(b)", 2.5)]
+        record = [tracing.Module(n, 5.0, train.id, train.name, 0.1, sec, "miss") for n, sec in missed]
+        record.append(tracing.Module("jit(hit)", 5.0, train.id, train.name, 0.1, 0.75, "hit"))
+        record.append(tracing.Module("jit(elsewhere)", 0.5, other.id, other.name, 0.1, 99.0, "miss"))
+        monkeypatch.setattr(tracing, "_modules", deque(record, maxlen=tracing.MAX_MODULES))
+        b = tracing.breakdown([level], [train, level, other])
+        assert b["missed"] == [("jit(scan_chunk)", 34.5), ("jit(top_k)", 20.0), ("jit(b)", 2.5), ("jit(a)", 0.5)]
+        assert tracing.line("level 0", b).split("; ", 1)[1] == (
+            "traced 12.2 s, lowered 3.5 s, compiled 8 modules in 60.2 s (2 read from the cache "
+            "in 1.2 s; 4 missed: jit(scan_chunk) 34.5, jit(top_k) 20.0, jit(b) 2.5)"
+        )
+        row = tracing.timing_row(level, b)
+        assert list(row) == tracing.TIMING_COLUMNS
+        assert tracing.TIMING_COLUMNS[-8:] == [
+            "compiles", "compile_s", "trace_s", "lower_s",
+            "cache_hits", "cache_misses", "cache_read_s", "miss_compile_s",
+        ]  # fmt: skip
+        assert [row[c] for c in tracing.TIMING_COLUMNS[-8:]] == [8, 60.25, 12.25, 3.5, 2, 4, 1.25, 57.5]
+
+    @pytest.mark.parametrize("nested", [False, True], ids=["one jit", "a jit inside a jit"])
+    def test_a_first_call_is_charged_its_trace_lowering_and_compile_once(self, nested):
+        inner = jax.jit(lambda x: jnp.tanh(x) * 5 + 2)
+        f = jax.jit(lambda x: inner(inner(x)) - 1) if nested else inner
+        x = jnp.ones(5)
+        with tracing.span("t/first") as first:
+            f(x).block_until_ready()
+        with tracing.span("t/second") as second:
+            f(x).block_until_ready()
+        assert first.trace_s > 0 and first.lower_s > 0 and first.compiles == 1 and first.compile_s > 0
+        # A jit traced inside a jit, and a trace inside a lowering, are
+        # charged their own seconds once: the three never exceed the wall.
+        assert first.trace_s + first.lower_s + first.compile_s <= first.seconds
+        charged = [getattr(second, k) for k in tracing.CHARGED]
+        assert charged == [0] * len(tracing.CHARGED)
+        (module,) = tracing.modules(first.start, first.end)
+        assert (module.span, module.span_name, module.cache) == (first.id, "t/first", "none")
+        assert module.name.startswith("jit(") and module.compile_s == first.compile_s
+        assert 0 < module.lower_s <= first.lower_s
+        assert not tracing.modules(second.start, second.end)
+
+    def test_the_persistent_cache_answers_miss_then_hit_by_module_and_span(self, tmp_path):
+        from jax.experimental.compilation_cache import compilation_cache as cc
+
+        keys = {
+            "jax_enable_compilation_cache": True,
+            "jax_compilation_cache_dir": str(tmp_path),
+            "jax_persistent_cache_min_compile_time_secs": 0,
+            "jax_persistent_cache_min_entry_size_bytes": -1,
+        }
+        before = {k: getattr(jax.config, k) for k in keys}
+        f = jax.jit(lambda x: jnp.cos(x) * 7 - 3)
+        x = jnp.ones(7)
+        try:
+            for k, v in keys.items():
+                jax.config.update(k, v)
+            cc.reset_cache()
+            with tracing.span("t/cold") as cold:
+                f(x).block_until_ready()
+            jax.clear_caches()  # what a new process starts with: the directory alone
+            with tracing.span("t/warm") as warm:
+                f(x).block_until_ready()
+        finally:
+            for k, v in before.items():
+                jax.config.update(k, v)
+            cc.reset_cache()
+        assert (cold.compiles, cold.cache_misses, cold.cache_hits) == (1, 1, 0)
+        assert cold.miss_compile_s == cold.compile_s > 0 and cold.cache_read_s == 0
+        assert (warm.compiles, warm.cache_misses, warm.cache_hits) == (1, 0, 1)
+        assert 0 < warm.cache_read_s <= warm.compile_s and warm.miss_compile_s == 0
+        (missed,), (hit,) = tracing.modules(cold.start, cold.end), tracing.modules(warm.start, warm.end)
+        assert (missed.cache, missed.span, missed.span_name) == ("miss", cold.id, "t/cold")
+        assert (hit.cache, hit.span, hit.span_name) == ("hit", warm.id, "t/warm")
+        assert missed.name == hit.name and cold.start <= missed.when <= cold.end
+        b = tracing.breakdown([cold])
+        assert b["missed"] == [(missed.name, cold.compile_s)]
+        assert f"1 missed: {missed.name} " in tracing.line("cold", b)
+
+    def test_a_module_compiled_on_another_thread_is_charged_to_that_threads_span(self):
+        f = jax.jit(lambda x: jnp.sinh(x) * 11)
+        x = jnp.ones(11)
+        seen = {}
+
+        def worker():
+            with tracing.span("t/worker") as s:
+                f(x).block_until_ready()
+            seen["span"] = s
+
+        with tracing.span("t/main") as main:
+            t = threading.Thread(target=worker)
+            t.start()
+            t.join(timeout=60)
+            assert not t.is_alive()
+        theirs = seen["span"]
+        assert theirs.compiles == 1 and theirs.trace_s > 0 and theirs.thread != main.thread
+        assert [getattr(main, k) for k in tracing.CHARGED] == [0] * len(tracing.CHARGED)
+        (module,) = tracing.modules(main.start, main.end)
+        assert (module.span, module.span_name) == (theirs.id, "t/worker")
+
+    def test_the_module_record_is_bounded_and_a_module_outside_every_span_is_kept(self, monkeypatch):
+        assert tracing._modules.maxlen == tracing.MAX_MODULES == 4096
+        monkeypatch.setattr(tracing, "_modules", deque(maxlen=2))
+        for i in range(3):
+            jax.jit(lambda x, i=i: x * (13 + i))(jnp.ones(13)).block_until_ready()
+        kept = tracing.modules()
+        assert len(kept) == 2 and all(m.span is None and m.span_name is None for m in kept)
+
     def test_gauges_hold_the_last_value_set(self):
         tracing.gauge("t_gauge", 3)
         tracing.gauge("t_gauge", 5)
@@ -189,6 +307,7 @@ def test_the_lowered_train_step_names_its_layers():
 # in level 1's save and, outside every level, at the run's end.
 RESUMED = "resumed"
 LADDER_SPANS = {
+    "setup/imports": None, "setup/config": None, "setup/distributed": None, "setup/backend": None,
     "harness/init": None, "init/mesh_model": None, "init/loaders": None,
     "init/state": None, "init/steps": None,
     "level": True, "level/load": RESUMED, "level/prune": False, "level/rewind": False,
@@ -201,14 +320,12 @@ LADDER_SPANS = {
 
 @pytest.fixture(scope="module")
 def ladder(tmp_path_factory):
-    from turboprune_tpu.config.compose import compose
-    from turboprune_tpu.driver import run
+    import run_experiment
+    from turboprune_tpu import driver
 
     tmp = tmp_path_factory.mktemp("traced")
-    def cfg(*more):
-        return compose("cifar10_imp", overrides=[*common, *more])
-
     common = [
+        "--config-name=cifar10_imp",
         f"experiment_params.base_dir={tmp / 'experiments'}",
         "experiment_params.num_devices=1",
         "experiment_params.epochs_per_level=2",
@@ -223,33 +340,40 @@ def ladder(tmp_path_factory):
         "pruning_params.prune_rate=0.9",
         "pruning_params.target_sparsity=0.9",
     ]
-    out = io.StringIO()
-    with tracing.span("t/ladder") as whole, contextlib.redirect_stdout(out):
-        expt_dir, summaries = run(cfg(f"experiment_params.profile_dir={tmp / 'profile'}"))
+    ran = []
+    real_run = driver.run
+
+    def main(*more):
+        """``run_experiment.main`` as the CLI calls it; (what it printed, the
+        spans it recorded, what ``driver.run`` returned to it)."""
+        out = io.StringIO()
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(driver, "run", lambda cfg: ran.append(real_run(cfg)) or ran[-1])
+            with tracing.span("t/ladder") as whole, contextlib.redirect_stdout(out):
+                assert run_experiment.main([*common, *more]) == 0
+        return out.getvalue(), tracing.recorded(t0=whole.start, t1=whole.end), ran[-1]
+
+    out, spans, (expt_dir, summaries) = main(f"experiment_params.profile_dir={tmp / 'profile'}")
     assert len(summaries) == 2
-    spans = tracing.recorded(t0=whole.start, t1=whole.end)
     timing = pd.read_csv(Path(expt_dir) / "metrics" / "level_timing.csv")
 
     # The same directory taken up at level 1 by a process that holds nothing,
     # under a session of the test's own: ``profile_dir`` starts none past
     # level 0, and the harness stops this one where level 1's set-up ends.
-    resumed = cfg(
-        "experiment_params.resume_experiment=true",
-        f"experiment_params.resume_experiment_stuff.resume_expt_name={Path(expt_dir).name}",
-        "experiment_params.resume_experiment_stuff.resume_level=1",
-    )
-    resumed_out = io.StringIO()
     tracing.start_profile(tmp / "profile_resumed" / "level1_resumed")
     try:
-        with tracing.span("t/ladder") as again, contextlib.redirect_stdout(resumed_out):
-            _, summaries = run(resumed)
+        resumed_out, resumed_spans, (_, summaries) = main(
+            "experiment_params.resume_experiment=true",
+            f"experiment_params.resume_experiment_stuff.resume_expt_name={Path(expt_dir).name}",
+            "experiment_params.resume_experiment_stuff.resume_level=1",
+        )
     finally:
         tracing.stop_profile()
     assert [s["level"] for s in summaries] == [1]
     return {
-        "spans": spans, "out": out.getvalue(), "expt_dir": Path(expt_dir), "profile": tmp / "profile",
-        "timing": timing, "resumed_spans": tracing.recorded(t0=again.start, t1=again.end),
-        "resumed_out": resumed_out.getvalue(), "profile_resumed": tmp / "profile_resumed",
+        "spans": spans, "out": out, "expt_dir": Path(expt_dir), "profile": tmp / "profile",
+        "timing": timing, "resumed_spans": resumed_spans,
+        "resumed_out": resumed_out, "profile_resumed": tmp / "profile_resumed",
     }  # fmt: skip
 
 
@@ -281,6 +405,7 @@ def test_every_recorded_name_is_one_the_time_line_knows(ladder):
     terms_or_inside = set(tracing.TERMS) | {n for n in known if n.startswith(("ckpt/", "init/"))}
     containers = {"level", "level/train", "epoch", "t/ladder"}
     assert known - terms_or_inside == containers
+    assert {n for n in known if n.startswith("setup/")} == {n for n in tracing.TERMS if n.startswith("setup/")}
 
 
 def test_level_self_times_sum_to_the_level_and_land_in_the_csv(ladder):
@@ -294,7 +419,7 @@ def test_level_self_times_sum_to_the_level_and_land_in_the_csv(ladder):
         assert 0 <= b["other_s"] < level.seconds
         assert row["total_s"] == pytest.approx(level.seconds)
         assert row["train_s"] == pytest.approx(b["terms"]["epoch/train"])
-        parts = [c for c in rows.columns if c.endswith("_s") and not c.startswith(("total", "ckpt_", "compile"))]
+        parts = [c for c in rows.columns if c.endswith("_s") and not c.startswith(("total", "ckpt_", "compile", "trace", "lower", "cache_", "miss_"))]
         assert row[parts].sum() + row["ckpt_s"] == pytest.approx(row["total_s"])
     assert rows["rewind_s"][0] == 0 and rows["rewind_s"][1] > 0
     # A level of a continuous run reads nothing: the columns stay and say 0.
@@ -306,8 +431,12 @@ def test_level_self_times_sum_to_the_level_and_land_in_the_csv(ladder):
     (wrote,) = [s for s in ladder["spans"] if s.name == "ckpt/write" and s.attrs["level"] == 0 and s.thread != waited.thread]
     assert rows["ckpt_wait_s"][0] == 0 and rows["ckpt_wait_s"][1] == pytest.approx(waited.seconds)
     assert rows["ckpt_write_s"][1] == pytest.approx(wrote.seconds)
-    # Level 0 compiles the epoch; level 1 reuses it and compiles its prune.
-    assert rows["compiles"][0] > 0
+    # Level 0 traces, lowers and compiles the epoch; level 1 reuses it and
+    # compiles its prune. No persistent cache in the tests: nothing is read
+    # from one and nothing missed.
+    assert rows["compiles"][0] > 0 and rows["trace_s"][0] > 0 and rows["lower_s"][0] > 0
+    assert (rows["trace_s"] + rows["lower_s"] + rows["compile_s"] <= rows["total_s"]).all()
+    assert not rows[["cache_hits", "cache_misses", "cache_read_s", "miss_compile_s"]].any().any()
 
 
 def test_a_resumed_level_says_what_it_read_in_its_row_and_its_spans(ladder):
@@ -329,29 +458,109 @@ def test_a_resumed_level_says_what_it_read_in_its_row_and_its_spans(ladder):
 @pytest.mark.parametrize(
     "out, titles, load",
     [
-        ("out", ["[time] set-up", "[time] level 0", "[time] level 1"], False),
-        ("resumed_out", ["[time] set-up", "[time] level 1"], True),
+        ("out", ["[time] set-up", "[time] start to first epoch", "[time] level 0", "[time] level 1"], False),
+        ("resumed_out", ["[time] set-up", "[time] start to first epoch", "[time] level 1"], True),
     ],
 )
 def test_the_operator_gets_a_time_line_per_level_and_one_for_setup(ladder, out, titles, load):
     lines = [ln for ln in ladder[out].splitlines() if ln.startswith("[time] ")]
     assert [ln.split(":")[0] for ln in lines] == titles
     assert "init " in lines[0] and "loaders " in lines[0] and "state " in lines[0]
-    for want in ("prune ", "rewind ", "setup ", "train ", "eval ", "log ", "save ", "other ", "compiled "):
+    for want in ("imports ", "config ", "distributed ", "backend "):
+        assert want in lines[0], want
+    for want in ("prune ", "rewind ", "setup ", "train ", "eval ", "log ", "save ", "other "):
         assert want in lines[-1], want
+    tail = r"; traced \d+\.\d s, lowered \d+\.\d s, compiled \d+ modules in \d+\.\d s \(0 read from the cache in 0\.0 s; 0 missed\)$"
+    assert all(re.search(tail, ln) for ln in lines), lines
     # Only a level that restored says so: "load 0.20 (read 0.19)", and its
     # rewind "(read ...)" too.
     assert ("load " in lines[-1]) == load and (lines[-1].count("(read ") == 2) == load
     assert (lines[-1].count("(read ") == 0) != load
+    level0 = lines[2]
     if not load:
-        assert "prune " not in lines[1]
+        assert "prune " not in level0
     # A level's save waits for the write of the level before and says so, and
     # the level says which write ended behind it. The first level a process
     # saves has neither: level 0, and a resumed process's level 1.
     behind = not load
     assert ("save " in lines[-1] and "(wait " in lines[-1]) == behind
     assert ("; wrote level 0 behind, " in lines[-1]) == behind
-    assert "wait " not in lines[1] and "behind" not in lines[1]
+    assert "wait " not in level0 and "behind" not in level0
+
+
+def _terms(line: str) -> tuple[float, dict]:
+    """(total, {term: seconds}) of a ``[time]`` line, parentheses left out."""
+    total, terms = re.match(r"\[time\] [^:]+: ([\d.]+) s = ([^;]+)", line).groups()
+    named = [part.split() for part in re.sub(r" \([^)]*\)", "", terms).split(" + ")]
+    return float(total), {name: float(sec) for name, sec in named}
+
+
+@pytest.mark.parametrize("out, level", [("out", 0), ("resumed_out", 1)])
+def test_the_first_epoch_of_a_process_gets_its_line_once(ladder, out, level):
+    lines = ladder[out].splitlines()
+    said = [i for i, ln in enumerate(lines) if ln.startswith("[time] start to first epoch:")]
+    assert len(said) == 1  # four epochs ran, and two in the resumed process
+    # After the first epoch's own console line and before the second's.
+    epochs = [i for i, ln in enumerate(lines) if ln.startswith(f"[L {level} E ")]
+    assert epochs[0] < said[0] < epochs[1]
+    total, terms = _terms(lines[said[0]])
+    want = ["imports", "config", "distributed", "backend", "init", "setup", "feed", "train", "eval", "log", "other"]
+    assert list(terms) == want
+    assert sum(terms.values()) == pytest.approx(total, abs=0.005 * (len(terms) + 1))
+    # Its roots: this process's set-up, and the first level/setup and epoch
+    # it ran, at whatever level; the line's total is theirs.
+    spans = ladder["spans" if level == 0 else "resumed_spans"]
+    first = {n: next(s for s in spans if s.name == n) for n in ("harness/init", "level/setup", "epoch")}
+    assert first["level/setup"].attrs["level"] == first["epoch"].attrs["level"] == level
+    roots = [s for s in spans if s.name.startswith("setup/")] + list(first.values())
+    assert sum(s.seconds for s in roots) == pytest.approx(total, abs=0.006)
+    assert terms["train"] == pytest.approx(next(s for s in spans if s.name == "epoch/train").seconds, abs=0.006)
+    # A fresh level 0 writes model_init and optimizer_init in its set-up.
+    assert ("write " in lines[said[0]]) == (level == 0)
+
+
+class _Dies(Exception):
+    pass
+
+
+class _DyingHarness:
+    """What ``driver.run`` needs of a harness, with a level 0 that sets up,
+    trains part of an epoch and raises."""
+
+    data_gauges: dict = {}
+
+    def __init__(self, cfg, expt_dir):
+        with tracing.span("harness/init"):
+            self.rows = []
+            self.metrics = SimpleNamespace(log_level_timing=self.rows.append)
+            self.ckpts = SimpleNamespace(wait=lambda: None)
+        type(self).last = self
+
+    def train_one_level(self, epochs, level):
+        with tracing.span("level/setup"):
+            x = jnp.ones(17)
+        with tracing.span("epoch", epoch=0):
+            with tracing.span("epoch/train"):
+                jax.jit(lambda x: x * 17 + level)(x).block_until_ready()
+                raise _Dies()
+
+
+def test_a_level_that_never_ends_says_where_it_got_to_and_writes_no_row(tmp_path, capsys):
+    from turboprune_tpu.config.compose import compose
+    from turboprune_tpu.driver import run
+
+    cfg = compose("cifar10_imp", overrides=[f"experiment_params.base_dir={tmp_path}"])
+    with pytest.raises(_Dies):
+        run(cfg, harness_cls=_DyingHarness)
+    lines = [ln for ln in capsys.readouterr().out.splitlines() if ln.startswith("[time] ")]
+    assert [ln.split(":")[0] for ln in lines] == ["[time] set-up", "[time] level 0 (unfinished)"]
+    total, terms = _terms(lines[1])
+    assert list(terms) == ["setup", "train", "other"] and terms["train"] > 0
+    assert sum(terms.values()) == pytest.approx(total, abs=0.02)
+    assert "compiled 2 modules" in lines[1]  # the set-up's array, the epoch's program
+    assert _DyingHarness.last.rows == []
+    level = tracing.recorded("level")[-1]
+    assert level.attrs["error"] == "_Dies" and total == pytest.approx(level.seconds, abs=0.006)
 
 
 def _host_span_names(session: Path) -> set[str]:

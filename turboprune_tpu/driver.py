@@ -186,6 +186,7 @@ def run(cfg: MainConfig, harness_cls: Optional[Type[PruningHarness]] = None):
         )
 
     summaries = []
+    level_span = None
     try:
         for level in range(start_level, len(densities)):
             density = densities[level]
@@ -233,6 +234,10 @@ def run(cfg: MainConfig, harness_cls: Optional[Type[PruningHarness]] = None):
             )
     finally:
         tracing.stop_profile()  # a level that raised must not leave one running
+        if level_span is not None and "error" in level_span.attrs:
+            # What ended it closed its spans on the way out: the level says
+            # where it had got to, and writes no row.
+            _say_time(f"level {level_span.attrs['level']} (unfinished)", [level_span])
         # Returning or raising, every level this run reported is on disk
         # before anyone is told the run is over.
         harness.ckpts.wait()

@@ -896,9 +896,22 @@ class PruningHarness:
         try:
             with tracing.span("epoch", epoch=epoch, **attrs):
                 yield
+            if not self._said_first_epoch:
+                self._say_first_epoch()
         finally:
             if profiled:
                 tracing.stop_profile()
+
+    _said_first_epoch = False
+
+    def _say_first_epoch(self) -> None:
+        """Once a harness, where its first epoch has closed: the operator's
+        line from process start to here. A run that dies in a long level 0
+        has said where its set-up went, and so has one that never ends."""
+        self._said_first_epoch = True
+        roots = tracing.first_epoch_roots()
+        if roots and is_primary():
+            print(tracing.line("start to first epoch", tracing.breakdown(roots)), flush=True)
 
     def _train_eval_log(self, row: dict, max_test_acc: float) -> float:
         """Train one epoch, evaluate, and log ``row`` (which already names

@@ -6,10 +6,30 @@ process-wide bounded recorder and opens a
 ``jax.profiler.TraceAnnotation("tp/<name>")``, so a running profiler session
 holds the same scope on the device trace's clock. ``level`` and ``epoch`` are
 inherited from the enclosing span: the spans of one level share its number.
-Every XLA compilation is charged to the innermost span open on the thread
-that compiled (``compiles``, ``compile_s``): "which step recompiled".
 ``breakdown`` and ``line`` turn a level's or set-up's spans into the
 operator's ``[time]`` line and the ``level_timing.csv`` row.
+
+What JAX does before a program runs is charged, as it ends, to the innermost
+span open on the thread that did it, from ``jax.monitoring``'s events:
+``trace_s`` (Python to jaxprs), ``lower_s`` (jaxpr to MLIR), ``compiles`` and
+``compile_s`` (modules that reached the backend, and their seconds there,
+which include a read from the persistent cache), ``cache_hits`` and
+``cache_read_s`` (modules the persistent cache served, and the seconds
+reading them), ``cache_misses`` and ``miss_compile_s`` (modules that asked
+the cache and were compiled and written; a module under the cache's minimum
+compile time is neither, warm or cold). The three stages nest, a jit traced
+inside a jit and a trace inside a lowering; each is charged its own seconds
+less the stages that ended inside it, so on every span ``trace_s + lower_s +
+compile_s`` is at most its wall-clock. ``modules(t0, t1)`` is the record by
+module, one ``Module`` a backend compile, the newest ``MAX_MODULES``: "which
+step recompiled" and "which module missed", by name and by span.
+
+The operator's lines (``driver.py``, the epoch loop): ``[time] set-up`` once
+the harness is built, ``[time] start to first epoch`` once the first epoch
+of a process closes, ``[time] level N`` when a level ends, and ``[time]
+level N (unfinished)`` for the level an exception or a signal ended. Each
+ends in ``traced T s, lowered L s, compiled K modules in S s (H read from
+the cache in R s; M missed: names)``.
 
 There is no off switch, so what recording costs is in every run: a few
 microseconds a span, with a budget of a few dozen spans per level or epoch
@@ -24,18 +44,19 @@ import threading
 import time
 from collections import defaultdict, deque
 from pathlib import Path
-from typing import Any, Optional, Sequence
+from typing import Any, NamedTuple, Optional, Sequence
 
 import jax
 
 PREFIX = "tp/"
 MAX_SPANS = 65536  # a 30-level ladder of 150 epochs records about 32,000
+MAX_MODULES = 4096  # a cell's process compiles 76-472, a cold 30-level ladder about 700
 INHERITED = ("level", "epoch")
 # What a ``[time]`` line names, in its order. The spans between these and the
 # root (``level``, ``level/train``, ``epoch``) are containers: their self
 # time, a span's duration less what its children cover, is the line's "other".
 TERMS = (
-    "setup/imports", "setup/config", "setup/distributed", "harness/init",
+    "setup/imports", "setup/config", "setup/distributed", "setup/backend", "harness/init",
     "level/load", "level/prune", "level/rewind", "level/agree", "level/setup",
     "epoch/feed", "epoch/train", "epoch/eval", "epoch/log", "epoch/ckpt",
     "level/finish", "level/save",
@@ -45,8 +66,14 @@ _CKPT = ("ckpt/read", "ckpt/fetch", "ckpt/wait", "ckpt/write", "ckpt/barrier")
 # thread (utils/checkpoint.py), so it is no span's child: a level's line and
 # row name the write that ENDED during the level, beside the level's own time.
 BEHIND = "ckpt/write"
+# What a span is charged, in the order of a ``[time]`` line's tail.
+CHARGED = (
+    "trace_s", "lower_s", "compiles", "compile_s",
+    "cache_hits", "cache_read_s", "cache_misses", "miss_compile_s",
+)  # fmt: skip
 
 _spans: deque = deque(maxlen=MAX_SPANS)  # closed spans, in closing order
+_modules: deque = deque(maxlen=MAX_MODULES)  # one a backend compile, in that order
 _ids = itertools.count(1)
 _local = threading.local()
 _mu = threading.Lock()
@@ -69,7 +96,9 @@ class Span:
         self.name, self.attrs = name, attrs
         self.start = self.end = 0.0
         self.thread = threading.get_ident()
-        self.compiles, self.compile_s = 0, 0.0
+        self.trace_s = self.lower_s = self.compile_s = 0.0
+        self.cache_read_s = self.miss_compile_s = 0.0
+        self.compiles = self.cache_hits = self.cache_misses = 0
 
     @property
     def seconds(self) -> float:
@@ -129,14 +158,90 @@ def recorded(
     ]
 
 
-def _on_duration(event: str, duration: float, **_kw) -> None:
-    stack = _stack()
-    if event == "/jax/core/compile/backend_compile_duration" and stack:
-        stack[-1].compiles += 1
-        stack[-1].compile_s += duration
+class Module(NamedTuple):
+    """One module that reached the backend: ``when`` it left it
+    (``perf_counter``), the innermost span open on its thread then (``None``
+    outside every span), and whether the persistent cache served it
+    (``"hit"``), was asked and then written (``"miss"``) or neither
+    (``"none"``: no cache, or a compile under its minimum time)."""
+
+    name: str
+    when: float
+    span: Optional[int]
+    span_name: Optional[str]
+    lower_s: float
+    compile_s: float
+    cache: str
 
 
+def modules(t0: float = float("-inf"), t1: float = float("inf")) -> list[Module]:
+    """The modules that left the backend inside [t0, t1], oldest first."""
+    return [m for m in list(_modules) if t0 <= m.when <= t1]
+
+
+_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_COMPILE = "/jax/core/compile/backend_compile_duration"
+_STAGES = {_TRACE: "trace_s", _LOWER: "lower_s", _COMPILE: "compile_s"}
+_CACHE_READ = "/jax/compilation_cache/cache_retrieval_time_sec"
+_CACHE = {"/jax/compilation_cache/cache_hits": "hit", "/jax/compilation_cache/cache_misses": "miss"}
+
+
+def _on_start(event: str, _value: float, **_kw) -> None:
+    """A stage begins on this thread (JAX records each stage's start as a
+    scalar): open a count of the seconds that stages inside it will take."""
+    if event in _STAGES:
+        if not hasattr(_local, "stages"):
+            _local.stages = []
+        _local.stages.append(0.0)
+
+
+def _on_duration(event: str, duration: float, **kw) -> None:
+    """A stage ends on this thread: charge what it took itself to the
+    innermost open span, and record a module that leaves the backend."""
+    field = _STAGES.get(event)
+    stack = _stack() if field or event == _CACHE_READ else None  # JAX times other things too
+    if field is None:
+        if stack:
+            stack[-1].cache_read_s += duration
+        return
+    stages = getattr(_local, "stages", None)
+    own = duration - stages.pop() if stages else duration
+    if stages:
+        stages[-1] += duration
+    top = stack[-1] if stack else None
+    if top is not None:
+        setattr(top, field, getattr(top, field) + own)
+    name = str(kw.get("fun_name"))
+    if event == _LOWER:
+        _local.lowered = (name, own)
+    elif event == _COMPILE:
+        lowered, cache = getattr(_local, "lowered", None), getattr(_local, "cache", "none")
+        _local.lowered, _local.cache = None, "none"
+        if top is not None:
+            top.compiles += 1
+            top.cache_hits += cache == "hit"
+            if cache == "miss":
+                top.cache_misses += 1
+                top.miss_compile_s += own
+        _modules.append(
+            Module(
+                name, time.perf_counter(), top and top.id, top and top.name,
+                lowered[1] if lowered and lowered[0] == name else 0.0, own, cache,
+            )
+        )  # fmt: skip
+
+
+def _on_event(event: str, **_kw) -> None:
+    """The persistent cache answers for the module this thread is compiling,
+    before that module's duration event."""
+    if event in _CACHE:
+        _local.cache = _CACHE[event]
+
+
+jax.monitoring.register_scalar_listener(_on_start)
 jax.monitoring.register_event_duration_secs_listener(_on_duration)
+jax.monitoring.register_event_listener(_on_event)
 
 
 def gauge(name: str, value: float) -> None:
@@ -160,21 +265,25 @@ def breakdown(roots: Sequence[Span], spans: Optional[Sequence[Span]] = None) -> 
     """What a ``[time]`` line says of ``roots`` and all recorded under them
     (or, for hand-made spans, found in ``spans``): the seconds of each of
     ``TERMS`` with its children, ``inside`` each term its direct children by
-    name, the containers' self time as ``other_s``, and the compilations
-    charged anywhere below. Terms and ``other_s`` sum to ``total_s``. Beside
-    them ``behind``: the ``(level, seconds)`` of each write that ended on
-    another thread while a root was open."""
+    name, the containers' self time as ``other_s``, and every count of
+    ``CHARGED`` summed over all of them, with ``missed``: the ``(name,
+    seconds)`` of the modules they compiled that the cache did not hold, the
+    longest first. Terms and ``other_s`` sum to ``total_s``. Beside them
+    ``behind``: the ``(level, seconds)`` of each write that ended on another
+    thread while a root was open."""
     terms: dict = defaultdict(float)
     inside: dict = defaultdict(lambda: defaultdict(float))
-    out = {"total_s": sum(r.seconds for r in roots), "other_s": 0.0, "compiles": 0, "compile_s": 0.0}
+    out = {"total_s": sum(r.seconds for r in roots), "other_s": 0.0, **dict.fromkeys(CHARGED, 0)}
+    seen = set()
     children: dict = defaultdict(list)
     for s in recorded(t0=min(r.start for r in roots)) if spans is None else spans:
         children[s.parent].append(s)
     todo = deque((root, None, 0) for root in roots)  # span, the term it lies in, depth in it
     while todo:
         s, term, depth = todo.popleft()
-        out["compiles"] += s.compiles
-        out["compile_s"] += s.compile_s
+        seen.add(s.id)
+        for key in CHARGED:
+            out[key] += getattr(s, key)
         if term is not None:
             if depth == 1:
                 inside[term][s.name] += s.seconds
@@ -184,6 +293,8 @@ def breakdown(roots: Sequence[Span], spans: Optional[Sequence[Span]] = None) -> 
         else:
             out["other_s"] += s.seconds - sum(c.seconds for c in children[s.id])
         todo.extend((c, term, depth + 1 if term else 0) for c in children[s.id])
+    missed = [m for m in list(_modules) if m.cache == "miss" and m.span in seen]
+    out["missed"] = [(m.name, m.compile_s) for m in sorted(missed, key=lambda m: -m.compile_s)]
     out["behind"] = [
         (s.attrs.get("level"), s.seconds)
         for s in (recorded() if spans is None else spans)
@@ -199,8 +310,9 @@ def _short(name: str) -> str:
 def line(title: str, b: dict) -> str:
     """``[time] level 3: 1.80 s = prune 0.11 + ... + save 0.08 (wait 0.00,
     barrier 0.00, fetch 0.07) + other 0.03; wrote level 2 behind, 0.27 s;
-    compiled 0 modules, 0.0 s``. A term is named only where its span ran:
-    ``load`` in a resumed level."""
+    traced 0.0 s, lowered 0.0 s, compiled 0 modules in 0.0 s (0 read from the
+    cache in 0.0 s; 0 missed)``. A term is named only where its span ran:
+    ``load`` in a resumed level; of the missed modules, the three longest."""
     parts = []
     for name in (n for n in TERMS if n in b["terms"]):
         part = f"{_short(name)} {b['terms'][name]:.2f}"
@@ -212,7 +324,12 @@ def line(title: str, b: dict) -> str:
         f"[time] {title}: {b['total_s']:.2f} s = "
         + " + ".join(parts + [f"other {b['other_s']:.2f}"])
         + "".join(f"; wrote level {level} behind, {s:.2f} s" for level, s in b["behind"])
-        + f"; compiled {b['compiles']} modules, {b['compile_s']:.1f} s"
+        + f"; traced {b['trace_s']:.1f} s, lowered {b['lower_s']:.1f} s, compiled "
+        f"{b['compiles']} modules in {b['compile_s']:.1f} s ({b['cache_hits']} read from the "
+        f"cache in {b['cache_read_s']:.1f} s; {b['cache_misses']} missed"
+        + (": " if b["missed"] else "")
+        + ", ".join(f"{name} {s:.1f}" for name, s in b["missed"][:3])
+        + ")"
     )
 
 
@@ -221,14 +338,15 @@ TIMING_COLUMNS = (
     + [f"{_short(n)}_s" for n in TERMS if n.startswith(("level/", "epoch/"))]
     + ["other_s"]
     + [n.replace("/", "_") + "_s" for n in _CKPT]
-    + ["compiles", "compile_s"]
+    + ["compiles", "compile_s", "trace_s", "lower_s"]
+    + ["cache_hits", "cache_misses", "cache_read_s", "miss_compile_s"]
 )
 
 
 def timing_row(level: Span, b: dict) -> dict:
     """The ``level_timing.csv`` row of one level, keyed by ``TIMING_COLUMNS``."""
     row = dict.fromkeys(TIMING_COLUMNS, 0.0)
-    row.update({k: b[k] for k in ("total_s", "other_s", "compiles", "compile_s")})
+    row.update({k: b[k] for k in ("total_s", "other_s", *CHARGED)})
     row.update(level=level.attrs.get("level"), density=level.attrs.get("density"))
     row.update({f"{_short(n)}_s": s for n, s in b["terms"].items()})
     for split in b["inside"].values():
@@ -245,6 +363,16 @@ def setup_roots() -> list[Span]:
     inits = [i for i, s in enumerate(spans) if s.name == "harness/init"]
     lo = inits[-2] + 1 if len(inits) > 1 else 0
     return [s for s in spans[lo : inits[-1]] if s.name.startswith("setup/")] + [spans[inits[-1]]]
+
+
+def first_epoch_roots() -> Optional[list[Span]]:
+    """``setup_roots()``, the first ``level/setup`` and the first ``epoch``
+    since the newest ``harness/init``: process start to the end of the first
+    epoch, at whatever level it ran. Nothing until that epoch has closed."""
+    roots = setup_roots()
+    later = recorded(t0=roots[-1].end)
+    firsts = [next((s for s in later if s.name == name), None) for name in ("level/setup", "epoch")]
+    return None if None in firsts else roots + firsts
 
 
 def start_profile(directory: str | Path) -> None:
