@@ -1,9 +1,10 @@
 """The hybrid language model (models/granite.py) against its plain reference
 (benchmarks/reference/granite.py, which shares no code with the package) on
 seeded weights: each kind of block and the whole model, forward, loss and
-gradients, with and without masks. Then what the rest of the system says of
-it: what is prunable, the planner's answer, the server's refusal, the
-config's cross-checks."""
+gradients, with and without masks; what a layer's backward pass keeps and
+what it rebuilds. Then what the rest of the system says of it: what is
+prunable, the planner's answer, the server's refusal, the config's
+cross-checks."""
 
 import dataclasses
 
@@ -18,6 +19,9 @@ from turboprune_tpu.config.schema import ConfigError
 from turboprune_tpu.models import LANGUAGE_MODELS, create_model, granite
 from turboprune_tpu.ops import masking
 from turboprune_tpu.train.steps import make_eval_step, make_train_step
+from turboprune_tpu.utils import tracing
+
+import remat_probe
 
 VOCAB, T, BATCH = 50, 32, 2
 TINY = [
@@ -138,6 +142,132 @@ def test_the_steps_count_target_tokens(seeded):
         np.testing.assert_allclose(
             float(got["loss_sum"]), float(jnp.sum(reference.token_losses(logits, targets))), rtol=1e-5
         )
+
+
+# ------------------------------------------------ what a backward pass keeps
+# By kind of layer, the shapes of granite.SAVED's values at BATCH x T tokens
+# of the tiny model: the mixer's result beside in_proj's output, or q, k, v.
+KEPT = {
+    "mamba": [(2, 32, 32), (2, 32, 148)],
+    "attention": [(2, 32, 32), (8, 32, 8), (4, 32, 8), (4, 32, 8)],
+}
+
+
+def _weighed(model, tokens):
+    return lambda p: jnp.sum(jnp.sin(model.apply({"params": p}, tokens)))
+
+
+@pytest.mark.parametrize("case", ["unit_embedding", "published_multipliers", "op_by_op"])
+def test_the_gradient_is_the_bare_checkpoints(seeded, case, monkeypatch):
+    """A value computed once and kept is the value computed twice: under the
+    model's policy every leaf's gradient is the one a bare ``nn.remat`` gives,
+    bit for bit in float32 on the CPU. With the published
+    ``embedding_multiplier`` of 12 that holds op by op, and compiled for every
+    leaf but the first layer's and the embedding's: XLA fuses ``12 * E[ids]``
+    into the first layer's ``x + 0.22 * y`` and contracts that sum of two
+    products into a multiply-add one way where ``y`` is rebuilt and the other
+    way where it is read; those leaves then differ in their last bits."""
+    model, params, _, tokens, _ = seeded
+    if case == "unit_embedding":
+        cfg = dataclasses.replace(model.cfg, embedding_multiplier=1.0)
+        model = granite.HybridLM(VOCAB, cfg, model.layer_types)
+    grad = jax.grad(_weighed(model, tokens))
+
+    def run():
+        if case == "op_by_op":
+            with jax.disable_jit():
+                return grad(params)
+        return jax.jit(grad)(params)
+
+    kept = run()
+    remat_probe.bare(monkeypatch)
+    rebuilt = run()
+    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(kept), jax.tree.leaves(rebuilt)):
+        name = jax.tree_util.keystr(path)
+        if case == "published_multipliers" and ("layers_0" in name or "embedding" in name):
+            _close(g, w, 1e-5)
+        else:
+            np.testing.assert_array_equal(g, w, err_msg=name)
+
+
+def test_a_backward_pass_is_handed_the_models_names_and_no_mlp_tensor(seeded, monkeypatch):
+    model, params, _, tokens, _ = seeded
+    loss = _weighed(model, tokens)
+    assert model.layer_types == ("mamba", "attention", "mamba")
+    assert remat_probe.kept_shapes(loss, params) == sorted(KEPT["mamba"] * 2 + KEPT["attention"])
+    # The MLP's gate and value [.., 2 * hidden] are rebuilt, as they always were.
+    wide = 2 * model.cfg.shared_intermediate_size
+    assert not [s for s, _ in remat_probe.residuals(loss, params) if s[-1:] == (wide,) and len(s) == 3]
+    with monkeypatch.context() as only_here:
+        only_here.setattr(granite, "SAVED", ("attn_k",))  # each model's own tuple decides
+        assert remat_probe.kept_shapes(loss, params) == [(4, 32, 8)]
+    remat_probe.bare(monkeypatch)
+    assert remat_probe.kept_shapes(loss, params) == []
+
+
+def test_the_gauges_say_what_one_trace_keeps(seeded):
+    """``remat_saved_values`` / ``remat_saved_mib``: what the newest trace of
+    a program that differentiates the layers keeps; the trace of one that
+    does not, and a run of the compiled program, set nothing."""
+    model, params, _, tokens, _ = seeded
+    grad = jax.jit(jax.grad(_weighed(model, tokens)))
+    tracing.gauge("remat_saved_values", -1)
+    jax.jit(_weighed(model, tokens))(params)
+    assert remat_probe.gauges()[0] == -1
+    grad(params)
+    shapes = KEPT["mamba"] * 2 + KEPT["attention"]
+    assert remat_probe.gauges() == [8, sum(4 * int(np.prod(s)) for s in shapes) / 2**20]
+    tracing.gauge("remat_saved_values", -1)
+    grad(params)
+    assert remat_probe.gauges()[0] == -1
+
+
+@pytest.mark.parametrize("kind", ["mamba", "attention"])
+def test_a_tag_no_policy_names_is_inert(seeded, kind, monkeypatch):
+    """Under a bare ``jax.checkpoint`` a mixer (the Mamba one at one group: the
+    program this model has always run) gives the outputs and gradients it
+    gives with no tag in it, and its backward pass is handed the layer's
+    arguments and nothing tagged."""
+    model, params, _, tokens, _ = seeded
+    c, seg = model.cfg, tokens[:, 1]
+    if kind == "mamba":
+        mixer = granite.MambaMixer(
+            c.mamba_n_heads, c.mamba_d_head, c.mamba_d_state, c.mamba_d_conv, c.mamba_chunk_size, c.rms_norm_eps
+        )
+        p, tags = params["layers_0"]["mixer"], 1
+    else:
+        mixer = granite.AttentionMixer(
+            c.num_attention_heads, c.num_key_value_heads, c.attention_head_dim, c.attention_multiplier
+        )
+        p, tags = params["layers_1"]["mixer"], 3
+    u = jax.random.normal(jax.random.PRNGKey(3), (BATCH, T, c.hidden_size))
+
+    def probe():  # fresh functions: a traced one is not traced again
+        layer = lambda p, u: jax.checkpoint(lambda p, u: mixer.apply({"params": p}, u, seg))(p, u)
+        weigh = lambda p, u: jnp.sum(jnp.sin(layer(p, u)))
+        values = jax.jit(layer)(p, u), jax.jit(jax.grad(weigh, argnums=(0, 1)))(p, u)
+        return values, remat_probe.primitives(weigh, p, u)["name"], remat_probe.residuals(weigh, p, u)
+
+    tagged, names, handed = probe()
+    # Arguments, a constant (``seg``) and this test's own cosine: nothing the layer computed.
+    assert handed and not [why for _, why in handed if "turboprune_tpu" in why or "named '" in why]
+    monkeypatch.setattr(granite, "checkpoint_name", lambda x, name: x)
+    plain, no_names, _ = probe()
+    assert (names, no_names) == (tags, 0)
+    for got, want in zip(jax.tree.leaves(tagged), jax.tree.leaves(plain)):
+        np.testing.assert_array_equal(got, want)
+
+
+def test_the_published_cut_keeps_22_values_of_1565_mib():
+    """ISSUE 37's count, from shapes alone: the mixer's result of ten layers,
+    q, k and v of one, ``in_proj``'s output of nine, at 8,192 tokens in bf16."""
+    model = create_model("granite_4_0_h_micro", 12544, num_layers=10, compute_dtype=jnp.bfloat16)
+    tokens = jnp.zeros((1, 2, 8192), jnp.int32)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0), tokens)["params"]
+    jax.eval_shape(jax.grad(_weighed(model, tokens)), params)
+    gauges = tracing.gauges()
+    assert gauges["remat_saved_values"] == 10 + 3 + 9
+    assert gauges["remat_saved_mib"] == (10 * 2048 + (2048 + 512 + 512) + 9 * 8512) * 8192 * 2 / 2**20 == 1565.0
 
 
 def test_what_is_prunable(seeded):

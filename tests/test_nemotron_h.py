@@ -4,8 +4,9 @@ the package) on seeded weights: the whole model and a chip's share of it,
 forward, loss and gradients; the shares of every kind of layer adding up to
 the uncut layer; a routing that overflows the pair buffer; the scan and the
 norm at one group being what they were; stacked kernels through everything
-that prunes; the router never masked. Then what the rest of the system says
-of it: the planner's answer, the config's cross-checks."""
+that prunes; the router never masked; what a layer's backward pass keeps and
+what it rebuilds. Then what the rest of the system says of it: the planner's
+answer, the config's cross-checks."""
 
 import dataclasses
 
@@ -22,6 +23,9 @@ from turboprune_tpu.ops import masking, moe
 from turboprune_tpu.ops.ssd import ssd_chunked
 from turboprune_tpu.pruning import criteria
 from turboprune_tpu.train.steps import make_eval_step, make_train_step
+from turboprune_tpu.utils import tracing
+
+import remat_probe
 
 VOCAB, T, BATCH = 50, 32, 2
 # The tiny preset's entry overrides (tests/test_nemotron_ladder.py runs them).
@@ -389,6 +393,102 @@ def test_granites_mixer_traces_to_the_program_it_was():
     assert "reshape" in str(grouped(jnp.zeros((1, 32, 64))))
     two = granite.MambaMixer(4, 16, 8, 4, 16, 1e-5, n_groups=2)
     assert jax.eval_shape(two.init, jax.random.PRNGKey(0), u, seg)["params"]["in_proj"]["kernel"].shape == (32, 2 * 64 + 4 * 8 + 4)
+
+
+# ------------------------------------------- (g) what a backward pass keeps
+# By kind of layer, the shapes of nemotron_h.SAVED's values at BATCH x T = 64
+# tokens of the tiny model: the router's logits, the experts chosen, the
+# pairs' order, latent_down's and shared_up's outputs; in_proj's output; q, k, v.
+KEPT = {
+    "E": [(64, 16), (64, 4), (256,), (2, 32, 32), (2, 32, 96)],
+    "M": [(2, 32, 164)],
+    "*": [(8, 32, 8), (4, 32, 8), (4, 32, 8)],
+}
+
+
+def _kept_bytes(pattern):  # float32 and int32 alike
+    return sum(4 * int(np.prod(shape)) for kind in pattern for shape in KEPT[kind])
+
+
+def _weighed(pattern, dtype=jnp.float32):
+    model = create_model("nemotron_h_tiny", VOCAB, layer_pattern=pattern, compute_dtype=dtype)
+    tokens = _tokens()
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), tokens)["params"]
+    return params, lambda p: jnp.sum(jnp.sin(model.apply({"params": p}, tokens)))
+
+
+@pytest.mark.parametrize("pattern", ["E", "M", "*", "EM*"])
+def test_the_gradient_is_the_bare_checkpoints_bit_for_bit(pattern, monkeypatch):
+    """A value computed once and kept is the value computed twice: under the
+    model's policy every leaf's gradient is the one a bare ``nn.remat`` gives,
+    bit for bit in float32 on the CPU."""
+    params, loss = _weighed(pattern)
+    kept = jax.jit(jax.grad(loss))(params)
+    remat_probe.bare(monkeypatch)
+    rebuilt = jax.jit(jax.grad(loss))(params)
+    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(kept), jax.tree.leaves(rebuilt)):
+        np.testing.assert_array_equal(g, w, err_msg=jax.tree_util.keystr(path))
+
+
+def test_an_expert_layers_backward_pass_rebuilds_less(monkeypatch):
+    """One ``top_k``, one sort and one float32 product at ``HIGHEST`` fewer
+    than under the bare checkpoint, and ``latent_down``'s and ``shared_up``'s
+    products with them; the two grouped products keep their rebuilt forward
+    (the kernels are interpreted here: one ``pallas_call`` each either way)."""
+    params, loss = _weighed("E")
+    kept = remat_probe.primitives(jax.grad(loss), params)
+    remat_probe.bare(monkeypatch)
+    rebuilt = remat_probe.primitives(jax.grad(loss), params)
+    fewer = {name: rebuilt[name] - kept[name] for name in rebuilt if rebuilt[name] != kept[name]}
+    assert (kept["top_k"], kept["sort"], kept["dot_general_highest"]) == (1, 1, 3)
+    assert (rebuilt["top_k"], rebuilt["sort"], rebuilt["dot_general_highest"]) == (2, 2, 4)
+    assert fewer["dot_general"] == 3 and fewer["name"] == len(KEPT["E"])
+    assert kept["pallas_call"] == rebuilt["pallas_call"] > 0
+
+
+@pytest.mark.parametrize("kind", ["E", "M", "*"])
+def test_a_backward_pass_is_handed_the_models_names_and_nothing_else_tagged(kind, monkeypatch):
+    params, loss = _weighed(kind)
+    assert remat_probe.kept_shapes(loss, params) == sorted(KEPT[kind])
+    with monkeypatch.context() as only_here:
+        only_here.setattr(nemotron_h, "SAVED", nemotron_h.SAVED[:1])  # each model's own tuple decides
+        assert remat_probe.kept_shapes(loss, params) == ([KEPT["E"][0]] if kind == "E" else [])
+    remat_probe.bare(monkeypatch)
+    assert remat_probe.kept_shapes(loss, params) == []
+
+
+@pytest.mark.parametrize("pattern", ["E", "M", "*", "EM*"])
+def test_the_gauges_say_what_one_trace_keeps(pattern):
+    """``remat_saved_values`` / ``remat_saved_mib``: what the newest trace of
+    a program that differentiates the layers keeps; the trace of one that
+    does not, and a run of the compiled program, set nothing."""
+    params, loss = _weighed(pattern)
+    grad = jax.jit(jax.grad(loss))
+    tracing.gauge("remat_saved_values", -1)
+    jax.jit(loss)(params)
+    assert remat_probe.gauges()[0] == -1
+    grad(params)
+    assert remat_probe.gauges() == [sum(len(KEPT[kind]) for kind in pattern), _kept_bytes(pattern) / 2**20]
+    tracing.gauge("remat_saved_values", -1)
+    grad(params)
+    assert remat_probe.gauges()[0] == -1
+
+
+def test_the_published_share_keeps_33_values_of_413_mib():
+    """ISSUE 37's count, from shapes alone: five ``E`` layers of five values,
+    five ``M`` of one, three of the ``*`` layer, at 8,192 tokens in bf16."""
+    model = create_model(
+        "nemotron_3_super_120b_a12b", 16384, layer_pattern="EMEMEMEMEM*", share=(8, 32, 0),
+        compute_dtype=jnp.bfloat16,
+    )  # fmt: skip
+    tokens = jnp.zeros((1, 2, 8192), jnp.int32)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0), tokens)["params"]
+    jax.eval_shape(jax.grad(lambda p: jnp.sum(model.apply({"params": p}, tokens))), params)
+    e = 8192 * (512 * 4 + 22 * 4 + 22 * 4 + 1024 * 2 + 672 * 2)
+    m, a = 8192 * 2320 * 2, 8192 * (512 + 128 + 128) * 2
+    gauges = tracing.gauges()
+    assert gauges["remat_saved_values"] == 5 * 5 + 5 + 3
+    assert gauges["remat_saved_mib"] == (5 * e + 5 * m + a) / 2**20 and gauges["remat_saved_mib"] == 412.625
 
 
 # ------------------------------------ (e), (f) stacked kernels and the router

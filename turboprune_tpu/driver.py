@@ -144,8 +144,7 @@ def _say_time(title: str, roots: list, gauges: Optional[dict] = None) -> dict:
     where there are any; returns the breakdown."""
     b = tracing.breakdown(roots)
     if is_primary():
-        said = "".join(f"; {k} {v:g}" for k, v in (gauges or {}).items())
-        print(tracing.line(title, b) + said, flush=True)
+        print(tracing.line(title, b, gauges), flush=True)
     return b
 
 

@@ -15,8 +15,15 @@ start: not the convolution, not the recurrence's state, not attention.
 
 Every layer is a ``jax.checkpoint`` (``nn.remat``): a backward pass keeps the
 layers' inputs and rebuilds one layer's inside, the chunked scan's decay
-matrices among it. That is part of the model, not an option: at 8,192 tokens
-one layer's internals are about 2 GB.
+matrices and the MLP's gate and value (256 MB a layer) among it. That is part
+of the model, not an option: at 8,192 tokens one layer's internals are about
+2 GB. What a layer keeps beside its input is ``SAVED``, a few narrow values
+tagged where they are made and named here (ops/remat.py), by bytes at 8,192 tokens in bf16:
+the mixer's result (32 MB a layer, so that neither ``out_proj`` nor ``o_proj``
+nor what feeds them runs again for the MLP's sake), attention's q, k and v
+(50 MB, once) and ``in_proj``'s output of the Mamba mixer (139.5 MB a layer).
+The scan's and attention's own outputs are rebuilt: the kernels run as often
+as under a bare checkpoint.
 
 The 2-D projections are ``kernel`` leaves and so prunable
 (ops/masking.py::is_prunable_path): ``in_proj`` and ``out_proj`` of the Mamba
@@ -32,7 +39,9 @@ models/nemotron_h.py imports ``RMSNorm``, ``MambaMixer`` (there with
 ``n_groups`` groups of heads and its own ``out_std``), ``AttentionMixer`` and
 ``_dense`` from here: a change to one of them is a change to both models, and
 at one group and the default ``out_std`` ``MambaMixer`` is the program this
-model has always run (tests/test_granite.py).
+model has always run (tests/test_granite.py). The mixers' tags are shared
+and decide nothing; what is kept is each model's own tuple at its own
+``nn.remat`` line.
 """
 
 from __future__ import annotations
@@ -44,7 +53,9 @@ from typing import Any
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
+from ..ops import remat
 from ..ops.flash import flash_attention_causal
 from ..ops.ssd import ssd_chunked
 
@@ -52,6 +63,8 @@ from ..ops.ssd import ssd_chunked
 # every ten layers.
 PERIOD = ("mamba",) * 5 + ("attention",) + ("mamba",) * 4
 FLASH_BLOCK = 512
+# What the backward pass of a layer keeps beside the layer's input.
+SAVED = ("mixer_out", "attn_q", "attn_k", "attn_v", "mamba_in_proj")
 
 
 def _dense(features: int, dtype, name: str, std: float = 0.02) -> nn.Dense:
@@ -119,6 +132,7 @@ class MambaMixer(nn.Module):
         conv_dim = inner + 2 * bc_dim
         with jax.named_scope("mamba/in_proj"):
             zxbcdt = _dense(inner + conv_dim + self.heads, self.dtype, "in_proj")(u)
+            zxbcdt = checkpoint_name(zxbcdt, "mamba_in_proj")
             z, xbc, dt = jnp.split(zxbcdt, [inner, inner + conv_dim], axis=-1)
 
         bound = 1.0 / math.sqrt(self.conv_width)
@@ -180,6 +194,7 @@ class AttentionMixer(nn.Module):
                 bsz * h, t, self.head_dim
             )
             q, k, v = rows(q, self.heads), rows(k, self.kv_heads), rows(v, self.kv_heads)
+            q, k, v = (checkpoint_name(x, f"attn_{n}") for x, n in ((q, "q"), (k, "k"), (v, "v")))
         with jax.named_scope("attn/flash"):
             block = math.gcd(t, FLASH_BLOCK)
             out = flash_attention_causal(q, k, v, seg, self.scale, block, block)
@@ -240,7 +255,7 @@ class HybridBlock(nn.Module):
                 c.mamba_n_heads, c.mamba_d_head, c.mamba_d_state, c.mamba_d_conv,
                 c.mamba_chunk_size, c.rms_norm_eps, self.dtype, name="mixer",
             )(u, seg)  # fmt: skip
-        x = x + jnp.asarray(c.residual_multiplier, self.dtype) * y
+        x = x + jnp.asarray(c.residual_multiplier, self.dtype) * checkpoint_name(y, "mixer_out")
         u = RMSNorm(c.rms_norm_eps, self.dtype, name="norm2")(x)
         y = SwiGLU(c.shared_intermediate_size, self.dtype, name="mlp")(u)
         return x + jnp.asarray(c.residual_multiplier, self.dtype) * y
@@ -262,8 +277,9 @@ class HybridLM(nn.Module):
         )
         # One leaf for the two uses: the model is tied.
         x = (c.embedding_multiplier * table[ids]).astype(self.dtype)
+        block = nn.remat(HybridBlock, policy=remat.keeping(SAVED))
         for i, kind in enumerate(self.layer_types):
-            x = nn.remat(HybridBlock)(kind, c, self.dtype, name=f"layers_{i}")(x, seg)
+            x = block(kind, c, self.dtype, name=f"layers_{i}")(x, seg)
         x = RMSNorm(c.rms_norm_eps, self.dtype, name="final_norm")(x)
         with jax.named_scope("lm_head"):
             logits = jnp.einsum(

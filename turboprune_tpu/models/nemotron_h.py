@@ -14,7 +14,15 @@ The input and the packing are models/granite.py's (``tokens [B, 2, T]``, ids
 and document ids; nothing crosses a document's start), and so are the blocks
 this file imports from it: ``RMSNorm``, ``MambaMixer`` (here with ``n_groups``
 groups), ``AttentionMixer`` and ``_dense``. Every layer is a
-``jax.checkpoint``.
+``jax.checkpoint``: a backward pass keeps the layer's input, rebuilds the
+layer's inside (the two grouped products of the experts and the kernels of
+the scan and of attention among it) and keeps ``SAVED``, values tagged where
+they are made (ops/remat.py) that are narrow and dear to rebuild. By bytes at
+8,192 tokens: an ``E`` layer's router logits (float32, 16.8 MB, for a product
+at ``Precision.HIGHEST``), its chosen experts and their sorted order (0.7 MB
+each, for a ``top_k`` and a sort), ``latent_down``'s and ``shared_up``'s
+outputs (16.8 and 11 MB); an ``M`` layer's ``in_proj`` output (38 MB); a
+``*`` layer's q, k and v (12.6 MB): 0.43 GB over one period.
 
 **A chip's share.** The model is built as one chip of a deployment holds it
 (``Share``): ``tensor_parallel`` chips divide every mixer's heads (and with
@@ -53,9 +61,17 @@ from typing import Any
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
-from ..ops import moe
+from ..ops import moe, remat
 from .granite import AttentionMixer, MambaMixer, RMSNorm, _dense
+
+
+# What the backward pass of a layer keeps beside the layer's input.
+SAVED = (
+    "router_logits", "router_top", "moe_order", "moe_latent_down", "moe_shared_up",
+    "mamba_in_proj", "attn_q", "attn_k", "attn_v",
+)  # fmt: skip
 
 
 @dataclasses.dataclass(frozen=True)
@@ -129,7 +145,7 @@ class Router(nn.Module):
         weight = self.param("weight", nn.initializers.normal(0.02), (h32.shape[-1], self.experts))
         bias = self.param("bias", nn.initializers.zeros, (self.experts,))
         logits = jnp.einsum("nd,de->ne", h32, weight, precision=jax.lax.Precision.HIGHEST)
-        return moe.route(logits, bias, self.top_k, self.scaling)
+        return moe.route(checkpoint_name(logits, "router_logits"), bias, self.top_k, self.scaling)
 
 
 class Experts(nn.Module):
@@ -177,7 +193,8 @@ class LatentMoE(nn.Module):
             )(h32.reshape(bsz * t, dim))
         self.sow("intermediates", "top", top)
         with jax.named_scope("moe/latent_down"):
-            z = _dense(c.moe_latent_size, self.dtype, "latent_down")(h).reshape(bsz * t, -1)
+            z = checkpoint_name(_dense(c.moe_latent_size, self.dtype, "latent_down")(h), "moe_latent_down")
+            z = z.reshape(bsz * t, -1)
         mixed, counters = Experts(
             c, self.experts_here, self.expert_offset, self.dtype, name="experts"
         )(z, top, weights)
@@ -187,7 +204,7 @@ class LatentMoE(nn.Module):
             mixed = mixed.astype(self.dtype).reshape(bsz, t, -1)
             routed = _dense(dim, self.dtype, "latent_up", self.out_std)(mixed)
         with jax.named_scope("moe/shared"):
-            up = _dense(self.shared_columns, self.dtype, "shared_up")(h)
+            up = checkpoint_name(_dense(self.shared_columns, self.dtype, "shared_up")(h), "moe_shared_up")
             shared = _dense(dim, self.dtype, "shared_down", self.out_std)(jnp.square(nn.relu(up)))
         return routed + shared
 
@@ -264,9 +281,9 @@ class NemotronH(nn.Module):
             "embedding", nn.initializers.normal(1.0), (self.vocab_size, c.hidden_size)
         )
         x = table[ids].astype(self.dtype)
+        block = nn.remat(NemotronBlock, policy=remat.keeping(SAVED))
         for i, kind in enumerate(self.pattern):
-            block = nn.remat(NemotronBlock)(kind, c, self.share, self.dtype, name=f"layers_{i}")
-            x = block(x, seg)
+            x = block(kind, c, self.share, self.dtype, name=f"layers_{i}")(x, seg)
         x = RMSNorm(c.layer_norm_epsilon, self.dtype, name="final_norm")(x)
         with jax.named_scope("lm_head"):
             return Head(self.vocab_size, self.dtype, name="lm_head")(x)

@@ -307,12 +307,13 @@ def _short(name: str) -> str:
     return name.rsplit("/", 1)[-1]
 
 
-def line(title: str, b: dict) -> str:
+def line(title: str, b: dict, gauges: Optional[dict] = None) -> str:
     """``[time] level 3: 1.80 s = prune 0.11 + ... + save 0.08 (wait 0.00,
     barrier 0.00, fetch 0.07) + other 0.03; wrote level 2 behind, 0.27 s;
     traced 0.0 s, lowered 0.0 s, compiled 0 modules in 0.0 s (0 read from the
-    cache in 0.0 s; 0 missed)``. A term is named only where its span ran:
-    ``load`` in a resumed level; of the missed modules, the three longest."""
+    cache in 0.0 s; 0 missed)``, then ``; name value`` for each of ``gauges``.
+    A term is named only where its span ran: ``load`` in a resumed level; of
+    the missed modules, the three longest."""
     parts = []
     for name in (n for n in TERMS if n in b["terms"]):
         part = f"{_short(name)} {b['terms'][name]:.2f}"
@@ -330,6 +331,7 @@ def line(title: str, b: dict) -> str:
         + (": " if b["missed"] else "")
         + ", ".join(f"{name} {s:.1f}" for name, s in b["missed"][:3])
         + ")"
+        + "".join(f"; {name} {value:g}" for name, value in (gauges or {}).items())
     )
 
 
