@@ -337,7 +337,7 @@ def test_the_routed_experts_are_a_plain_loop_over_the_experts_held(capacity, til
 
     def ours(z, logits, up, down):
         top, w = moe.route(logits, bias, k, 5.0)
-        out, counted = moe.routed_experts(z, top, w, up, down, offset, capacity, tile)
+        out, counted = moe.routed_experts(z, top, w, (up, down), offset, capacity, tile)
         return jnp.sum(jnp.sin(out)), (out, counted, top)
 
     def theirs(z, logits, up, down):

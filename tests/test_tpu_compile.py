@@ -29,7 +29,11 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 import chip_smoke
-from turboprune_tpu.ops.flash import flash_attention, flash_attention_causal
+from turboprune_tpu.ops.flash import (
+    flash_attention,
+    flash_attention_blockdiff,
+    flash_attention_causal,
+)
 from turboprune_tpu.ops import ssd
 from turboprune_tpu.ops.ssd import ssd_chunked
 
@@ -133,6 +137,31 @@ def test_causal_flash_kernel_compiles_for_v5e(one_chip, no_persistent_cache, bac
     assert "tpu_custom_call" in text and all(name in text for name in names)
 
 
+@pytest.mark.parametrize("backward", [False, True], ids=["forward", "grad"])
+def test_blockdiff_flash_kernel_compiles_for_v5e(one_chip, no_persistent_cache, backward):
+    """The block-diffusion cell's shapes: 4 query heads on one key/value head,
+    the clean and the noised copy of 8,192 tokens, blocks of 512."""
+    q, kv, rows = _placed(
+        (
+            jax.ShapeDtypeStruct((4, 16384, 128), jnp.bfloat16),
+            jax.ShapeDtypeStruct((1, 16384, 128), jnp.bfloat16),
+            jax.ShapeDtypeStruct((1, 8192), jnp.int32),
+        ),
+        one_chip,
+    )
+
+    def forward(q, k, v, doc, blk):
+        return flash_attention_blockdiff(q, k, v, doc, blk, 128**-0.5, 512, 512, interpret=False)
+
+    def loss(q, k, v, doc, blk):
+        return forward(q, k, v, doc, blk).astype(jnp.float32).sum()
+
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if backward else forward
+    text = jax.jit(fn).lower(q, kv, kv, rows, rows).compile().as_text()
+    names = ("flash_blockdiff_fwd", "flash_blockdiff_dq", "flash_blockdiff_dkv") if backward else ("flash_blockdiff_fwd",)
+    assert "tpu_custom_call" in text and all(name in text for name in names)
+
+
 # One Mamba-2 layer's scan at published widths over 8,192 tokens: granite's
 # 64 heads at chunks of 256, and one chip's group of Nemotron-3-Super, 16
 # heads at chunks of 128.
@@ -220,7 +249,7 @@ def test_routed_experts_compile_for_v5e_as_grouped_kernels(one_chip, no_persiste
 
     def loss(z, logits, up, down):
         top, weights = moe.route(logits, jnp.zeros(experts), k, 5.0)
-        out, counters = moe.routed_experts(z, top, weights, up, down, 0, capacity, tile)
+        out, counters = moe.routed_experts(z, top, weights, (up, down), 0, capacity, tile)
         return out.sum(), counters
 
     # On the CPU backend the kernels would be interpreted: compile the chip's.
@@ -230,6 +259,37 @@ def test_routed_experts_compile_for_v5e_as_grouped_kernels(one_chip, no_persiste
     text = compiled.as_text()
     assert (capacity, tile) == (10496, 128)
     assert text.count("tpu_custom_call") >= 6  # two forward, four backward, and the rounds' own
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2**30
+
+
+def test_gated_routed_experts_compile_for_v5e_as_grouped_kernels(one_chip, no_persistent_cache):
+    """One block-diffusion layer's routed part at published widths over
+    16,384 rows, forward and backward: the softmax router's top-8 of 128, the
+    16 experts held with three kernels each, the 26,624-row pair buffer."""
+    from turboprune_tpu.ops import moe
+
+    rows, k, experts, held, hidden, width = 16384, 8, 128, 16, 2048, 768
+    capacity, tile = moe.pair_capacity(rows, k, experts, held), moe.pair_tile(rows, k, experts)
+    z, logits, up, down = _placed(
+        (
+            jax.ShapeDtypeStruct((rows, hidden), jnp.bfloat16),
+            jax.ShapeDtypeStruct((rows, experts), jnp.float32),
+            jax.ShapeDtypeStruct((held, hidden, width), jnp.bfloat16),
+            jax.ShapeDtypeStruct((held, width, hidden), jnp.bfloat16),
+        ),
+        one_chip,
+    )
+
+    def loss(z, logits, gate, up, down):
+        top, weights = moe.route_softmax(logits, k)
+        out, counters = moe.routed_experts(z, top, weights, (gate, up, down), 0, capacity, tile)
+        return out.sum(), counters
+
+    with mock.patch.object(moe, "_use_interpret", lambda: False):
+        lowered = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4), has_aux=True)).lower(z, logits, up, up, down)
+    compiled = lowered.compile()
+    assert (capacity, tile) == (26624, 128)
+    assert compiled.as_text().count("tpu_custom_call") >= 9  # three forward, six backward, and the rounds' own
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2**30
 
 

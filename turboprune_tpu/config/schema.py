@@ -163,6 +163,11 @@ class DatasetConfig:
     layout_seed: int = 0
     # How the seed draws ids (TOKEN_SKEWS): p(i) about 1 / i, or all alike.
     token_skew: str = "log_uniform"
+    # Block-diffusion batches (data/tokens.py): documents cut into blocks of
+    # this many tokens, a noise level a block, the vocabulary's last id the
+    # mask's. 0 = next-token batches. Which of the two a model trains on is
+    # the model's (models.BLOCK_DIFFUSION_MODELS), checked below.
+    block_length: int = 0
 
     @property
     def is_tokens(self) -> bool:
@@ -173,7 +178,7 @@ class DatasetConfig:
         token model's parameters do not depend on the length, so its dummy
         is short and its init program small."""
         if self.is_tokens:
-            return (1, 2, min(self.seq_len, 512)), "int32"
+            return (1, 5 if self.block_length else 2, min(self.seq_len, 512)), "int32"
         return (1, self.image_size, self.image_size, 3), "float32"
 
     def validate(self) -> None:
@@ -216,6 +221,11 @@ class DatasetConfig:
                     "num_classes (the vocabulary held) >= 2"
                 )
             _check_choice("dataset_params.token_skew", self.token_skew, TOKEN_SKEWS)
+            if self.block_length < 0 or (self.block_length and self.num_classes < 3):
+                raise ConfigError(
+                    "dataset_params.block_length must not be negative, and block-diffusion "
+                    "batches need a vocabulary of 3 (two ids and the mask's) or more"
+                )
             if not (1 <= self.doc_len_min <= self.seq_len) or self.doc_len_sigma < 0:
                 raise ConfigError(
                     "doc_len_min must lie in [1, seq_len] and doc_len_sigma "
@@ -258,7 +268,7 @@ class ModelConfig:
     # published. Image models have one depth and reject the knob.
     num_hidden_layers: int = 0
     # A model built as one chip's share of a deployment
-    # (models/nemotron_h.py): the stretch of the published layer pattern that
+    # (models/nemotron_h.py, models/sdar.py): the stretch of the published layer pattern that
     # is run ("" = all of it), over how many chips each layer's heads and the
     # shared expert's columns (tensor_parallel) and its routed experts
     # (expert_parallel) are divided, and which of the latter this chip is.
@@ -644,6 +654,17 @@ class MainConfig:
                 f"dataset_name={self.dataset_params.dataset_name!r} do not go "
                 "together: a language model trains on a token dataset "
                 f"({TOKEN_DATASETS}), an image model on images"
+            )
+        from ..models import BLOCK_DIFFUSION_MODELS
+
+        if (self.model_params.model_name in BLOCK_DIFFUSION_MODELS) != bool(
+            self.dataset_params.block_length
+        ):
+            raise ConfigError(
+                f"model_name={self.model_params.model_name!r} and dataset_params.block_length="
+                f"{self.dataset_params.block_length} do not go together: a model trained by "
+                f"diffusion over blocks ({BLOCK_DIFFUSION_MODELS}) reads noised batches "
+                "(block_length > 0), every other model next-token batches (0)"
             )
         if self.model_params.num_hidden_layers and not is_lm:
             raise ConfigError(

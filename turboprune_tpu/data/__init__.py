@@ -65,6 +65,7 @@ def create_loaders(cfg) -> Any:
             layout_seed=dp.layout_seed,
             seed=seed,
             token_skew=dp.token_skew,
+            block_length=dp.block_length,
         )
     if dp.dataloader_type == "synthetic":
         return SyntheticLoaders(

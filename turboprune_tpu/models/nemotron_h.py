@@ -167,7 +167,7 @@ class Experts(nn.Module):
         kernel_down = self.param("kernel_down", init, (up[0], up[2], up[1]))
         routing = (z.shape[0], c.num_experts_per_tok, c.n_routed_experts)
         return moe.routed_experts(
-            z, top, weights, kernel_up.astype(self.dtype), kernel_down.astype(self.dtype),
+            z, top, weights, (kernel_up.astype(self.dtype), kernel_down.astype(self.dtype)),
             self.expert_offset, moe.pair_capacity(*routing, self.experts_here),
             moe.pair_tile(*routing),
         )  # fmt: skip

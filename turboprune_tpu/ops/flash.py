@@ -1,10 +1,15 @@
 """First-party Pallas TPU flash attention (forward + backward kernels).
 
-Two entry points over the same recurrence. ``flash_attention``: non-causal
+Three entry points over the same recurrence. ``flash_attention``: non-causal
 multi-head attention with one key-validity mask shared by the batch (the ViT
 path; described first). ``flash_attention_causal``: causal attention inside
-packed documents with grouped key/value heads (the language-model path; its
-kernels are the second half of this file and say what differs).
+packed documents with grouped key/value heads (the next-token language
+models' path; its kernels are the second part of this file and say what
+differs). ``flash_attention_blockdiff``: the block-diffusion mask over a clean
+and a noised copy of every packed sequence (models/sdar.py; the third part).
+The two packed families share the three bodies of the recurrence
+(``_fwd_step``, ``_dq_step``, ``_dkv_step``) and differ in which (query, key)
+pairs a body is told to keep and which blocks of scores it is run for.
 
 Attention is computed blockwise so the S x S score matrix never materializes in HBM: for each
 query block the kernel streams key/value blocks through VMEM, carrying the
@@ -327,6 +332,37 @@ def _keep(qseg_ref, kseg_ref, qi, ki, block_q, block_k):
     return (qseg_ref[0] == kseg_ref[0]) & (qpos >= kpos)
 
 
+def _fwd_step(keep, q_ref, k_ref, v_ref, acc, m, l, scale):
+    """One block of scores of the forward recurrence, ``keep`` [Bq, Bk] of it."""
+    s = jnp.where(keep, _dot_t1(q_ref[0], k_ref[0]) * scale, NEG_BIG)
+    m_old = m[:]
+    m_new = jnp.maximum(m_old, s.max(axis=1, keepdims=True))
+    p = jnp.where(keep, jnp.exp(s - m_new), 0.0)
+    corr = jnp.exp(m_old - m_new)
+    l[:] = l[:] * corr + p.sum(axis=1, keepdims=True)
+    acc[:] = acc[:] * corr + _dot(p.astype(v_ref.dtype), v_ref[0])
+    m[:] = m_new
+
+
+def _dq_step(keep, q_ref, k_ref, v_ref, do_ref, lse_ref, drow_ref, dq_acc, scale):
+    k = k_ref[0]
+    s = _dot_t1(q_ref[0], k) * scale
+    p = jnp.where(keep, jnp.exp(s - lse_ref[0]), 0.0)
+    dp = _dot_t1(do_ref[0], v_ref[0])
+    ds = p * (dp - drow_ref[0]) * scale
+    dq_acc[:] = dq_acc[:] + _dot(ds.astype(k.dtype), k)
+
+
+def _dkv_step(keep, q_ref, k_ref, v_ref, do_ref, lse_ref, drow_ref, dk_acc, dv_acc, scale):
+    q, do = q_ref[0], do_ref[0]
+    s = _dot_t1(q, k_ref[0]) * scale
+    p = jnp.where(keep, jnp.exp(s - lse_ref[0]), 0.0)
+    dv_acc[:] = dv_acc[:] + _dot_t0(p.astype(do.dtype), do)
+    dp = _dot_t1(do, v_ref[0])
+    ds = p * (dp - drow_ref[0]) * scale
+    dk_acc[:] = dk_acc[:] + _dot_t0(ds.astype(q.dtype), q)
+
+
 def _causal_fwd_kernel(qmin, qmax, kmin, kmax, q_ref, k_ref, v_ref, qseg_ref,
                        kseg_ref, o_ref, lse_ref, acc, m, l, *, scale, heads,
                        block_q, block_k):
@@ -343,14 +379,7 @@ def _causal_fwd_kernel(qmin, qmax, kmin, kmax, q_ref, k_ref, v_ref, qseg_ref,
                    block_q, block_k))
     def _():
         keep = _keep(qseg_ref, kseg_ref, qi, ki, block_q, block_k)
-        s = jnp.where(keep, _dot_t1(q_ref[0], k_ref[0]) * scale, NEG_BIG)
-        m_old = m[:]
-        m_new = jnp.maximum(m_old, s.max(axis=1, keepdims=True))
-        p = jnp.where(keep, jnp.exp(s - m_new), 0.0)
-        corr = jnp.exp(m_old - m_new)
-        l[:] = l[:] * corr + p.sum(axis=1, keepdims=True)
-        acc[:] = acc[:] * corr + _dot(p.astype(v_ref.dtype), v_ref[0])
-        m[:] = m_new
+        _fwd_step(keep, q_ref, k_ref, v_ref, acc, m, l, scale)
 
     @pl.when(ki == nk - 1)
     def _():
@@ -372,12 +401,7 @@ def _causal_dq_kernel(qmin, qmax, kmin, kmax, q_ref, k_ref, v_ref, qseg_ref,
                    block_q, block_k))
     def _():
         keep = _keep(qseg_ref, kseg_ref, qi, ki, block_q, block_k)
-        k = k_ref[0]
-        s = _dot_t1(q_ref[0], k) * scale
-        p = jnp.where(keep, jnp.exp(s - lse_ref[0]), 0.0)
-        dp = _dot_t1(do_ref[0], v_ref[0])
-        ds = p * (dp - drow_ref[0]) * scale
-        dq_acc[:] = dq_acc[:] + _dot(ds.astype(k.dtype), k)
+        _dq_step(keep, q_ref, k_ref, v_ref, do_ref, lse_ref, drow_ref, dq_acc, scale)
 
     @pl.when(ki == nk - 1)
     def _():
@@ -403,13 +427,7 @@ def _causal_dkv_kernel(qmin, qmax, kmin, kmax, q_ref, k_ref, v_ref, qseg_ref,
                    block_q, block_k))
     def _():
         keep = _keep(qseg_ref, kseg_ref, qi, ki, block_q, block_k)
-        q, do = q_ref[0], do_ref[0]
-        s = _dot_t1(q, k_ref[0]) * scale
-        p = jnp.where(keep, jnp.exp(s - lse_ref[0]), 0.0)
-        dv_acc[:] = dv_acc[:] + _dot_t0(p.astype(do.dtype), do)
-        dp = _dot_t1(do, v_ref[0])
-        ds = p * (dp - drow_ref[0]) * scale
-        dk_acc[:] = dk_acc[:] + _dot_t0(ds.astype(q.dtype), q)
+        _dkv_step(keep, q_ref, k_ref, v_ref, do_ref, lse_ref, drow_ref, dk_acc, dv_acc, scale)
 
     @pl.when(j == last)
     def _():
@@ -578,3 +596,313 @@ def _fac_bwd(scale, block_q, block_k, interpret, residuals, g):
 
 
 flash_attention_causal.defvjp(_fac_fwd, _fac_bwd)
+
+
+# ------------------------------------------- block diffusion, packed, grouped
+# Block-diffusion training (Arriola et al., arXiv:2503.09573) runs every
+# packed sequence of T tokens twice in one pass, as 2T rows: rows < T the
+# clean copy, rows >= T the noised copy of the same tokens, which share their
+# document ``doc`` and the ordinal ``blk`` of their block inside it (blocks of
+# ``block_length`` tokens counted from the document's first; its last may be
+# short). keep(q, k) = doc(q) == doc(k) and
+#
+#     clean q, clean k:    blk(k) <= blk(q)      block-causal
+#     noised q, clean k:   blk(k) <  blk(q)      the strictly earlier blocks
+#     noised q, noised k:  blk(k) == blk(q)      its own block
+#     clean q, noised k:   never
+#
+# Every row keeps itself, so no row's sum is empty. Documents lie one after
+# the other and a block is a run of tokens, so what a query keeps of the clean
+# keys is one interval of key rows, and of the noised keys another:
+# ``_blockdiff_bounds`` gives each query row the two (lo, hi), the kernels get
+# the pair that belongs to the key block's half through the index map, and
+# ``_interval_keep`` is two comparisons. A block of scores runs where the
+# smallest lo and the largest hi of the query block's rows span the key block
+# (scalar prefetch, as the causal family): in the clean-onto-noised quadrant
+# nothing runs, in the noised-onto-noised one the diagonal and, where a block
+# of tokens lies across a kernel block's edge, its neighbour.
+#
+# The three bodies are the causal family's.
+
+
+def _blockdiff_bounds(doc, blk):
+    """(lo, hi) [B, 2, 2T, 1] int32: the key rows (of the 2T) that query row
+    ``[b, :, i]`` keeps, ``[:, 0]`` among the clean keys and ``[:, 1]`` among
+    the noised ones; an empty interval is (2T, -1)."""
+    doc, blk = doc.astype(jnp.int32), blk.astype(jnp.int32)
+    t = doc.shape[1]
+    at = jnp.arange(t, dtype=jnp.int32)[None]
+    before = lambda x: jnp.pad(x, ((0, 0), (1, 0)), constant_values=-1)[:, :t]
+    new_doc = doc != before(doc)
+    new_blk = new_doc | (blk != before(blk))
+    doc_start = jax.lax.cummax(jnp.where(new_doc, at, 0), axis=1)
+    blk_start = jax.lax.cummax(jnp.where(new_blk, at, 0), axis=1)
+    # A block ends where the next begins, or the sequence does.
+    last = jnp.pad(new_blk[:, 1:], ((0, 0), (0, 1)), constant_values=True)
+    blk_end = jax.lax.cummin(jnp.where(last, at, t), axis=1, reverse=True)
+    none_lo, none_hi = jnp.full_like(at + doc, 2 * t), jnp.full_like(at + doc, -1)
+    first = blk_start == doc_start  # a noised row of a document's first block: no clean key
+    lo = jnp.stack(
+        [
+            jnp.concatenate([doc_start, jnp.where(first, none_lo, doc_start)], axis=1),
+            jnp.concatenate([none_lo, t + blk_start], axis=1),
+        ],
+        axis=1,
+    )
+    hi = jnp.stack(
+        [
+            jnp.concatenate([blk_end, jnp.where(first, none_hi, blk_start - 1)], axis=1),
+            jnp.concatenate([none_hi, t + blk_end], axis=1),
+        ],
+        axis=1,
+    )
+    return lo[..., None], hi[..., None]
+
+
+def _interval_runs(lo_blk, hi_blk, batch, qi, ki, nq, nk):
+    """Whether key block ``ki`` lies inside what query block ``qi`` spans of
+    its half. ``lo_blk``, ``hi_blk``: [B * 2 * nq] key blocks."""
+    i = (batch * 2 + ki // (nk // 2)) * nq + qi
+    return (lo_blk[i] <= ki) & (ki <= hi_blk[i])
+
+
+def _interval_keep(lo_ref, hi_ref, ki, block_q, block_k):
+    """[Bq, Bk]: the key row lies in the query row's interval."""
+    kpos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
+    return (kpos >= lo_ref[0, 0]) & (kpos <= hi_ref[0, 0])
+
+
+def _blockdiff_fwd_kernel(lo_blk, hi_blk, q_ref, k_ref, v_ref, lo_ref, hi_ref,
+                          o_ref, lse_ref, acc, m, l, *, scale, heads, block_q,
+                          block_k):
+    bh, qi, ki = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    nq, nk = pl.num_programs(1), pl.num_programs(2)
+
+    @pl.when(ki == 0)
+    def _():
+        acc[:] = jnp.zeros_like(acc)
+        m[:] = jnp.full_like(m, -jnp.inf)
+        l[:] = jnp.zeros_like(l)
+
+    @pl.when(_interval_runs(lo_blk, hi_blk, bh // heads, qi, ki, nq, nk))
+    def _():
+        keep = _interval_keep(lo_ref, hi_ref, ki, block_q, block_k)
+        _fwd_step(keep, q_ref, k_ref, v_ref, acc, m, l, scale)
+
+    @pl.when(ki == nk - 1)
+    def _():
+        o_ref[0] = (acc[:] / l[:]).astype(o_ref.dtype)
+        lse_ref[0] = m[:] + jnp.log(l[:])
+
+
+def _blockdiff_dq_kernel(lo_blk, hi_blk, q_ref, k_ref, v_ref, lo_ref, hi_ref,
+                         do_ref, lse_ref, drow_ref, dq_ref, dq_acc, *, scale,
+                         heads, block_q, block_k):
+    bh, qi, ki = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    nq, nk = pl.num_programs(1), pl.num_programs(2)
+
+    @pl.when(ki == 0)
+    def _():
+        dq_acc[:] = jnp.zeros_like(dq_acc)
+
+    @pl.when(_interval_runs(lo_blk, hi_blk, bh // heads, qi, ki, nq, nk))
+    def _():
+        keep = _interval_keep(lo_ref, hi_ref, ki, block_q, block_k)
+        _dq_step(keep, q_ref, k_ref, v_ref, do_ref, lse_ref, drow_ref, dq_acc, scale)
+
+    @pl.when(ki == nk - 1)
+    def _():
+        dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
+
+
+def _blockdiff_dkv_kernel(lo_blk, hi_blk, q_ref, k_ref, v_ref, lo_ref, hi_ref,
+                          do_ref, lse_ref, drow_ref, dk_ref, dv_ref, dk_acc,
+                          dv_acc, *, scale, kv_heads, nq, block_q, block_k):
+    # The innermost axis as in the causal family: the group's query heads,
+    # and each head's query blocks.
+    bh, ki, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    nk, last = pl.num_programs(1), pl.num_programs(2) - 1
+
+    @pl.when(j == 0)
+    def _():
+        dk_acc[:] = jnp.zeros_like(dk_acc)
+        dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    @pl.when(_interval_runs(lo_blk, hi_blk, bh // kv_heads, j % nq, ki, nq, nk))
+    def _():
+        keep = _interval_keep(lo_ref, hi_ref, ki, block_q, block_k)
+        _dkv_step(keep, q_ref, k_ref, v_ref, do_ref, lse_ref, drow_ref, dk_acc, dv_acc, scale)
+
+    @pl.when(j == last)
+    def _():
+        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+
+
+def _blockdiff_setup(q, k, doc, blk, block_q, block_k):
+    """Checks the shapes; returns (heads, kv_heads, group, nq, nk), the rows'
+    bounds and the query blocks' spans for the scalar prefetch."""
+    (bh, rows, _), (bsz, t) = q.shape, doc.shape
+    if rows != 2 * t or blk.shape != doc.shape or bh % bsz or k.shape[0] % bsz:
+        raise ValueError(
+            f"flash_attention_blockdiff: q {q.shape} and k {k.shape} are not the clean and "
+            f"the noised copy of doc {doc.shape}, blk {blk.shape}"
+        )
+    heads, kv_heads = bh // bsz, k.shape[0] // bsz
+    if heads % kv_heads:
+        raise ValueError(
+            f"flash_attention_blockdiff: {heads} query heads are not a multiple "
+            f"of {kv_heads} key/value heads"
+        )
+    if t % block_q or t % block_k:
+        raise ValueError(
+            f"flash_attention_blockdiff: seq {t} must be a multiple of "
+            f"block_q={block_q} and block_k={block_k}"
+        )
+    lo, hi = _blockdiff_bounds(doc, blk)
+    nq, nk = rows // block_q, rows // block_k
+    lo_blk = lo.reshape(bsz, 2, nq, block_q).min(axis=3) // block_k
+    hi_blk = hi.reshape(bsz, 2, nq, block_q).max(axis=3) // block_k
+    dims = (heads, kv_heads, heads // kv_heads, nq, nk)
+    return dims, lo, hi, (lo_blk.reshape(-1), hi_blk.reshape(-1))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def flash_attention_blockdiff(
+    q: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    doc: jax.Array,
+    blk: jax.Array,
+    scale: float,
+    block_q: int = 512,
+    block_k: int = 512,
+    interpret: Optional[bool] = None,
+) -> jax.Array:
+    """Blockwise attention under the block-diffusion mask. q: [batch * heads,
+    2 * seq, head_dim], rows < seq the clean copy and the rest the noised
+    one; k, v: [batch * kv_heads, 2 * seq, head_dim] likewise (batch-major,
+    heads a multiple of kv_heads); doc, blk: [batch, seq] ints, the document
+    of each token and its block's ordinal inside it, documents one after the
+    other. ``block_q``/``block_k`` must divide ``seq``. Returns the shape of q."""
+    o, _ = _fab_fwd(q, k, v, doc, blk, scale, block_q, block_k, interpret)
+    return o
+
+
+def _fab_fwd(q, k, v, doc, blk, scale, block_q, block_k, interpret):
+    if interpret is None:
+        interpret = _use_interpret()
+    (heads, _, group, nq, nk), lo, hi, spans = _blockdiff_setup(q, k, doc, blk, block_q, block_k)
+    bh, rows, d = q.shape
+    half = nk // 2
+    bound = pl.BlockSpec((1, 1, block_q, 1), lambda b, qi, ki, *_: (b // heads, ki // half, qi, 0))
+    o, lse = pl.pallas_call(
+        functools.partial(_blockdiff_fwd_kernel, scale=scale, heads=heads,
+                          block_q=block_q, block_k=block_k),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(bh, nq, nk),
+            in_specs=[
+                pl.BlockSpec((1, block_q, d), lambda b, qi, ki, *_: (b, qi, 0)),
+                pl.BlockSpec((1, block_k, d), lambda b, qi, ki, *_: (b // group, ki, 0)),
+                pl.BlockSpec((1, block_k, d), lambda b, qi, ki, *_: (b // group, ki, 0)),
+                bound,
+                bound,
+            ],
+            out_specs=[
+                pl.BlockSpec((1, block_q, d), lambda b, qi, ki, *_: (b, qi, 0)),
+                pl.BlockSpec((1, block_q, 1), lambda b, qi, ki, *_: (b, qi, 0)),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_q, d), jnp.float32),
+                pltpu.VMEM((block_q, 1), jnp.float32),
+                pltpu.VMEM((block_q, 1), jnp.float32),
+            ],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((bh, rows, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, rows, 1), jnp.float32),
+        ],
+        interpret=interpret,
+        name="flash_blockdiff_fwd",
+    )(*spans, q, k, v, lo, hi)
+    return o, (q, k, v, doc, blk, o, lse)
+
+
+def _fab_bwd(scale, block_q, block_k, interpret, residuals, g):
+    if interpret is None:
+        interpret = _use_interpret()
+    q, k, v, doc, blk, o, lse = residuals
+    (heads, kv_heads, group, nq, nk), lo, hi, spans = _blockdiff_setup(
+        q, k, doc, blk, block_q, block_k
+    )
+    bh, _, d = q.shape
+    half = nk // 2
+    drow = jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32), axis=-1,
+                   keepdims=True)
+
+    bound = pl.BlockSpec((1, 1, block_q, 1), lambda b, qi, ki, *_: (b // heads, ki // half, qi, 0))
+    dq = pl.pallas_call(
+        functools.partial(_blockdiff_dq_kernel, scale=scale, heads=heads,
+                          block_q=block_q, block_k=block_k),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(bh, nq, nk),
+            in_specs=[
+                pl.BlockSpec((1, block_q, d), lambda b, qi, ki, *_: (b, qi, 0)),
+                pl.BlockSpec((1, block_k, d), lambda b, qi, ki, *_: (b // group, ki, 0)),
+                pl.BlockSpec((1, block_k, d), lambda b, qi, ki, *_: (b // group, ki, 0)),
+                bound,
+                bound,
+                pl.BlockSpec((1, block_q, d), lambda b, qi, ki, *_: (b, qi, 0)),
+                pl.BlockSpec((1, block_q, 1), lambda b, qi, ki, *_: (b, qi, 0)),
+                pl.BlockSpec((1, block_q, 1), lambda b, qi, ki, *_: (b, qi, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, block_q, d), lambda b, qi, ki, *_: (b, qi, 0)),
+            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        interpret=interpret,
+        name="flash_blockdiff_dq",
+    )(*spans, q, k, v, lo, hi, g, lse, drow)
+
+    q_row = lambda b, ki, j, *_: (b * group + j // nq, j % nq, 0)
+    bound = pl.BlockSpec(
+        (1, 1, block_q, 1), lambda b, ki, j, *_: (b // kv_heads, ki // half, j % nq, 0)
+    )
+    dk, dv = pl.pallas_call(
+        functools.partial(_blockdiff_dkv_kernel, scale=scale, kv_heads=kv_heads,
+                          nq=nq, block_q=block_q, block_k=block_k),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(k.shape[0], nk, group * nq),
+            in_specs=[
+                pl.BlockSpec((1, block_q, d), q_row),
+                pl.BlockSpec((1, block_k, d), lambda b, ki, j, *_: (b, ki, 0)),
+                pl.BlockSpec((1, block_k, d), lambda b, ki, j, *_: (b, ki, 0)),
+                bound,
+                bound,
+                pl.BlockSpec((1, block_q, d), q_row),
+                pl.BlockSpec((1, block_q, 1), q_row),
+                pl.BlockSpec((1, block_q, 1), q_row),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, block_k, d), lambda b, ki, j, *_: (b, ki, 0)),
+                pl.BlockSpec((1, block_k, d), lambda b, ki, j, *_: (b, ki, 0)),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_k, d), jnp.float32),
+                pltpu.VMEM((block_k, d), jnp.float32),
+            ],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct(k.shape, k.dtype),
+            jax.ShapeDtypeStruct(v.shape, v.dtype),
+        ],
+        interpret=interpret,
+        name="flash_blockdiff_dkv",
+    )(*spans, q, k, v, lo, hi, g, lse, drow)
+    return dq, dk, dv, None, None
+
+
+flash_attention_blockdiff.defvjp(_fab_fwd, _fab_bwd)

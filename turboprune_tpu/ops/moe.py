@@ -6,10 +6,23 @@ each token; a chip holds ``experts_here`` of the experts, those from
 holds. What the other experts would add is not computed here and not stood
 in for: the result is this chip's part of the layer's sum.
 
-    s = sigmoid(logits)                                   float32, all experts
-    top = the top_k largest of s + bias                   (bias: no gradient)
-    w_i = scaling * s_i / (sum_{j in top} s_j + 1e-20)    over all top_k chosen
-    part = sum over i in top, i held here, of  w_i * W2_i relu(W1_i z)^2
+    part = sum over i in top, i held here, of  w_i * f_i(z)
+
+Two routers give (top, w), both float32 over all experts:
+
+    ``route``           s = sigmoid(logits);  top = the top_k largest of s + bias
+                        (bias: no gradient);  w_i = scaling * s_i / (sum_top s + 1e-20)
+    ``route_softmax``   p = softmax(logits);  top = the top_k largest of p;
+                        w_i = p_i / sum_top p
+
+and an expert is what its stacked kernels say (``routed_experts``'s
+``kernels``): two of them, ``f(z) = W2 relu(W1 z)^2``; three, gated,
+``f(z) = W_down (silu(W_gate z) * W_up z)``. Everything else (the sort, the
+buffer, the grouped products, the further rounds, the scatter-add, the
+counters) is one path for both: which router and which expert a model has is
+read off what it passes, at trace time, and the program of a model that
+passes two kernels is what it was before there were three
+(tests/test_sdar.py holds its text to the commit before).
 
 The pairs held here are a data-dependent number; the work is not. They are
 sorted by expert and laid into a buffer of ``capacity`` rows, a size the
@@ -36,7 +49,7 @@ the experts chosen, in ``route`` before anything reads them, and
 ``moe_order``, the pairs' sorted order, in ``routed_experts``: 0.7 MB each
 at 8,192 tokens choosing 22, against a ``top_k`` and a sort of 180,224 keys
 to rebuild them. Nothing inside ``_every_round`` is tagged: a backward pass
-runs the two grouped products' forward again.
+runs the grouped products' forward again.
 """
 
 from __future__ import annotations
@@ -66,6 +79,14 @@ TILE, SMALL_TILE = 128, 8
 KERNEL_BLOCK_BYTES = 3 * 2**20
 
 
+# What stands between an expert's first kernels' products and its last
+# kernel, by how many come first: ``relu(up)^2``, or gated, ``silu(gate) * up``.
+BETWEEN = {
+    1: lambda up: jnp.square(jax.nn.relu(up)),
+    2: lambda gate, up: jax.nn.silu(gate) * up,
+}
+
+
 def route(logits: jax.Array, bias: jax.Array, top_k: int, scaling: float):
     """(top [N, K] int32, weights [N, K] float32) of ``logits`` [N, E]."""
     s = jax.nn.sigmoid(logits.astype(jnp.float32))
@@ -75,6 +96,16 @@ def route(logits: jax.Array, bias: jax.Array, top_k: int, scaling: float):
     top = checkpoint_name(top, "router_top")
     chosen = jnp.take_along_axis(s, top, axis=-1)
     return top, scaling * chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
+
+
+def route_softmax(logits: jax.Array, top_k: int):
+    """(top [N, K] int32, weights [N, K] float32) of ``logits`` [N, E]: the
+    softmax over all experts, its ``top_k`` largest, renormalised over them."""
+    p = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    _, top = lax.top_k(p, top_k)
+    top = checkpoint_name(top, "router_top")  # before its first use, as in ``route``
+    chosen = jnp.take_along_axis(p, top, axis=-1)
+    return top, chosen / jnp.sum(chosen, axis=-1, keepdims=True)
 
 
 def pair_tile(tokens: int, top_k: int, experts: int) -> int:
@@ -93,6 +124,17 @@ def pair_capacity(tokens: int, top_k: int, experts: int, experts_here: int) -> i
     rows = math.ceil(CAPACITY_FACTOR * expected / tile) * tile + experts_here * tile
     worst = tokens * min(top_k, experts_here) + experts_here * (tile - 1)
     return min(rows, math.ceil(worst / tile) * tile)
+
+
+def rounds(top: jax.Array, expert_offset: int, held: int, capacity: int, tile: int) -> jax.Array:
+    """How many rounds of ``capacity`` rows ``routed_experts`` takes for
+    ``top`` [N, K]: one, and one more for each further buffer the held pairs
+    fill, each expert's rows on whole tiles (int32 scalar; a counter for a
+    model that wants it, beside ``COUNTERS``)."""
+    local = top.reshape(-1) - expert_offset
+    sizes = jnp.sum(local[:, None] == jnp.arange(held, dtype=local.dtype), axis=0, dtype=jnp.int32)
+    rows = jnp.sum(-(-sizes // tile) * tile)
+    return jnp.maximum(-(-rows // capacity), 1).astype(jnp.int32)
 
 
 def _kernel_block(k: int, n: int, itemsize: int) -> tuple[int, int]:
@@ -146,8 +188,7 @@ def routed_experts(
     z: jax.Array,
     top: jax.Array,
     weights: jax.Array,
-    kernel_up: jax.Array,
-    kernel_down: jax.Array,
+    kernels: tuple,
     expert_offset: int,
     capacity: int,
     tile: int = TILE,
@@ -155,11 +196,12 @@ def routed_experts(
     """This chip's part of the routed sum, and the layer's counters.
 
     z [N, L] (the experts' input); top [N, K] expert ids over all experts;
-    weights [N, K] float32; kernel_up [H, L, F] and kernel_down [H, F, L],
-    the H experts ``expert_offset .. expert_offset + H`` (already masked);
-    ``capacity`` rows a round, whole ``tile``s.
+    weights [N, K] float32; ``kernels`` (up [H, L, F], down [H, F, L]) or,
+    gated, (gate [H, L, F], up [H, L, F], down [H, F, L]), the H experts
+    ``expert_offset .. expert_offset + H`` (already masked); ``capacity``
+    rows a round, whole ``tile``s.
     Returns ([N, L] float32, {counter: int32 scalar})."""
-    held = kernel_up.shape[0]
+    held = kernels[0].shape[0]
     if capacity % tile:
         raise ValueError(f"a buffer of {capacity} rows is not whole tiles of {tile}")
     with jax.named_scope("moe/dispatch"):
@@ -176,7 +218,7 @@ def routed_experts(
             "aligned_ends": jnp.cumsum(aligned),
         }
     out, computed = _every_round(
-        z, weights.reshape(-1), kernel_up, kernel_down, plan, top.shape[1], capacity, tile
+        z, weights.reshape(-1), tuple(kernels), plan, top.shape[1], capacity, tile
     )
     counters = {
         "moe_pairs": jnp.sum(sizes),
@@ -187,12 +229,12 @@ def routed_experts(
 
 
 @functools.partial(jax.jit, static_argnames=("k", "capacity", "tile"))
-def _one_round(lo, z, flat_weights, kernel_up, kernel_down, plan, k, capacity, tile):
+def _one_round(lo, z, flat_weights, kernels, plan, k, capacity, tile):
     """Rows ``lo .. lo + capacity`` of the aligned order: their part of the
     sum [N, L] float32, and how many of them are pairs. A traced program of
     its own: the scopes below then reach the device trace under their own
     names, where ``jax.vjp`` of plain code would write ``jvp(moe/experts)``."""
-    held = kernel_up.shape[0]
+    held = kernels[0].shape[0]
     starts, ends = plan["aligned_starts"], plan["aligned_ends"]
     with jax.named_scope("moe/dispatch"):
         at = lo + jnp.arange(capacity, dtype=ends.dtype)
@@ -206,24 +248,25 @@ def _one_round(lo, z, flat_weights, kernel_up, kernel_down, plan, k, capacity, t
         # The rows past the last pair are the last expert's: zeros it multiplies.
         group = group.at[-1].add(capacity - jnp.sum(group)).astype(jnp.int32)
     with jax.named_scope("moe/experts"):
-        h = grouped_product(x, kernel_up, group, tile, z.dtype)
-        y = grouped_product(jnp.square(jax.nn.relu(h)), kernel_down, group, tile, jnp.float32)
+        *first, down = kernels
+        h = BETWEEN[len(first)](*(grouped_product(x, kernel, group, tile, z.dtype) for kernel in first))
+        y = grouped_product(h, down, group, tile, jnp.float32)
     with jax.named_scope("moe/combine"):
         part = jnp.zeros(z.shape, jnp.float32).at[rows].add(y * w[:, None])
     return part, jnp.sum(valid, dtype=jnp.int32)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
-def _every_round(z, flat_weights, kernel_up, kernel_down, plan, k, capacity, tile):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _every_round(z, flat_weights, kernels, plan, k, capacity, tile):
     """One round, and while pairs lie past it (an imbalance) another: a loop
     whose length is the routing's, so its gradient is written out below: the
     first round's as ``jax.vjp`` gives it, each further round's rebuilt from
     the round's input and added."""
-    return _every_round_fwd(z, flat_weights, kernel_up, kernel_down, plan, k, capacity, tile)[0]
+    return _every_round_fwd(z, flat_weights, kernels, plan, k, capacity, tile)[0]
 
 
-def _every_round_fwd(z, flat_weights, kernel_up, kernel_down, plan, k, capacity, tile):
-    operands = (z, flat_weights, kernel_up, kernel_down)
+def _every_round_fwd(z, flat_weights, kernels, plan, k, capacity, tile):
+    operands = (z, flat_weights, kernels)
     first = lambda *operands: _one_round(0, *operands, plan, k, capacity, tile)
     part, pull, rows = jax.vjp(first, *operands, has_aux=True)
 

@@ -15,6 +15,14 @@ accumulate exact epoch averages without per-step device syncs — replacing
 torchmetrics' dist_sync_on_step + loss all_reduce AVG
 (base_harness.py:54-60,192-200) with arithmetic that is already correct
 under the jit partitioner.
+
+Three kinds of batch go through the same step (``Batch`` below): images with
+integer labels (CE summed over the batch); packed token sequences with
+next-token targets (CE over the targets that are not the padding label, ``n``
+their count); and packed sequences noised for block-diffusion training, whose
+labels are a pair (targets, weights): the loss is the weighted sum over the
+masked targets divided by the tokens, ``n`` the tokens (data/tokens.py). Which
+one a step has it reads off the labels, never off a model's name.
 """
 
 from __future__ import annotations
@@ -28,9 +36,12 @@ import optax
 from ..ops.masking import PyTree, apply_masks
 from .state import TrainState
 
-# (images NHWC, integer labels [B]) or, for a language model, (tokens
-# [B, 2, T], next-token targets [B, T] with the padding label where a token
-# has none): which one a step has it reads off the labels' rank.
+# Three kinds of batch, and which one a step has it reads off the labels:
+# (images NHWC, integer labels [B]); for a language model trained on next
+# tokens, (tokens [B, 2, T], targets [B, T] with the padding label where a
+# token has none), told by the labels' rank; for one trained by diffusion
+# over blocks, (tokens [B, 5, T], (targets [B, T], weights [B, T])), a batch
+# with weights, told by the pair (data/tokens.py makes both token batches).
 Batch = tuple[jax.Array, jax.Array]
 
 
@@ -53,6 +64,32 @@ def masked_cross_entropy(logits: jax.Array, labels: jax.Array):
         jnp.sum(valid & hit).astype(jnp.float32),
         jnp.sum(valid).astype(jnp.float32),
     )
+
+
+def weighted_cross_entropy(logits: jax.Array, targets: jax.Array, weights: jax.Array):
+    """(weighted CE summed, weighted hits, tokens) of a batch with weights, in
+    fp32: the sum over the targets that are not the padding label of ``weight
+    * CE``, and how many tokens the batch holds (``weights >= 0``: a target's
+    weight is positive, a token without a target has 0, a place without a
+    token -1). The sum over the tokens is the loss's divisor: with ``1 / t``
+    a masked target its quotient is the block-diffusion bound a token."""
+    safe = jnp.maximum(targets, 0)
+    logp = jax.nn.log_softmax(logits.astype(jnp.float32))
+    per_row = -jnp.take_along_axis(logp, safe[..., None], axis=-1)[..., 0]
+    w = jnp.where(targets >= 0, weights.astype(jnp.float32), 0.0)
+    hit = jnp.argmax(logits, axis=-1) == safe
+    return (
+        jnp.sum(w * per_row),
+        jnp.sum(jnp.where(hit, w, 0.0)),
+        jnp.sum(weights >= 0).astype(jnp.float32),
+    )
+
+
+def token_loss_sums(logits: jax.Array, labels):
+    """(loss sum, hits, count) of a token batch, weighted or not."""
+    if isinstance(labels, tuple):
+        return weighted_cross_entropy(logits, *labels)
+    return masked_cross_entropy(logits, labels)
 
 
 def _forward_train(model, params, masks, batch_stats, images, rng):
@@ -109,12 +146,13 @@ def make_train_step(
                     model, params, state.masks, state.batch_stats, images, step_rng
                 )
             with jax.named_scope("loss"):
-                if labels.ndim > 1:
-                    # Token targets: the mean is over the valid ones, and the
-                    # logits [B, T, V] stay inside the gradient's scope. The
-                    # image path below keeps its arithmetic, and with it the
+                if isinstance(labels, tuple) or labels.ndim > 1:
+                    # Token targets: the mean is over the valid ones (over the
+                    # tokens, for a batch with weights), and the logits
+                    # [B, T, V] stay inside the gradient's scope. The image
+                    # path below keeps its arithmetic, and with it the
                     # compiled program its cells have cached.
-                    loss_sum, correct, n = masked_cross_entropy(logits, labels)
+                    loss_sum, correct, n = token_loss_sums(logits, labels)
                     return loss_sum / n, (None, new_batch_stats, loss_sum, n, correct, counters)
                 n = jnp.asarray(labels.shape[0], jnp.float32)
                 loss_sum = cross_entropy_sum(logits, labels)
@@ -225,7 +263,7 @@ def make_eval_step(model) -> Callable[[TrainState, Batch], dict]:
             variables["batch_stats"] = state.batch_stats
         with jax.named_scope("eval_forward"):
             logits = model.apply(variables, images, train=False)
-        loss_sum, correct, count = masked_cross_entropy(logits, labels)
+        loss_sum, correct, count = token_loss_sums(logits, labels)
         return {"loss_sum": loss_sum, "correct": correct, "count": count}
 
     return eval_step
