@@ -1,9 +1,8 @@
 """The block-diffusion path of the Pallas flash kernel
 (ops/flash.py::flash_attention_blockdiff) against a dense oracle built from
-the rule, forward and the three gradients, in interpret mode; and the causal
-call, whose bodies this path shares and may not have changed."""
-
-import hashlib
+the rule, forward and the three gradients, in interpret mode; and its walk:
+the (query block, key block) pairs the kernels visit are the pairs the square
+grid ran, in its order, and no other block is read."""
 
 import jax
 import jax.numpy as jnp
@@ -12,9 +11,12 @@ import pytest
 
 from turboprune_tpu.ops.flash import (
     _blockdiff_bounds,
+    _blockdiff_pairs,
+    blockdiff_walk_counts,
     flash_attention_blockdiff,
-    flash_attention_causal,
 )
+
+import flash_walk
 
 BATCH, HEADS, KV_HEADS, T, D = 2, 4, 2, 64, 8
 SCALE = 0.3
@@ -66,12 +68,49 @@ LAYOUTS = {
     "blocks_of_3": ([[7, 30], [1, 2, 50]], 3),  # T is no multiple of the block length
     "blocks_of_16": ([[9], [48, 59]], 16),
 }
+# The walk's worst cases and edges: one block of tokens as long as its
+# document (``blk`` constant over it: the noised-onto-noised quadrant runs
+# whole), documents of one kernel block each, documents of one token.
+WORST = {
+    "one_block_one_document": ([[], []], T),
+    "one_block_a_document": ([[5, 16, 17, 40], [32]], T),
+    "documents_of_one_kernel_block": ([[16, 32, 48], [16, 32, 48]], 4),
+    "documents_of_one_token": ([list(range(1, T)), list(range(1, T, 2))], 4),
+}
+EVERY = {**LAYOUTS, **WORST}
+
+
+def random_layout(seed):
+    rng = np.random.default_rng(seed)
+    starts = [sorted(rng.choice(np.arange(1, T), size=rng.integers(0, 9), replace=False).tolist()) for _ in range(BATCH)]
+    return starts, int(rng.choice([1, 3, 4, 16, T]))
+
+
+def layout_of(name):
+    return random_layout(int(name[7:])) if name.startswith("random_") else EVERY[name]
+
+
+def square_grid(doc, blk, block_q, block_k):
+    """[B, nq, nk] bool: the pairs the square grid's predicate ran, written
+    out a (batch row, query block, key block) at a time as the kernels of the
+    commit before the walk tested it (``_interval_runs`` on scalars)."""
+    lo, hi = (np.asarray(x)[..., 0] for x in _blockdiff_bounds(doc, blk))
+    bsz, rows = lo.shape[0], lo.shape[2]
+    nq, nk = rows // block_q, rows // block_k
+    runs = np.zeros((bsz, nq, nk), bool)
+    for b in range(bsz):
+        for qi in range(nq):
+            for ki in range(nk):
+                half = ki // (nk // 2)
+                at = slice(qi * block_q, (qi + 1) * block_q)
+                runs[b, qi, ki] = lo[b, half, at].min() // block_k <= ki <= hi[b, half, at].max() // block_k
+    return runs
 
 
 @pytest.mark.parametrize("layout", LAYOUTS)
 @pytest.mark.parametrize("blocks", [(16, 16), (64, 64), (16, 32), (32, 16)])
 def test_forward_equals_the_rule(layout, blocks):
-    starts, block_length = LAYOUTS[layout]
+    starts, block_length = EVERY[layout]
     doc, blk = ordinals(starts, T, block_length)
     q, k, v = inputs()
     with jax.default_matmul_precision("highest"):
@@ -83,7 +122,7 @@ def test_forward_equals_the_rule(layout, blocks):
 @pytest.mark.parametrize("layout", ["packed", "blocks_of_3"])
 @pytest.mark.parametrize("blocks", [(16, 16), (32, 16)])
 def test_gradients_equal_the_rule(layout, blocks):
-    starts, block_length = LAYOUTS[layout]
+    starts, block_length = EVERY[layout]
     doc, blk = ordinals(starts, T, block_length)
     q, k, v = inputs(seed=1)
     keep = rule(doc, blk)
@@ -159,17 +198,43 @@ def test_shapes_that_do_not_fit_are_refused(change, match):
         flash_attention_blockdiff(*change(*inputs(), doc, blk), SCALE, 16, 16)
 
 
-def test_the_causal_call_is_the_program_it_was():
-    """The causal family's three bodies are now functions this path calls
-    too: its lowered program (forward and the three gradients, interpret
-    mode) hashes to what the commit before gave."""
-    rng = np.random.default_rng(20261001)
-    q = jnp.asarray(rng.normal(size=(4, 32, 8)), jnp.float32)
-    k, v = (jnp.asarray(rng.normal(size=(2, 32, 8)), jnp.float32) for _ in range(2))
-    seg = jnp.asarray(np.cumsum(np.isin(np.arange(32), [5, 16]))[None], jnp.int32)
-    f = lambda q, k, v: flash_attention_causal(q, k, v, seg, 0.35, 16, 8)
-    g = jax.jit(lambda q, k, v: jax.grad(lambda q, k, v: jnp.sum(jnp.sin(f(q, k, v))), argnums=(0, 1, 2))(q, k, v))
-    text = g.lower(q, k, v).as_text()
-    assert hashlib.sha256(text.encode()).hexdigest() == (
-        "edf025376cf91099562cee5e1965f8ffd5511a94d0e42064b5ff5e03ed9adf26"
-    )
+ALL = [*EVERY, "random_0", "random_1", "random_2"]
+
+
+@pytest.mark.parametrize("blocks", [(16, 16), (16, 32)])
+@pytest.mark.parametrize("layout", ALL)
+def test_the_walk_lists_the_pairs_the_square_grid_ran_in_its_order(layout, blocks):
+    starts, block_length = layout_of(layout)
+    doc, blk = ordinals(starts, T, block_length)
+    square = square_grid(doc, blk, *blocks)
+    n_run = flash_walk.assert_lists(_blockdiff_pairs(doc, blk, *blocks)[2], square, HEADS // KV_HEADS)
+    # Clean onto noised never runs, so no list outgrows three quadrants; every key block is met.
+    nq, nk = square.shape[1:]
+    assert not square[:, : nq // 2, nk // 2 :].any() and n_run.max() <= 3 * nq * nk // 4
+    assert square.any(axis=1).all()
+
+
+@pytest.mark.parametrize("layout", ALL)
+def test_the_counts_are_the_walks(layout):
+    starts, block_length = layout_of(layout)
+    doc, blk = ordinals(starts, T, block_length)
+    a_row = square_grid(doc, blk, 16, 16).sum(axis=(1, 2))
+    run, walked = blockdiff_walk_counts(doc, blk, 16, 16)
+    assert (run.dtype, walked.dtype) == (jnp.int32, jnp.int32)
+    assert (int(run), int(walked)) == (a_row.sum(), BATCH * a_row.max())
+
+
+@pytest.mark.parametrize("layout", WORST)
+def test_forward_and_gradients_equal_the_rule_on_the_worst_cases(layout):
+    test_forward_equals_the_rule(layout, (16, 16))
+    test_gradients_equal_the_rule(layout, (16, 16))
+
+
+@pytest.mark.parametrize("layout", ["packed", "blocks_of_16", "documents_of_one_token", "random_2"])
+def test_poison_in_a_block_reaches_the_blocks_paired_with_it_and_no_other(layout):
+    starts, block_length = layout_of(layout)
+    doc, blk = ordinals(starts, T, block_length)
+    flash_walk.assert_poison_stays_in_its_pairs(
+        lambda q, k, v: flash_attention_blockdiff(q, k, v, doc, blk, SCALE, 16, 16),
+        square_grid(doc, blk, 16, 16), *inputs(seed=3), HEADS, KV_HEADS, 16, every=3,
+    )  # fmt: skip

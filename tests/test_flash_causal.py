@@ -1,7 +1,9 @@
 """The causal, grouped, packed path of the Pallas flash kernel
 (ops/flash.py::flash_attention_causal) against a plain masked softmax,
-forward and gradient, in interpret mode; and the bidirectional call, which
-the language model's path may not have changed by a bit."""
+forward and gradient, in interpret mode; its walk (the pairs of blocks the
+kernels visit are the pairs the square grid ran, in its order, and no other
+block is read); and the bidirectional call, which the language model's path
+may not have changed by a bit."""
 
 import hashlib
 
@@ -10,7 +12,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from turboprune_tpu.ops.flash import _block_ranges, flash_attention, flash_attention_causal
+from turboprune_tpu.ops.flash import _block_ranges, _causal_setup, flash_attention, flash_attention_causal
+
+import flash_walk
 
 BATCH, HEADS, KV_HEADS, T, D = 2, 4, 2, 64, 8
 SCALE = 0.3
@@ -39,8 +43,9 @@ def inputs(seed=0, dtype=jnp.float32):
 
 
 @pytest.mark.parametrize("blocks", [(16, 16), (32, 32), (64, 64), (16, 32), (32, 16)])
-def test_forward_equals_a_plain_masked_softmax(blocks):
-    q, k, v, seg = inputs()
+def test_forward_equals_a_plain_masked_softmax(blocks, seg=None):
+    q, k, v, packed = inputs()
+    seg = packed if seg is None else seg
     with jax.default_matmul_precision("highest"):
         got = flash_attention_causal(q, k, v, seg, SCALE, *blocks)
         want = plain(q, k, v, seg, SCALE)
@@ -48,8 +53,9 @@ def test_forward_equals_a_plain_masked_softmax(blocks):
 
 
 @pytest.mark.parametrize("blocks", [(16, 16), (64, 64), (16, 32)])
-def test_gradients_equal_a_plain_masked_softmax(blocks):
-    q, k, v, seg = inputs(seed=1)
+def test_gradients_equal_a_plain_masked_softmax(blocks, seg=None):
+    q, k, v, packed = inputs(seed=1)
+    seg = packed if seg is None else seg
     weigh = lambda fn: jax.grad(lambda q, k, v: jnp.sum(jnp.sin(fn(q, k, v))), argnums=(0, 1, 2))
     with jax.default_matmul_precision("highest"):
         got = weigh(lambda q, k, v: flash_attention_causal(q, k, v, seg, SCALE, *blocks))(q, k, v)
@@ -78,7 +84,7 @@ def test_blocks_of_other_documents_are_skipped_and_change_nothing():
     got = flash_attention_causal(q, k, v, seg, SCALE, 16, 16)
     np.testing.assert_array_equal(np.asarray(got[:, 16:]), np.asarray(clean[:, 16:]))
     lo, hi = _block_ranges(seg, 16)
-    assert lo.tolist() == hi.tolist() == [0, 1, 2, 3] * BATCH
+    assert lo.tolist() == hi.tolist() == [[0, 1, 2, 3]] * BATCH
 
 
 def test_bf16_operands():
@@ -100,6 +106,80 @@ def test_bf16_operands():
 def test_shapes_that_do_not_fit_are_refused(change, match):
     with pytest.raises(ValueError, match=match):
         flash_attention_causal(*change(*inputs()), SCALE, 16, 16)
+
+
+# Segment ids [BATCH, T] by the tokens at which documents start: packed (the
+# oracle tests' layout), one document, documents of one kernel block each,
+# documents of one token, and ids that do not ascend (the test of blocks is
+# then conservative, and the walk lists what it accepts all the same).
+LAYOUTS = {
+    "packed": [[5, 16, 17, 40], [32]],
+    "one_document": [[], []],
+    "documents_of_one_kernel_block": [[16, 32, 48], [16, 32, 48]],
+    "documents_of_one_token": [list(range(1, T)), list(range(1, T, 2))],
+    "random_0": None,
+    "random_1": None,
+    "random_2": None,
+    "ids_that_do_not_ascend": None,
+}
+
+
+def segments(layout):
+    if layout == "ids_that_do_not_ascend":
+        return jnp.asarray(np.random.default_rng(5).integers(0, 3, size=(BATCH, T)) // 2 * np.array([[1], [2]]), jnp.int32)
+    starts = LAYOUTS[layout]
+    if starts is None:
+        rng = np.random.default_rng(int(layout[7:]))
+        starts = [rng.choice(np.arange(1, T), size=rng.integers(0, 9), replace=False) for _ in range(BATCH)]
+    flags = np.zeros((BATCH, T), np.int32)
+    for b, at in enumerate(starts):
+        flags[b, list(at)] = 1
+    return jnp.asarray(np.cumsum(flags, axis=1))
+
+
+def square_grid(seg, block_q, block_k):
+    """[B, nq, nk] bool: the pairs the square grid's predicate ran, written out
+    a (batch row, query block, key block) at a time as the kernels of the
+    commit before the walk tested it (``_runs`` on scalars)."""
+    seg = np.asarray(seg)
+    nq, nk = T // block_q, T // block_k
+    runs = np.zeros((BATCH, nq, nk), bool)
+    for b in range(BATCH):
+        for qi in range(nq):
+            for ki in range(nk):
+                qs, ks = seg[b, qi * block_q : (qi + 1) * block_q], seg[b, ki * block_k : (ki + 1) * block_k]
+                causal = ki * block_k <= qi * block_q + (block_q - 1)
+                runs[b, qi, ki] = causal and ks.max() >= qs.min() and ks.min() <= qs.max()
+    return runs
+
+
+@pytest.mark.parametrize("blocks", [(16, 16), (16, 32)])
+@pytest.mark.parametrize("layout", LAYOUTS)
+def test_the_walk_lists_the_pairs_the_square_grid_ran_in_its_order(layout, blocks):
+    seg = segments(layout)
+    q, k, _, _ = inputs()
+    square = square_grid(seg, *blocks)
+    n_run = flash_walk.assert_lists(_causal_setup(q, k, seg, *blocks)[3], square, HEADS // KV_HEADS)
+    nq, nk = square.shape[1:]
+    assert n_run.max() <= nq * (nk + 1)  # no longer than the causal order leaves
+
+
+@pytest.mark.parametrize(
+    "layout", ["one_document", "documents_of_one_kernel_block", "documents_of_one_token", "ids_that_do_not_ascend"]
+)
+def test_forward_and_gradients_equal_a_plain_masked_softmax_on_the_walks_edges(layout):
+    test_forward_equals_a_plain_masked_softmax((16, 16), segments(layout))
+    test_gradients_equal_a_plain_masked_softmax((16, 16), segments(layout))
+
+
+@pytest.mark.parametrize("layout", ["documents_of_one_token", "random_1", "random_2"])
+def test_poison_in_a_block_reaches_the_blocks_paired_with_it_and_no_other(layout):
+    seg = segments(layout)
+    q, k, v, _ = inputs(seed=3)
+    flash_walk.assert_poison_stays_in_its_pairs(
+        lambda q, k, v: flash_attention_causal(q, k, v, seg, SCALE, 16, 16),
+        square_grid(seg, 16, 16), q, k, v, HEADS, KV_HEADS, 16,
+    )  # fmt: skip
 
 
 def test_the_bidirectional_call_is_the_program_it_was():

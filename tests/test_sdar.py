@@ -152,11 +152,15 @@ def test_the_steps_read_the_weighted_loss_off_the_batch(whole):
         e = jax.jit(make_eval_step(model))(state, (tokens, labels))
         ref = reference.loss(reference.forward(params, spec, tokens), *labels)
     assert set(m) == {"loss_sum", "correct", "count", *model.counters}
-    assert model.counters == (*moe.COUNTERS, "moe_rounds", "masked_targets")
+    assert model.counters == (*moe.COUNTERS, "moe_rounds", "masked_targets", *sdar.FLASH_COUNTERS)
     assert float(m["count"]) == BATCH * T and abs(float(m["loss_sum"]) / (BATCH * T) - float(ref)) < 1e-5
     masked = int((np.asarray(labels[0]) >= 0).sum())
     assert int(m["masked_targets"]) == masked and int(sums["masked_targets"]) == 2 * masked
     assert int(m["moe_rounds"]) == 2 and int(m["moe_dropped_pairs"]) == 0  # a round a layer
+    # Attention's walk, two layers of two sequences: a kernel block is a whole copy here, so a
+    # sequence runs clean onto clean, noised onto clean and noised onto noised, and no step more.
+    assert int(m["flash_steps_run"]) == int(m["flash_steps_walked"]) == 2 * BATCH * 3
+    assert int(sums["flash_steps_run"]) == 2 * int(m["flash_steps_run"])
     assert set(e) == {"loss_sum", "correct", "count"} and float(e["loss_sum"]) == pytest.approx(float(m["loss_sum"]), rel=1e-6)
     # A place without a token (weight -1) is no token; weights count nothing else.
     logits = jax.random.normal(jax.random.PRNGKey(2), (BATCH, T, VOCAB))
@@ -167,6 +171,23 @@ def test_the_steps_read_the_weighted_loss_off_the_batch(whole):
     np.testing.assert_allclose(
         weighted_cross_entropy(logits, targets, ones)[0], masked_cross_entropy(logits, targets)[0], rtol=1e-6
     )
+
+
+@pytest.mark.parametrize(
+    "counted, want",
+    [
+        (None, None),  # a job that keeps no counters
+        ({"moe_pairs": 131072.0, "moe_load_max": 1700.0}, None),  # a program from before the walk
+        ({"flash_steps_run": 0.0, "flash_steps_walked": 0.0}, None),
+        ({"flash_steps_run": 960.0, "flash_steps_walked": 960.0}, 100.0),
+        ({"flash_steps_run": 960.0, "flash_steps_walked": 8192.0}, 11.71875),
+    ],
+)
+def test_the_walks_metric_reads_the_two_counters_or_nothing(counted, want):
+    from benchmarks import registry
+
+    read = registry.load_metric("flash_blockdiff_walk_run_pct").read
+    assert read({} if counted is None else {"moe_softmax": counted}) == want
 
 
 # ------------------------------------------------- (b) the shares add up
@@ -381,7 +402,7 @@ _SPARSE_EXPERT_PROGRAM = """
 import hashlib, jax, jax.numpy as jnp, numpy as np
 from turboprune_tpu.models import create_model
 
-model = create_model("nemotron_h_tiny", 50, share=(2, 4, 1))
+model = create_model("nemotron_h_tiny", 50, share=(2, 4, 1), layer_pattern="EMEM")
 rng = np.random.default_rng(20261001)
 flags = np.zeros((2, 32), np.int32)
 flags[0, [5, 16, 17]], flags[1, [20]] = 1, 1
@@ -403,7 +424,9 @@ def test_the_sparse_expert_hybrid_is_the_program_it_was():
     """ops/moe.py now takes an expert's kernels as a tuple and has a second
     router beside the first: the lowered text of the other model that runs
     it (forward, counters and every gradient) hashes to what the commit
-    before gave. In a process of its own, as the hash was taken: inside a
+    before gave. Its expert and scan layers only: the attention layer's
+    kernels walk another grid since PR 39, and its hash is the commit's
+    before that one, taken there without that layer. In a process of its own, as the hash was taken: inside a
     worker of the whole suite, after other files' tests, the same lowering
     gave another text (which of them leaves what behind was not found)."""
     import subprocess
@@ -413,4 +436,4 @@ def test_the_sparse_expert_hybrid_is_the_program_it_was():
         [sys.executable, "-c", _SPARSE_EXPERT_PROGRAM], capture_output=True, text=True, check=True,
         cwd=pathlib.Path(__file__).resolve().parents[1],
     )  # fmt: skip
-    assert out.stdout.split()[-1] == "feef9721d7889fd64b9858a7e11d838d735fc8c4dca02f402f5950bfc83e43e1"
+    assert out.stdout.split()[-1] == "26853773ad03c8da462d14549e96c1cb6aabdd14184d418f39fd9266ae058586"

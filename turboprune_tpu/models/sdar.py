@@ -72,8 +72,10 @@ Named scopes: ``attn/qkv``, ``attn/qk_norm``, ``attn/rope``, ``attn/flash``,
 ``attn/out_proj``, ``moe/router``, ``moe/dispatch``, ``moe/experts``,
 ``moe/combine``, ``lm_head``. Counters sown into ``counters`` (the train step
 sums them over the layers): ops/moe.py's ``COUNTERS``, ``moe_rounds`` (the
-rounds the pair buffer took, one a layer unless pairs outgrew it) and
-``masked_targets`` (the rows whose noised id is not the clean one); where a
+rounds the pair buffer took, one a layer unless pairs outgrew it),
+``masked_targets`` (the rows whose noised id is not the clean one) and
+``flash_steps_run`` / ``flash_steps_walked`` (the pairs of blocks a head's
+attention kernel ran and the grid steps it walked for them, a layer); where a
 caller makes ``intermediates`` mutable, each layer's MoE input and choice.
 """
 
@@ -90,11 +92,14 @@ from jax.ad_checkpoint import checkpoint_name
 
 from ..data.tokens import BLK, CLEAN, DOC, NOISED, POS
 from ..ops import moe, remat
-from ..ops.flash import flash_attention_blockdiff
+from ..ops.flash import blockdiff_walk_counts, flash_attention_blockdiff
 from .granite import FLASH_BLOCK, RMSNorm, _dense
 from .nemotron_h import Head, Share
 
 MASK_ROW = 0.01  # what the mask's embedding row is multiplied by as it is read
+# What a layer's attention sows: the (query block, key block) pairs a head's
+# kernel ran, and the grid steps it walked for them (ops/flash.py).
+FLASH_COUNTERS = ("flash_steps_run", "flash_steps_walked")
 
 
 # What the backward pass of a layer keeps beside the layer's input.
@@ -176,6 +181,8 @@ class BlockDiffusionAttention(nn.Module):
         with jax.named_scope("attn/flash"):
             block = math.gcd(rows // 2, FLASH_BLOCK)
             out = flash_attention_blockdiff(q, k, v, doc, blk, 1.0 / math.sqrt(d), block, block)
+            for name, value in zip(FLASH_COUNTERS, blockdiff_walk_counts(doc, blk, block, block)):
+                self.sow("counters", name, value)
         with jax.named_scope("attn/out_proj"):
             out = out.reshape(bsz, self.heads, rows, d).transpose(0, 2, 1, 3)
             return _dense(dim, self.dtype, "o_proj")(out.reshape(bsz, rows, -1))
@@ -274,7 +281,7 @@ class Sdar(nn.Module):
     dtype: Any = jnp.float32
 
     # What its layers and itself sow into ``counters`` (train/steps.py).
-    counters = (*moe.COUNTERS, "moe_rounds", "masked_targets")
+    counters = (*moe.COUNTERS, "moe_rounds", "masked_targets", *FLASH_COUNTERS)
 
     @nn.compact
     def __call__(self, tokens, train: bool = False):

@@ -8,8 +8,11 @@ models' path; its kernels are the second part of this file and say what
 differs). ``flash_attention_blockdiff``: the block-diffusion mask over a clean
 and a noised copy of every packed sequence (models/sdar.py; the third part).
 The two packed families share the three bodies of the recurrence
-(``_fwd_step``, ``_dq_step``, ``_dkv_step``) and differ in which (query, key)
-pairs a body is told to keep and which blocks of scores it is run for.
+(``_fwd_step``, ``_dq_step``, ``_dkv_step``) and the three kernels around
+them, and differ in which (query, key) pairs a body is told to keep and which
+blocks of scores it is run for. Their grids are not the square of blocks: the
+inner axis walks the list of (query block, key block) pairs that run, read
+from scalar prefetch ("the packed families' walk", before the second part).
 
 Attention is computed blockwise so the S x S score matrix never materializes in HBM: for each
 query block the kernel streams key/value blocks through VMEM, carrying the
@@ -18,7 +21,7 @@ innermost grid dimension — the flash-attention recurrence on the hardware
 it was shaped for (MXU matmuls with fp32 accumulators, VPU for the
 exp/max/sum, ~(BLOCK x BLOCK) live scores).
 
-The backward pass is two more Pallas kernels over the same block grid
+The backward pass is two more Pallas kernels over the same blocks
 (recompute-based, flash2-style): residuals are just (o, logsumexp), so
 training memory stays O(S) per head instead of O(S^2).
 
@@ -293,43 +296,104 @@ def _fa_bwd(scale, block_q, block_k, interpret, residuals, g):
 flash_attention.defvjp(_fa_fwd, _fa_bwd)
 
 
-# ------------------------------------------------- causal, packed, grouped
-# Attention of packed documents: a query sees the keys of its own document
-# at or before its own position. Query head ``h`` of ``Hq`` reads key/value
-# head ``h // (Hq / Hkv)``; the kernels never materialise the repeated heads.
+# ------------------------------------------------ the packed families' walk
+# Both packed families below mask most of the square of (query block, key
+# block) pairs whole: the causal order and the documents' extents in one, the
+# block-diffusion rule in the other. Their kernels do not visit that square.
+# Each rule says on the device which pairs run (``_runs``, ``_interval_runs``:
+# a [batch, query blocks, key blocks] matrix), ``_walk`` lists them in the
+# order the square grid met them, and the kernels' inner grid axis is that
+# list: step ``s`` of a batch row reads its pair from scalar prefetch, every
+# BlockSpec's index map reads the same word, and a block of scores that does
+# not run is never fetched. The forward and dq kernels walk query-major (a
+# query block's key blocks ascending), the dkv kernel key-major (a key block's
+# query heads of the group, and each head's query blocks ascending), so a
+# block's pairs are accumulated in the order they always were.
 #
-# A block of scores that the causal order or the documents' extents mask
-# whole is skipped: ``_block_ranges`` gives each block of tokens the smallest
-# and largest segment id it holds, the kernels get them as scalars before the
-# body runs (scalar prefetch), and a (query block, key block) pair runs only
-# where the key block starts at or before the query block's last row and the
-# two ranges of ids overlap. The test is exact for documents stored one after
-# the other, and conservative (never wrong) for any other ids. Every query
-# sees itself, so the diagonal block always runs and no row's sum is empty.
-#
-# Operands go to the MXU in the dtype they come in (bf16 on the chip) and
-# accumulate in float32.
+# A step's word holds the pair and three flags: the pair is the first of its
+# outer block (the accumulators are zeroed), the last (the result is written),
+# a pair at all. **Every query block and every key block has at least one
+# pair** (a row keeps itself under both rules, so the diagonal runs), hence
+# every block of every result is zeroed and written once. The inner axis is as
+# long as the batch's longest list (a traced grid bound: Mosaic and interpret
+# mode both take one), never a guess at the layout; a batch row whose list is
+# shorter repeats its last pair's word without the flags, which fetches
+# nothing new, writes nothing and skips the body. The words' array is as long
+# as the square, which no list can outgrow.
+_FIRST, _LAST, _RUN = 1 << 28, 1 << 29, 1 << 30
+_OUTER_BITS, _INNER_BITS = 12, 16
 
 
-def _block_ranges(segment_ids, block):
-    """(min, max) of the segment ids in each block of ``block`` tokens, each
-    flattened to [B * T / block] int32."""
-    blocks = segment_ids.astype(jnp.int32).reshape(segment_ids.shape[0], -1, block)
-    return blocks.min(axis=2).reshape(-1), blocks.max(axis=2).reshape(-1)
+def _walk(pairs):
+    """``pairs`` [B, outer, inner] bool -> (words [B * outer * inner] int32,
+    the pairs of each batch row [B] int32). Word ``b * outer * inner + s`` is
+    the ``s``-th true entry of ``pairs[b]`` in row-major order, as ``outer <<
+    16 | inner`` under the three flags; from the row's count on, the last
+    one's without flags."""
+    bsz, n_outer, n_inner = pairs.shape
+    if n_outer > 1 << _OUTER_BITS or n_inner > 1 << _INNER_BITS:
+        raise ValueError(f"flash attention: a grid of {n_outer} x {n_inner} blocks outgrows the walk's word")
+    n = n_outer * n_inner
+    rank = jnp.cumsum(pairs.reshape(bsz, n), axis=1, dtype=jnp.int32)
+    n_run = rank[:, -1]
+    s = jnp.arange(n, dtype=jnp.int32)
+    # Where the (s + 1)-th pair lies in the square: the entries ranked s or lower.
+    at = jax.vmap(functools.partial(jnp.searchsorted, side="right", method="compare_all"))(
+        rank, jnp.minimum(s, n_run[:, None] - 1)
+    ).astype(jnp.int32)
+    outer, run = at // n_inner, s < n_run[:, None]
+    first = run & ((s == 0) | (outer != jnp.roll(outer, 1, axis=1)))
+    last = run & ((s == n_run[:, None] - 1) | (outer != jnp.roll(outer, -1, axis=1)))
+    words = outer << _INNER_BITS | at % n_inner | first * _FIRST | last * _LAST | run * _RUN
+    return words.reshape(-1), n_run
 
 
-def _runs(qmin, qmax, kmin, kmax, batch, qi, ki, nq, nk, block_q, block_k):
-    q, k = batch * nq + qi, batch * nk + ki
-    causal = ki * block_k <= qi * block_q + (block_q - 1)
-    return causal & (kmax[k] >= qmin[q]) & (kmin[k] <= qmax[q])
+def _pair(word):
+    return (word >> _INNER_BITS) & ((1 << _OUTER_BITS) - 1), word & ((1 << _INNER_BITS) - 1)
 
 
-def _keep(qseg_ref, kseg_ref, qi, ki, block_q, block_k):
-    """[Bq, Bk]: same document, key at or before the query."""
-    shape = (block_q, block_k)
-    qpos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
-    kpos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
-    return (qseg_ref[0] == kseg_ref[0]) & (qpos >= kpos)
+def _reader(rows, n):
+    """``(b, s, walk) -> word`` of grid step ``s`` of kernel row ``b``, where
+    ``rows`` kernel rows share a batch row's ``n`` words."""
+    return lambda b, s, walk: walk[(b // rows) * n + s]
+
+
+def _query_major(heads, group, n, block_q, block_k, d):
+    """For the forward and dq kernels, whose rows are query heads and whose
+    words hold (query block, key block): ``at(b, s, walk) -> (batch row, qi,
+    ki)`` of a grid step, and the BlockSpecs of the operands that both
+    families have: a query block's [Bq, d] and [Bq, 1], a key/value block of
+    the head's group."""
+    word = _reader(heads, n)
+    at = lambda b, s, w: (b // heads, *_pair(word(b, s, w)))
+    q_at = lambda b, s, w: (b, at(b, s, w)[1], 0)
+    q_rows, q_col = pl.BlockSpec((1, block_q, d), q_at), pl.BlockSpec((1, block_q, 1), q_at)
+    kv = pl.BlockSpec((1, block_k, d), lambda b, s, w: (b // group, at(b, s, w)[2], 0))
+    return at, q_rows, q_col, kv
+
+
+def _key_major(kv_heads, group, nq, n, block_q, block_k, d):
+    """The same for the dkv kernel, whose rows are key/value heads and whose
+    words hold (key block, ``j``): query head ``b * group + j // nq`` (the
+    batch-major layouts make that the right batch too), query block ``j % nq``."""
+    word = _reader(kv_heads, n)
+
+    def at(b, s, w):
+        ki, j = _pair(word(b, s, w))
+        return b // kv_heads, j % nq, ki
+
+    def q_at(b, s, w):
+        j = _pair(word(b, s, w))[1]
+        return b * group + j // nq, j % nq, 0
+
+    q_rows, q_col = pl.BlockSpec((1, block_q, d), q_at), pl.BlockSpec((1, block_q, 1), q_at)
+    kv = pl.BlockSpec((1, block_k, d), lambda b, s, w: (b, at(b, s, w)[2], 0))
+    return at, q_rows, q_col, kv
+
+
+def _by_key(pairs, group):
+    """[B, nq, nk] -> [B, nk, group * nq]: the dkv kernel's square."""
+    return jnp.tile(pairs.transpose(0, 2, 1), (1, 1, group))
 
 
 def _fwd_step(keep, q_ref, k_ref, v_ref, acc, m, l, scale):
@@ -363,82 +427,128 @@ def _dkv_step(keep, q_ref, k_ref, v_ref, do_ref, lse_ref, drow_ref, dk_acc, dv_a
     dk_acc[:] = dk_acc[:] + _dot_t0(ds.astype(q.dtype), q)
 
 
-def _causal_fwd_kernel(qmin, qmax, kmin, kmax, q_ref, k_ref, v_ref, qseg_ref,
-                       kseg_ref, o_ref, lse_ref, acc, m, l, *, scale, heads,
-                       block_q, block_k):
-    bh, qi, ki = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    nq, nk = pl.num_programs(1), pl.num_programs(2)
+def _walk_fwd_kernel(walk, q_ref, k_ref, v_ref, a_ref, b_ref, o_ref, lse_ref, acc, m, l, *,
+                     keep, scale, heads, n):
+    """``keep(a_ref, b_ref, qi, ki)`` [Bq, Bk] is the family's rule, ``a_ref``
+    and ``b_ref`` the two operands it reads."""
+    word = _reader(heads, n)(pl.program_id(0), pl.program_id(1), walk)
 
-    @pl.when(ki == 0)
+    @pl.when(word & _FIRST != 0)
     def _():
         acc[:] = jnp.zeros_like(acc)
         m[:] = jnp.full_like(m, -jnp.inf)
         l[:] = jnp.zeros_like(l)
 
-    @pl.when(_runs(qmin, qmax, kmin, kmax, bh // heads, qi, ki, nq, nk,
-                   block_q, block_k))
+    @pl.when(word & _RUN != 0)
     def _():
-        keep = _keep(qseg_ref, kseg_ref, qi, ki, block_q, block_k)
-        _fwd_step(keep, q_ref, k_ref, v_ref, acc, m, l, scale)
+        _fwd_step(keep(a_ref, b_ref, *_pair(word)), q_ref, k_ref, v_ref, acc, m, l, scale)
 
-    @pl.when(ki == nk - 1)
+    @pl.when(word & _LAST != 0)
     def _():
         o_ref[0] = (acc[:] / l[:]).astype(o_ref.dtype)
         lse_ref[0] = m[:] + jnp.log(l[:])
 
 
-def _causal_dq_kernel(qmin, qmax, kmin, kmax, q_ref, k_ref, v_ref, qseg_ref,
-                      kseg_ref, do_ref, lse_ref, drow_ref, dq_ref, dq_acc, *,
-                      scale, heads, block_q, block_k):
-    bh, qi, ki = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    nq, nk = pl.num_programs(1), pl.num_programs(2)
+def _walk_dq_kernel(walk, q_ref, k_ref, v_ref, a_ref, b_ref, do_ref, lse_ref, drow_ref, dq_ref,
+                    dq_acc, *, keep, scale, heads, n):
+    word = _reader(heads, n)(pl.program_id(0), pl.program_id(1), walk)
 
-    @pl.when(ki == 0)
+    @pl.when(word & _FIRST != 0)
     def _():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    @pl.when(_runs(qmin, qmax, kmin, kmax, bh // heads, qi, ki, nq, nk,
-                   block_q, block_k))
+    @pl.when(word & _RUN != 0)
     def _():
-        keep = _keep(qseg_ref, kseg_ref, qi, ki, block_q, block_k)
-        _dq_step(keep, q_ref, k_ref, v_ref, do_ref, lse_ref, drow_ref, dq_acc, scale)
+        _dq_step(keep(a_ref, b_ref, *_pair(word)), q_ref, k_ref, v_ref, do_ref, lse_ref, drow_ref,
+                 dq_acc, scale)
 
-    @pl.when(ki == nk - 1)
+    @pl.when(word & _LAST != 0)
     def _():
         dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
 
 
-def _causal_dkv_kernel(qmin, qmax, kmin, kmax, q_ref, k_ref, v_ref, qseg_ref,
-                       kseg_ref, do_ref, lse_ref, drow_ref, dk_ref, dv_ref,
-                       dk_acc, dv_acc, *, scale, kv_heads, nq, block_q,
-                       block_k):
-    # The innermost axis walks the group's query heads, and each head's
-    # query blocks: they all add into this key block's dk and dv.
-    bh, ki, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    nk, last = pl.num_programs(1), pl.num_programs(2) - 1
-    qi = j % nq
+def _walk_dkv_kernel(walk, q_ref, k_ref, v_ref, a_ref, b_ref, do_ref, lse_ref, drow_ref, dk_ref,
+                     dv_ref, dk_acc, dv_acc, *, keep, scale, kv_heads, nq, n):
+    # A key block's pairs: the group's query heads, and each head's query
+    # blocks; they all add into this key block's dk and dv.
+    word = _reader(kv_heads, n)(pl.program_id(0), pl.program_id(1), walk)
 
-    @pl.when(j == 0)
+    @pl.when(word & _FIRST != 0)
     def _():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    @pl.when(_runs(qmin, qmax, kmin, kmax, bh // kv_heads, qi, ki, nq, nk,
-                   block_q, block_k))
+    @pl.when(word & _RUN != 0)
     def _():
-        keep = _keep(qseg_ref, kseg_ref, qi, ki, block_q, block_k)
-        _dkv_step(keep, q_ref, k_ref, v_ref, do_ref, lse_ref, drow_ref, dk_acc, dv_acc, scale)
+        ki, j = _pair(word)
+        _dkv_step(keep(a_ref, b_ref, j % nq, ki), q_ref, k_ref, v_ref, do_ref, lse_ref, drow_ref,
+                  dk_acc, dv_acc, scale)
 
-    @pl.when(j == last)
+    @pl.when(word & _LAST != 0)
     def _():
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
+# ------------------------------------------------- causal, packed, grouped
+# Attention of packed documents: a query sees the keys of its own document
+# at or before its own position. Query head ``h`` of ``Hq`` reads key/value
+# head ``h // (Hq / Hkv)``; the kernels never materialise the repeated heads.
+#
+# Which pairs run: ``_block_ranges`` gives each block of tokens the smallest
+# and largest segment id it holds, and a (query block, key block) pair runs
+# only where the key block starts at or before the query block's last row and
+# the two ranges of ids overlap (``_runs``). The test is exact for documents
+# stored one after the other, and conservative (never wrong) for any other
+# ids. Every query sees itself, so the diagonal pair always runs: no row's sum
+# is empty, and every query block and every key block is in the walk.
+#
+# Operands go to the MXU in the dtype they come in (bf16 on the chip) and
+# accumulate in float32.
+
+
+def _block_ranges(segment_ids, block):
+    """(min, max) [B, T / block] int32 of the segment ids in each block of
+    ``block`` tokens."""
+    blocks = segment_ids.astype(jnp.int32).reshape(segment_ids.shape[0], -1, block)
+    return blocks.min(axis=2), blocks.max(axis=2)
+
+
+def _runs(qmin, qmax, kmin, kmax, block_q, block_k):
+    """[B, nq, nk]: the pairs of blocks that run."""
+    qi = jnp.arange(qmin.shape[1], dtype=jnp.int32)[:, None]
+    ki = jnp.arange(kmin.shape[1], dtype=jnp.int32)[None, :]
+    causal = ki * block_k <= qi * block_q + (block_q - 1)
+    return causal & (kmax[:, None, :] >= qmin[:, :, None]) & (kmin[:, None, :] <= qmax[:, :, None])
+
+
+def _keep(qseg_ref, kseg_ref, qi, ki, block_q, block_k):
+    """[Bq, Bk]: same document, key at or before the query."""
+    shape = (block_q, block_k)
+    qpos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    kpos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    return (qseg_ref[0] == kseg_ref[0]) & (qpos >= kpos)
+
+
+def _segment_specs(at, block_q, block_k):
+    """BlockSpecs of the segment ids as ``_keep`` takes them: the query
+    block's as a column, the key block's as a row."""
+
+    def column(b, s, w):
+        row, qi, _ = at(b, s, w)
+        return row, qi, 0
+
+    def line(b, s, w):
+        row, _, ki = at(b, s, w)
+        return row, 0, ki
+
+    return [pl.BlockSpec((1, block_q, 1), column), pl.BlockSpec((1, 1, block_k), line)]
+
+
 def _causal_setup(q, k, segment_ids, block_q, block_k):
-    """Checks the shapes; returns (batch, heads, kv_heads, group, nq, nk),
-    the segment ids as the kernels' two tiles take them, and the blocks'
-    ranges for the scalar prefetch."""
+    """Checks the shapes; returns (heads, kv_heads, group, nq, nk), the
+    segment ids as the kernels' two tiles take them, and the pairs of blocks
+    that run [B, nq, nk]."""
     (bh, s_len, _), bsz = q.shape, segment_ids.shape[0]
     if segment_ids.shape != (bsz, s_len) or bh % bsz or k.shape[0] % bsz:
         raise ValueError(
@@ -457,9 +567,9 @@ def _causal_setup(q, k, segment_ids, block_q, block_k):
             f"block_q={block_q} and block_k={block_k}"
         )
     seg = segment_ids.astype(jnp.int32)
-    ranges = _block_ranges(seg, block_q) + _block_ranges(seg, block_k)
-    dims = (bsz, heads, kv_heads, heads // kv_heads, s_len // block_q, s_len // block_k)
-    return dims, seg[:, :, None], seg[:, None, :], ranges
+    pairs = _runs(*_block_ranges(seg, block_q), *_block_ranges(seg, block_k), block_q, block_k)
+    dims = (heads, kv_heads, heads // kv_heads, s_len // block_q, s_len // block_k)
+    return dims, seg[:, :, None], seg[:, None, :], pairs
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
@@ -485,27 +595,18 @@ def flash_attention_causal(
 def _fac_fwd(q, k, v, segment_ids, scale, block_q, block_k, interpret):
     if interpret is None:
         interpret = _use_interpret()
-    (_, heads, _, group, nq, nk), qseg, kseg, ranges = _causal_setup(
-        q, k, segment_ids, block_q, block_k
-    )
+    (heads, _, group, nq, nk), qseg, kseg, pairs = _causal_setup(q, k, segment_ids, block_q, block_k)
     bh, s_len, d = q.shape
+    walk, n_run = _walk(pairs)
+    at, q_rows, q_col, kv = _query_major(heads, group, nq * nk, block_q, block_k, d)
     o, lse = pl.pallas_call(
-        functools.partial(_causal_fwd_kernel, scale=scale, heads=heads,
-                          block_q=block_q, block_k=block_k),
+        functools.partial(_walk_fwd_kernel, scale=scale, heads=heads, n=nq * nk,
+                          keep=functools.partial(_keep, block_q=block_q, block_k=block_k)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
-            grid=(bh, nq, nk),
-            in_specs=[
-                pl.BlockSpec((1, block_q, d), lambda b, qi, ki, *_: (b, qi, 0)),
-                pl.BlockSpec((1, block_k, d), lambda b, qi, ki, *_: (b // group, ki, 0)),
-                pl.BlockSpec((1, block_k, d), lambda b, qi, ki, *_: (b // group, ki, 0)),
-                pl.BlockSpec((1, block_q, 1), lambda b, qi, ki, *_: (b // heads, qi, 0)),
-                pl.BlockSpec((1, 1, block_k), lambda b, qi, ki, *_: (b // heads, 0, ki)),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, block_q, d), lambda b, qi, ki, *_: (b, qi, 0)),
-                pl.BlockSpec((1, block_q, 1), lambda b, qi, ki, *_: (b, qi, 0)),
-            ],
+            num_scalar_prefetch=1,
+            grid=(bh, n_run.max()),
+            in_specs=[q_rows, kv, kv, *_segment_specs(at, block_q, block_k)],
+            out_specs=[q_rows, q_col],
             scratch_shapes=[
                 pltpu.VMEM((block_q, d), jnp.float32),
                 pltpu.VMEM((block_q, 1), jnp.float32),
@@ -518,7 +619,7 @@ def _fac_fwd(q, k, v, segment_ids, scale, block_q, block_k, interpret):
         ],
         interpret=interpret,
         name="flash_causal_fwd",
-    )(*ranges, q, k, v, qseg, kseg)
+    )(walk, q, k, v, qseg, kseg)
     return o, (q, k, v, segment_ids, o, lse)
 
 
@@ -526,60 +627,40 @@ def _fac_bwd(scale, block_q, block_k, interpret, residuals, g):
     if interpret is None:
         interpret = _use_interpret()
     q, k, v, segment_ids, o, lse = residuals
-    (_, heads, kv_heads, group, nq, nk), qseg, kseg, ranges = _causal_setup(
+    (heads, kv_heads, group, nq, nk), qseg, kseg, pairs = _causal_setup(
         q, k, segment_ids, block_q, block_k
     )
-    bh, _, d = q.shape
+    d = q.shape[2]
+    keep = functools.partial(_keep, block_q=block_q, block_k=block_k)
     drow = jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32), axis=-1,
                    keepdims=True)
 
+    walk, n_run = _walk(pairs)
+    at, q_rows, q_col, kv = _query_major(heads, group, nq * nk, block_q, block_k, d)
     dq = pl.pallas_call(
-        functools.partial(_causal_dq_kernel, scale=scale, heads=heads,
-                          block_q=block_q, block_k=block_k),
+        functools.partial(_walk_dq_kernel, scale=scale, heads=heads, n=nq * nk, keep=keep),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
-            grid=(bh, nq, nk),
-            in_specs=[
-                pl.BlockSpec((1, block_q, d), lambda b, qi, ki, *_: (b, qi, 0)),
-                pl.BlockSpec((1, block_k, d), lambda b, qi, ki, *_: (b // group, ki, 0)),
-                pl.BlockSpec((1, block_k, d), lambda b, qi, ki, *_: (b // group, ki, 0)),
-                pl.BlockSpec((1, block_q, 1), lambda b, qi, ki, *_: (b // heads, qi, 0)),
-                pl.BlockSpec((1, 1, block_k), lambda b, qi, ki, *_: (b // heads, 0, ki)),
-                pl.BlockSpec((1, block_q, d), lambda b, qi, ki, *_: (b, qi, 0)),
-                pl.BlockSpec((1, block_q, 1), lambda b, qi, ki, *_: (b, qi, 0)),
-                pl.BlockSpec((1, block_q, 1), lambda b, qi, ki, *_: (b, qi, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, block_q, d), lambda b, qi, ki, *_: (b, qi, 0)),
+            num_scalar_prefetch=1,
+            grid=(q.shape[0], n_run.max()),
+            in_specs=[q_rows, kv, kv, *_segment_specs(at, block_q, block_k), q_rows, q_col, q_col],
+            out_specs=q_rows,
             scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
         name="flash_causal_dq",
-    )(*ranges, q, k, v, qseg, kseg, g, lse, drow)
+    )(walk, q, k, v, qseg, kseg, g, lse, drow)
 
-    # Rows of q for key/value row ``b``: head ``b * group + j // nq`` (the
-    # batch-major layouts make that the right batch too), block ``j % nq``.
-    q_row = lambda b, ki, j, *_: (b * group + j // nq, j % nq, 0)
+    n = group * nq * nk
+    walk, n_run = _walk(_by_key(pairs, group))
+    at, q_rows, q_col, kv = _key_major(kv_heads, group, nq, n, block_q, block_k, d)
     dk, dv = pl.pallas_call(
-        functools.partial(_causal_dkv_kernel, scale=scale, kv_heads=kv_heads,
-                          nq=nq, block_q=block_q, block_k=block_k),
+        functools.partial(_walk_dkv_kernel, scale=scale, kv_heads=kv_heads, nq=nq, n=n, keep=keep),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
-            grid=(k.shape[0], nk, group * nq),
-            in_specs=[
-                pl.BlockSpec((1, block_q, d), q_row),
-                pl.BlockSpec((1, block_k, d), lambda b, ki, j, *_: (b, ki, 0)),
-                pl.BlockSpec((1, block_k, d), lambda b, ki, j, *_: (b, ki, 0)),
-                pl.BlockSpec((1, block_q, 1), lambda b, ki, j, *_: (b // kv_heads, j % nq, 0)),
-                pl.BlockSpec((1, 1, block_k), lambda b, ki, j, *_: (b // kv_heads, 0, ki)),
-                pl.BlockSpec((1, block_q, d), q_row),
-                pl.BlockSpec((1, block_q, 1), q_row),
-                pl.BlockSpec((1, block_q, 1), q_row),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, block_k, d), lambda b, ki, j, *_: (b, ki, 0)),
-                pl.BlockSpec((1, block_k, d), lambda b, ki, j, *_: (b, ki, 0)),
-            ],
+            num_scalar_prefetch=1,
+            grid=(k.shape[0], n_run.max()),
+            in_specs=[q_rows, kv, kv, *_segment_specs(at, block_q, block_k), q_rows, q_col, q_col],
+            out_specs=[kv, kv],
             scratch_shapes=[
                 pltpu.VMEM((block_k, d), jnp.float32),
                 pltpu.VMEM((block_k, d), jnp.float32),
@@ -591,7 +672,7 @@ def _fac_bwd(scale, block_q, block_k, interpret, residuals, g):
         ],
         interpret=interpret,
         name="flash_causal_dkv",
-    )(*ranges, q, k, v, qseg, kseg, g, lse, drow)
+    )(walk, q, k, v, qseg, kseg, g, lse, drow)
     return dq, dk, dv, None
 
 
@@ -616,13 +697,20 @@ flash_attention_causal.defvjp(_fac_fwd, _fac_bwd)
 # keys is one interval of key rows, and of the noised keys another:
 # ``_blockdiff_bounds`` gives each query row the two (lo, hi), the kernels get
 # the pair that belongs to the key block's half through the index map, and
-# ``_interval_keep`` is two comparisons. A block of scores runs where the
-# smallest lo and the largest hi of the query block's rows span the key block
-# (scalar prefetch, as the causal family): in the clean-onto-noised quadrant
-# nothing runs, in the noised-onto-noised one the diagonal and, where a block
-# of tokens lies across a kernel block's edge, its neighbour.
+# ``_interval_keep`` is two comparisons.
 #
-# The three bodies are the causal family's.
+# Which pairs run: those whose key block lies between the smallest lo and the
+# largest hi of the query block's rows, in the key block's half
+# (``_interval_runs``). In the clean-onto-noised quadrant nothing runs, in the
+# noised-onto-noised one the diagonal and, where a block of tokens lies across
+# a kernel block's edge, its neighbour; but with one block of tokens as long
+# as its document that quadrant runs whole, and so can the other two: no
+# length short of three quarters of the square is safe for every ``doc`` and
+# ``blk``, which is why the walk's length is the lists' own. A clean query
+# block runs its own clean key block and a noised one its own noised key
+# block, so every query block and every key block is in the walk.
+#
+# The three bodies and the three kernels around them are both families'.
 
 
 def _blockdiff_bounds(doc, blk):
@@ -659,11 +747,14 @@ def _blockdiff_bounds(doc, blk):
     return lo[..., None], hi[..., None]
 
 
-def _interval_runs(lo_blk, hi_blk, batch, qi, ki, nq, nk):
-    """Whether key block ``ki`` lies inside what query block ``qi`` spans of
-    its half. ``lo_blk``, ``hi_blk``: [B * 2 * nq] key blocks."""
-    i = (batch * 2 + ki // (nk // 2)) * nq + qi
-    return (lo_blk[i] <= ki) & (ki <= hi_blk[i])
+def _interval_runs(lo_blk, hi_blk, nk):
+    """[B, nq, nk]: whether key block ``ki`` lies inside what query block
+    ``qi`` spans of the key block's half. ``lo_blk``, ``hi_blk``: [B, 2, nq]
+    key blocks, the clean half's and the noised half's."""
+    bsz, _, nq = lo_blk.shape
+    ki = jnp.arange(nk, dtype=jnp.int32).reshape(2, 1, nk // 2)
+    runs = (lo_blk[..., None] <= ki) & (ki <= hi_blk[..., None])  # [B, 2, nq, nk / 2]
+    return runs.transpose(0, 2, 1, 3).reshape(bsz, nq, nk)
 
 
 def _interval_keep(lo_ref, hi_ref, ki, block_q, block_k):
@@ -672,78 +763,46 @@ def _interval_keep(lo_ref, hi_ref, ki, block_q, block_k):
     return (kpos >= lo_ref[0, 0]) & (kpos <= hi_ref[0, 0])
 
 
-def _blockdiff_fwd_kernel(lo_blk, hi_blk, q_ref, k_ref, v_ref, lo_ref, hi_ref,
-                          o_ref, lse_ref, acc, m, l, *, scale, heads, block_q,
-                          block_k):
-    bh, qi, ki = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    nq, nk = pl.num_programs(1), pl.num_programs(2)
+def _bound_specs(at, half, block_q):
+    """BlockSpecs of (lo, hi) as ``_interval_keep`` takes them: the query
+    block's rows' bounds in the key block's half."""
 
-    @pl.when(ki == 0)
-    def _():
-        acc[:] = jnp.zeros_like(acc)
-        m[:] = jnp.full_like(m, -jnp.inf)
-        l[:] = jnp.zeros_like(l)
+    def bound(b, s, w):
+        row, qi, ki = at(b, s, w)
+        return row, ki // half, qi, 0
 
-    @pl.when(_interval_runs(lo_blk, hi_blk, bh // heads, qi, ki, nq, nk))
-    def _():
-        keep = _interval_keep(lo_ref, hi_ref, ki, block_q, block_k)
-        _fwd_step(keep, q_ref, k_ref, v_ref, acc, m, l, scale)
-
-    @pl.when(ki == nk - 1)
-    def _():
-        o_ref[0] = (acc[:] / l[:]).astype(o_ref.dtype)
-        lse_ref[0] = m[:] + jnp.log(l[:])
+    return [pl.BlockSpec((1, 1, block_q, 1), bound)] * 2
 
 
-def _blockdiff_dq_kernel(lo_blk, hi_blk, q_ref, k_ref, v_ref, lo_ref, hi_ref,
-                         do_ref, lse_ref, drow_ref, dq_ref, dq_acc, *, scale,
-                         heads, block_q, block_k):
-    bh, qi, ki = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    nq, nk = pl.num_programs(1), pl.num_programs(2)
-
-    @pl.when(ki == 0)
-    def _():
-        dq_acc[:] = jnp.zeros_like(dq_acc)
-
-    @pl.when(_interval_runs(lo_blk, hi_blk, bh // heads, qi, ki, nq, nk))
-    def _():
-        keep = _interval_keep(lo_ref, hi_ref, ki, block_q, block_k)
-        _dq_step(keep, q_ref, k_ref, v_ref, do_ref, lse_ref, drow_ref, dq_acc, scale)
-
-    @pl.when(ki == nk - 1)
-    def _():
-        dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
+def _blockdiff_pairs(doc, blk, block_q, block_k):
+    """The rows' bounds (lo, hi) and the pairs of blocks that run [B, nq, nk]."""
+    bsz, t = doc.shape
+    if blk.shape != doc.shape or t % block_q or t % block_k:
+        raise ValueError(
+            f"flash_attention_blockdiff: doc {doc.shape} and blk {blk.shape} must be alike, "
+            f"and seq {t} a multiple of block_q={block_q} and block_k={block_k}"
+        )
+    lo, hi = _blockdiff_bounds(doc, blk)
+    nq = 2 * t // block_q
+    lo_blk = lo.reshape(bsz, 2, nq, block_q).min(axis=3) // block_k
+    hi_blk = hi.reshape(bsz, 2, nq, block_q).max(axis=3) // block_k
+    return lo, hi, _interval_runs(lo_blk, hi_blk, 2 * t // block_k)
 
 
-def _blockdiff_dkv_kernel(lo_blk, hi_blk, q_ref, k_ref, v_ref, lo_ref, hi_ref,
-                          do_ref, lse_ref, drow_ref, dk_ref, dv_ref, dk_acc,
-                          dv_acc, *, scale, kv_heads, nq, block_q, block_k):
-    # The innermost axis as in the causal family: the group's query heads,
-    # and each head's query blocks.
-    bh, ki, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    nk, last = pl.num_programs(1), pl.num_programs(2) - 1
-
-    @pl.when(j == 0)
-    def _():
-        dk_acc[:] = jnp.zeros_like(dk_acc)
-        dv_acc[:] = jnp.zeros_like(dv_acc)
-
-    @pl.when(_interval_runs(lo_blk, hi_blk, bh // kv_heads, j % nq, ki, nq, nk))
-    def _():
-        keep = _interval_keep(lo_ref, hi_ref, ki, block_q, block_k)
-        _dkv_step(keep, q_ref, k_ref, v_ref, do_ref, lse_ref, drow_ref, dk_acc, dv_acc, scale)
-
-    @pl.when(j == last)
-    def _():
-        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+def blockdiff_walk_counts(doc, blk, block_q: int = 512, block_k: int = 512):
+    """(pairs run, steps walked) int32 of ``flash_attention_blockdiff`` on
+    this batch, a query head and kernel: the (query block, key block) pairs
+    its rule runs, and the grid steps of the walk, every batch row as long as
+    the longest. The dkv kernel's are these times the group's heads."""
+    n_run = _blockdiff_pairs(doc, blk, block_q, block_k)[2].sum(axis=(1, 2), dtype=jnp.int32)
+    return n_run.sum(), n_run.shape[0] * n_run.max()
 
 
 def _blockdiff_setup(q, k, doc, blk, block_q, block_k):
     """Checks the shapes; returns (heads, kv_heads, group, nq, nk), the rows'
-    bounds and the query blocks' spans for the scalar prefetch."""
+    bounds and the pairs of blocks that run."""
     (bh, rows, _), (bsz, t) = q.shape, doc.shape
-    if rows != 2 * t or blk.shape != doc.shape or bh % bsz or k.shape[0] % bsz:
+    if rows != 2 * t or bh % bsz or k.shape[0] % bsz:
         raise ValueError(
             f"flash_attention_blockdiff: q {q.shape} and k {k.shape} are not the clean and "
             f"the noised copy of doc {doc.shape}, blk {blk.shape}"
@@ -754,17 +813,8 @@ def _blockdiff_setup(q, k, doc, blk, block_q, block_k):
             f"flash_attention_blockdiff: {heads} query heads are not a multiple "
             f"of {kv_heads} key/value heads"
         )
-    if t % block_q or t % block_k:
-        raise ValueError(
-            f"flash_attention_blockdiff: seq {t} must be a multiple of "
-            f"block_q={block_q} and block_k={block_k}"
-        )
-    lo, hi = _blockdiff_bounds(doc, blk)
-    nq, nk = rows // block_q, rows // block_k
-    lo_blk = lo.reshape(bsz, 2, nq, block_q).min(axis=3) // block_k
-    hi_blk = hi.reshape(bsz, 2, nq, block_q).max(axis=3) // block_k
-    dims = (heads, kv_heads, heads // kv_heads, nq, nk)
-    return dims, lo, hi, (lo_blk.reshape(-1), hi_blk.reshape(-1))
+    lo, hi, pairs = _blockdiff_pairs(doc, blk, block_q, block_k)
+    return (heads, kv_heads, heads // kv_heads, rows // block_q, rows // block_k), lo, hi, pairs
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
@@ -792,27 +842,20 @@ def flash_attention_blockdiff(
 def _fab_fwd(q, k, v, doc, blk, scale, block_q, block_k, interpret):
     if interpret is None:
         interpret = _use_interpret()
-    (heads, _, group, nq, nk), lo, hi, spans = _blockdiff_setup(q, k, doc, blk, block_q, block_k)
+    (heads, _, group, nq, nk), lo, hi, pairs = _blockdiff_setup(q, k, doc, blk, block_q, block_k)
     bh, rows, d = q.shape
-    half = nk // 2
-    bound = pl.BlockSpec((1, 1, block_q, 1), lambda b, qi, ki, *_: (b // heads, ki // half, qi, 0))
+    walk, n_run = _walk(pairs)
+    at, q_rows, q_col, kv = _query_major(heads, group, nq * nk, block_q, block_k, d)
     o, lse = pl.pallas_call(
-        functools.partial(_blockdiff_fwd_kernel, scale=scale, heads=heads,
-                          block_q=block_q, block_k=block_k),
+        functools.partial(
+            _walk_fwd_kernel, scale=scale, heads=heads, n=nq * nk,
+            keep=lambda lo, hi, qi, ki: _interval_keep(lo, hi, ki, block_q, block_k),
+        ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(bh, nq, nk),
-            in_specs=[
-                pl.BlockSpec((1, block_q, d), lambda b, qi, ki, *_: (b, qi, 0)),
-                pl.BlockSpec((1, block_k, d), lambda b, qi, ki, *_: (b // group, ki, 0)),
-                pl.BlockSpec((1, block_k, d), lambda b, qi, ki, *_: (b // group, ki, 0)),
-                bound,
-                bound,
-            ],
-            out_specs=[
-                pl.BlockSpec((1, block_q, d), lambda b, qi, ki, *_: (b, qi, 0)),
-                pl.BlockSpec((1, block_q, 1), lambda b, qi, ki, *_: (b, qi, 0)),
-            ],
+            num_scalar_prefetch=1,
+            grid=(bh, n_run.max()),
+            in_specs=[q_rows, kv, kv, *_bound_specs(at, nk // 2, block_q)],
+            out_specs=[q_rows, q_col],
             scratch_shapes=[
                 pltpu.VMEM((block_q, d), jnp.float32),
                 pltpu.VMEM((block_q, 1), jnp.float32),
@@ -825,7 +868,7 @@ def _fab_fwd(q, k, v, doc, blk, scale, block_q, block_k, interpret):
         ],
         interpret=interpret,
         name="flash_blockdiff_fwd",
-    )(*spans, q, k, v, lo, hi)
+    )(walk, q, k, v, lo, hi)
     return o, (q, k, v, doc, blk, o, lse)
 
 
@@ -833,63 +876,40 @@ def _fab_bwd(scale, block_q, block_k, interpret, residuals, g):
     if interpret is None:
         interpret = _use_interpret()
     q, k, v, doc, blk, o, lse = residuals
-    (heads, kv_heads, group, nq, nk), lo, hi, spans = _blockdiff_setup(
+    (heads, kv_heads, group, nq, nk), lo, hi, pairs = _blockdiff_setup(
         q, k, doc, blk, block_q, block_k
     )
-    bh, _, d = q.shape
-    half = nk // 2
+    d = q.shape[2]
+    keep = lambda lo, hi, qi, ki: _interval_keep(lo, hi, ki, block_q, block_k)
     drow = jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32), axis=-1,
                    keepdims=True)
 
-    bound = pl.BlockSpec((1, 1, block_q, 1), lambda b, qi, ki, *_: (b // heads, ki // half, qi, 0))
+    walk, n_run = _walk(pairs)
+    at, q_rows, q_col, kv = _query_major(heads, group, nq * nk, block_q, block_k, d)
     dq = pl.pallas_call(
-        functools.partial(_blockdiff_dq_kernel, scale=scale, heads=heads,
-                          block_q=block_q, block_k=block_k),
+        functools.partial(_walk_dq_kernel, scale=scale, heads=heads, n=nq * nk, keep=keep),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(bh, nq, nk),
-            in_specs=[
-                pl.BlockSpec((1, block_q, d), lambda b, qi, ki, *_: (b, qi, 0)),
-                pl.BlockSpec((1, block_k, d), lambda b, qi, ki, *_: (b // group, ki, 0)),
-                pl.BlockSpec((1, block_k, d), lambda b, qi, ki, *_: (b // group, ki, 0)),
-                bound,
-                bound,
-                pl.BlockSpec((1, block_q, d), lambda b, qi, ki, *_: (b, qi, 0)),
-                pl.BlockSpec((1, block_q, 1), lambda b, qi, ki, *_: (b, qi, 0)),
-                pl.BlockSpec((1, block_q, 1), lambda b, qi, ki, *_: (b, qi, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, block_q, d), lambda b, qi, ki, *_: (b, qi, 0)),
+            num_scalar_prefetch=1,
+            grid=(q.shape[0], n_run.max()),
+            in_specs=[q_rows, kv, kv, *_bound_specs(at, nk // 2, block_q), q_rows, q_col, q_col],
+            out_specs=q_rows,
             scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
         name="flash_blockdiff_dq",
-    )(*spans, q, k, v, lo, hi, g, lse, drow)
+    )(walk, q, k, v, lo, hi, g, lse, drow)
 
-    q_row = lambda b, ki, j, *_: (b * group + j // nq, j % nq, 0)
-    bound = pl.BlockSpec(
-        (1, 1, block_q, 1), lambda b, ki, j, *_: (b // kv_heads, ki // half, j % nq, 0)
-    )
+    n = group * nq * nk
+    walk, n_run = _walk(_by_key(pairs, group))
+    at, q_rows, q_col, kv = _key_major(kv_heads, group, nq, n, block_q, block_k, d)
     dk, dv = pl.pallas_call(
-        functools.partial(_blockdiff_dkv_kernel, scale=scale, kv_heads=kv_heads,
-                          nq=nq, block_q=block_q, block_k=block_k),
+        functools.partial(_walk_dkv_kernel, scale=scale, kv_heads=kv_heads, nq=nq, n=n, keep=keep),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(k.shape[0], nk, group * nq),
-            in_specs=[
-                pl.BlockSpec((1, block_q, d), q_row),
-                pl.BlockSpec((1, block_k, d), lambda b, ki, j, *_: (b, ki, 0)),
-                pl.BlockSpec((1, block_k, d), lambda b, ki, j, *_: (b, ki, 0)),
-                bound,
-                bound,
-                pl.BlockSpec((1, block_q, d), q_row),
-                pl.BlockSpec((1, block_q, 1), q_row),
-                pl.BlockSpec((1, block_q, 1), q_row),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, block_k, d), lambda b, ki, j, *_: (b, ki, 0)),
-                pl.BlockSpec((1, block_k, d), lambda b, ki, j, *_: (b, ki, 0)),
-            ],
+            num_scalar_prefetch=1,
+            grid=(k.shape[0], n_run.max()),
+            in_specs=[q_rows, kv, kv, *_bound_specs(at, nk // 2, block_q), q_rows, q_col, q_col],
+            out_specs=[kv, kv],
             scratch_shapes=[
                 pltpu.VMEM((block_k, d), jnp.float32),
                 pltpu.VMEM((block_k, d), jnp.float32),
@@ -901,7 +921,7 @@ def _fab_bwd(scale, block_q, block_k, interpret, residuals, g):
         ],
         interpret=interpret,
         name="flash_blockdiff_dkv",
-    )(*spans, q, k, v, lo, hi, g, lse, drow)
+    )(walk, q, k, v, lo, hi, g, lse, drow)
     return dq, dk, dv, None, None
 
 
