@@ -113,13 +113,15 @@ def test_flash_kernel_compiles_for_v5e(
 
 
 # The language-model cell's attention layer: 32 query heads over 8 key/value
-# heads of 64, one packed sequence of 8,192 tokens, blocks of 512.
+# heads of 64, one packed sequence of 8,192 tokens, blocks of 512; and the
+# convolution-hybrid cell's, a tensor-parallel quarter of the same: 8 over 2.
 @pytest.mark.parametrize("backward", [False, True], ids=["forward", "grad"])
-def test_causal_flash_kernel_compiles_for_v5e(one_chip, no_persistent_cache, backward):
+@pytest.mark.parametrize("heads, kv_heads", [(32, 8), (8, 2)])
+def test_causal_flash_kernel_compiles_for_v5e(one_chip, no_persistent_cache, heads, kv_heads, backward):
     q, kv, seg = _placed(
         (
-            jax.ShapeDtypeStruct((32, 8192, 64), jnp.bfloat16),
-            jax.ShapeDtypeStruct((8, 8192, 64), jnp.bfloat16),
+            jax.ShapeDtypeStruct((heads, 8192, 64), jnp.bfloat16),
+            jax.ShapeDtypeStruct((kv_heads, 8192, 64), jnp.bfloat16),
             jax.ShapeDtypeStruct((1, 8192), jnp.int32),
         ),
         one_chip,
@@ -262,13 +264,23 @@ def test_routed_experts_compile_for_v5e_as_grouped_kernels(one_chip, no_persiste
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2**30
 
 
-def test_gated_routed_experts_compile_for_v5e_as_grouped_kernels(one_chip, no_persistent_cache):
-    """One block-diffusion layer's routed part at published widths over
-    16,384 rows, forward and backward: the softmax router's top-8 of 128, the
-    16 experts held with three kernels each, the 26,624-row pair buffer."""
+@pytest.mark.parametrize(
+    "rows, k, experts, held, width, buffer",
+    [(16384, 8, 128, 16, 768, 26624), (8192, 4, 32, 8, 1792, 13312)],
+    ids=["blockdiff", "conv_hybrid"],
+)
+def test_gated_routed_experts_compile_for_v5e_as_grouped_kernels(
+    one_chip, no_persistent_cache, rows, k, experts, held, width, buffer
+):
+    """One routed layer's experts at published widths, forward and backward,
+    three kernels an expert. The block-diffusion cell's: 16,384 rows, top-8
+    of 128, 16 experts of 768 held, a 26,624-row pair buffer. The
+    convolution-hybrid cell's: 8,192 rows, top-4 of 32, 8 experts of 1,792
+    held, 13,312 rows (its sigmoid router's choice is a ``top_k`` like the
+    softmax router's here)."""
     from turboprune_tpu.ops import moe
 
-    rows, k, experts, held, hidden, width = 16384, 8, 128, 16, 2048, 768
+    hidden = 2048
     capacity, tile = moe.pair_capacity(rows, k, experts, held), moe.pair_tile(rows, k, experts)
     z, logits, up, down = _placed(
         (
@@ -288,7 +300,7 @@ def test_gated_routed_experts_compile_for_v5e_as_grouped_kernels(one_chip, no_pe
     with mock.patch.object(moe, "_use_interpret", lambda: False):
         lowered = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4), has_aux=True)).lower(z, logits, up, up, down)
     compiled = lowered.compile()
-    assert (capacity, tile) == (26624, 128)
+    assert (capacity, tile) == (buffer, 128)
     assert compiled.as_text().count("tpu_custom_call") >= 9  # three forward, six backward, and the rounds' own
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2**30
 

@@ -268,9 +268,10 @@ class ModelConfig:
     # published. Image models have one depth and reject the knob.
     num_hidden_layers: int = 0
     # A model built as one chip's share of a deployment
-    # (models/nemotron_h.py, models/sdar.py): the stretch of the published layer pattern that
-    # is run ("" = all of it), over how many chips each layer's heads and the
-    # shared expert's columns (tensor_parallel) and its routed experts
+    # (models/nemotron_h.py, models/sdar.py, models/lfm2.py): the stretch of
+    # the published layer pattern that is run ("" = all of it), over how many
+    # chips each layer's heads, a shared expert's or dense MLP's columns and a
+    # short convolution's channels (tensor_parallel) and its routed experts
     # (expert_parallel) are divided, and which of the latter this chip is.
     layer_pattern: str = ""
     tensor_parallel: int = 1

@@ -13,7 +13,7 @@ from typing import Any, Callable
 
 import jax.numpy as jnp
 
-from . import densenet, granite, nemotron_h, resnet, sdar, vgg, vit
+from . import densenet, granite, lfm2, nemotron_h, resnet, sdar, vgg, vit
 from .densenet import DenseNet
 from .granite import HybridLM
 from .nemotron_h import NemotronH
@@ -53,16 +53,22 @@ MODEL_REGISTRY: dict[str, Callable] = {
     "nemotron_h_tiny": nemotron_h.nemotron_h_tiny,
     "sdar_30b_a3b": sdar.sdar_30b_a3b,
     "sdar_moe_tiny": sdar.sdar_moe_tiny,
+    "lfm2_8b_a1b": lfm2.lfm2_8b_a1b,
+    "lfm2_moe_tiny": lfm2.lfm2_moe_tiny,
 }
 # Models that read packed token batches (data/tokens.py) and return logits
 # over their vocabulary, ``num_classes``.
 LANGUAGE_MODELS = (
     "granite_4_0_h_micro", "hybrid_lm_tiny", "nemotron_3_super_120b_a12b", "nemotron_h_tiny",
-    "sdar_30b_a3b", "sdar_moe_tiny",
+    "sdar_30b_a3b", "sdar_moe_tiny", "lfm2_8b_a1b", "lfm2_moe_tiny",
 )  # fmt: skip
 # Of those, the ones built as one chip's share of a deployment
-# (models/nemotron_h.py, models/sdar.py): they take ``layer_pattern`` and ``share``.
-SHARED_MODELS = ("nemotron_3_super_120b_a12b", "nemotron_h_tiny", "sdar_30b_a3b", "sdar_moe_tiny")
+# (models/nemotron_h.py, models/sdar.py, models/lfm2.py): they take
+# ``layer_pattern`` and ``share``.
+SHARED_MODELS = (
+    "nemotron_3_super_120b_a12b", "nemotron_h_tiny", "sdar_30b_a3b", "sdar_moe_tiny",
+    "lfm2_8b_a1b", "lfm2_moe_tiny",
+)  # fmt: skip
 # And the ones trained by diffusion over blocks (models/sdar.py): their batch
 # is the noised one, ``dataset_params.block_length`` > 0.
 BLOCK_DIFFUSION_MODELS = ("sdar_30b_a3b", "sdar_moe_tiny")
@@ -97,7 +103,7 @@ def create_model(
     ``width_overrides``. ``num_layers`` is a language model's depth (0 = as
     published); it always runs its causal flash kernel, whatever
     ``attention_impl`` says of the ViTs. ``layer_pattern`` and ``share`` are
-    models/nemotron_h.py's and models/sdar.py's: the stretch of the published
+    models/nemotron_h.py's, models/sdar.py's and models/lfm2.py's: the stretch of the published
     pattern that is run, and (tensor_parallel, expert_parallel, expert_rank)
     of the deployment whose one chip this is."""
     if model_name not in MODEL_REGISTRY:

@@ -139,13 +139,15 @@ class Router(nn.Module):
     experts: int
     top_k: int
     scaling: float
+    eps: float = 1e-20  # beside the chosen scores' sum (models/lfm2.py's source has 1e-6)
 
     @nn.compact
     def __call__(self, h32):
         weight = self.param("weight", nn.initializers.normal(0.02), (h32.shape[-1], self.experts))
         bias = self.param("bias", nn.initializers.zeros, (self.experts,))
         logits = jnp.einsum("nd,de->ne", h32, weight, precision=jax.lax.Precision.HIGHEST)
-        return moe.route(checkpoint_name(logits, "router_logits"), bias, self.top_k, self.scaling)
+        logits = checkpoint_name(logits, "router_logits")
+        return moe.route(logits, bias, self.top_k, self.scaling, self.eps)
 
 
 class Experts(nn.Module):

@@ -11,7 +11,7 @@ in for: the result is this chip's part of the layer's sum.
 Two routers give (top, w), both float32 over all experts:
 
     ``route``           s = sigmoid(logits);  top = the top_k largest of s + bias
-                        (bias: no gradient);  w_i = scaling * s_i / (sum_top s + 1e-20)
+                        (bias: no gradient);  w_i = scaling * s_i / (sum_top s + eps)
     ``route_softmax``   p = softmax(logits);  top = the top_k largest of p;
                         w_i = p_i / sum_top p
 
@@ -87,15 +87,15 @@ BETWEEN = {
 }
 
 
-def route(logits: jax.Array, bias: jax.Array, top_k: int, scaling: float):
-    """(top [N, K] int32, weights [N, K] float32) of ``logits`` [N, E]."""
+def route(logits: jax.Array, bias: jax.Array, top_k: int, scaling: float, eps: float = 1e-20):
+    """(top [N, K] int32, weights [N, K] float32) of ``logits`` [N, E]; ``eps`` as the model's source has it."""
     s = jax.nn.sigmoid(logits.astype(jnp.float32))
     _, top = lax.top_k(s + lax.stop_gradient(bias.astype(jnp.float32)), top_k)
     # Before its first use: tagged on return, the gather below still reads
     # the ``top_k``'s own result and a backward pass sorts again.
     top = checkpoint_name(top, "router_top")
     chosen = jnp.take_along_axis(s, top, axis=-1)
-    return top, scaling * chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
+    return top, scaling * chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + eps)
 
 
 def route_softmax(logits: jax.Array, top_k: int):
