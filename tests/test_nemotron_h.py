@@ -321,14 +321,24 @@ def _plain_experts(z, top, weights, up, down, offset):
 
 
 @pytest.mark.parametrize(
-    "capacity, tile, favoured",
-    [(128, 8, None), (16, 8, None), (64, 16, 5), (128, 128, None)],
-    ids=["one_round", "many_rounds", "many_rounds_one_full_expert", "tile_of_128"],
-)
-def test_the_routed_experts_are_a_plain_loop_over_the_experts_held(capacity, tile, favoured):
+    "capacity, tile, favoured, n, latent",
+    [
+        (128, 8, None, 64, 32), (16, 8, None, 64, 32), (64, 16, 5, 64, 32), (128, 128, None, 64, 32),
+        (640, 128, None, 128, 1024), (256, 128, None, 128, 1024),
+    ],
+    ids=[
+        "one_round", "many_rounds", "many_rounds_one_full_expert", "tile_of_128",
+        "row_kernels", "row_kernels_many_rounds",
+    ],
+)  # fmt: skip
+def test_the_routed_experts_are_a_plain_loop_over_the_experts_held(capacity, tile, favoured, n, latent):
     """ops/moe.py alone, experts 4-7 of 16, whatever the buffer's size and
-    however many rounds the pairs take: result, gradients and counters."""
-    n, k, experts, held, offset, latent, width = 64, 4, 16, 4, 4, 32, 48
+    however many rounds the pairs take: result, gradients and counters. At
+    rows of 1,024 float32 values and whole tiles of tokens the rows travel
+    through the two Pallas row kernels (interpreted here), in every round."""
+    k, experts, held, offset, width = 4, 16, 4, 4, 48
+    forms = lambda: [tracing.gauges().get(f"moe_row_{form}_calls", 0) for form in ("kernel", "xla")]
+    before = forms()
     k0, k1, k2, k3 = jax.random.split(jax.random.PRNGKey(3), 4)
     z, logits = jax.random.normal(k0, (n, latent)), jax.random.normal(k1, (n, experts))
     up = 0.2 * jax.random.normal(k2, (held, latent, width))
@@ -354,6 +364,8 @@ def test_the_routed_experts_are_a_plain_loop_over_the_experts_held(capacity, til
         "moe_pairs": load.sum(), "moe_dropped_pairs": 0, "moe_load_max": load.max()
     }  # fmt: skip
     assert favoured is None or load.max() == n
+    kernel_calls, xla_calls = (after - was for after, was in zip(forms(), before))
+    assert (xla_calls == 0 and kernel_calls >= 2) if latent == 1024 else kernel_calls == 0
     _close(out, want, 1e-5)
     for g, w in zip(grads, ref_grads):
         _close(g, w, 1e-4)

@@ -18,6 +18,7 @@ the one that runs it may load the library.
 """
 
 import os
+import re
 import time
 
 from unittest import mock
@@ -252,7 +253,7 @@ def test_routed_experts_compile_for_v5e_as_grouped_kernels(one_chip, no_persiste
     def loss(z, logits, up, down):
         top, weights = moe.route(logits, jnp.zeros(experts), k, 5.0)
         out, counters = moe.routed_experts(z, top, weights, (up, down), 0, capacity, tile)
-        return out.sum(), counters
+        return jnp.sin(out).sum(), counters  # a backward pass that needs the result, as a next layer's does
 
     # On the CPU backend the kernels would be interpreted: compile the chip's.
     with mock.patch.object(moe, "_use_interpret", lambda: False):
@@ -261,6 +262,12 @@ def test_routed_experts_compile_for_v5e_as_grouped_kernels(one_chip, no_persiste
     text = compiled.as_text()
     assert (capacity, tile) == (10496, 128)
     assert text.count("tpu_custom_call") >= 6  # two forward, four backward, and the rounds' own
+    # The combine's float32 rows of 1,024 are added by the row kernel; the
+    # dispatch's bfloat16 rows of 1,024 are half a tile of words, so their
+    # gradients are still added by XLA's scatter.
+    assert "moe_add_rows" in text
+    assert re.search(rf"bf16\[{tokens},{latent}\]\S* scatter\(", text)
+    assert not re.search(rf"f32\[{tokens},{latent}\]\S* scatter\(", text)
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2**30
 
 
@@ -295,13 +302,19 @@ def test_gated_routed_experts_compile_for_v5e_as_grouped_kernels(
     def loss(z, logits, gate, up, down):
         top, weights = moe.route_softmax(logits, k)
         out, counters = moe.routed_experts(z, top, weights, (gate, up, down), 0, capacity, tile)
-        return out.sum(), counters
+        return jnp.sin(out).sum(), counters  # a backward pass that needs the result, as a next layer's does
 
     with mock.patch.object(moe, "_use_interpret", lambda: False):
         lowered = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4), has_aux=True)).lower(z, logits, up, up, down)
     compiled = lowered.compile()
+    text = compiled.as_text()
     assert (capacity, tile) == (buffer, 128)
-    assert compiled.as_text().count("tpu_custom_call") >= 9  # three forward, six backward, and the rounds' own
+    assert text.count("tpu_custom_call") >= 9  # three forward, six backward, and the rounds' own
+    # Rows of 2,048 in both dtypes: the combine's scatter-add and the
+    # gather's transpose are the row kernel, in the first round and in the
+    # loop of further ones, and no scatter of XLA's over the tokens is left.
+    assert text.count("moe_add_rows") >= 4
+    assert not re.search(rf"(?:bf16|f32)\[{rows},{hidden}\]\S* scatter\(", text)
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2**30
 
 

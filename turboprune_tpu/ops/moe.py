@@ -31,18 +31,43 @@ router sends here, and a tile for each expert), every expert's rows starting on 
 multiple of ``tile``, so that a tile of rows belongs to one expert. The two
 products run over the whole buffer as grouped matrix products (the Pallas
 kernels of ``jax.experimental.pallas.ops.tpu.megablox``, a tile of rows
-against its expert's kernel): the rows no pair fills are zeros, add nothing
-to the result or to a gradient, and are computed all the same, so a step
-costs what the configuration says and not what the seed's routing says. No
+against its expert's kernel): the rows no pair fills are zeros (or, where
+``add_rows`` is the kernel, which never visits them, a copy of a token's row
+under a weight of zero), add nothing to the result or to a gradient, and are
+computed all the same, so a step costs what the configuration says and not
+what the seed's routing says. No
 pair is ever dropped: pairs that outgrow the buffer run through the same
 round again, once for each further ``capacity`` rows they fill (at most the
 worst case, every token choosing every held expert), so an imbalance costs
 time and never changes the result. ``moe_dropped_pairs`` is counted, not
 assumed: the pairs routed here less the rows of them handed to the products.
 
-Named scopes: ``moe/dispatch`` (sort, group sizes, the gather of the pairs'
-rows), ``moe/experts`` (the two grouped products and the activation between
-them), ``moe/combine`` (the weighted scatter-add back to the tokens).
+What moves the rows between the token array and the buffer: out of the
+token array, XLA's gather (``take_rows``), which runs at the memory's pace
+(0.18 ms for the block-diffusion cell's 26,624 bfloat16 rows of 2,048); into
+it, whatever their shape allows, decided at trace time
+(``rows_move_in_tiles``). A row that is whole (8, 128) tiles of 32-bit words
+(1,024 float32 values, 2,048 bfloat16 ones, and any multiple) in a buffer of
+``TILE``-row tiles is added by a Pallas kernel (``add_rows``): the
+scatter-add read as a gather, a tile of 128 tokens fetching the rows that add
+to it (from one sort of the buffer's rows by destination, made once a round)
+by one copy each, a chunk of 128 started together and waited together, and
+adding them in float32 in VMEM, so that no row of HBM is read, added to and
+written back and no collision is serialised. The rows no pair fills are never
+visited. Both scatter-adds of a round are that kernel: the combine's, and
+the gather's transpose in a backward pass (accumulated in float32, cast once).
+Such a row travels under a view in which it is whole tiles, which costs XLA
+one pass over the kernel's source (a reshape). Any other shape (the small
+tile, the tiny models' widths, the sparse-expert cell's bfloat16 rows of
+1,024) is added by XLA's scatter-add, row by row, as every shape was before.
+``moe_row_kernel_calls`` and ``moe_row_xla_calls`` (utils/tracing.py) count
+the traced calls of the two functions by the form they took.
+
+Named scopes: ``moe/dispatch`` (sort, group sizes, the sort by destination,
+the gather of the pairs' rows and, in a backward pass, ``add_rows`` of their
+gradients), ``moe/experts`` (the grouped products and the activation between
+them, nothing else), ``moe/combine`` (``add_rows``, the weighted scatter-add
+back to the tokens, and in a backward pass the gather of its cotangent).
 
 Two values are tagged for a ``jax.checkpoint`` policy (ops/remat.py): ``router_top``,
 the experts chosen, in ``route`` before anything reads them, and
@@ -61,8 +86,11 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm, tgmm
 
+from ..utils import tracing
 from .flash import _use_interpret
 
 # What a layer of routed experts counts a step (summed over the layers by
@@ -77,6 +105,9 @@ TILE, SMALL_TILE = 128, 8
 # A product's block of an expert's kernel stays under this in VMEM (twice, for
 # the two buffers a block has).
 KERNEL_BLOCK_BYTES = 3 * 2**20
+# A row travels as 32-bit words, 128 to a lane row; a copy moves whole
+# (8, 128) tiles of them.
+LANES, SUBLANES = 128, 8
 
 
 # What stands between an expert's first kernels' products and its last
@@ -184,6 +215,250 @@ def _grouped_bwd(tile, out_dtype, saved, dy):
 grouped_product.defvjp(_grouped_fwd, _grouped_bwd)
 
 
+# ------------------------------------------------------------- rows in tiles
+# A row copy moves whole (8, 128) tiles of 32-bit words: Mosaic refuses a
+# slice of one row of a tiled [R, L] array, whatever its dtype. So a row
+# travels under a view in which it IS whole tiles, ``[R * L / 128, 128]``,
+# its ``L / 128`` lane rows a multiple of 8 (float32) or 16 (bfloat16, whose
+# lane rows lie in pairs, 2k and 2k + 1 in the halves of one row of words).
+# The view is one pass of XLA's over the kernel's source (a reshape that moves
+# data: the tiles of ``[R, L]`` hold 8 rows' chunks, the view's 8 chunks of
+# one row); the result comes out of the kernel in its own layout.
+
+
+def _whole_tiles(dtype, width: int) -> bool:
+    """Whether a row of ``width`` values is whole tiles of 32-bit words."""
+    if dtype not in (jnp.float32, jnp.bfloat16):
+        return False
+    return width % (LANES * SUBLANES * 4 // jnp.dtype(dtype).itemsize) == 0
+
+
+def rows_move_in_tiles(dtype, width: int, rows: int, rows_out: int, tile: int) -> bool:
+    """Whether the rows of a ``[rows, width]`` buffer are added to
+    ``[rows_out, width]`` tokens by the Pallas kernel: decided on shape, at
+    trace time."""
+    return tile == TILE and rows % TILE == 0 and rows_out % TILE == 0 and _whole_tiles(dtype, width)
+
+
+def _halves(words):
+    """The two bfloat16 values of each word (low half, high half), as float32."""
+    value = lambda bits: lax.bitcast_convert_type(bits, jnp.float32)
+    return value(words << 16), value(words & jnp.uint32(0xFFFF0000))
+
+
+def _row_copy(src, dst, sem, q, i, r):
+    """Row ``i`` of ``src`` to row ``r`` of ``dst``, ``q`` lane rows each."""
+    at = lambda n: pl.ds(pl.multiple_of(n * q, q), q)
+    return pltpu.make_async_copy(src.at[at(i)], dst.at[at(r)], sem)
+
+
+def _wait_rows(src, dst, sem, q, n):
+    """Until ``n`` row copies on ``sem`` have landed (each counts the same bytes)."""
+
+    def wait(_, carry):
+        _row_copy(src, dst, sem, q, 0, 0).wait()
+        return carry
+
+    lax.fori_loop(0, n, wait, 0)
+
+
+def _add_kernel(order_ref, bounds_ref, dest_ref, w_ref, upd_ref, out_ref, rows, acc, sems, fetches, *, q, packed, one_pass):
+    tile = out_ref.shape[0]
+    t, tiles = pl.program_id(0), pl.num_programs(0)
+    here = lax.broadcasted_iota(jnp.int32, (tile, TILE), 0) + t * tile
+    at = lax.broadcasted_iota(jnp.int32, (TILE, 1), 0)
+
+    def stretch(t):
+        """Tile ``t``'s stretch of the sorted order, and the chunks of 128
+        sorted rows it lies in: at least one, so that every tile has a first
+        fetch for the tile before it to start."""
+        lo, hi = bounds_ref[t], bounds_ref[t + 1]
+        return lo, hi, lo // TILE, jnp.maximum((hi + TILE - 1) // TILE, lo // TILE + 1)
+
+    def span(lo, hi, c):
+        a = jnp.maximum(lo, c * TILE)
+        return a, jnp.maximum(jnp.minimum(hi, (c + 1) * TILE), a)
+
+    def fetch(lo, hi, c, slot):
+        a, b = span(lo, hi, c)
+
+        def start(j, carry):
+            _row_copy(upd_ref, rows.at[slot], sems.at[slot], q, order_ref[j], j - c * TILE).start()
+            return carry
+
+        lax.fori_loop(a, b, start, 0)
+
+    lo, hi, c0, c1 = stretch(t)
+
+    @pl.when(t == 0)
+    def _():
+        fetches[0] = 0
+        fetch(lo, hi, c0, 0)
+
+    acc[...] = jnp.zeros(acc.shape, acc.dtype)
+
+    def chunk(c, carry):
+        """The stretch's part of sorted rows ``c * 128 ..``, fetched while
+        the chunk before it was added: added by one product with the
+        [tile, 128] matrix that holds row ``j``'s weight at (its destination,
+        ``j``), zeros elsewhere (also for the chunk's rows of the neighbouring
+        tiles, which are not fetched)."""
+        slot = fetches[0] % 2
+        fetches[0] += 1
+
+        # The next chunk's copies fly while this one's rows are added: this
+        # tile's next, or the next tile's first.
+        @pl.when(c + 1 < c1)
+        def _():
+            fetch(lo, hi, c + 1, 1 - slot)
+
+        @pl.when((c + 1 == c1) & (t + 1 < tiles))
+        def _():
+            fetch(*stretch(t + 1)[:3], 1 - slot)
+
+        a, b = span(lo, hi, c)
+        _wait_rows(upd_ref, rows.at[slot], sems.at[slot], q, b - a)
+        # bfloat16 lane rows 2k and 2k + 1 are the halves of row k of 32-bit words.
+        words = rows.at[slot].bitcast(jnp.uint32) if packed else rows.at[slot]
+
+        @pl.when(b > a)
+        def _():
+            fetched = (at >= a - c * TILE) & (at < b - c * TILE)
+            # bfloat16 values under weights of one: a single pass is exact.
+            operand = (lambda v: v.astype(jnp.bfloat16)) if one_pass else (lambda v: v)
+            precision = None if one_pass else lax.Precision.HIGHEST
+            place = operand(jnp.where(here == dest_ref[pl.ds(c, 1), :], w_ref[pl.ds(c, 1), :], 0.0))
+            per_word = 2 if packed else 1
+            for s in range(q // per_word):
+                part = jnp.where(fetched, words[pl.ds(s, TILE, stride=q // per_word), :], 0)
+                for k, values in enumerate(_halves(part) if packed else (part,)):
+                    cols = slice((per_word * s + k) * LANES, (per_word * s + k + 1) * LANES)
+                    acc[:, cols] += jnp.dot(
+                        place, operand(values), precision=precision, preferred_element_type=jnp.float32
+                    )
+
+        return carry
+
+    lax.fori_loop(c0, c1, chunk, 0)
+    out_ref[...] = acc[...].astype(out_ref.dtype)
+
+
+def _add_call(upd, by_dest, weights, rows_out, out_dtype):
+    order, dest, bounds, sorted_weights = by_dest
+    q = upd.shape[1] // LANES
+    packed = upd.dtype == jnp.bfloat16
+    w = jnp.ones(order.shape, jnp.float32) if weights is None else sorted_weights
+    resident = pl.BlockSpec((order.shape[0] // TILE, TILE), lambda t, *_: (0, 0))
+    return pl.pallas_call(
+        functools.partial(_add_kernel, q=q, packed=packed, one_pass=packed and weights is None),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(rows_out // TILE,),
+            in_specs=[resident, resident, pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((TILE, upd.shape[1]), lambda t, *_: (t, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, TILE * q, LANES), upd.dtype),  # a chunk's rows, and the next one's on their way
+                pltpu.VMEM((TILE, upd.shape[1]), jnp.float32),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SMEM((1,), jnp.int32),  # chunks fetched so far: its parity is the slot
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((rows_out, upd.shape[1]), out_dtype),
+        interpret=_use_interpret(),
+        name="moe_add_rows",
+    )(order, bounds, dest.reshape(-1, TILE), w.reshape(-1, TILE), upd.reshape(-1, LANES))
+
+
+def by_destination(idx: jax.Array, rows_out: int, weights: jax.Array):
+    """What ``add_rows`` walks: the buffer's rows sorted by the row of the
+    token array they add to (``idx``; negative: none, sorted last), as
+    (order [R], their destinations [R], [rows_out / 128 + 1] bounds of each
+    tile of 128 destinations' stretch of the order, their ``weights`` [R]
+    float32): one sort, and no gather of the weights after it."""
+    dest = jnp.where(idx >= 0, idx, rows_out).astype(jnp.int32)
+    rows = jnp.arange(idx.shape[0], dtype=jnp.int32)
+    dest, order, weights = lax.sort((dest, rows, weights.astype(jnp.float32)), num_keys=1)
+    tiles = jnp.arange(0, rows_out + 1, TILE, dtype=jnp.int32)
+    return order, dest, jnp.searchsorted(dest, tiles, method="compare_all").astype(jnp.int32), weights
+
+
+@jax.custom_vjp
+def _take_rows(src, rows, valid, by_dest):
+    return src[rows]
+
+
+def _take_fwd(src, rows, valid, by_dest):
+    return src[rows], (rows, valid, by_dest, src.shape[0])
+
+
+def _take_bwd(saved, d):
+    rows, valid, by_dest, rows_out = saved
+    return _add_rows(d, rows, valid, by_dest, None, rows_out, d.dtype), None, None, None
+
+
+_take_rows.defvjp(_take_fwd, _take_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _add_rows(upd, rows, valid, by_dest, weights, rows_out, out_dtype):
+    return _add_call(upd, by_dest, weights, rows_out, out_dtype)
+
+
+def _add_fwd(upd, rows, valid, by_dest, weights, rows_out, out_dtype):
+    return _add_call(upd, by_dest, weights, rows_out, out_dtype), (upd, rows, valid, weights)
+
+
+def _add_bwd(rows_out, out_dtype, saved, d):
+    upd, rows, valid, weights = saved
+    if weights is None:
+        return jnp.where(valid[:, None], d[rows], 0).astype(upd.dtype), None, None, None, None
+    # A row without a pair reads the row it points at, under its weight of zero.
+    taken = d[rows].astype(jnp.float32)
+    d_weights = jnp.where(valid, jnp.sum(taken * upd.astype(jnp.float32), axis=-1), 0)
+    return (taken * weights[:, None]).astype(upd.dtype), None, None, None, d_weights.astype(weights.dtype)
+
+
+_add_rows.defvjp(_add_fwd, _add_bwd)
+
+
+def take_rows(src: jax.Array, rows: jax.Array, valid: jax.Array, by_dest=None) -> jax.Array:
+    """[R, L] in ``src``'s dtype: ``src[rows[i]]`` where ``valid[i]``. XLA's
+    gather whatever the shape: it moves these rows at the memory's pace
+    (PERF.md section 6, PR 41). With ``by_dest`` (``by_destination`` of the
+    same rows, at a shape that ``rows_move_in_tiles``) its transpose is ``add_rows``'s
+    kernel, accumulated in float32 and cast once to ``src``'s dtype, which
+    never visits a row that is not ``valid``; such a row of the result is
+    then left as the copy of ``src[rows[i]]`` it was fetched as (a pass over
+    the buffer saved: its weight is zero, so nothing of it reaches the sum or
+    a gradient). Else the rows that are not ``valid`` are zeros and the
+    transpose is XLA's scatter-add in ``src``'s dtype."""
+    if by_dest is None:
+        tracing.count("moe_row_xla_calls")
+        return jnp.where(valid[:, None], src[rows], 0)
+    tracing.count("moe_row_kernel_calls")
+    return _take_rows(src, rows, valid, by_dest)
+
+
+def add_rows(upd: jax.Array, rows: jax.Array, valid: jax.Array, rows_out: int, weights: jax.Array, by_dest=None):
+    """[rows_out, L] float32: row ``i`` of ``upd`` times ``weights[i]``
+    added to row ``rows[i]``, for the ``i`` that are ``valid`` (the others'
+    weights are zero). With ``by_dest`` (of these rows and weights, for a shape
+    that ``rows_move_in_tiles``), the scatter-add read as a gather, a Pallas kernel
+    over tiles of 128 destinations: each walks its stretch of the buffer's
+    rows sorted by destination, fetches them from HBM by one copy a row, a
+    chunk of 128 started together and waited together, adds a chunk to the
+    tile's float32 block in VMEM by one product that places each row at its
+    destination under its weight, and writes the block once: no
+    read-modify-write of HBM, no collision, and the rows without a
+    destination are never visited. Its transpose is XLA's gather. Else XLA's
+    scatter-add."""
+    if by_dest is None:
+        tracing.count("moe_row_xla_calls")
+        return jnp.zeros((rows_out, upd.shape[1]), jnp.float32).at[rows].add(upd * weights[:, None])
+    tracing.count("moe_row_kernel_calls")
+    return _add_rows(upd, rows, valid, by_dest, weights, rows_out, jnp.float32)
+
+
 def routed_experts(
     z: jax.Array,
     top: jax.Array,
@@ -243,7 +518,13 @@ def _one_round(lo, z, flat_weights, kernels, plan, k, capacity, tile):
         valid = within < plan["sizes"][expert]  # else a row that aligns, or lies past every pair
         pair = plan["order"][jnp.where(valid, plan["starts"][expert] + within, 0)]
         rows, w = pair // k, jnp.where(valid, flat_weights[pair], 0)
-        x = jnp.where(valid[:, None], z[rows], 0)
+        # Which of the round's two scatter-adds the row kernel runs: the
+        # combine's (float32 rows) and the gather's transpose (rows in z's
+        # dtype, whole tiles only where float32 ones are). One sort of the
+        # buffer's rows by destination serves both.
+        in_tiles = [rows_move_in_tiles(dtype, z.shape[1], capacity, z.shape[0], tile) for dtype in (jnp.float32, z.dtype)]
+        to_tokens = by_destination(jnp.where(valid, rows, -1), z.shape[0], w) if in_tiles[0] else None
+        x = take_rows(z, rows, valid, to_tokens if in_tiles[1] else None)
         group = jnp.clip(jnp.minimum(ends, lo + capacity) - jnp.maximum(starts, lo), 0)
         # The rows past the last pair are the last expert's: zeros it multiplies.
         group = group.at[-1].add(capacity - jnp.sum(group)).astype(jnp.int32)
@@ -252,7 +533,7 @@ def _one_round(lo, z, flat_weights, kernels, plan, k, capacity, tile):
         h = BETWEEN[len(first)](*(grouped_product(x, kernel, group, tile, z.dtype) for kernel in first))
         y = grouped_product(h, down, group, tile, jnp.float32)
     with jax.named_scope("moe/combine"):
-        part = jnp.zeros(z.shape, jnp.float32).at[rows].add(y * w[:, None])
+        part = add_rows(y, rows, valid, z.shape[0], w, to_tokens)
     return part, jnp.sum(valid, dtype=jnp.int32)
 
 
