@@ -280,8 +280,9 @@ def test_no_pair_is_dropped_when_the_routing_overflows_the_buffer(favoured):
         (_, (logits, counted)), grads = jax.jit(jax.value_and_grad(ours, has_aux=True))(params)
         ref_logits = jax.jit(lambda p: reference.forward(p, spec, ids, seg))(params)
         ref_grads = jax.jit(jax.grad(theirs))(params)
-    pairs, dropped, fullest = (int(counted[k][0]) for k in moe.COUNTERS)
+    pairs, dropped, fullest, run = (int(counted[k][0]) for k in moe.COUNTERS)
     assert dropped == 0 and fullest == BATCH * T  # every token at the favoured expert
+    assert pairs <= run <= pairs + 4 * 7 and run % 8 == 0  # the four held experts' rows on whole tiles of 8, no slack
     # One expert's 64 pairs and the others' few fit the buffer; four times 64 do not.
     assert (pairs > capacity) == (favoured is None) and (favoured is not None or pairs == BATCH * T * 4)
     _close(logits, ref_logits, 1e-5)
@@ -361,7 +362,8 @@ def test_the_routed_experts_are_a_plain_loop_over_the_experts_held(capacity, til
         (_, want), ref_grads = jax.jit(jax.value_and_grad(theirs, every, has_aux=True))(z, logits, up, down)
     load = np.bincount(np.asarray(top).ravel(), minlength=experts)[offset : offset + held]
     assert {name: int(v) for name, v in counted.items()} == {
-        "moe_pairs": load.sum(), "moe_dropped_pairs": 0, "moe_load_max": load.max()
+        "moe_pairs": load.sum(), "moe_dropped_pairs": 0, "moe_load_max": load.max(),
+        "moe_rows_run": (-(-load // tile) * tile).sum(),  # each expert's rows on whole tiles, no more
     }  # fmt: skip
     assert favoured is None or load.max() == n
     kernel_calls, xla_calls = (after - was for after, was in zip(forms(), before))

@@ -24,18 +24,32 @@ read off what it passes, at trace time, and the program of a model that
 passes two kernels is what it was before there were three
 (tests/test_sdar.py holds its text to the commit before).
 
-The pairs held here are a data-dependent number; the work is not. They are
-sorted by expert and laid into a buffer of ``capacity`` rows, a size the
-configuration fixes (``pair_capacity``: half as many pairs again as a uniform
-router sends here, and a tile for each expert), every expert's rows starting on a
-multiple of ``tile``, so that a tile of rows belongs to one expert. The two
-products run over the whole buffer as grouped matrix products (the Pallas
-kernels of ``jax.experimental.pallas.ops.tpu.megablox``, a tile of rows
-against its expert's kernel): the rows no pair fills are zeros (or, where
-``add_rows`` is the kernel, which never visits them, a copy of a token's row
-under a weight of zero), add nothing to the result or to a gradient, and are
-computed all the same, so a step costs what the configuration says and not
-what the seed's routing says. No
+The pairs held here are a data-dependent number. What the configuration
+fixes is the memory and the programs: the pairs are sorted by expert and laid
+into a buffer of ``capacity`` rows (``pair_capacity``: half as many pairs
+again as a uniform router sends here, and a tile for each expert), every
+expert's rows starting on a multiple of ``tile``, so that a tile of rows
+belongs to one expert, and no shape depends on the routing, so no seed
+compiles anything. What follows the routing is the tiles the products run.
+They are grouped matrix products (the Pallas kernels of
+``jax.experimental.pallas.ops.tpu.megablox``, a tile of rows against its
+expert's kernel) whose group sizes are the rows the round's pairs fill, each
+expert's on whole tiles, and nothing else: the kernels' grid is as long as
+the groups' tiles (a scalar they read), so they stop at the last pair's tile
+and run no tile of the buffer's slack (``moe_rows_run`` counts the rows they
+are handed; a step costs what its routing's rows cost). **The rows past the
+last group are written by no product**: memory nobody initialised (NaN under
+the interpreter, whatever was there on a chip). Whatever reads such a row
+selects and never multiplies: the activation between the products is
+elementwise (a row's garbage stays in its row, read only by the next
+product, which stops where the first did); ``tgmm`` visits a group's tiles
+and masks them by a select; ``add_rows`` by the kernel never visits a row
+that is not ``valid``, by XLA takes ``where(valid)`` before the weight; the
+weights' gradient is a ``where(valid)``; the counters read the plan, not the
+buffer (each reader says so where it reads). Within a group the rows no pair
+fills (an expert's last, part-filled tile) are zeros (or, where ``add_rows``
+is the kernel, a copy of a token's row under a weight of zero): computed, and
+adding nothing to the result or to a gradient. No
 pair is ever dropped: pairs that outgrow the buffer run through the same
 round again, once for each further ``capacity`` rows they fill (at most the
 worst case, every token choosing every held expert), so an imbalance costs
@@ -95,9 +109,10 @@ from .flash import _use_interpret
 
 # What a layer of routed experts counts a step (summed over the layers by
 # train/steps.py and over the steps by ``scan_chunk``): the pairs routed to
-# experts held here, those of them no product computed, and the fullest held
-# expert's pairs.
-COUNTERS = ("moe_pairs", "moe_dropped_pairs", "moe_load_max")
+# experts held here, those of them no product computed, the fullest held
+# expert's pairs, and the rows of the buffer the grouped products ran (the
+# pairs and what aligns each expert's last tile, over every round).
+COUNTERS = ("moe_pairs", "moe_dropped_pairs", "moe_load_max", "moe_rows_run")
 CAPACITY_FACTOR = 1.5
 # The rows a product takes at a time, and what an expert's rows are aligned
 # to: the matrix unit's 128 where an expert expects as many, else a sublane.
@@ -183,7 +198,9 @@ def _kernel_block(k: int, n: int, itemsize: int) -> tuple[int, int]:
 def grouped_product(x, kernels, sizes, tile, out_dtype):
     """``x`` [R, K] against ``kernels`` [G, K, N] by groups of rows: rows
     ``sum(sizes[:g]) .. sum(sizes[:g + 1])`` meet ``kernels[g]``. ``sizes``
-    (int32) sum to R and are multiples of ``tile``. Returns [R, N]."""
+    (int32) are multiples of ``tile`` and sum to at most R: the kernel's grid
+    is ``sum(sizes) / tile`` tiles of rows long, and the rows past them are
+    neither read nor written. Returns [R, N], those rows uninitialised."""
     k, n = kernels.shape[1:]
     return gmm(
         x, kernels, sizes, out_dtype, (tile, *_kernel_block(k, n, kernels.dtype.itemsize)),
@@ -200,11 +217,15 @@ def _grouped_bwd(tile, out_dtype, saved, dy):
     x, kernels, sizes = saved
     k, n = kernels.shape[1:]
     dy = dy.astype(x.dtype)
+    # Past the last group ``dx`` is as uninitialised as the forward's result there.
     dx = gmm(
         dy, kernels, sizes, x.dtype, (tile, *_kernel_block(n, k, kernels.dtype.itemsize)),
         transpose_rhs=True, interpret=_use_interpret(),
     )  # fmt: skip
-    # A block of the kernel's gradient is accumulated in float32.
+    # A block of the kernel's gradient is accumulated in float32. Rows no
+    # product wrote (``x`` where it is the activation, ``dy`` where it is the
+    # next product's ``dx``) are safe here: ``tgmm`` walks each group's tiles,
+    # none past the last group, and masks a tile's rows by a select.
     dk = tgmm(
         x.swapaxes(0, 1), dy, sizes, kernels.dtype, (tile, *_kernel_block(k, n, 4)),
         num_actual_groups=kernels.shape[0], interpret=_use_interpret(),
@@ -393,6 +414,7 @@ def _take_fwd(src, rows, valid, by_dest):
 
 def _take_bwd(saved, d):
     rows, valid, by_dest, rows_out = saved
+    # ``d`` past the last group is uninitialised: the kernel never visits a row that is not ``valid``.
     return _add_rows(d, rows, valid, by_dest, None, rows_out, d.dtype), None, None, None
 
 
@@ -412,7 +434,9 @@ def _add_bwd(rows_out, out_dtype, saved, d):
     upd, rows, valid, weights = saved
     if weights is None:
         return jnp.where(valid[:, None], d[rows], 0).astype(upd.dtype), None, None, None, None
-    # A row without a pair reads the row it points at, under its weight of zero.
+    # A row without a pair reads the row it points at, under its weight of zero:
+    # ``taken`` is the cotangent's, real in every row. ``upd`` past the last
+    # group is not (no product wrote it), and only ``d_weights`` reads it: by a select.
     taken = d[rows].astype(jnp.float32)
     d_weights = jnp.where(valid, jnp.sum(taken * upd.astype(jnp.float32), axis=-1), 0)
     return (taken * weights[:, None]).astype(upd.dtype), None, None, None, d_weights.astype(weights.dtype)
@@ -434,6 +458,7 @@ def take_rows(src: jax.Array, rows: jax.Array, valid: jax.Array, by_dest=None) -
     transpose is XLA's scatter-add in ``src``'s dtype."""
     if by_dest is None:
         tracing.count("moe_row_xla_calls")
+        # Its transpose is this select's: a cotangent's uninitialised rows are never multiplied.
         return jnp.where(valid[:, None], src[rows], 0)
     tracing.count("moe_row_kernel_calls")
     return _take_rows(src, rows, valid, by_dest)
@@ -451,10 +476,13 @@ def add_rows(upd: jax.Array, rows: jax.Array, valid: jax.Array, rows_out: int, w
     destination under its weight, and writes the block once: no
     read-modify-write of HBM, no collision, and the rows without a
     destination are never visited. Its transpose is XLA's gather. Else XLA's
-    scatter-add."""
+    scatter-add. Rows of ``upd`` that are not ``valid`` may be uninitialised
+    (no product wrote them): they are selected away, not multiplied by zero."""
     if by_dest is None:
         tracing.count("moe_row_xla_calls")
-        return jnp.zeros((rows_out, upd.shape[1]), jnp.float32).at[rows].add(upd * weights[:, None])
+        # NaN times a weight of zero is NaN: the select comes first.
+        upd = jnp.where(valid[:, None], upd, 0) * weights[:, None]
+        return jnp.zeros((rows_out, upd.shape[1]), jnp.float32).at[rows].add(upd)
     tracing.count("moe_row_kernel_calls")
     return _add_rows(upd, rows, valid, by_dest, weights, rows_out, jnp.float32)
 
@@ -492,13 +520,14 @@ def routed_experts(
             "aligned_starts": jnp.cumsum(aligned) - aligned,
             "aligned_ends": jnp.cumsum(aligned),
         }
-    out, computed = _every_round(
+    out, (computed, run) = _every_round(
         z, weights.reshape(-1), tuple(kernels), plan, top.shape[1], capacity, tile
     )
     counters = {
         "moe_pairs": jnp.sum(sizes),
         "moe_dropped_pairs": jnp.sum(sizes) - computed,
         "moe_load_max": jnp.max(sizes),
+        "moe_rows_run": run,
     }
     return out, {name: jnp.asarray(counters[name], jnp.int32) for name in COUNTERS}
 
@@ -506,7 +535,8 @@ def routed_experts(
 @functools.partial(jax.jit, static_argnames=("k", "capacity", "tile"))
 def _one_round(lo, z, flat_weights, kernels, plan, k, capacity, tile):
     """Rows ``lo .. lo + capacity`` of the aligned order: their part of the
-    sum [N, L] float32, and how many of them are pairs. A traced program of
+    sum [N, L] float32, and (how many of them are pairs, how many the
+    products ran), both from the plan. A traced program of
     its own: the scopes below then reach the device trace under their own
     names, where ``jax.vjp`` of plain code would write ``jvp(moe/experts)``."""
     held = kernels[0].shape[0]
@@ -525,16 +555,18 @@ def _one_round(lo, z, flat_weights, kernels, plan, k, capacity, tile):
         in_tiles = [rows_move_in_tiles(dtype, z.shape[1], capacity, z.shape[0], tile) for dtype in (jnp.float32, z.dtype)]
         to_tokens = by_destination(jnp.where(valid, rows, -1), z.shape[0], w) if in_tiles[0] else None
         x = take_rows(z, rows, valid, to_tokens if in_tiles[1] else None)
-        group = jnp.clip(jnp.minimum(ends, lo + capacity) - jnp.maximum(starts, lo), 0)
-        # The rows past the last pair are the last expert's: zeros it multiplies.
-        group = group.at[-1].add(capacity - jnp.sum(group)).astype(jnp.int32)
+        # Each expert's rows of this round, whole tiles; the rows past the last
+        # pair's tile are no group's, and no product reads or writes them.
+        group = jnp.clip(jnp.minimum(ends, lo + capacity) - jnp.maximum(starts, lo), 0).astype(jnp.int32)
     with jax.named_scope("moe/experts"):
         *first, down = kernels
+        # Elementwise: an uninitialised row (and its gradient) stays in its
+        # row, read only by the next product, which stops where these did.
         h = BETWEEN[len(first)](*(grouped_product(x, kernel, group, tile, z.dtype) for kernel in first))
         y = grouped_product(h, down, group, tile, jnp.float32)
     with jax.named_scope("moe/combine"):
         part = add_rows(y, rows, valid, z.shape[0], w, to_tokens)
-    return part, jnp.sum(valid, dtype=jnp.int32)
+    return part, (jnp.sum(valid, dtype=jnp.int32), jnp.sum(group))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
@@ -549,16 +581,16 @@ def _every_round(z, flat_weights, kernels, plan, k, capacity, tile):
 def _every_round_fwd(z, flat_weights, kernels, plan, k, capacity, tile):
     operands = (z, flat_weights, kernels)
     first = lambda *operands: _one_round(0, *operands, plan, k, capacity, tile)
-    part, pull, rows = jax.vjp(first, *operands, has_aux=True)
+    part, pull, counted = jax.vjp(first, *operands, has_aux=True)
 
     def further(state):
-        lo, part, rows = state
+        lo, part, counted = state
         more = _one_round(lo, *operands, plan, k, capacity, tile)
-        return lo + capacity, part + more[0], rows + more[1]
+        return lo + capacity, part + more[0], jax.tree.map(jnp.add, counted, more[1])
 
     pending = lambda state: state[0] < plan["aligned_ends"][-1]
-    _, part, rows = lax.while_loop(pending, further, (jnp.int32(capacity), part, rows))
-    return (part, rows), (pull, operands, plan)
+    _, part, counted = lax.while_loop(pending, further, (jnp.int32(capacity), part, counted))
+    return (part, counted), (pull, operands, plan)
 
 
 def _every_round_bwd(k, capacity, tile, saved, cotangents):
