@@ -51,10 +51,13 @@
 #   9. exec-manifest round-trip — rebuild the static compile-surface
 #      manifest (jit entries x compile sites x bucket sets x plan kinds)
 #      and diff it against the checked-in
-#      turboprune_tpu/analysis/exec_manifest.json. Drift means code grew
-#      or moved an executable the manifest doesn't know: re-emit with
-#      --exec-manifest emit and review the diff like a lockfile change.
-#  10. compile audit            — the runtime mirror of stage 8: patch
+#      turboprune_tpu/analysis/exec_manifest.json. The lockfile locks the
+#      SET: entries by (file, name, reason), sites by (file, target), plan
+#      kinds by file, and no line number, so a line that moves is no
+#      drift. Drift means code grew, dropped or renamed an executable the
+#      manifest doesn't know: re-emit with --exec-manifest emit and review
+#      the diff like a lockfile change.
+#  10. compile audit            — the runtime mirror of stage 9: patch
 #      jax's backend_compile, drive the serving engine (warmup + padded
 #      predict) and the jitted train step, and fail on any XLA compile
 #      not attributed to a manifest entry, or any compiled (plan,

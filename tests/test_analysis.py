@@ -2790,6 +2790,30 @@ class TestExecManifest:
         out = capsys.readouterr().out
         assert "nm" in out and "drift" in out.lower()
 
+    def test_a_moved_line_is_no_drift_and_a_renamed_entry_is(self, tmp_path, capsys, monkeypatch):
+        """The lockfile locks the set (entries by file, name and reason,
+        sites by file and target, plan kinds by file), not where in a file a
+        body stands: the checked-in file holds no line number, and one that
+        does, every line off by one, still diffs clean."""
+        import turboprune_tpu.analysis.exec_manifest as em
+
+        for row in em.load_manifest()["entries"] + em.load_manifest()["compile_sites"]:
+            assert not {"line", "end"} & set(row)
+        moved = em.build_manifest()
+        for row in moved["entries"] + moved["compile_sites"]:
+            row.update({k: row[k] + 1 for k in ("line", "end") if k in row})
+        moved["plan_kinds"] = {k: f"{v.partition(':')[0]}:1" for k, v in moved["plan_kinds"].items()}
+        p = tmp_path / "exec_manifest.json"
+        monkeypatch.setattr(em, "MANIFEST_PATH", p)
+        p.write_text(json.dumps(moved))
+        assert em.run_exec_manifest("diff") == 0
+        assert "clean" in capsys.readouterr().out
+        moved["entries"][0]["name"] += "_renamed"
+        p.write_text(json.dumps(moved))
+        assert em.run_exec_manifest("diff") == 1
+        out = capsys.readouterr().out
+        assert "_renamed" in out and "+ entries" in out and "- entries" in out
+
     def test_unknown_mode_is_usage_error(self):
         from turboprune_tpu.analysis.exec_manifest import run_exec_manifest
 

@@ -2,8 +2,9 @@
 (ops/flash.py::flash_attention_causal) against a plain masked softmax,
 forward and gradient, in interpret mode; its walk (the pairs of blocks the
 kernels visit are the pairs the square grid ran, in its order, and no other
-block is read); and the bidirectional call, which the language model's path
-may not have changed by a bit."""
+block is read); the bidirectional call, which the language model's path
+may not have changed by a bit; and the two packed families' calls, which are
+written once and lower to the programs they were."""
 
 import hashlib
 
@@ -12,7 +13,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from turboprune_tpu.ops.flash import _block_ranges, _causal_setup, flash_attention, flash_attention_causal
+from turboprune_tpu.data.tokens import block_ordinals
+from turboprune_tpu.ops.flash import (
+    _block_ranges, _causal_setup, flash_attention, flash_attention_blockdiff, flash_attention_causal,
+)  # fmt: skip
 
 import flash_walk
 
@@ -196,3 +200,34 @@ def test_the_bidirectional_call_is_the_program_it_was():
     assert hashlib.sha256(text.encode()).hexdigest() == (
         "23c63effc2c0d248fc9f1d0bbe75e7d8842bbe9fdf9f5f4e9e7e3af35340d7e8"
     )
+
+
+@pytest.mark.parametrize(
+    "family, sha256",
+    [
+        ("causal", "6c5edd0d84d3a06ec3587ba93c0ec36486111fc6a5d5746cb0959b3e4a5db71f"),
+        ("blockdiff", "e26e2a0ea3dd17ee3f59c1ebac051f884517d16891fc62bdf728c7eb9c8ceab9"),
+    ],
+)
+def test_a_packed_familys_call_is_the_program_it_was(family, sha256):
+    """The two packed families' three ``pallas_call``s are written once
+    (``_packed_fwd``, ``_packed_bwd`` over a ``_Family``): the lowered program
+    of each entry point (forward and its three gradients, interpret mode,
+    grouped heads, documents that start inside kernel blocks, block_q other
+    than block_k) hashes to what commit 7802b81 gave, where each family had
+    its own copy of the calls."""
+    rng = np.random.default_rng(20261004)
+    q = jnp.asarray(rng.normal(size=(8, 64, 8)), jnp.float32)
+    k, v = (jnp.asarray(rng.normal(size=(4, 64, 8)), jnp.float32) for _ in range(2))
+    flags = np.zeros((2, 64), np.int32)
+    flags[0, [5, 16, 17, 40]], flags[1, [22]] = 1, 1
+    seg = np.cumsum(flags, axis=1)
+    if family == "causal":
+        f = lambda q, k, v: flash_attention_causal(q, k, v, jnp.asarray(seg), 0.35, 16, 32)
+    else:  # 64 rows are the two copies of 32 tokens
+        doc = seg[:, :32]
+        blk = block_ordinals(doc, 4)[0]
+        f = lambda q, k, v: flash_attention_blockdiff(q, k, v, jnp.asarray(doc), jnp.asarray(blk), 0.35, 16, 32)
+    g = jax.jit(lambda q, k, v: jax.grad(lambda q, k, v: jnp.sum(jnp.sin(f(q, k, v))), argnums=(0, 1, 2))(q, k, v))
+    text = g.lower(q, k, v).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == sha256

@@ -16,7 +16,7 @@ import pytest
 from benchmarks.reference import granite as reference
 from turboprune_tpu.config import compose
 from turboprune_tpu.config.schema import ConfigError
-from turboprune_tpu.models import LANGUAGE_MODELS, create_model, granite
+from turboprune_tpu.models import LANGUAGE_MODELS, blocks, create_model, granite
 from turboprune_tpu.ops import masking
 from turboprune_tpu.train.steps import make_eval_step, make_train_step
 from turboprune_tpu.utils import tracing
@@ -231,12 +231,12 @@ def test_a_tag_no_policy_names_is_inert(seeded, kind, monkeypatch):
     model, params, _, tokens, _ = seeded
     c, seg = model.cfg, tokens[:, 1]
     if kind == "mamba":
-        mixer = granite.MambaMixer(
+        mixer = blocks.MambaMixer(
             c.mamba_n_heads, c.mamba_d_head, c.mamba_d_state, c.mamba_d_conv, c.mamba_chunk_size, c.rms_norm_eps
         )
         p, tags = params["layers_0"]["mixer"], 1
     else:
-        mixer = granite.AttentionMixer(
+        mixer = blocks.AttentionMixer(
             c.num_attention_heads, c.num_key_value_heads, c.attention_head_dim, c.attention_multiplier
         )
         p, tags = params["layers_1"]["mixer"], 3
@@ -251,7 +251,7 @@ def test_a_tag_no_policy_names_is_inert(seeded, kind, monkeypatch):
     tagged, names, handed = probe()
     # Arguments, a constant (``seg``) and this test's own cosine: nothing the layer computed.
     assert handed and not [why for _, why in handed if "turboprune_tpu" in why or "named '" in why]
-    monkeypatch.setattr(granite, "checkpoint_name", lambda x, name: x)
+    monkeypatch.setattr(blocks, "checkpoint_name", lambda x, name: x)
     plain, no_names, _ = probe()
     assert (names, no_names) == (tags, 0)
     for got, want in zip(jax.tree.leaves(tagged), jax.tree.leaves(plain)):

@@ -19,8 +19,7 @@ import pytest
 from benchmarks.reference import lfm2_moe as reference
 from turboprune_tpu.config import compose
 from turboprune_tpu.models import BLOCK_DIFFUSION_MODELS, LANGUAGE_MODELS, SHARED_MODELS, create_model, lfm2
-from turboprune_tpu.models.granite import SwiGLU
-from turboprune_tpu.models.nemotron_h import Share
+from turboprune_tpu.models.blocks import GatedExperts, RotaryAttention, Router, Share, SparseMoE, SwiGLU
 from turboprune_tpu.ops import masking, moe
 
 import remat_probe
@@ -55,6 +54,23 @@ def _batch(seed=0, one_id=False):
     seg = np.cumsum(flags, axis=1)
     tokens = jnp.asarray(np.stack([ids, seg], axis=1), jnp.int32)
     return tokens, reference.next_token_targets(tokens[:, 0], tokens[:, 1])
+
+
+def _sparse_moe(c, experts_here, expert_offset):
+    """A routed layer's feed-forward as models/lfm2.py builds it."""
+    return SparseMoE(
+        Router(c.num_experts, c.num_experts_per_tok, c.routed_scaling_factor, lfm2.ROUTER_EPS),
+        GatedExperts(
+            c.hidden_size, c.moe_intermediate_size, c.num_experts, c.num_experts_per_tok,
+            experts_here, expert_offset,
+        ),
+    )  # fmt: skip
+
+
+def _attention(c, heads, kv_heads):
+    """An attention layer's mixer as models/lfm2.py calls it: ``(params, u, seg)``."""
+    mixer = RotaryAttention(heads, kv_heads, c.head_dim, c.norm_eps, c.rope_theta)
+    return lambda p, u, seg: mixer.apply({"params": p}, u, *lfm2.packed_causal(seg))
 
 
 def _spec(model) -> dict:
@@ -183,12 +199,10 @@ def test_a_packed_pair_is_the_two_documents_run_apart(whole, kind):
     x = jnp.asarray(rng.normal(size=(1, T, c.hidden_size)), jnp.float32)
     if kind == "conv":
         mixer, p = lfm2.ShortConv(c.hidden_size, c.conv_L_cache), params["layers_0"]["mixer"]
+        run = jax.jit(lambda x, s: mixer.apply({"params": p}, x, s))
     else:
-        mixer = lfm2.RotaryAttention(
-            c.num_attention_heads, c.num_key_value_heads, c.head_dim, c.norm_eps, c.rope_theta
-        )
-        p = params["layers_1"]["mixer"]
-    run = jax.jit(lambda x, s: mixer.apply({"params": p}, x, s))
+        mixer = _attention(c, c.num_attention_heads, c.num_key_value_heads)
+        run = jax.jit(lambda x, s: mixer(params["layers_1"]["mixer"], x, s))
     pad = lambda x: jnp.pad(x, ((0, 0), (0, T - x.shape[1]), (0, 0)))
     with jax.default_matmul_precision("highest"):
         packed = run(x, seg)
@@ -233,7 +247,7 @@ def test_the_shares_of_every_divided_part_add_up_to_the_uncut_reference(whole, p
             for rank in range(chips):
                 experts = jnp.arange(4 * rank, 4 * rank + 4)
                 mine = _slice(p, **{f"experts/kernel_{k}": (0, experts) for k in ("gate", "up", "down")})
-                out, sown = lfm2.SparseMoE(c, 4, 4 * rank).apply({"params": mine}, x, mutable=["counters"])
+                out, sown = _sparse_moe(c, 4, 4 * rank).apply({"params": mine}, x, mutable=["counters"])
                 assert int(sown["counters"]["moe_dropped_pairs"][0]) == 0
                 got = got + out
         elif part == "conv":
@@ -254,8 +268,7 @@ def test_the_shares_of_every_divided_part_add_up_to_the_uncut_reference(whole, p
                     p, **{"q_proj/kernel": (1, q), "k_proj/kernel": (1, kv),
                           "v_proj/kernel": (1, kv), "o_proj/kernel": (0, q)},
                 )  # fmt: skip
-                attn = lfm2.RotaryAttention(1, 1, d, c.norm_eps, c.rope_theta)
-                got = got + attn.apply({"params": cut}, x, seg)
+                got = got + _attention(c, 1, 1)(cut, x, seg)
         else:
             p, n = params["layers_0"]["mlp"], c.intermediate_size // chips
             want = reference.mlp(x, p)
@@ -292,7 +305,7 @@ def test_no_pair_is_dropped_under_a_routing_that_needs_a_second_round(whole):
     p = dict(p, experts={k: v[:8] for k, v in p["experts"].items()})
     rng = np.random.default_rng(9)
     x = jnp.asarray(rng.normal(size=(1, 1, 32)) + 0.05 * rng.normal(size=(BATCH, T, 32)), jnp.float32)
-    layer = lfm2.SparseMoE(c, 8, 0)
+    layer = _sparse_moe(c, 8, 0)
     weigh = lambda fn: jax.jit(jax.value_and_grad(lambda p, x: jnp.sum(jnp.sin(fn(p, x))), argnums=(0, 1)))
     ours = lambda p, x: layer.apply({"params": p}, x)
     theirs = lambda p, x: reference.sparse_moe(x, p, dict(spec, expert_offset=0))

@@ -18,7 +18,7 @@ import pytest
 from benchmarks.reference import nemotron_h as reference
 from turboprune_tpu.config import compose
 from turboprune_tpu.config.schema import ConfigError
-from turboprune_tpu.models import LANGUAGE_MODELS, create_model, granite, nemotron_h
+from turboprune_tpu.models import LANGUAGE_MODELS, blocks, create_model, nemotron_h
 from turboprune_tpu.ops import masking, moe
 from turboprune_tpu.ops.ssd import ssd_chunked
 from turboprune_tpu.pruning import criteria
@@ -232,7 +232,7 @@ def test_the_shares_of_a_scan_layer_add_up_to_the_uncut_layer(whole):
                       "dt_bias": (0, head), "A_log": (0, head), "D": (0, head),
                       "gate_norm/scale": (0, chan), "out_proj/kernel": (0, chan)},
             )  # fmt: skip
-            mixer = granite.MambaMixer(per, hd, n, c.conv_kernel, c.chunk_size, c.layer_norm_epsilon)
+            mixer = blocks.MambaMixer(per, hd, n, c.conv_kernel, c.chunk_size, c.layer_norm_epsilon)
             total += mixer.apply({"params": part}, u, seg)
     _close(total, want, 1e-5)
 
@@ -245,7 +245,7 @@ def test_the_shares_of_an_attention_layer_add_up_to_the_uncut_layer(whole):
     with jax.default_matmul_precision("highest"):
         want = reference.block(u, seg, {"norm": {"scale": jnp.ones(c.hidden_size)}, "mixer": p}, spec) - u
         total = jnp.zeros_like(u)
-        norm = granite.RMSNorm(c.layer_norm_epsilon)
+        norm = blocks.RMSNorm(c.layer_norm_epsilon)
         h = norm.apply({"params": {"scale": jnp.ones(c.hidden_size)}}, u)
         for kv in range(c.num_key_value_heads):  # a key/value head and its two query heads to a chip
             q = jnp.arange(2 * kv * d, 2 * (kv + 1) * d)
@@ -254,7 +254,7 @@ def test_the_shares_of_an_attention_layer_add_up_to_the_uncut_layer(whole):
                 p, **{"q_proj/kernel": (1, q), "k_proj/kernel": (1, k), "v_proj/kernel": (1, k),
                       "o_proj/kernel": (0, q)},
             )  # fmt: skip
-            total += granite.AttentionMixer(2, 1, d, d**-0.5).apply({"params": part}, h, seg)
+            total += blocks.AttentionMixer(2, 1, d, d**-0.5).apply({"params": part}, h, seg)
     _close(total, want, 1e-5)
 
 
@@ -397,15 +397,15 @@ def test_granites_mixer_traces_to_the_program_it_was():
     """At one group and the default ``out_std`` neither the grouped norm nor
     the grouped scan adds an operation: no reshape of the norm's input, no
     fourth axis on B and C."""
-    mixer = granite.MambaMixer(4, 16, 8, 4, 16, 1e-5)
+    mixer = blocks.MambaMixer(4, 16, 8, 4, 16, 1e-5)
     u, seg = jnp.zeros((1, 32, 32)), jnp.zeros((1, 32), jnp.int32)
     params = jax.eval_shape(mixer.init, jax.random.PRNGKey(0), u, seg)["params"]
     assert params["in_proj"]["kernel"].shape == (32, 2 * 64 + 2 * 8 + 4)
-    norm = jax.make_jaxpr(lambda x: granite.RMSNorm(1e-5).apply({"params": {"scale": jnp.ones(64)}}, x))
+    norm = jax.make_jaxpr(lambda x: blocks.RMSNorm(1e-5).apply({"params": {"scale": jnp.ones(64)}}, x))
     assert "reshape" not in str(norm(jnp.zeros((1, 32, 64))))
-    grouped = jax.make_jaxpr(lambda x: granite.RMSNorm(1e-5, groups=2).apply({"params": {"scale": jnp.ones(64)}}, x))
+    grouped = jax.make_jaxpr(lambda x: blocks.RMSNorm(1e-5, groups=2).apply({"params": {"scale": jnp.ones(64)}}, x))
     assert "reshape" in str(grouped(jnp.zeros((1, 32, 64))))
-    two = granite.MambaMixer(4, 16, 8, 4, 16, 1e-5, n_groups=2)
+    two = blocks.MambaMixer(4, 16, 8, 4, 16, 1e-5, n_groups=2)
     assert jax.eval_shape(two.init, jax.random.PRNGKey(0), u, seg)["params"]["in_proj"]["kernel"].shape == (32, 2 * 64 + 4 * 8 + 4)
 
 

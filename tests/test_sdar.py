@@ -6,8 +6,8 @@ a chip's share of it, logits, loss and every leaf's gradient; the shares of a
 whole layer and of the head adding up to the uncut reference; the router; a
 routing that overflows the pair buffer because every row is one id; the two
 copies agreeing; what a layer's backward pass keeps; stacked kernels as
-layers; and the sparse-expert hybrid's program, which shares ops/moe.py, being
-what it was."""
+layers; and the four language models' programs, which share models/blocks.py
+and the kernels under it, being what they were."""
 
 import dataclasses
 import pathlib
@@ -22,7 +22,7 @@ from turboprune_tpu.config import compose
 from turboprune_tpu.config.schema import ConfigError
 from turboprune_tpu.data import tokens as tk
 from turboprune_tpu.models import BLOCK_DIFFUSION_MODELS, LANGUAGE_MODELS, SHARED_MODELS, create_model, sdar
-from turboprune_tpu.models.nemotron_h import Head, Share
+from turboprune_tpu.models.blocks import GatedExperts, Head, RotaryAttention, Share, SparseMoE, rotary
 from turboprune_tpu.ops import masking, moe
 from turboprune_tpu.train.steps import make_eval_step, make_train_step
 
@@ -226,15 +226,24 @@ def test_the_shares_of_a_whole_layer_and_of_the_head_add_up_to_the_uncut_referen
                 p["attn"], **{"q_proj/kernel": (1, q), "k_proj/kernel": (1, kv),
                               "v_proj/kernel": (1, kv), "o_proj/kernel": (0, q)},
             )  # fmt: skip
-            attn = sdar.BlockDiffusionAttention(1, 1, d, c.rms_norm_eps, c.rope_theta)
-            h = h + attn.apply({"params": part}, norm(p["input_norm"]["scale"], x), doc, blk, pos)
+            attn = RotaryAttention(1, 1, d, c.rms_norm_eps, c.rope_theta)
+            h = h + attn.apply(
+                {"params": part}, norm(p["input_norm"]["scale"], x), *sdar.block_diffusion(doc, blk, pos)
+            )
         y = h
         for rank in range(chips):
             experts = jnp.arange(4 * rank, 4 * rank + 4)
             part = _slice(
                 p["mlp"], **{f"experts/kernel_{k}": (0, experts) for k in ("gate", "up", "down")}
             )
-            out, sown = sdar.SparseMoE(c, 4, 4 * rank).apply(
+            layer = SparseMoE(
+                sdar.SoftmaxRouter(c.num_experts, c.num_experts_per_tok),
+                GatedExperts(
+                    c.hidden_size, c.moe_intermediate_size, c.num_experts, c.num_experts_per_tok,
+                    4, 4 * rank,
+                ),
+            )  # fmt: skip
+            out, sown = layer.apply(
                 {"params": part}, norm(p["post_attention_norm"]["scale"], h), mutable=["counters"]
             )
             assert int(sown["counters"]["moe_dropped_pairs"][0]) == 0
@@ -324,10 +333,10 @@ def test_rotary_is_the_references_and_restarts_with_a_document():
     seg = np.asarray([[0] * 5 + [1] * 7])
     pos = jnp.asarray(tk.block_ordinals(seg, BLOCK)[1])
     assert pos[0].tolist() == [0, 1, 2, 3, 4, 0, 1, 2, 3, 4, 5, 6]
-    got = sdar.rotary(x, pos, 1e6)
+    got = rotary(x, pos, 1e6)
     np.testing.assert_allclose(got, reference.rotary(x, pos, 1e6), atol=1e-6)
     np.testing.assert_array_equal(got[:, 0], x[:, 0])  # position 0 turns nothing
-    same = sdar.rotary(jnp.broadcast_to(x[:, :1], x.shape), pos, 1e6)
+    same = rotary(jnp.broadcast_to(x[:, :1], x.shape), pos, 1e6)
     np.testing.assert_array_equal(same[:, 0], same[:, 5])  # a document's first token, again
 
 
@@ -398,15 +407,21 @@ def test_the_registry_and_the_configs_cross_checks():
         sdar.held(sdar.SdarConfig(**sdar.SDAR_MOE_TINY), Share(3, 1, 0))
 
 
-_SPARSE_EXPERT_PROGRAM = """
-import hashlib, jax, jax.numpy as jnp, numpy as np
-from turboprune_tpu.models import create_model
+_PROGRAM = """
+import hashlib, json, sys, jax, jax.numpy as jnp, numpy as np
+from turboprune_tpu.data.tokens import block_ordinals
+from turboprune_tpu.models import BLOCK_DIFFUSION_MODELS, create_model
 
-model = create_model("nemotron_h_tiny", 50, share=(2, 4, 1), layer_pattern="EMEM")
+name, kwargs = sys.argv[1], json.loads(sys.argv[2])
+model = create_model(name, 50, **kwargs)
 rng = np.random.default_rng(20261001)
 flags = np.zeros((2, 32), np.int32)
 flags[0, [5, 16, 17]], flags[1, [20]] = 1, 1
-tokens = jnp.asarray(np.stack([rng.integers(0, 50, (2, 32)), np.cumsum(flags, axis=1)], axis=1), jnp.int32)
+ids, seg = rng.integers(0, 49, (2, 32)), np.cumsum(flags, axis=1)
+rows = [ids, seg]
+if name in BLOCK_DIFFUSION_MODELS:
+    rows += [*block_ordinals(seg, 4), np.where(rng.random((2, 32)) < 0.3, 49, ids)]
+tokens = jnp.asarray(np.stack(rows, axis=1), jnp.int32)
 shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0), tokens)["params"]
 
 
@@ -420,26 +435,42 @@ print(hashlib.sha256(text.encode()).hexdigest())
 """
 
 
-def test_the_sparse_expert_hybrid_is_the_program_it_was():
-    """ops/moe.py now takes an expert's kernels as a tuple and has a second
-    router beside the first: the lowered text of the other model that runs
-    it (forward, counters and every gradient) hashes to what the commit
-    before gave. Its expert and scan layers only: the attention layer's
-    kernels walk another grid since PR 39, and its hash is the commit's
-    before that one, taken there without that layer. **PR 42 moved the
-    text by design and the hash was retaken at its commit**: a round's group
-    sizes no longer hand the buffer's slack to the last expert (one
-    scatter-add less in the plan), XLA's ``add_rows`` selects before it
-    weighs, and ``moe_rows_run`` is a fourth counter; what the test holds
-    from there on is that a later change to a neighbour (another router,
-    another expert, another model) leaves this program alone. In a process of its own, as the hash was taken: inside a
-    worker of the whole suite, after other files' tests, the same lowering
-    gave another text (which of them leaves what behind was not found)."""
+@pytest.mark.parametrize(
+    "name, kwargs, sha256",
+    [
+        ("hybrid_lm_tiny", {}, "8542c23bbe2f1c5a13677dad4ec68269c58046c62239f397f03e75ccf868ad9d"),
+        (
+            "nemotron_h_tiny", {"share": (2, 4, 1), "layer_pattern": "EM*E"},
+            "108fb53b3ac9c6b76c2657a1d70e71d39441aa23a42ecd5cbf938b234aa7c8db",
+        ),
+        ("sdar_moe_tiny", {"share": (2, 4, 1)}, "3c40ece12117a4a70de00e8bca69800331ff37a2ddf410d9c3faf3734d3552a6"),
+        ("lfm2_moe_tiny", {"share": (2, 4, 1)}, "027a0c615d4c6093812f59ec5e5f5356973a8c31949241ad449f968d45f5d192"),
+    ],
+    ids=lambda v: v if isinstance(v, str) and len(v) < 64 else "",
+)  # fmt: skip
+def test_the_sparse_expert_hybrid_is_the_program_it_was(name, kwargs, sha256):
+    """The four language models share their blocks (models/blocks.py) and
+    their kernels (ops/moe.py, ops/flash.py, ops/ssd.py): the lowered text of
+    each tiny model (forward, counters and every gradient), with every kind of
+    layer it has and at a share that is not the whole model where it takes
+    one, hashes to what commit 7802b81 gave, the commit before the blocks
+    were moved into a file of their own and their copies merged. What the
+    test holds from there on is that a change to a neighbour (another router,
+    another expert, another model, a merged block) leaves these programs
+    alone; a PR that moves one by design retakes its hash at its own commit
+    and says so here, as PR 42 did for ops/moe.py's group sizes. The
+    sparse-expert hybrid's case was ``EMEM`` until PR 43 and its hash PR
+    42's: that hash went only because the case now runs ``EM*E``, the
+    attention layer with the rest. In a process of its own, as the hash was
+    taken: inside a worker of the whole suite, after other files' tests, the
+    same lowering gave another text (which of them leaves what behind was not
+    found)."""
+    import json
     import subprocess
     import sys
 
     out = subprocess.run(
-        [sys.executable, "-c", _SPARSE_EXPERT_PROGRAM], capture_output=True, text=True, check=True,
-        cwd=pathlib.Path(__file__).resolve().parents[1],
+        [sys.executable, "-c", _PROGRAM, name, json.dumps(kwargs)], capture_output=True, text=True,
+        check=True, cwd=pathlib.Path(__file__).resolve().parents[1],
     )  # fmt: skip
-    assert out.stdout.split()[-1] == "fccc89a4c98f005d492aba2bc20e4b925c6a4c42a2b91cdcb80f6dc154eafe1e"
+    assert out.stdout.split()[-1] == sha256

@@ -46,7 +46,7 @@ from pathlib import Path
 from typing import Callable, Optional
 
 from .drivers import default_step_entry, resolve_runtime_target
-from .exec_manifest import covers, executable_names, load_manifest
+from .exec_manifest import build_manifest, covers, executable_names, load_manifest
 
 __all__ = ["AuditError", "CompileLedger", "run_compile_audit"]
 
@@ -164,17 +164,21 @@ def _posix_rel(path: str) -> str:
         return p.as_posix()
 
 
-def _manifest_spans(manifest: dict) -> list:
+def _manifest_spans(manifest: dict, tree: dict) -> list:
     """(file, start, end, label) windows a triggering repo frame may sit
-    in. Compile-site lines get a small slop: the jit call and the
-    ``.lower()``/``.compile()`` it feeds span a few lines."""
+    in: the entries and compile sites the checked-in ``manifest`` knows,
+    where ``tree`` (a fresh ``build_manifest()``) finds them; the lockfile
+    holds no line numbers. Compile-site lines get a small slop: the jit call
+    and the ``.lower()``/``.compile()`` it feeds span a few lines."""
+    entries = {(e["file"], e["name"], e["reason"]) for e in manifest.get("entries", ())}
+    sites = {(s["file"], s["target"]) for s in manifest.get("compile_sites", ())}
     spans = []
-    for e in manifest.get("entries", ()):
-        spans.append((e["file"], e["line"], e["end"], f"entry {e['name']}"))
-    for s in manifest.get("compile_sites", ()):
-        spans.append(
-            (s["file"], s["line"], s["line"] + 20, f"site {s['target']}")
-        )
+    for e in tree.get("entries", ()):
+        if (e["file"], e["name"], e["reason"]) in entries:
+            spans.append((e["file"], e["line"], e["end"], f"entry {e['name']}"))
+    for s in tree.get("compile_sites", ()):
+        if (s["file"], s["target"]) in sites:
+            spans.append((s["file"], s["line"], s["line"] + 20, f"site {s['target']}"))
     return spans
 
 
@@ -306,7 +310,7 @@ def run_compile_audit(target: str = "all", print_fn: Callable = print) -> int:
             "commit it before auditing against it"
         )
     names = executable_names(manifest)
-    spans = _manifest_spans(manifest)
+    spans = _manifest_spans(manifest, build_manifest())
 
     ledger = CompileLedger()
     problems: list = []

@@ -21,10 +21,16 @@ this module writes the property itself down. It statically enumerates
   vocabulary AOT cache keys may carry.
 
 The product (entries+sites) x (bucket union) x (plan kinds) is the entire
-legal compile surface. It is checked in as ``exec_manifest.json`` next to
-this file; ``graftlint --exec-manifest diff`` fails when code grows a jit
-entry / bucket / plan kind the manifest doesn't know (re-emit to accept),
-and ``--compile-audit`` (compile_audit.py) holds a real run to it.
+legal compile surface. Its *set* is checked in as ``exec_manifest.json``
+next to this file (``locked``): entries by (file, name, reason), compile
+sites by (file, target), bucket sets, plan kinds by file. Where in its file a
+body or a call stands (``line``, ``end``) is a fact about the tree a build
+ran on, not part of the lock: ``build_manifest()`` gives it, the checked-in
+file does not hold it, and a line that moves is no drift.
+``graftlint --exec-manifest diff`` fails when code grows, drops or renames a
+jit entry / compile site / bucket / plan kind the lockfile doesn't know
+(re-emit to accept), and ``--compile-audit`` (compile_audit.py) holds a real
+run to it, with its line windows from a fresh build.
 
 Pure stdlib at import time, like the rest of the package; the yaml parse
 degrades to a regex scan when PyYAML is unavailable.
@@ -35,6 +41,7 @@ from __future__ import annotations
 import ast
 import json
 import re
+from collections import Counter
 from pathlib import Path
 from typing import Optional
 
@@ -48,11 +55,12 @@ __all__ = [
     "covers",
     "executable_names",
     "load_manifest",
+    "locked",
     "run_exec_manifest",
 ]
 
 MANIFEST_PATH = Path(__file__).resolve().parent / "exec_manifest.json"
-MANIFEST_VERSION = 1
+MANIFEST_VERSION = 2  # 2: the checked-in file holds no line numbers
 
 
 def _repo_root() -> Path:
@@ -333,26 +341,47 @@ def covers(manifest: dict, plan_kind: str, bucket: int) -> bool:
     )
 
 
+def locked(manifest: dict) -> dict:
+    """What the lockfile locks of a manifest: the same keys with every line
+    number dropped (an entry's ``line`` / ``end``, a site's ``line``, the
+    ``:line`` after a plan kind's file). Two bodies of one name and reason in
+    one file (two lambdas) stay two."""
+    return {
+        **manifest,
+        "version": MANIFEST_VERSION,
+        "entries": sorted(
+            ({k: e[k] for k in ("file", "name", "reason")} for e in manifest.get("entries", ())),
+            key=lambda e: (e["file"], e["name"], e["reason"]),
+        ),
+        "compile_sites": sorted(
+            ({k: s[k] for k in ("file", "target")} for s in manifest.get("compile_sites", ())),
+            key=lambda s: (s["file"], s["target"]),
+        ),
+        "plan_kinds": {k: v.partition(":")[0] for k, v in manifest.get("plan_kinds", {}).items()},
+    }
+
+
 def _dumps(manifest: dict) -> str:
     return json.dumps(manifest, indent=1, sort_keys=True) + "\n"
 
 
 def _diff_lists(name, old, new, print_fn) -> int:
-    o = {json.dumps(x, sort_keys=True) for x in old}
-    n = {json.dumps(x, sort_keys=True) for x in new}
+    o = Counter(json.dumps(x, sort_keys=True) for x in old)
+    n = Counter(json.dumps(x, sort_keys=True) for x in new)
     bad = 0
-    for item in sorted(n - o):
+    for item in sorted((n - o).elements()):
         print_fn(f"  + {name}: {item}")
         bad += 1
-    for item in sorted(o - n):
+    for item in sorted((o - n).elements()):
         print_fn(f"  - {name}: {item}")
         bad += 1
     return bad
 
 
 def run_exec_manifest(mode: str = "diff", paths=None, print_fn=print) -> int:
-    """CLI driver: ``emit`` writes the manifest, ``print`` dumps it,
-    ``diff`` (the check.sh stage) rebuilds and compares to the checked-in
+    """CLI driver: ``emit`` writes the manifest's lock (``locked``),
+    ``print`` dumps the manifest with the tree's line numbers, ``diff`` (the
+    check.sh stage) rebuilds and compares lock to lock with the checked-in
     file — exit 1 on drift, with the drift itemized."""
     if mode not in ("emit", "diff", "print"):
         raise ValueError(
@@ -364,7 +393,7 @@ def run_exec_manifest(mode: str = "diff", paths=None, print_fn=print) -> int:
         print_fn(_dumps(manifest).rstrip("\n"))
         return 0
     if mode == "emit":
-        MANIFEST_PATH.write_text(_dumps(manifest), encoding="utf-8")
+        MANIFEST_PATH.write_text(_dumps(locked(manifest)), encoding="utf-8")
         print_fn(
             f"exec-manifest: wrote {_rel(MANIFEST_PATH)} "
             f"({len(manifest['entries'])} entries, "
@@ -380,6 +409,8 @@ def run_exec_manifest(mode: str = "diff", paths=None, print_fn=print) -> int:
             "--exec-manifest emit and commit it"
         )
         return 1
+    # A file from before version 2 holds lines: they are dropped, not compared.
+    checked_in, manifest = locked(checked_in), locked(manifest)
     bad = 0
     for key in ("entries", "compile_sites"):
         bad += _diff_lists(key, checked_in.get(key, []), manifest[key], print_fn)

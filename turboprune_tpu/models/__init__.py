@@ -9,14 +9,12 @@ stem surgery is a constructor argument instead of post-hoc module patching.
 
 from __future__ import annotations
 
-from typing import Any, Callable
+from typing import Any, Callable, NamedTuple
 
 import jax.numpy as jnp
 
 from . import densenet, granite, lfm2, nemotron_h, resnet, sdar, vgg, vit
 from .densenet import DenseNet
-from .granite import HybridLM
-from .nemotron_h import NemotronH
 from .resnet import ResNet, resnet18, resnet34, resnet50, resnet101, resnet152
 from .vgg import VGG
 from .vit import VisionTransformer
@@ -47,31 +45,48 @@ MODEL_REGISTRY: dict[str, Callable] = {
     "deit_small_distilled_patch16_224": vit.deit_small_distilled_patch16_224,
     "deit_base_distilled_patch16_224": vit.deit_base_distilled_patch16_224,
     "deit_base_distilled_patch16_384": vit.deit_base_distilled_patch16_384,
-    "granite_4_0_h_micro": granite.granite_4_0_h_micro,
-    "hybrid_lm_tiny": granite.hybrid_lm_tiny,
-    "nemotron_3_super_120b_a12b": nemotron_h.nemotron_3_super_120b_a12b,
-    "nemotron_h_tiny": nemotron_h.nemotron_h_tiny,
-    "sdar_30b_a3b": sdar.sdar_30b_a3b,
-    "sdar_moe_tiny": sdar.sdar_moe_tiny,
-    "lfm2_8b_a1b": lfm2.lfm2_8b_a1b,
-    "lfm2_moe_tiny": lfm2.lfm2_moe_tiny,
 }
-# Models that read packed token batches (data/tokens.py) and return logits
-# over their vocabulary, ``num_classes``.
-LANGUAGE_MODELS = (
-    "granite_4_0_h_micro", "hybrid_lm_tiny", "nemotron_3_super_120b_a12b", "nemotron_h_tiny",
-    "sdar_30b_a3b", "sdar_moe_tiny", "lfm2_8b_a1b", "lfm2_moe_tiny",
-)  # fmt: skip
-# Of those, the ones built as one chip's share of a deployment
-# (models/nemotron_h.py, models/sdar.py, models/lfm2.py): they take
-# ``layer_pattern`` and ``share``.
-SHARED_MODELS = (
-    "nemotron_3_super_120b_a12b", "nemotron_h_tiny", "sdar_30b_a3b", "sdar_moe_tiny",
-    "lfm2_8b_a1b", "lfm2_moe_tiny",
-)  # fmt: skip
-# And the ones trained by diffusion over blocks (models/sdar.py): their batch
-# is the noised one, ``dataset_params.block_length`` > 0.
-BLOCK_DIFFUSION_MODELS = ("sdar_30b_a3b", "sdar_moe_tiny")
+
+
+class LanguageModel(NamedTuple):
+    """A model that reads packed token batches (data/tokens.py) and returns
+    logits over its vocabulary, ``num_classes``. Its factory takes
+    ``(num_classes, *, num_layers, dtype, layer_pattern, share)`` and refuses
+    what it has no use for."""
+
+    factory: Callable
+    shared: bool = False  # built as one chip's share of a deployment: takes ``share``
+    block_diffusion: bool = False  # reads noised batches, ``dataset_params.block_length`` > 0
+
+    @property
+    def name(self) -> str:
+        """What it is registered under: its factory's own name."""
+        return self.factory.__name__
+
+
+# The one place that says what kind a language model is. A new one is a file
+# beside these (its blocks from models/blocks.py), an entry here, its conf/
+# files and its tests.
+LANGUAGE_TABLE = (
+    LanguageModel(granite.granite_4_0_h_micro),
+    LanguageModel(granite.hybrid_lm_tiny),
+    LanguageModel(nemotron_h.nemotron_3_super_120b_a12b, shared=True),
+    LanguageModel(nemotron_h.nemotron_h_tiny, shared=True),
+    LanguageModel(sdar.sdar_30b_a3b, shared=True, block_diffusion=True),
+    LanguageModel(sdar.sdar_moe_tiny, shared=True, block_diffusion=True),
+    LanguageModel(lfm2.lfm2_8b_a1b, shared=True),
+    LanguageModel(lfm2.lfm2_moe_tiny, shared=True),
+)
+MODEL_REGISTRY.update({lm.name: lm.factory for lm in LANGUAGE_TABLE})
+LANGUAGE_MODELS = tuple(lm.name for lm in LANGUAGE_TABLE)
+SHARED_MODELS = tuple(lm.name for lm in LANGUAGE_TABLE if lm.shared)
+BLOCK_DIFFUSION_MODELS = tuple(lm.name for lm in LANGUAGE_TABLE if lm.block_diffusion)
+
+
+def is_language_model(model) -> bool:
+    """Whether ``model`` is what a factory of ``LANGUAGE_TABLE`` builds: its
+    class is defined in that factory's file."""
+    return type(model).__module__ in {lm.factory.__module__ for lm in LANGUAGE_TABLE}
 
 
 def create_model(
@@ -103,9 +118,9 @@ def create_model(
     ``width_overrides``. ``num_layers`` is a language model's depth (0 = as
     published); it always runs its causal flash kernel, whatever
     ``attention_impl`` says of the ViTs. ``layer_pattern`` and ``share`` are
-    models/nemotron_h.py's, models/sdar.py's and models/lfm2.py's: the stretch of the published
-    pattern that is run, and (tensor_parallel, expert_parallel, expert_rank)
-    of the deployment whose one chip this is."""
+    the ``SHARED_MODELS``': the stretch of the published pattern that is run,
+    and (tensor_parallel, expert_parallel, expert_rank) of the deployment
+    whose one chip this is; a model with no use for one refuses it."""
     if model_name not in MODEL_REGISTRY:
         raise ValueError(
             f"Model {model_name!r} not in registry: {sorted(MODEL_REGISTRY)}"
@@ -118,13 +133,10 @@ def create_model(
                 f"{model_name!r} has no compacted or gathered form "
                 "(sparse/graph.py): it runs masked"
             )
-        if model_name in SHARED_MODELS:
-            kwargs = {"layer_pattern": layer_pattern, "share": tuple(share)}
-        elif layer_pattern or tuple(share):
-            raise ValueError(f"{model_name!r} has no layer_pattern and no share")
         return MODEL_REGISTRY[model_name](
-            num_classes, num_layers=num_layers, dtype=compute_dtype, **kwargs
-        )
+            num_classes, num_layers=num_layers, dtype=compute_dtype,
+            layer_pattern=layer_pattern, share=tuple(share),
+        )  # fmt: skip
     if model_name.startswith("deit"):
         kwargs = {"attention_impl": attention_impl, "mesh": mesh}
     elif attention_impl != "dense":
@@ -145,10 +157,11 @@ __all__ = [
     "MODEL_REGISTRY",
     "create_model",
     "DenseNet",
-    "HybridLM",
     "BLOCK_DIFFUSION_MODELS",
     "LANGUAGE_MODELS",
-    "NemotronH",
+    "LANGUAGE_TABLE",
+    "SHARED_MODELS",
+    "is_language_model",
     "ResNet",
     "VGG",
     "VisionTransformer",

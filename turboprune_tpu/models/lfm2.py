@@ -23,16 +23,14 @@ experts; the head is the embedding.
 
 The input and the packing are models/granite.py's (``tokens [B, 2, T]``, ids
 and document ids; nothing crosses a document's start: not the convolution,
-not attention, not the positions). What this file shares it imports:
-``RMSNorm``, ``_dense``, ``_same_document`` and ``SwiGLU`` from
-models/granite.py, ``rotary`` and ``GatedExperts`` (the pair buffer over three
-stacked kernels; it reads ``hidden_size``, ``moe_intermediate_size``,
-``num_experts`` and ``num_experts_per_tok`` off whatever configuration it is
-given) from models/sdar.py, the sigmoid ``Router`` (float32, a selection
-bias; here with the source's 1e-6) and ``Share`` from models/nemotron_h.py,
-and the packed causal kernels from ops/flash.py.
+not attention, not the positions). What this file shares is
+models/blocks.py's: ``RMSNorm``, ``dense``, ``same_document``, ``SwiGLU``,
+``RotaryAttention`` (here under ``packed_causal``: a document's own positions
+and ops/flash.py's packed causal kernels), ``SparseMoE`` over ``GatedExperts``
+(the pair buffer over three stacked kernels) and the sigmoid ``Router``
+(float32, a selection bias; here with the source's 1e-6), and ``Share``.
 
-**A chip's share** (``held``; ``Share`` is models/nemotron_h.py's):
+**A chip's share** (``Share.of``, which divides what ``Lfm2Config.DIVIDED`` lists):
 ``tensor_parallel`` chips divide the query heads, each holding the key/value
 heads its query heads read, the convolution's channels (the same channels of
 ``B``, ``C`` and ``u``, and the rows of ``W_out`` that read them), the dense
@@ -77,13 +75,13 @@ from typing import Any
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
-from jax.ad_checkpoint import checkpoint_name
 
 from ..ops import moe, remat
 from ..ops.flash import flash_attention_causal
-from .granite import FLASH_BLOCK, RMSNorm, SwiGLU, _dense, _same_document
-from .nemotron_h import Router, Share
-from .sdar import GatedExperts, rotary
+from .blocks import (
+    FLASH_BLOCK, GatedExperts, RMSNorm, RotaryAttention, Router, Share, SparseMoE, SwiGLU, dense,
+    same_document,
+)  # fmt: skip
 
 # What the backward pass of a layer keeps beside the layer's input.
 SAVED = ("router_logits", "router_top", "moe_order", "attn_q", "attn_k", "attn_v")
@@ -112,35 +110,24 @@ class Lfm2Config:
     routed_scaling_factor: float
     num_hidden_layers: int
 
+    # What ``Share.of`` divides (models/blocks.py), by field.
+    DIVIDED = {
+        "query_heads": "num_attention_heads", "conv_channels": "hidden_size",
+        "dense_columns": "intermediate_size",
+    }  # fmt: skip
+    KV_HEADS, EXPERTS = "num_key_value_heads", "num_experts"
+
 
 def held(c: Lfm2Config, share: Share) -> dict:
     """What this chip holds of each layer."""
-    tp, ep = share.tensor_parallel, share.expert_parallel
-    for name, count in (
-        ("num_attention_heads", c.num_attention_heads), ("hidden_size", c.hidden_size),
-        ("intermediate_size", c.intermediate_size),
-    ):  # fmt: skip
-        if count % tp:
-            raise ValueError(f"{name} {count} does not divide over {tp} chips")
-    if c.num_experts % ep or not 0 <= share.expert_rank < ep:
-        raise ValueError(f"{c.num_experts} experts, rank {share.expert_rank} of {ep}")
-    experts_here = c.num_experts // ep
-    return dict(
-        query_heads=c.num_attention_heads // tp,
-        # A key/value head is held by every chip that holds a query head of its group.
-        kv_heads=max(c.num_key_value_heads // tp, 1),
-        conv_channels=c.hidden_size // tp,
-        dense_columns=c.intermediate_size // tp,
-        experts_here=experts_here,
-        expert_offset=share.expert_rank * experts_here,
-    )
+    return share.of(c)
 
 
 def positions(seg):
     """[B, T] int32: each token's index inside its document, from the
     document ids alone."""
     at = jnp.arange(seg.shape[1], dtype=jnp.int32)
-    starts = jnp.where(_same_document(seg, 1), 0, at)  # a document's first token: its index
+    starts = jnp.where(same_document(seg, 1), 0, at)  # a document's first token: its index
     return at - jax.lax.cummax(starts, axis=1)
 
 
@@ -155,7 +142,7 @@ class ShortConv(nn.Module):
     @nn.compact
     def __call__(self, u, seg):
         with jax.named_scope("conv/in_proj"):
-            b, c, x = jnp.split(_dense(3 * self.channels, self.dtype, "in_proj")(u), 3, axis=-1)
+            b, c, x = jnp.split(dense(3 * self.channels, self.dtype, "in_proj")(u), 3, axis=-1)
         bound = 1.0 / math.sqrt(self.width)
         taps = self.param(
             "conv_taps",
@@ -169,69 +156,23 @@ class ShortConv(nn.Module):
             for k in range(self.width):
                 shift = self.width - 1 - k
                 earlier = jnp.pad(v, ((0, 0), (shift, 0), (0, 0)))[:, : v.shape[1]]
-                conv = conv + taps[k] * jnp.where(_same_document(seg, shift)[..., None], earlier, 0)
+                conv = conv + taps[k] * jnp.where(same_document(seg, shift)[..., None], earlier, 0)
             y = c * conv
         with jax.named_scope("conv/out_proj"):
-            return _dense(u.shape[-1], self.dtype, "out_proj")(y)
+            return dense(u.shape[-1], self.dtype, "out_proj")(y)
 
 
-class RotaryAttention(nn.Module):
-    heads: int
-    kv_heads: int
-    head_dim: int
-    eps: float
-    theta: float
-    dtype: Any = jnp.float32
+def packed_causal(seg):
+    """``RotaryAttention``'s rule for packed documents ``seg`` [B, T]: a
+    token's position is its index inside its document, and a query sees the
+    keys of its document at or before itself (ops/flash.py's second part)."""
 
-    @nn.compact
-    def __call__(self, u, seg):
-        bsz, t, dim = u.shape
-        d = self.head_dim
-        with jax.named_scope("attn/qkv"):
-            q = _dense(self.heads * d, self.dtype, "q_proj")(u).reshape(bsz, t, self.heads, d)
-            k = _dense(self.kv_heads * d, self.dtype, "k_proj")(u).reshape(bsz, t, self.kv_heads, d)
-            v = _dense(self.kv_heads * d, self.dtype, "v_proj")(u).reshape(bsz, t, self.kv_heads, d)
-        with jax.named_scope("attn/qk_norm"):
-            q = RMSNorm(self.eps, self.dtype, name="q_norm")(q)
-            k = RMSNorm(self.eps, self.dtype, name="k_norm")(k)
-        with jax.named_scope("attn/rope"):
-            pos = positions(seg)
-            q, k = rotary(q, pos, self.theta), rotary(k, pos, self.theta)
-            by_head = lambda x: x.transpose(0, 2, 1, 3).reshape(-1, t, d)
-            q, k, v = (
-                checkpoint_name(by_head(x), f"attn_{n}") for x, n in ((q, "q"), (k, "k"), (v, "v"))
-            )
-        with jax.named_scope("attn/flash"):
-            block = math.gcd(t, FLASH_BLOCK)
-            out = flash_attention_causal(q, k, v, seg, 1.0 / math.sqrt(d), block, block)
-        with jax.named_scope("attn/out_proj"):
-            out = out.reshape(bsz, self.heads, t, d).transpose(0, 2, 1, 3)
-            return _dense(dim, self.dtype, "o_proj")(out.reshape(bsz, t, -1))
+    def kernel(q, k, v):
+        block = math.gcd(q.shape[1], FLASH_BLOCK)
+        scale = 1.0 / math.sqrt(q.shape[2])
+        return flash_attention_causal(q, k, v, seg, scale, block, block), {}  # nothing to sow
 
-
-class SparseMoE(nn.Module):
-    cfg: Lfm2Config
-    experts_here: int
-    expert_offset: int
-    dtype: Any = jnp.float32
-
-    @nn.compact
-    def __call__(self, h32):
-        """``h32`` [B, T, D]: the layer's normed input, float32."""
-        c = self.cfg
-        flat = h32.reshape(-1, h32.shape[-1])
-        with jax.named_scope("moe/router"):
-            top, weights = Router(
-                c.num_experts, c.num_experts_per_tok, c.routed_scaling_factor, ROUTER_EPS,
-                name="router",
-            )(flat)  # fmt: skip
-        self.sow("intermediates", "top", top)
-        out, counters = GatedExperts(
-            c, self.experts_here, self.expert_offset, self.dtype, name="experts"
-        )(flat.astype(self.dtype), top, weights)
-        for name, value in counters.items():
-            self.sow("counters", name, value)
-        return out.astype(self.dtype).reshape(h32.shape)
+    return lambda: positions(seg), kernel
 
 
 class Lfm2Block(nn.Module):
@@ -243,7 +184,7 @@ class Lfm2Block(nn.Module):
 
     @nn.compact
     def __call__(self, x, seg):
-        c, here = self.cfg, held(self.cfg, self.share)
+        c, here = self.cfg, self.share.of(self.cfg)
         u = RMSNorm(c.norm_eps, self.dtype, name="operator_norm")(x)
         if self.kind == "conv":
             y = ShortConv(here["conv_channels"], c.conv_L_cache, self.dtype, name="mixer")(u, seg)
@@ -251,7 +192,7 @@ class Lfm2Block(nn.Module):
             y = RotaryAttention(
                 here["query_heads"], here["kv_heads"], c.head_dim, c.norm_eps, c.rope_theta,
                 self.dtype, name="mixer",
-            )(u, seg)  # fmt: skip
+            )(u, *packed_causal(seg))  # fmt: skip
         else:
             raise ValueError(f"no layer kind {self.kind!r} {KINDS}")
         h = x + y
@@ -261,8 +202,16 @@ class Lfm2Block(nn.Module):
         self.sow("intermediates", "moe_in", h)
         u = RMSNorm(c.norm_eps, jnp.float32, name="ffn_norm")(h)
         return h + SparseMoE(
-            c, here["experts_here"], here["expert_offset"], self.dtype, name="mlp"
-        )(u)
+            Router(
+                c.num_experts, c.num_experts_per_tok, c.routed_scaling_factor, ROUTER_EPS,
+                parent=None,
+            ),
+            GatedExperts(
+                c.hidden_size, c.moe_intermediate_size, c.num_experts, c.num_experts_per_tok,
+                here["experts_here"], here["expert_offset"], self.dtype, parent=None,
+            ),
+            name="mlp",
+        )(u)  # fmt: skip
 
 
 class Lfm2(nn.Module):

@@ -11,9 +11,10 @@ a LatentMoE: sparse routed experts in a latent space beside one shared expert.
         out = W_up (sum_{i in top} w_i f_i(z)) + V2 relu(V1 h)^2
 
 The input and the packing are models/granite.py's (``tokens [B, 2, T]``, ids
-and document ids; nothing crosses a document's start), and so are the blocks
-this file imports from it: ``RMSNorm``, ``MambaMixer`` (here with ``n_groups``
-groups), ``AttentionMixer`` and ``_dense``. Every layer is a
+and document ids; nothing crosses a document's start). What this file shares
+is models/blocks.py's: ``RMSNorm``, ``MambaMixer`` (here with ``n_groups``
+groups), ``AttentionMixer``, ``dense``, the sigmoid ``Router``, the untied
+``Head`` and ``Share``. Every layer is a
 ``jax.checkpoint``: a backward pass keeps the layer's input, rebuilds the
 layer's inside (the two grouped products of the experts and the kernels of
 the scan and of attention among it) and keeps ``SAVED``, values tagged where
@@ -25,7 +26,8 @@ outputs (16.8 and 11 MB); an ``M`` layer's ``in_proj`` output (38 MB); a
 ``*`` layer's q, k and v (12.6 MB): 0.43 GB over one period.
 
 **A chip's share.** The model is built as one chip of a deployment holds it
-(``Share``): ``tensor_parallel`` chips divide every mixer's heads (and with
+(``Share.of``, which divides what ``NemotronHConfig.DIVIDED`` lists):
+``tensor_parallel`` chips divide every mixer's heads (and with
 them Mamba's groups), the shared expert's columns and nothing else;
 ``expert_parallel`` chips divide the routed experts, and ``expert_rank`` says
 which of them are here. The router keeps its width and its ``top_k`` and
@@ -46,7 +48,7 @@ flipped 22nd place moves the output by a whole expert.
 
 Named scopes: ``moe/router``, ``moe/latent_down``, ``moe/dispatch``,
 ``moe/experts``, ``moe/combine``, ``moe/latent_up``, ``moe/shared`` beside
-granite.py's ``mamba/*``, ``ssd``, ``attn/*`` and ``lm_head``. Each ``E``
+blocks.py's ``mamba/*``, ``ssd``, ``attn/*``, and ``lm_head``. Each ``E``
 layer sows ops/moe.py's ``COUNTERS`` into the ``counters`` collection (the
 train step sums them into its metrics), and, where a caller makes
 ``intermediates`` mutable, the layer's input and the experts it chose.
@@ -64,7 +66,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from ..ops import moe, remat
-from .granite import AttentionMixer, MambaMixer, RMSNorm, _dense
+from .blocks import AttentionMixer, Head, MambaMixer, RMSNorm, Router, Share, dense
 
 
 # What the backward pass of a layer keeps beside the layer's input.
@@ -98,56 +100,13 @@ class NemotronHConfig:
     moe_shared_expert_intermediate_size: int
     num_hidden_layers: int
 
-
-@dataclasses.dataclass(frozen=True)
-class Share:
-    """Over how many chips a layer is divided, and which of them this is."""
-
-    tensor_parallel: int = 1
-    expert_parallel: int = 1
-    expert_rank: int = 0
-
-    def of(self, c: NemotronHConfig) -> dict:
-        """What this chip holds of each layer."""
-        tp, ep = self.tensor_parallel, self.expert_parallel
-        for name, count in (
-            ("mamba_num_heads", c.mamba_num_heads), ("n_groups", c.n_groups),
-            ("num_attention_heads", c.num_attention_heads),
-            ("moe_shared_expert_intermediate_size", c.moe_shared_expert_intermediate_size),
-        ):  # fmt: skip
-            if count % tp:
-                raise ValueError(f"{name} {count} does not divide over {tp} chips")
-        if c.n_routed_experts % ep or not 0 <= self.expert_rank < ep:
-            raise ValueError(f"{c.n_routed_experts} experts, rank {self.expert_rank} of {ep}")
-        experts_here = c.n_routed_experts // ep
-        return dict(
-            mamba_heads=c.mamba_num_heads // tp,
-            mamba_groups=c.n_groups // tp,
-            query_heads=c.num_attention_heads // tp,
-            # A key/value head is held by every chip that holds a query head of its group.
-            kv_heads=max(c.num_key_value_heads // tp, 1),
-            shared_columns=c.moe_shared_expert_intermediate_size // tp,
-            experts_here=experts_here,
-            expert_offset=self.expert_rank * experts_here,
-        )
-
-
-class Router(nn.Module):
-    """Float32 whatever the compute dtype. ``weight`` is a matrix and not a
-    ``kernel``: it is never masked."""
-
-    experts: int
-    top_k: int
-    scaling: float
-    eps: float = 1e-20  # beside the chosen scores' sum (models/lfm2.py's source has 1e-6)
-
-    @nn.compact
-    def __call__(self, h32):
-        weight = self.param("weight", nn.initializers.normal(0.02), (h32.shape[-1], self.experts))
-        bias = self.param("bias", nn.initializers.zeros, (self.experts,))
-        logits = jnp.einsum("nd,de->ne", h32, weight, precision=jax.lax.Precision.HIGHEST)
-        logits = checkpoint_name(logits, "router_logits")
-        return moe.route(logits, bias, self.top_k, self.scaling, self.eps)
+    # What ``Share.of`` divides (models/blocks.py), by field.
+    DIVIDED = {
+        "mamba_heads": "mamba_num_heads", "mamba_groups": "n_groups",
+        "query_heads": "num_attention_heads",
+        "shared_columns": "moe_shared_expert_intermediate_size",
+    }  # fmt: skip
+    KV_HEADS, EXPERTS = "num_key_value_heads", "n_routed_experts"
 
 
 class Experts(nn.Module):
@@ -195,7 +154,7 @@ class LatentMoE(nn.Module):
             )(h32.reshape(bsz * t, dim))
         self.sow("intermediates", "top", top)
         with jax.named_scope("moe/latent_down"):
-            z = checkpoint_name(_dense(c.moe_latent_size, self.dtype, "latent_down")(h), "moe_latent_down")
+            z = checkpoint_name(dense(c.moe_latent_size, self.dtype, "latent_down")(h), "moe_latent_down")
             z = z.reshape(bsz * t, -1)
         mixed, counters = Experts(
             c, self.experts_here, self.expert_offset, self.dtype, name="experts"
@@ -204,10 +163,10 @@ class LatentMoE(nn.Module):
             self.sow("counters", name, value)
         with jax.named_scope("moe/latent_up"):
             mixed = mixed.astype(self.dtype).reshape(bsz, t, -1)
-            routed = _dense(dim, self.dtype, "latent_up", self.out_std)(mixed)
+            routed = dense(dim, self.dtype, "latent_up", self.out_std)(mixed)
         with jax.named_scope("moe/shared"):
-            up = checkpoint_name(_dense(self.shared_columns, self.dtype, "shared_up")(h), "moe_shared_up")
-            shared = _dense(dim, self.dtype, "shared_down", self.out_std)(jnp.square(nn.relu(up)))
+            up = checkpoint_name(dense(self.shared_columns, self.dtype, "shared_up")(h), "moe_shared_up")
+            shared = dense(dim, self.dtype, "shared_down", self.out_std)(jnp.square(nn.relu(up)))
         return routed + shared
 
 
@@ -246,18 +205,6 @@ class NemotronBlock(nn.Module):
         else:
             raise ValueError(f"no layer kind {self.kind!r} (M, * or E)")
         return x + y
-
-
-class Head(nn.Module):
-    vocab_size: int
-    dtype: Any = jnp.float32
-
-    @nn.compact
-    def __call__(self, x):
-        kernel = self.param("kernel", nn.initializers.normal(0.02), (x.shape[-1], self.vocab_size))
-        return jnp.einsum(
-            "btd,dv->btv", x, kernel.astype(self.dtype), preferred_element_type=jnp.float32
-        )
 
 
 class NemotronH(nn.Module):

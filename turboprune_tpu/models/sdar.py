@@ -47,7 +47,7 @@ rows a step and is then most of the whole tree's gradient norm, one sum that
 no averaging steadies; through the multiplier its gradient is a row's like
 any other's.
 
-**A chip's share** (``Share``, as models/nemotron_h.py): ``tensor_parallel``
+**A chip's share** (models/blocks.py's ``Share``): ``tensor_parallel``
 chips divide the query heads, each holding the key/value heads its query
 heads read (one held by several chips where there are more chips than
 key/value heads), and the vocabulary (``num_classes`` is what is held);
@@ -93,8 +93,7 @@ from jax.ad_checkpoint import checkpoint_name
 from ..data.tokens import BLK, CLEAN, DOC, NOISED, POS
 from ..ops import moe, remat
 from ..ops.flash import blockdiff_walk_counts, flash_attention_blockdiff
-from .granite import FLASH_BLOCK, RMSNorm, _dense
-from .nemotron_h import Head, Share
+from .blocks import FLASH_BLOCK, GatedExperts, Head, RMSNorm, RotaryAttention, Share, SparseMoE
 
 MASK_ROW = 0.01  # what the mask's embedding row is multiplied by as it is read
 # What a layer's attention sows: the (query block, key block) pairs a head's
@@ -121,71 +120,28 @@ class SdarConfig:
     moe_intermediate_size: int
     num_hidden_layers: int
 
+    # What ``Share.of`` divides (models/blocks.py), by field.
+    DIVIDED = {"query_heads": "num_attention_heads"}
+    KV_HEADS, EXPERTS = "num_key_value_heads", "num_experts"
+
 
 def held(c: SdarConfig, share: Share) -> dict:
     """What this chip holds of each layer."""
-    tp, ep = share.tensor_parallel, share.expert_parallel
-    if c.num_attention_heads % tp:
-        raise ValueError(f"num_attention_heads {c.num_attention_heads} does not divide over {tp} chips")
-    if c.num_experts % ep or not 0 <= share.expert_rank < ep:
-        raise ValueError(f"{c.num_experts} experts, rank {share.expert_rank} of {ep}")
-    experts_here = c.num_experts // ep
-    return dict(
-        query_heads=c.num_attention_heads // tp,
-        # A key/value head is held by every chip that holds a query head of its group.
-        kv_heads=max(c.num_key_value_heads // tp, 1),
-        experts_here=experts_here,
-        expert_offset=share.expert_rank * experts_here,
-    )
+    return share.of(c)
 
 
-def rotary(x, pos, theta: float):
-    """``x`` [B, R, H, D] rotated by ``pos`` [B, R]: the halves of the head
-    dimension as one complex number a frequency, ``theta ** (-2 j / D)``."""
-    half = x.shape[-1] // 2
-    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    angle = pos.astype(jnp.float32)[..., None, None] * freq  # [B, R, 1, D / 2]
-    cos, sin = jnp.cos(angle), jnp.sin(angle)
-    x1, x2 = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1).astype(x.dtype)
+def block_diffusion(doc, blk, pos):
+    """``RotaryAttention``'s rule for the two copies of a sequence, the clean
+    rows then the noised: ``doc``, ``blk``, ``pos`` [B, T] are the same for
+    both, and the mask is ops/flash.py's third part."""
 
+    def kernel(q, k, v):
+        block = math.gcd(doc.shape[1], FLASH_BLOCK)
+        scale = 1.0 / math.sqrt(q.shape[2])
+        out = flash_attention_blockdiff(q, k, v, doc, blk, scale, block, block)
+        return out, dict(zip(FLASH_COUNTERS, blockdiff_walk_counts(doc, blk, block, block)))
 
-class BlockDiffusionAttention(nn.Module):
-    heads: int
-    kv_heads: int
-    head_dim: int
-    eps: float
-    theta: float
-    dtype: Any = jnp.float32
-
-    @nn.compact
-    def __call__(self, u, doc, blk, pos):
-        """``u`` [B, 2T, D], the clean rows then the noised; ``doc``, ``blk``,
-        ``pos`` [B, T], the same for both copies."""
-        bsz, rows, dim = u.shape
-        d = self.head_dim
-        with jax.named_scope("attn/qkv"):
-            q = _dense(self.heads * d, self.dtype, "q_proj")(u).reshape(bsz, rows, self.heads, d)
-            k = _dense(self.kv_heads * d, self.dtype, "k_proj")(u).reshape(bsz, rows, self.kv_heads, d)
-            v = _dense(self.kv_heads * d, self.dtype, "v_proj")(u).reshape(bsz, rows, self.kv_heads, d)
-        with jax.named_scope("attn/qk_norm"):
-            q = RMSNorm(self.eps, self.dtype, name="q_norm")(q)
-            k = RMSNorm(self.eps, self.dtype, name="k_norm")(k)
-        with jax.named_scope("attn/rope"):
-            both = jnp.concatenate([pos, pos], axis=1)
-            q, k = rotary(q, both, self.theta), rotary(k, both, self.theta)
-            by_head = lambda x: x.transpose(0, 2, 1, 3).reshape(-1, rows, d)
-            q, k, v = (
-                checkpoint_name(by_head(x), f"attn_{n}") for x, n in ((q, "q"), (k, "k"), (v, "v"))
-            )
-        with jax.named_scope("attn/flash"):
-            block = math.gcd(rows // 2, FLASH_BLOCK)
-            out = flash_attention_blockdiff(q, k, v, doc, blk, 1.0 / math.sqrt(d), block, block)
-            for name, value in zip(FLASH_COUNTERS, blockdiff_walk_counts(doc, blk, block, block)):
-                self.sow("counters", name, value)
-        with jax.named_scope("attn/out_proj"):
-            out = out.reshape(bsz, self.heads, rows, d).transpose(0, 2, 1, 3)
-            return _dense(dim, self.dtype, "o_proj")(out.reshape(bsz, rows, -1))
+    return lambda: jnp.concatenate([pos, pos], axis=1), kernel
 
 
 class SoftmaxRouter(nn.Module):
@@ -202,57 +158,6 @@ class SoftmaxRouter(nn.Module):
         return moe.route_softmax(checkpoint_name(logits, "router_logits"), self.top_k)
 
 
-class GatedExperts(nn.Module):
-    """The routed experts held here, as three stacked kernels
-    ``[experts, in, out]``."""
-
-    cfg: SdarConfig
-    experts_here: int
-    expert_offset: int
-    dtype: Any = jnp.float32
-
-    @nn.compact
-    def __call__(self, h, top, weights):
-        c = self.cfg
-        init = nn.initializers.normal(0.02)
-        up = (self.experts_here, c.hidden_size, c.moe_intermediate_size)
-        kernels = (
-            self.param("kernel_gate", init, up),
-            self.param("kernel_up", init, up),
-            self.param("kernel_down", init, (up[0], up[2], up[1])),
-        )
-        routing = (h.shape[0], c.num_experts_per_tok, c.num_experts)
-        capacity, tile = moe.pair_capacity(*routing, self.experts_here), moe.pair_tile(*routing)
-        out, counters = moe.routed_experts(
-            h, top, weights, tuple(k.astype(self.dtype) for k in kernels), self.expert_offset,
-            capacity, tile,
-        )  # fmt: skip
-        counters["moe_rounds"] = moe.rounds(top, self.expert_offset, self.experts_here, capacity, tile)
-        return out, counters
-
-
-class SparseMoE(nn.Module):
-    cfg: SdarConfig
-    experts_here: int
-    expert_offset: int
-    dtype: Any = jnp.float32
-
-    @nn.compact
-    def __call__(self, h32):
-        """``h32`` [B, R, D]: the layer's normed input, float32."""
-        c = self.cfg
-        flat = h32.reshape(-1, h32.shape[-1])
-        with jax.named_scope("moe/router"):
-            top, weights = SoftmaxRouter(c.num_experts, c.num_experts_per_tok, name="router")(flat)
-        self.sow("intermediates", "top", top)
-        out, counters = GatedExperts(
-            c, self.experts_here, self.expert_offset, self.dtype, name="experts"
-        )(flat.astype(self.dtype), top, weights)
-        for name, value in counters.items():
-            self.sow("counters", name, value)
-        return out.astype(self.dtype).reshape(h32.shape)
-
-
 class SdarBlock(nn.Module):
     cfg: SdarConfig
     share: Share
@@ -260,17 +165,22 @@ class SdarBlock(nn.Module):
 
     @nn.compact
     def __call__(self, x, doc, blk, pos):
-        c, here = self.cfg, held(self.cfg, self.share)
+        c, here = self.cfg, self.share.of(self.cfg)
         u = RMSNorm(c.rms_norm_eps, self.dtype, name="input_norm")(x)
-        h = x + BlockDiffusionAttention(
+        h = x + RotaryAttention(
             here["query_heads"], here["kv_heads"], c.head_dim, c.rms_norm_eps, c.rope_theta,
             self.dtype, name="attn",
-        )(u, doc, blk, pos)  # fmt: skip
+        )(u, *block_diffusion(doc, blk, pos))  # fmt: skip
         self.sow("intermediates", "moe_in", h)
         u = RMSNorm(c.rms_norm_eps, jnp.float32, name="post_attention_norm")(h)
         return h + SparseMoE(
-            c, here["experts_here"], here["expert_offset"], self.dtype, name="mlp"
-        )(u)
+            SoftmaxRouter(c.num_experts, c.num_experts_per_tok, parent=None),
+            GatedExperts(
+                c.hidden_size, c.moe_intermediate_size, c.num_experts, c.num_experts_per_tok,
+                here["experts_here"], here["expert_offset"], self.dtype, parent=None,
+            ),
+            name="mlp",
+        )(u)  # fmt: skip
 
 
 class Sdar(nn.Module):

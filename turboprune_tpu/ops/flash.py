@@ -8,11 +8,12 @@ models' path; its kernels are the second part of this file and say what
 differs). ``flash_attention_blockdiff``: the block-diffusion mask over a clean
 and a noised copy of every packed sequence (models/sdar.py; the third part).
 The two packed families share the three bodies of the recurrence
-(``_fwd_step``, ``_dq_step``, ``_dkv_step``) and the three kernels around
-them, and differ in which (query, key) pairs a body is told to keep and which
-blocks of scores it is run for. Their grids are not the square of blocks: the
-inner axis walks the list of (query block, key block) pairs that run, read
-from scalar prefetch ("the packed families' walk", before the second part).
+(``_fwd_step``, ``_dq_step``, ``_dkv_step``), the three kernels around them
+and the three calls of those, and differ in which (query, key) pairs a body
+is told to keep and which blocks of scores it is run for. Their grids are not
+the square of blocks: the inner axis walks the list of (query block, key
+block) pairs that run, read from scalar prefetch ("the packed families'
+walk", before the second part).
 
 Attention is computed blockwise so the S x S score matrix never materializes in HBM: for each
 query block the kernel streams key/value blocks through VMEM, carrying the
@@ -43,7 +44,7 @@ raises: nothing here re-routes to interpret mode or to dense attention.
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -320,6 +321,10 @@ flash_attention.defvjp(_fa_fwd, _fa_bwd)
 # shorter repeats its last pair's word without the flags, which fetches
 # nothing new, writes nothing and skips the body. The words' array is as long
 # as the square, which no list can outgrow.
+#
+# The three ``pallas_call``s are written once (``_packed_fwd``,
+# ``_packed_bwd``) over what a family supplies as a ``_Family``: its kernels'
+# names, its set-up, its rule and the two operands the rule reads.
 _FIRST, _LAST, _RUN = 1 << 28, 1 << 29, 1 << 30
 _OUTER_BITS, _INNER_BITS = 12, 16
 
@@ -490,6 +495,101 @@ def _walk_dkv_kernel(walk, q_ref, k_ref, v_ref, a_ref, b_ref, do_ref, lse_ref, d
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
+class _Family(NamedTuple):
+    """What the two packed families' calls differ by. A family is a value:
+    nothing selects one but the entry point that holds it."""
+
+    # The kernels' names begin with it: traces and benchmarks/metrics/ tell the families apart by it.
+    name: str
+    setup: Callable  # (q, k, *rule, block_q, block_k) -> (heads, kv_heads, group, nq, nk), a, b, pairs
+    keep: Callable  # (block_q, block_k) -> the kernels' ``keep(a_ref, b_ref, qi, ki)`` [Bq, Bk]
+    specs: Callable  # (at, nk, block_q, block_k) -> the BlockSpecs of ``a`` and ``b``
+
+
+def _packed_fwd(family, q, k, v, rule, scale, block_q, block_k, interpret):
+    """``rule``: the arrays the family's mask is made from, as its entry
+    point takes them."""
+    if interpret is None:
+        interpret = _use_interpret()
+    (heads, _, group, nq, nk), a, b, pairs = family.setup(q, k, *rule, block_q, block_k)
+    bh, rows, d = q.shape
+    walk, n_run = _walk(pairs)
+    at, q_rows, q_col, kv = _query_major(heads, group, nq * nk, block_q, block_k, d)
+    o, lse = pl.pallas_call(
+        functools.partial(_walk_fwd_kernel, scale=scale, heads=heads, n=nq * nk,
+                          keep=family.keep(block_q, block_k)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(bh, n_run.max()),
+            in_specs=[q_rows, kv, kv, *family.specs(at, nk, block_q, block_k)],
+            out_specs=[q_rows, q_col],
+            scratch_shapes=[
+                pltpu.VMEM((block_q, d), jnp.float32),
+                pltpu.VMEM((block_q, 1), jnp.float32),
+                pltpu.VMEM((block_q, 1), jnp.float32),
+            ],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((bh, rows, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, rows, 1), jnp.float32),
+        ],
+        interpret=interpret,
+        name=f"{family.name}_fwd",
+    )(walk, q, k, v, a, b)
+    return o, (q, k, v, *rule, o, lse)
+
+
+def _packed_bwd(family, scale, block_q, block_k, interpret, residuals, g):
+    if interpret is None:
+        interpret = _use_interpret()
+    q, k, v, *rule, o, lse = residuals
+    (heads, kv_heads, group, nq, nk), a, b, pairs = family.setup(q, k, *rule, block_q, block_k)
+    d = q.shape[2]
+    keep = family.keep(block_q, block_k)
+    drow = jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32), axis=-1,
+                   keepdims=True)
+
+    walk, n_run = _walk(pairs)
+    at, q_rows, q_col, kv = _query_major(heads, group, nq * nk, block_q, block_k, d)
+    dq = pl.pallas_call(
+        functools.partial(_walk_dq_kernel, scale=scale, heads=heads, n=nq * nk, keep=keep),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(q.shape[0], n_run.max()),
+            in_specs=[q_rows, kv, kv, *family.specs(at, nk, block_q, block_k), q_rows, q_col, q_col],
+            out_specs=q_rows,
+            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        interpret=interpret,
+        name=f"{family.name}_dq",
+    )(walk, q, k, v, a, b, g, lse, drow)
+
+    n = group * nq * nk
+    walk, n_run = _walk(_by_key(pairs, group))
+    at, q_rows, q_col, kv = _key_major(kv_heads, group, nq, n, block_q, block_k, d)
+    dk, dv = pl.pallas_call(
+        functools.partial(_walk_dkv_kernel, scale=scale, kv_heads=kv_heads, nq=nq, n=n, keep=keep),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(k.shape[0], n_run.max()),
+            in_specs=[q_rows, kv, kv, *family.specs(at, nk, block_q, block_k), q_rows, q_col, q_col],
+            out_specs=[kv, kv],
+            scratch_shapes=[
+                pltpu.VMEM((block_k, d), jnp.float32),
+                pltpu.VMEM((block_k, d), jnp.float32),
+            ],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct(k.shape, k.dtype),
+            jax.ShapeDtypeStruct(v.shape, v.dtype),
+        ],
+        interpret=interpret,
+        name=f"{family.name}_dkv",
+    )(walk, q, k, v, a, b, g, lse, drow)
+    return (dq, dk, dv, *[None] * len(rule))
+
+
 # ------------------------------------------------- causal, packed, grouped
 # Attention of packed documents: a query sees the keys of its own document
 # at or before its own position. Query head ``h`` of ``Hq`` reads key/value
@@ -572,6 +672,14 @@ def _causal_setup(q, k, segment_ids, block_q, block_k):
     return dims, seg[:, :, None], seg[:, None, :], pairs
 
 
+_CAUSAL = _Family(
+    "flash_causal",
+    _causal_setup,
+    lambda block_q, block_k: functools.partial(_keep, block_q=block_q, block_k=block_k),
+    lambda at, nk, block_q, block_k: _segment_specs(at, block_q, block_k),
+)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
 def flash_attention_causal(
     q: jax.Array,
@@ -592,91 +700,11 @@ def flash_attention_causal(
     return o
 
 
-def _fac_fwd(q, k, v, segment_ids, scale, block_q, block_k, interpret):
-    if interpret is None:
-        interpret = _use_interpret()
-    (heads, _, group, nq, nk), qseg, kseg, pairs = _causal_setup(q, k, segment_ids, block_q, block_k)
-    bh, s_len, d = q.shape
-    walk, n_run = _walk(pairs)
-    at, q_rows, q_col, kv = _query_major(heads, group, nq * nk, block_q, block_k, d)
-    o, lse = pl.pallas_call(
-        functools.partial(_walk_fwd_kernel, scale=scale, heads=heads, n=nq * nk,
-                          keep=functools.partial(_keep, block_q=block_q, block_k=block_k)),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(bh, n_run.max()),
-            in_specs=[q_rows, kv, kv, *_segment_specs(at, block_q, block_k)],
-            out_specs=[q_rows, q_col],
-            scratch_shapes=[
-                pltpu.VMEM((block_q, d), jnp.float32),
-                pltpu.VMEM((block_q, 1), jnp.float32),
-                pltpu.VMEM((block_q, 1), jnp.float32),
-            ],
-        ),
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, s_len, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, s_len, 1), jnp.float32),
-        ],
-        interpret=interpret,
-        name="flash_causal_fwd",
-    )(walk, q, k, v, qseg, kseg)
-    return o, (q, k, v, segment_ids, o, lse)
+def _fac_fwd(q, k, v, segment_ids, *static):
+    return _packed_fwd(_CAUSAL, q, k, v, (segment_ids,), *static)
 
 
-def _fac_bwd(scale, block_q, block_k, interpret, residuals, g):
-    if interpret is None:
-        interpret = _use_interpret()
-    q, k, v, segment_ids, o, lse = residuals
-    (heads, kv_heads, group, nq, nk), qseg, kseg, pairs = _causal_setup(
-        q, k, segment_ids, block_q, block_k
-    )
-    d = q.shape[2]
-    keep = functools.partial(_keep, block_q=block_q, block_k=block_k)
-    drow = jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32), axis=-1,
-                   keepdims=True)
-
-    walk, n_run = _walk(pairs)
-    at, q_rows, q_col, kv = _query_major(heads, group, nq * nk, block_q, block_k, d)
-    dq = pl.pallas_call(
-        functools.partial(_walk_dq_kernel, scale=scale, heads=heads, n=nq * nk, keep=keep),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(q.shape[0], n_run.max()),
-            in_specs=[q_rows, kv, kv, *_segment_specs(at, block_q, block_k), q_rows, q_col, q_col],
-            out_specs=q_rows,
-            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        ),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        interpret=interpret,
-        name="flash_causal_dq",
-    )(walk, q, k, v, qseg, kseg, g, lse, drow)
-
-    n = group * nq * nk
-    walk, n_run = _walk(_by_key(pairs, group))
-    at, q_rows, q_col, kv = _key_major(kv_heads, group, nq, n, block_q, block_k, d)
-    dk, dv = pl.pallas_call(
-        functools.partial(_walk_dkv_kernel, scale=scale, kv_heads=kv_heads, nq=nq, n=n, keep=keep),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(k.shape[0], n_run.max()),
-            in_specs=[q_rows, kv, kv, *_segment_specs(at, block_q, block_k), q_rows, q_col, q_col],
-            out_specs=[kv, kv],
-            scratch_shapes=[
-                pltpu.VMEM((block_k, d), jnp.float32),
-                pltpu.VMEM((block_k, d), jnp.float32),
-            ],
-        ),
-        out_shape=[
-            jax.ShapeDtypeStruct(k.shape, k.dtype),
-            jax.ShapeDtypeStruct(v.shape, v.dtype),
-        ],
-        interpret=interpret,
-        name="flash_causal_dkv",
-    )(walk, q, k, v, qseg, kseg, g, lse, drow)
-    return dq, dk, dv, None
-
-
-flash_attention_causal.defvjp(_fac_fwd, _fac_bwd)
+flash_attention_causal.defvjp(_fac_fwd, functools.partial(_packed_bwd, _CAUSAL))
 
 
 # ------------------------------------------- block diffusion, packed, grouped
@@ -710,7 +738,8 @@ flash_attention_causal.defvjp(_fac_fwd, _fac_bwd)
 # block runs its own clean key block and a noised one its own noised key
 # block, so every query block and every key block is in the walk.
 #
-# The three bodies and the three kernels around them are both families'.
+# The three bodies, the three kernels around them and the three calls
+# (``_packed_fwd``, ``_packed_bwd``) are both families'.
 
 
 def _blockdiff_bounds(doc, blk):
@@ -817,6 +846,14 @@ def _blockdiff_setup(q, k, doc, blk, block_q, block_k):
     return (heads, kv_heads, heads // kv_heads, rows // block_q, rows // block_k), lo, hi, pairs
 
 
+_BLOCKDIFF = _Family(
+    "flash_blockdiff",
+    _blockdiff_setup,
+    lambda block_q, block_k: lambda lo, hi, qi, ki: _interval_keep(lo, hi, ki, block_q, block_k),
+    lambda at, nk, block_q, block_k: _bound_specs(at, nk // 2, block_q),
+)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
 def flash_attention_blockdiff(
     q: jax.Array,
@@ -839,90 +876,8 @@ def flash_attention_blockdiff(
     return o
 
 
-def _fab_fwd(q, k, v, doc, blk, scale, block_q, block_k, interpret):
-    if interpret is None:
-        interpret = _use_interpret()
-    (heads, _, group, nq, nk), lo, hi, pairs = _blockdiff_setup(q, k, doc, blk, block_q, block_k)
-    bh, rows, d = q.shape
-    walk, n_run = _walk(pairs)
-    at, q_rows, q_col, kv = _query_major(heads, group, nq * nk, block_q, block_k, d)
-    o, lse = pl.pallas_call(
-        functools.partial(
-            _walk_fwd_kernel, scale=scale, heads=heads, n=nq * nk,
-            keep=lambda lo, hi, qi, ki: _interval_keep(lo, hi, ki, block_q, block_k),
-        ),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(bh, n_run.max()),
-            in_specs=[q_rows, kv, kv, *_bound_specs(at, nk // 2, block_q)],
-            out_specs=[q_rows, q_col],
-            scratch_shapes=[
-                pltpu.VMEM((block_q, d), jnp.float32),
-                pltpu.VMEM((block_q, 1), jnp.float32),
-                pltpu.VMEM((block_q, 1), jnp.float32),
-            ],
-        ),
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, rows, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, rows, 1), jnp.float32),
-        ],
-        interpret=interpret,
-        name="flash_blockdiff_fwd",
-    )(walk, q, k, v, lo, hi)
-    return o, (q, k, v, doc, blk, o, lse)
+def _fab_fwd(q, k, v, doc, blk, *static):
+    return _packed_fwd(_BLOCKDIFF, q, k, v, (doc, blk), *static)
 
 
-def _fab_bwd(scale, block_q, block_k, interpret, residuals, g):
-    if interpret is None:
-        interpret = _use_interpret()
-    q, k, v, doc, blk, o, lse = residuals
-    (heads, kv_heads, group, nq, nk), lo, hi, pairs = _blockdiff_setup(
-        q, k, doc, blk, block_q, block_k
-    )
-    d = q.shape[2]
-    keep = lambda lo, hi, qi, ki: _interval_keep(lo, hi, ki, block_q, block_k)
-    drow = jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32), axis=-1,
-                   keepdims=True)
-
-    walk, n_run = _walk(pairs)
-    at, q_rows, q_col, kv = _query_major(heads, group, nq * nk, block_q, block_k, d)
-    dq = pl.pallas_call(
-        functools.partial(_walk_dq_kernel, scale=scale, heads=heads, n=nq * nk, keep=keep),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(q.shape[0], n_run.max()),
-            in_specs=[q_rows, kv, kv, *_bound_specs(at, nk // 2, block_q), q_rows, q_col, q_col],
-            out_specs=q_rows,
-            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        ),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        interpret=interpret,
-        name="flash_blockdiff_dq",
-    )(walk, q, k, v, lo, hi, g, lse, drow)
-
-    n = group * nq * nk
-    walk, n_run = _walk(_by_key(pairs, group))
-    at, q_rows, q_col, kv = _key_major(kv_heads, group, nq, n, block_q, block_k, d)
-    dk, dv = pl.pallas_call(
-        functools.partial(_walk_dkv_kernel, scale=scale, kv_heads=kv_heads, nq=nq, n=n, keep=keep),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(k.shape[0], n_run.max()),
-            in_specs=[q_rows, kv, kv, *_bound_specs(at, nk // 2, block_q), q_rows, q_col, q_col],
-            out_specs=[kv, kv],
-            scratch_shapes=[
-                pltpu.VMEM((block_k, d), jnp.float32),
-                pltpu.VMEM((block_k, d), jnp.float32),
-            ],
-        ),
-        out_shape=[
-            jax.ShapeDtypeStruct(k.shape, k.dtype),
-            jax.ShapeDtypeStruct(v.shape, v.dtype),
-        ],
-        interpret=interpret,
-        name="flash_blockdiff_dkv",
-    )(walk, q, k, v, lo, hi, g, lse, drow)
-    return dq, dk, dv, None, None
-
-
-flash_attention_blockdiff.defvjp(_fab_fwd, _fab_bwd)
+flash_attention_blockdiff.defvjp(_fab_fwd, functools.partial(_packed_bwd, _BLOCKDIFF))

@@ -348,9 +348,8 @@ def build_graph(model, params: Any) -> PropagationGraph:
     """Propagation graph for a supported model, with channel counts read
     from the concrete ``params`` tree (so width-overridden models analyze
     correctly too). Raises CompactionError for unsupported architectures."""
+    from ..models import is_language_model
     from ..models.densenet import DenseNet
-    from ..models.granite import HybridLM
-    from ..models.nemotron_h import NemotronH
     from ..models.resnet import ResNet
     from ..models.vgg import VGG
     from ..models.vit import VisionTransformer
@@ -363,13 +362,14 @@ def build_graph(model, params: Any) -> PropagationGraph:
         return _densenet_graph(model, params)
     if isinstance(model, VisionTransformer):
         return _vit_graph(model, params)
-    if isinstance(model, (HybridLM, NemotronH)):
+    if is_language_model(model):
         # Their compactable axes are known (the SwiGLU hidden axis, a scan
         # head with its slices of in_proj, conv, gate norm and out_proj, a
-        # key/value head with its group of query heads; an expert's hidden
-        # axis, one Space for each expert of a stacked kernel, and the latent
-        # axis its experts share) and have no Space yet: the planner's answer
-        # for these families is ``masked``.
+        # key/value head with its group of query heads, a channel of the
+        # short convolution; an expert's hidden axis, one Space for each
+        # expert of a stacked kernel, and the latent axis its experts share)
+        # and have no Space yet: the planner's answer for every model of the
+        # registry's language table is ``masked``.
         raise CompactionError(
             "the hybrid language models have no propagation graph yet: the "
             "SwiGLU and expert hidden axes, scan heads and grouped attention "
