@@ -27,7 +27,7 @@ The first failed check raises and the process exits non-zero: no phase is
 wrapped in try/except. The last line of stdout is one JSON object naming
 the device as JAX reports it. Without a TPU the script refuses to start.
 
-Each phase is a plain function of its sizes, so tests/test_tpu_compile.py
+Each phase is a plain function of its sizes, so tests/test_chip_smoke.py
 calls the train and four-device phases at tiny size on the CPU mesh.
 """
 

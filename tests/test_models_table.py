@@ -14,7 +14,7 @@ from turboprune_tpu import models
 from turboprune_tpu.sparse.graph import CompactionError, build_graph
 
 MODELS = pathlib.Path(models.__file__).parent
-FILES = ("granite", "nemotron_h", "sdar", "lfm2")
+FILES = ("granite", "nemotron_h", "sdar", "lfm2", "brumby")
 
 
 def _imported(name: str) -> set:
@@ -38,7 +38,7 @@ def test_no_model_file_imports_another_and_blocks_imports_none():
 def test_the_public_tuples_are_the_tables_in_the_order_they_had():
     assert models.LANGUAGE_MODELS == (
         "granite_4_0_h_micro", "hybrid_lm_tiny", "nemotron_3_super_120b_a12b", "nemotron_h_tiny",
-        "sdar_30b_a3b", "sdar_moe_tiny", "lfm2_8b_a1b", "lfm2_moe_tiny",
+        "sdar_30b_a3b", "sdar_moe_tiny", "lfm2_8b_a1b", "lfm2_moe_tiny", "brumby_14b_base", "brumby_tiny",
     )  # fmt: skip
     assert models.SHARED_MODELS == models.LANGUAGE_MODELS[2:]
     assert models.BLOCK_DIFFUSION_MODELS == ("sdar_30b_a3b", "sdar_moe_tiny")
@@ -50,9 +50,9 @@ def test_the_public_tuples_are_the_tables_in_the_order_they_had():
     assert not models.is_language_model(models.create_model("resnet18", 10))
 
 
-@pytest.mark.parametrize("name", ["hybrid_lm_tiny", "nemotron_h_tiny", "sdar_moe_tiny", "lfm2_moe_tiny"])
+@pytest.mark.parametrize("name", ["hybrid_lm_tiny", "nemotron_h_tiny", "sdar_moe_tiny", "lfm2_moe_tiny", "brumby_tiny"])
 def test_the_planner_is_told_why_a_language_model_runs_masked(name):
-    """Each of the four files' models gets the language models' reason and
+    """Each of the five files' models gets the language models' reason and
     not "compaction supports ResNet, VGG, DenseNet and ViT"."""
     model = models.create_model(name, 50)
     assert models.is_language_model(model)

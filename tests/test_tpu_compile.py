@@ -1,16 +1,17 @@
 """What can be shown about the chip without one.
 
-Two kinds of test, kept in ONE file because only one process may hold the
-TPU compiler's library and pytest-xdist hands a file to one worker:
+Compiles, kept in ONE file because only one process may hold the TPU
+compiler's library and pytest-xdist hands a file to one worker:
 
-* Compiles for a DESCRIBED TPU v5e (``jax.experimental.topologies``; the
+* For a DESCRIBED TPU v5e (``jax.experimental.topologies``; the
   compiler is installed here, no chip is attached): the Pallas flash kernel
   forward and backward at the shapes the main path uses, ring attention on
   a 2x2 mesh, and (slow) the BASELINE ResNet50 batch-512 train step with its
   memory count. A compile that passes is not a run; it is what interpret
   mode cannot show (tiling, VMEM, whether the program fits).
-* chip_smoke.py's phases at tiny size on the virtual CPU mesh, so the
-  script the chip runs is not first executed on the chip.
+* Where the compile cache is placed (utils/compile_cache.py).
+
+chip_smoke.py's phases at tiny size run in tests/test_chip_smoke.py.
 
 The topology is described inside a fixture, never at import, in a skipif or
 in a parametrize argument: every xdist worker imports this file, and only
@@ -19,7 +20,6 @@ the one that runs it may load the library.
 
 import os
 import re
-import time
 
 from unittest import mock
 
@@ -35,7 +35,7 @@ from turboprune_tpu.ops.flash import (
     flash_attention_blockdiff,
     flash_attention_causal,
 )
-from turboprune_tpu.ops import ssd
+from turboprune_tpu.ops import retention, ssd
 from turboprune_tpu.ops.ssd import ssd_chunked
 
 V5E_HBM_BYTES = 16 * 2**30
@@ -227,6 +227,41 @@ def test_grouped_scan_compiles_for_v5e(one_chip, no_persistent_cache):
     assert "ssd_scan_fwd" in text and "ssd_scan_bwd" in text
 
 
+# One layer's power retention as one chip of eight holds Brumby-14B-Base: 5
+# query heads on 1 key/value head of 128, 32,768 tokens in chunks of 512.
+@pytest.mark.parametrize("backward", [False, True], ids=["forward", "grad"])
+def test_power_retention_compiles_for_v5e_and_fits(one_chip, no_persistent_cache, backward):
+    """Retention must come out as its Pallas kernels, whose state (4.06 MiB
+    of features by values, float32) and blocks must fit the chip's fast
+    memory, and nothing as wide as the features may be among the program's
+    temporaries: the gradient holds the states entering the 64 chunks (260
+    MiB), the float32 gradients and what every token is scaled by (389 MiB as
+    compiled), the plain forward 16 MiB."""
+    q, kv, lam, seg = _placed(
+        (
+            jax.ShapeDtypeStruct((1, 1, 5, 32768, 128), jnp.bfloat16),
+            jax.ShapeDtypeStruct((1, 1, 32768, 128), jnp.bfloat16),
+            jax.ShapeDtypeStruct((1, 32768, 1), jnp.float32),
+            jax.ShapeDtypeStruct((1, 32768), jnp.int32),
+        ),
+        one_chip,
+    )
+
+    def forward(q, k, v, lam, seg):
+        return retention.power_retention(q, k, v, lam, seg, chunk=512)
+
+    def loss(q, k, v, lam, seg):
+        return jnp.square(forward(q, k, v, lam, seg).astype(jnp.float32)).sum()
+
+    fn = jax.grad(loss, argnums=(0, 1, 2, 3)) if backward else forward
+    with mock.patch.object(retention, "_use_interpret", return_value=False):  # the CPU backend would interpret
+        compiled = jax.jit(fn).lower(q, kv, kv, lam, seg).compile()
+    text = compiled.as_text()
+    names = ("retention_fwd", "retention_bwd") if backward else ("retention_fwd",)
+    assert "tpu_custom_call" in text and all(name in text for name in names)
+    assert compiled.memory_analysis().temp_size_in_bytes < (448 if backward else 32) * 2**20
+
+
 def test_routed_experts_compile_for_v5e_as_grouped_kernels(one_chip, no_persistent_cache):
     """One LatentMoE layer's routed part at published widths over 8,192
     tokens, forward and backward: top-22 of 512, the 16 experts held, the
@@ -389,61 +424,6 @@ def test_resnet50_batch512_train_step_fits_one_v5e(one_chip, no_persistent_cache
         memory.temp_size_in_bytes + memory.argument_size_in_bytes
         < V5E_HBM_BYTES
     )
-
-
-# ------------------------------------------------- chip_smoke at tiny size
-TINY = dict(
-    config_name="cifar10_imp",  # ResNet18, 32x32
-    batch=32,
-    num_train=64,
-    num_test=32,
-    steps=2,
-)
-
-
-def test_chip_smoke_one_chip_phases_at_tiny_size(tmp_path):
-    began = time.perf_counter()
-    run = chip_smoke.phase_train(
-        base_dir=tmp_path,
-        platform="cpu",
-        target_sparsity=0.3,
-        num_devices=1,
-        **TINY,
-    )
-    assert [r["level"] for r in run["levels"]] == [0, 1, 2]
-    chip_smoke.phase_serve(
-        expt_dir=run["expt_dir"],
-        platform="cpu",
-        request_sizes=(1, 3),
-        final_level=2,
-    )
-    # What the smoke prints of a phase comes from the program's own record
-    # of the modules that reached XLA (utils/tracing.py), on any thread.
-    compile_s, modules, hits, misses = chip_smoke.compiled_since(began)
-    assert "jit(train_step)" in modules and compile_s > 0 and (hits, misses) == (0, 0)
-
-
-def test_chip_smoke_data_parallel_phase_at_tiny_size(tmp_path):
-    chip_smoke.phase_data_parallel(
-        devices=4,
-        mask_tol=5e-2,
-        base_dir=tmp_path,
-        platform="cpu",
-        target_sparsity=0.2,
-        **TINY,
-    )
-
-
-def test_chip_smoke_ring_phase_at_tiny_size():
-    chip_smoke.phase_ring(data=2, model=2, batch=4, seq=197, dim=384, heads=6)
-
-
-def test_chip_smoke_refuses_to_start_without_a_tpu(tmp_path, monkeypatch, capsys):
-    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
-    with pytest.raises(SystemExit) as e:
-        chip_smoke.main([])
-    assert e.value.code not in (0, None)
-    assert capsys.readouterr().out == ""  # no phase ran, no result line
 
 
 class TestCompileCachePlacement:

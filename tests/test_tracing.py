@@ -279,6 +279,21 @@ class TestRecorder:
         tracing.gauge("t_gauge", 5)
         assert tracing.gauges()["t_gauge"] == 5
 
+    def test_the_gauges_set_while_a_program_is_traced_are_told_apart(self):
+        """What the first-epoch line prints: no module's names listed anywhere."""
+
+        def built(x):
+            tracing.gauge("t_traced_gauge", x.shape[0])
+            tracing.count("t_traced_count")
+            return x + 1
+
+        tracing.count("t_host_count")
+        jax.jit(built)(jnp.zeros(7))
+        got = tracing.trace_gauges()
+        assert got["t_traced_gauge"] == 7 and got["t_traced_count"] == 1
+        assert "t_host_count" not in got and "t_gauge" not in got
+        assert tracing.gauges()["t_host_count"] == 1
+
 
 def test_the_lowered_train_step_names_its_layers():
     from turboprune_tpu.models import create_model
@@ -470,8 +485,10 @@ def test_the_operator_gets_a_time_line_per_level_and_one_for_setup(ladder, out, 
         assert want in lines[0], want
     for want in ("prune ", "rewind ", "setup ", "train ", "eval ", "log ", "save ", "other "):
         assert want in lines[-1], want
-    tail = r"; traced \d+\.\d s, lowered \d+\.\d s, compiled \d+ modules in \d+\.\d s \(0 read from the cache in 0\.0 s; 0 missed\)$"
-    assert all(re.search(tail, ln) for ln in lines), lines
+    tail = r"; traced \d+\.\d s, lowered \d+\.\d s, compiled \d+ modules in \d+\.\d s \(0 read from the cache in 0\.0 s; 0 missed\)"
+    # The first epoch's line goes on with what the process's programs set while they were
+    # traced (tracing.trace_gauges(): this process's, so other tests' too), "; name value" each.
+    assert all(re.search(tail + (r"(; \S+ \S+)*$" if i == 1 else "$"), ln) for i, ln in enumerate(lines)), lines
     # Only a level that restored says so: "load 0.20 (read 0.19)", and its
     # rewind "(read ...)" too.
     assert ("load " in lines[-1]) == load and (lines[-1].count("(read ") == 2) == load

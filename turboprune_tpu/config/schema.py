@@ -268,7 +268,7 @@ class ModelConfig:
     # published. Image models have one depth and reject the knob.
     num_hidden_layers: int = 0
     # A model built as one chip's share of a deployment
-    # (models/nemotron_h.py, models/sdar.py, models/lfm2.py): the stretch of
+    # (models/nemotron_h.py, models/sdar.py, models/lfm2.py, models/brumby.py): the stretch of
     # the published layer pattern that is run ("" = all of it), over how many
     # chips each layer's heads, a shared expert's or dense MLP's columns and a
     # short convolution's channels (tensor_parallel) and its routed experts

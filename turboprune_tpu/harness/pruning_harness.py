@@ -911,9 +911,11 @@ class PruningHarness:
         self._said_first_epoch = True
         roots = tracing.first_epoch_roots()
         if roots and is_primary():
-            # The step program has been traced: what its backward pass keeps (ops/remat.py).
-            kept = {k: v for k, v in tracing.gauges().items() if k.startswith("remat_saved_")}
-            print(tracing.line("start to first epoch", tracing.breakdown(roots), kept), flush=True)
+            # The step program has been traced: what the code that built it
+            # set while it was (what its backward pass keeps, ops/remat.py;
+            # which form its kernels took; its loss's blocks, train/steps.py).
+            built = tracing.trace_gauges()
+            print(tracing.line("start to first epoch", tracing.breakdown(roots), built), flush=True)
 
     def _train_eval_log(self, row: dict, max_test_acc: float) -> float:
         """Train one epoch, evaluate, and log ``row`` (which already names

@@ -13,7 +13,7 @@ from typing import Any, Callable, NamedTuple
 
 import jax.numpy as jnp
 
-from . import densenet, granite, lfm2, nemotron_h, resnet, sdar, vgg, vit
+from . import brumby, densenet, granite, lfm2, nemotron_h, resnet, sdar, vgg, vit
 from .densenet import DenseNet
 from .resnet import ResNet, resnet18, resnet34, resnet50, resnet101, resnet152
 from .vgg import VGG
@@ -76,6 +76,8 @@ LANGUAGE_TABLE = (
     LanguageModel(sdar.sdar_moe_tiny, shared=True, block_diffusion=True),
     LanguageModel(lfm2.lfm2_8b_a1b, shared=True),
     LanguageModel(lfm2.lfm2_moe_tiny, shared=True),
+    LanguageModel(brumby.brumby_14b_base, shared=True),
+    LanguageModel(brumby.brumby_tiny, shared=True),
 )
 MODEL_REGISTRY.update({lm.name: lm.factory for lm in LANGUAGE_TABLE})
 LANGUAGE_MODELS = tuple(lm.name for lm in LANGUAGE_TABLE)

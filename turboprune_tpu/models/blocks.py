@@ -1,5 +1,5 @@
 """The blocks that more than one language model runs. The model files
-(granite.py, nemotron_h.py, sdar.py, lfm2.py) import from here and this file
+(granite.py, nemotron_h.py, sdar.py, lfm2.py, brumby.py) import from here and this file
 imports none of them: a block that two models use is written here once, a
 block that one uses stays with it (tests/test_models_table.py holds the
 imports to it). An edit here is an edit to every model that names the block, and
@@ -10,12 +10,13 @@ document each token belongs to. Nothing crosses a document's start: not a
 convolution, not the recurrence's state, not attention, not a position.
 
     dense, RMSNorm, same_document, rotary       what every block is made of
-    MambaMixer, AttentionMixer, SwiGLU          granite.py, nemotron_h.py (lfm2.py: SwiGLU)
+    positions                                   a token's index in its document: lfm2.py, brumby.py
+    MambaMixer, AttentionMixer, SwiGLU          granite.py, nemotron_h.py (lfm2.py, brumby.py: SwiGLU)
     RotaryAttention                             sdar.py, lfm2.py, each with its kernel
     Share                                       a chip's share of a layer: all but granite.py
     Router                                      sigmoid: nemotron_h.py, lfm2.py
     GatedExperts, SparseMoE                     sdar.py, lfm2.py, each with its router
-    Head                                        untied: nemotron_h.py, sdar.py
+    Head                                        untied: nemotron_h.py, sdar.py, brumby.py
 
 The tags (``checkpoint_name``) and the named scopes (each model's docstring
 lists its own) are shared and decide nothing; what a layer's backward pass
@@ -74,6 +75,14 @@ def same_document(seg, shift: int):
     """[B, T]: token ``t - shift`` exists and lies in ``t``'s document."""
     earlier = jnp.pad(seg, ((0, 0), (shift, 0)), constant_values=-1)[:, : seg.shape[1]]
     return earlier == seg
+
+
+def positions(seg):
+    """[B, T] int32: each token's index inside its document, from the
+    document ids alone."""
+    at = jnp.arange(seg.shape[1], dtype=jnp.int32)
+    starts = jnp.where(same_document(seg, 1), 0, at)  # a document's first token: its index
+    return at - jax.lax.cummax(starts, axis=1)
 
 
 def _dt_bias_init(key, shape, dtype=jnp.float32):
@@ -255,7 +264,9 @@ class Share:
         it says what there is to divide: ``c.DIVIDED`` maps each key of the
         answer to the field whose count ``tensor_parallel`` chips divide
         evenly, ``c.KV_HEADS`` and ``c.EXPERTS`` name the fields that count the
-        key/value heads and the routed experts."""
+        key/value heads and the routed experts. A dense model names no
+        ``EXPERTS``: nothing of it is divided over ``expert_parallel`` chips,
+        and its answer has no experts."""
         tp, ep = self.tensor_parallel, self.expert_parallel
         held = {}
         for key, name in c.DIVIDED.items():
@@ -263,15 +274,15 @@ class Share:
             if count % tp:
                 raise ValueError(f"{name} {count} does not divide over {tp} chips")
             held[key] = count // tp
-        experts = getattr(c, c.EXPERTS)
+        # A key/value head is held by every chip that holds a query head of its group.
+        held["kv_heads"] = max(getattr(c, c.KV_HEADS) // tp, 1)
+        experts = getattr(c, c.EXPERTS) if c.EXPERTS else 1
         if experts % ep or not 0 <= self.expert_rank < ep:
             raise ValueError(f"{experts} experts, rank {self.expert_rank} of {ep}")
+        if not c.EXPERTS:
+            return held
         return dict(
-            held,
-            # A key/value head is held by every chip that holds a query head of its group.
-            kv_heads=max(getattr(c, c.KV_HEADS) // tp, 1),
-            experts_here=experts // ep,
-            expert_offset=self.expert_rank * (experts // ep),
+            held, experts_here=experts // ep, expert_offset=self.expert_rank * (experts // ep)
         )
 
 
@@ -348,14 +359,27 @@ class SparseMoE(nn.Module):
         return out.astype(dtype).reshape(h32.shape)
 
 
+def head_output(logits_of, x, reduce=None):
+    """What every language model's ``__call__`` returns: the logits
+    ``logits_of(x)``; or, where the caller gives a ``reduce``,
+    ``reduce(logits_of, x)`` in their place. ``reduce`` runs ``logits_of`` on
+    as many of ``x``'s tokens at a time as it likes and returns what it makes
+    of them (train/steps.py's loss a block of tokens at a time), so that
+    ``[tokens, vocabulary]`` need never exist whole."""
+    return logits_of(x) if reduce is None else reduce(logits_of, x)
+
+
 class Head(nn.Module):
+    """The untied head; ``reduce`` as ``head_output`` takes it."""
+
     vocab_size: int
     dtype: Any = jnp.float32
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, reduce=None):
         kernel = self.param("kernel", nn.initializers.normal(0.02), (x.shape[-1], self.vocab_size))
-        return jnp.einsum(
+        logits_of = lambda x: jnp.einsum(
             "btd,dv->btv", x, kernel.astype(self.dtype), preferred_element_type=jnp.float32
         )
+        return head_output(logits_of, x, reduce)
 

@@ -54,7 +54,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from ..ops import remat
-from .blocks import AttentionMixer, MambaMixer, RMSNorm, SwiGLU
+from .blocks import AttentionMixer, MambaMixer, RMSNorm, SwiGLU, head_output
 
 # granite-4.0-h-micro's period of ``layer_types``: attention at index 5 of
 # every ten layers.
@@ -117,7 +117,9 @@ class HybridLM(nn.Module):
     dtype: Any = jnp.float32
 
     @nn.compact
-    def __call__(self, tokens, train: bool = False):
+    def __call__(self, tokens, train: bool = False, reduce=None):
+        """The logits ``[B, T, V]``; or, given ``reduce``, what it makes of
+        them a block of tokens at a time (models/blocks.py's ``head_output``)."""
         del train  # no dropout, no batch statistics
         c = self.cfg
         ids, seg = tokens[:, 0], tokens[:, 1]
@@ -131,10 +133,10 @@ class HybridLM(nn.Module):
             x = block(kind, c, self.dtype, name=f"layers_{i}")(x, seg)
         x = RMSNorm(c.rms_norm_eps, self.dtype, name="final_norm")(x)
         with jax.named_scope("lm_head"):
-            logits = jnp.einsum(
+            logits_of = lambda x: jnp.einsum(
                 "btd,vd->btv", x, table.astype(self.dtype), preferred_element_type=jnp.float32
-            )
-            return logits / c.logits_scaling
+            ) / c.logits_scaling
+            return head_output(logits_of, x, reduce)
 
 
 # granite-4.0-h-micro as published (huggingface.co/ibm-granite/

@@ -80,7 +80,7 @@ from ..ops import moe, remat
 from ..ops.flash import flash_attention_causal
 from .blocks import (
     FLASH_BLOCK, GatedExperts, RMSNorm, RotaryAttention, Router, Share, SparseMoE, SwiGLU, dense,
-    same_document,
+    head_output, positions, same_document,
 )  # fmt: skip
 
 # What the backward pass of a layer keeps beside the layer's input.
@@ -121,14 +121,6 @@ class Lfm2Config:
 def held(c: Lfm2Config, share: Share) -> dict:
     """What this chip holds of each layer."""
     return share.of(c)
-
-
-def positions(seg):
-    """[B, T] int32: each token's index inside its document, from the
-    document ids alone."""
-    at = jnp.arange(seg.shape[1], dtype=jnp.int32)
-    starts = jnp.where(same_document(seg, 1), 0, at)  # a document's first token: its index
-    return at - jax.lax.cummax(starts, axis=1)
 
 
 class ShortConv(nn.Module):
@@ -225,7 +217,9 @@ class Lfm2(nn.Module):
     counters = (*moe.COUNTERS, "moe_rounds")
 
     @nn.compact
-    def __call__(self, tokens, train: bool = False):
+    def __call__(self, tokens, train: bool = False, reduce=None):
+        """The logits ``[B, T, V]``; or, given ``reduce``, what it makes of
+        them a block of tokens at a time (models/blocks.py's ``head_output``)."""
         del train  # no dropout, no batch statistics
         c = self.cfg
         ids, seg = tokens[:, 0], tokens[:, 1]
@@ -241,9 +235,10 @@ class Lfm2(nn.Module):
             )(x, seg)
         x = RMSNorm(c.norm_eps, self.dtype, name="final_norm")(x)
         with jax.named_scope("lm_head"):
-            return jnp.einsum(
+            logits_of = lambda x: jnp.einsum(
                 "btd,vd->btv", x, table.astype(self.dtype), preferred_element_type=jnp.float32
             )
+            return head_output(logits_of, x, reduce)
 
 
 # LFM2-8B-A1B as published (huggingface.co/LiquidAI/LFM2-8B-A1B, config.json):

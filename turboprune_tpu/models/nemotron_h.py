@@ -218,7 +218,9 @@ class NemotronH(nn.Module):
     counters = moe.COUNTERS
 
     @nn.compact
-    def __call__(self, tokens, train: bool = False):
+    def __call__(self, tokens, train: bool = False, reduce=None):
+        """The logits ``[B, T, V]``; or, given ``reduce``, what it makes of
+        them a block of tokens at a time (models/blocks.py's ``Head``)."""
         del train  # no dropout, no batch statistics
         c = self.cfg
         ids, seg = tokens[:, 0], tokens[:, 1]
@@ -235,7 +237,7 @@ class NemotronH(nn.Module):
             x = block(kind, c, self.share, self.dtype, name=f"layers_{i}")(x, seg)
         x = RMSNorm(c.layer_norm_epsilon, self.dtype, name="final_norm")(x)
         with jax.named_scope("lm_head"):
-            return Head(self.vocab_size, self.dtype, name="lm_head")(x)
+            return Head(self.vocab_size, self.dtype, name="lm_head")(x, reduce)
 
 
 # NVIDIA-Nemotron-3-Super-120B-A12B-BF16 as published (huggingface.co/nvidia/

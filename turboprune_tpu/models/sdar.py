@@ -194,7 +194,9 @@ class Sdar(nn.Module):
     counters = (*moe.COUNTERS, "moe_rounds", "masked_targets", *FLASH_COUNTERS)
 
     @nn.compact
-    def __call__(self, tokens, train: bool = False):
+    def __call__(self, tokens, train: bool = False, reduce=None):
+        """The logits ``[B, T, V]``; or, given ``reduce``, what it makes of
+        them a block of tokens at a time (models/blocks.py's ``Head``)."""
         del train  # no dropout, no batch statistics
         c = self.cfg
         clean, noised = tokens[:, CLEAN], tokens[:, NOISED]
@@ -212,7 +214,7 @@ class Sdar(nn.Module):
         # The clean rows have fed keys and values; the loss reads the noised.
         x = RMSNorm(c.rms_norm_eps, self.dtype, name="final_norm")(x[:, clean.shape[1] :])
         with jax.named_scope("lm_head"):
-            return Head(self.vocab_size, self.dtype, name="lm_head")(x)
+            return Head(self.vocab_size, self.dtype, name="lm_head")(x, reduce)
 
 
 # SDAR-30B-A3B-Chat as published (huggingface.co/JetLM/SDAR-30B-A3B-Chat,

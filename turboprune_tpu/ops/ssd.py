@@ -62,6 +62,26 @@ def _decay(log_decay: jax.Array, keep: jax.Array) -> jax.Array:
     return jnp.exp(jnp.where(keep, log_decay, -jnp.inf))
 
 
+def chunk_decays(log_decay: jax.Array, seg: jax.Array):
+    """What scales every (token, head) of a chunked recurrence whose state is
+    multiplied by ``exp(log_decay)`` a token and cut at document starts
+    (ops/retention.py walks the same chunks). log_decay [B, C, Q, H] float32,
+    seg [B, C, Q]. Returns the inclusive sums ``cum`` [B, C, Q, H], the masked
+    decays from a token to its chunk's end (``to_end``) and from the chunk's
+    start to the token (``from_start``), both [B, C, Q, H], and what the state
+    entering a chunk is scaled by at its end (``carried`` [B, C, H])."""
+    cum = jnp.cumsum(log_decay, axis=2)  # inclusive
+    total = cum[:, :, -1]  # [B, C, H]
+    last_seg = seg[:, :, -1]  # [B, C]
+    before = jnp.concatenate(
+        [jnp.full((seg.shape[0], 1), NO_DOCUMENT, seg.dtype), last_seg[:, :-1]], axis=1
+    )
+    to_end = _decay(total[:, :, None] - cum, (seg == last_seg[..., None])[..., None])
+    from_start = _decay(cum, (seg == before[..., None])[..., None])
+    carried = _decay(total, (last_seg == before)[..., None])  # [B, C, H]
+    return cum, to_end, from_start, carried
+
+
 def _head_block(heads: int, p: int, n: int, chunk: int) -> int:
     """How many heads a grid step of the kernels takes, from the shapes alone;
     0 where the kernels do not take the shape. They tile a chunk by ``TILE``,
@@ -398,18 +418,9 @@ def ssd_chunked(
     nc = (t + pad) // chunk
     f32 = jnp.float32
 
-    # What scales every (token, head), [B, C, Q, H], and every (chunk, head).
     seg = segment_ids.reshape(bsz, nc, chunk)
     dtc = dt.astype(f32).reshape(bsz, nc, chunk, heads)
-    cum = jnp.cumsum(dtc * a.astype(f32), axis=2)  # inclusive
-    total = cum[:, :, -1]  # [B, C, H]
-    last_seg = seg[:, :, -1]  # [B, C]
-    before = jnp.concatenate(
-        [jnp.full((bsz, 1), NO_DOCUMENT, seg.dtype), last_seg[:, :-1]], axis=1
-    )
-    to_end = _decay(total[:, :, None] - cum, (seg == last_seg[..., None])[..., None])
-    from_start = _decay(cum, (seg == before[..., None])[..., None])
-    carried = _decay(total, (last_seg == before)[..., None])  # [B, C, H]
+    cum, to_end, from_start, carried = chunk_decays(dtc * a.astype(f32), seg)
 
     # The four kinds a head, [B, C, 4, H, Q]: whole lanes of tokens.
     rows = jnp.stack([v.transpose(0, 1, 3, 2) for v in (dtc, cum, to_end, from_start)], axis=2)

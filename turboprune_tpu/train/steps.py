@@ -23,6 +23,18 @@ their count); and packed sequences noised for block-diffusion training, whose
 labels are a pair (targets, weights): the loss is the weighted sum over the
 masked targets divided by the tokens, ``n`` the tokens (data/tokens.py). Which
 one a step has it reads off the labels, never off a model's name.
+
+**The loss in blocks.** A token batch whose logits would take more than
+``LOGITS_BYTES`` (tokens x vocabulary x 4, read off the shapes as the step
+is traced) never has them whole: the model is handed ``sums_in_blocks`` as
+its head's ``reduce`` (models/blocks.py's ``Head``) and returns the three
+sums, head and loss run ``LOSS_BLOCK`` tokens at a time under a
+``jax.checkpoint`` (a block's logits are rebuilt in the backward pass), in
+the train step, in the eval step and so in ``evaluate()`` and every probe.
+Smaller batches run as they always did, program for program
+(tests/test_loss_blocks.py). Gauge ``loss_blocks_per_step``: the blocks of
+the newest such trace; a process whose steps all have their logits whole
+never sets it.
 """
 
 from __future__ import annotations
@@ -34,7 +46,14 @@ import jax.numpy as jnp
 import optax
 
 from ..ops.masking import PyTree, apply_masks
+from ..utils import tracing
 from .state import TrainState
+
+# Float32 logits of a token batch beyond which head and loss run in blocks: 0.75 GiB. The four
+# 8,192-token entries read 0.38 to 0.58 GiB and keep their programs; two such sequences a step
+# (1.0 GiB) and one of 32,768 tokens (2.3 GiB) run in blocks.
+LOGITS_BYTES = 3 * 2**28
+LOSS_BLOCK = 2048  # tokens a block, at most
 
 # Three kinds of batch, and which one a step has it reads off the labels:
 # (images NHWC, integer labels [B]); for a language model trained on next
@@ -92,8 +111,47 @@ def token_loss_sums(logits: jax.Array, labels):
     return masked_cross_entropy(logits, labels)
 
 
-def _forward_train(model, params, masks, batch_stats, images, rng):
-    """(logits, new batch statistics, the layers' counters). A model that
+def loss_blocks(model, labels) -> int:
+    """How many blocks of tokens a step's head and loss run in: 0 where the
+    logits are formed whole (an image batch; a token batch whose float32
+    logits fit ``LOGITS_BYTES``), else the fewest equal blocks of at most
+    ``LOSS_BLOCK`` tokens a sequence divides into."""
+    targets = labels[0] if isinstance(labels, tuple) else labels
+    if targets.ndim < 2:
+        return 0
+    if 4 * targets.size * getattr(model, "vocab_size", 0) <= LOGITS_BYTES:
+        return 0
+    t = targets.shape[-1]
+    blocks = next(n for n in range(-(-t // LOSS_BLOCK), t + 1) if t % n == 0)
+    tracing.gauge("loss_blocks_per_step", blocks)
+    return blocks
+
+
+def sums_in_blocks(labels, blocks: int) -> Callable:
+    """A head's ``reduce`` (models/blocks.py): ``token_loss_sums`` of the
+    logits of ``x`` [B, T, D] against ``labels``, ``T / blocks`` tokens of
+    every sequence at a time. Each block is a ``jax.checkpoint``: what a
+    backward pass keeps of it is its three sums."""
+
+    def reduce(logits_of, x):
+        split = lambda a: jnp.moveaxis(a.reshape(a.shape[0], blocks, -1, *a.shape[2:]), 1, 0)
+
+        @jax.checkpoint
+        def block(sums, inp):
+            x_block, labels_block = inp
+            with jax.named_scope("loss"):
+                new = token_loss_sums(logits_of(x_block), labels_block)
+            return tuple(a + b for a, b in zip(sums, new)), None
+
+        zero = (jnp.zeros((), jnp.float32),) * 3
+        return jax.lax.scan(block, zero, (split(x), jax.tree.map(split, labels)))[0]
+
+    return reduce
+
+
+def _forward_train(model, params, masks, batch_stats, images, rng, **head):
+    """(logits, new batch statistics, the layers' counters); with ``reduce``
+    in ``head``, what the model's head makes of the logits in their place. A model that
     names ``counters`` (models/nemotron_h.py) sows int32 scalars under those
     names into the ``counters`` collection, a layer at a time; they come back
     summed over the layers. Every other model returns {} and runs as before."""
@@ -111,7 +169,7 @@ def _forward_train(model, params, masks, batch_stats, images, rng):
     names = getattr(model, "counters", ())
     if names:
         logits, sown = model.apply(
-            variables, images, train=True, mutable=["counters"], rngs={"dropout": rng}
+            variables, images, train=True, mutable=["counters"], rngs={"dropout": rng}, **head
         )
         leaves = jax.tree_util.tree_leaves_with_path(sown["counters"])
         return logits, batch_stats, {
@@ -120,7 +178,7 @@ def _forward_train(model, params, masks, batch_stats, images, rng):
         }
     # No mutable collections (plain VGG, ViT): mutable=[] would make flax
     # return a (logits, state) tuple — don't pass it at all.
-    logits = model.apply(variables, images, train=True, rngs={"dropout": rng})
+    logits = model.apply(variables, images, train=True, rngs={"dropout": rng}, **head)
     return logits, batch_stats, {}
 
 
@@ -138,13 +196,19 @@ def make_train_step(
         images, labels = batch
         step_rng = jax.random.fold_in(state.rng, state.step)
 
+        blocks = loss_blocks(model, labels)
+        head = {"reduce": sums_in_blocks(labels, blocks)} if blocks else {}
+
         def loss_fn(params):
             # Named scopes label the device trace's operations by layer; the
             # backward pass comes out as transpose(jvp(forward)).
             with jax.named_scope("forward"):
                 logits, new_batch_stats, counters = _forward_train(
-                    model, params, state.masks, state.batch_stats, images, step_rng
+                    model, params, state.masks, state.batch_stats, images, step_rng, **head
                 )
+            if blocks:  # the head has made the loss's sums of the logits, a block at a time
+                loss_sum, correct, n = logits
+                return loss_sum / n, (None, new_batch_stats, loss_sum, n, correct, counters)
             with jax.named_scope("loss"):
                 if isinstance(labels, tuple) or labels.ndim > 1:
                     # Token targets: the mean is over the valid ones (over the
@@ -261,9 +325,12 @@ def make_eval_step(model) -> Callable[[TrainState, Batch], dict]:
         variables = {"params": apply_masks(state.params, state.masks)}
         if state.batch_stats:
             variables["batch_stats"] = state.batch_stats
+        blocks = loss_blocks(model, labels)
+        head = {"reduce": sums_in_blocks(labels, blocks)} if blocks else {}
         with jax.named_scope("eval_forward"):
-            logits = model.apply(variables, images, train=False)
-        loss_sum, correct, count = token_loss_sums(logits, labels)
+            logits = model.apply(variables, images, train=False, **head)
+        # In blocks, the head has made the sums of the logits already.
+        loss_sum, correct, count = logits if blocks else token_loss_sums(logits, labels)
         return {"loss_sum": loss_sum, "correct": correct, "count": count}
 
     return eval_step

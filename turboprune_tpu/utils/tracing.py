@@ -78,6 +78,7 @@ _ids = itertools.count(1)
 _local = threading.local()
 _mu = threading.Lock()
 _gauges: dict[str, float] = {}
+_traced: set[str] = set()  # the gauges set while a program was being traced
 _profiling = False
 
 
@@ -244,21 +245,35 @@ jax.monitoring.register_event_duration_secs_listener(_on_duration)
 jax.monitoring.register_event_listener(_on_event)
 
 
+def _set(name: str, value: float) -> None:
+    _gauges[name] = value
+    if getattr(_local, "stages", None):  # a stage is open on this thread
+        _traced.add(name)
+
+
 def gauge(name: str, value: float) -> None:
     """Set a named number of the process (the harness's plan gauges)."""
     with _mu:
-        _gauges[name] = value
+        _set(name, value)
 
 
 def count(name: str) -> None:
     """Add one to a gauge that counts what the process did (``mask_reads``)."""
     with _mu:
-        _gauges[name] = _gauges.get(name, 0) + 1
+        _set(name, _gauges.get(name, 0) + 1)
 
 
 def gauges() -> dict[str, float]:
     with _mu:
         return dict(_gauges)
+
+
+def trace_gauges() -> dict[str, float]:
+    """The gauges that code set while JAX was tracing it: what a program was
+    built as (which form a kernel took, what a backward pass keeps, a loss's
+    blocks), whatever module named them."""
+    with _mu:
+        return {name: _gauges[name] for name in sorted(_traced)}
 
 
 def breakdown(roots: Sequence[Span], spans: Optional[Sequence[Span]] = None) -> dict:
