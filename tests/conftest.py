@@ -40,15 +40,26 @@ from turboprune_tpu.analysis.pytest_plugin import graftsan  # noqa: E402, F401
 # late (test_sdar, test_tpu_compile) started last and were the tail the run
 # waited for, two to four minutes past an even share (ROADMAP D9). So the files
 # over about 100 s of their own go first, longest first (seconds of the builder's
-# whole runs at PR 44); the rest follow in name order, and a file keeps its order.
+# whole run at PR 45, 559 s down to 51); the rest follow in name order, and a file keeps
+# its order.
 LONG_FILES = (
-    "test_harness", "test_nemotron_h", "test_models_masking", "test_level_resume", "test_sdar",
-    "test_integration_extra", "test_mid_level_resume", "test_multiprocess", "test_tpu_compile",
-    "test_nm", "test_chip_smoke", "test_compact_train", "test_granite", "test_plan", "test_checkpoint",
-    "test_sparse", "test_moe_groups", "test_loss_blocks", "test_ring", "test_lfm2", "test_brumby",
-    "test_serve", "test_scan_epoch", "test_ssd", "test_granite_ladder", "test_flash_blockdiff", "test_fleet",
-    "test_flash_causal", "test_tracing",
+    "test_harness", "test_nemotron_h", "test_sdar", "test_level_resume", "test_integration_extra",
+    "test_models_masking", "test_mid_level_resume", "test_multiprocess", "test_granite", "test_sparse",
+    "test_tpu_compile", "test_chip_smoke", "test_plan", "test_lfm2", "test_nm", "test_checkpoint",
+    "test_compact_train", "test_ring", "test_moe_groups", "test_granite_ladder", "test_brumby",
+    "test_scan_epoch", "test_flash_causal", "test_ssd", "test_flash_blockdiff", "test_loss_blocks",
+    "test_tracing", "test_serve", "test_fleet",
 )  # fmt: skip
+
+
+def pytest_configure(config):
+    # xdist's loadfile scheduler sorts the files by how many tests each holds, most first, unless
+    # told not to (its ``--no-loadscope-reorder``, which the driver's command does not pass): that
+    # undid the order above, and the files of few long tests (test_integration_extra's 4 in 330 s,
+    # test_mid_level_resume's 6 in 300 s, test_chip_smoke, test_multiprocess) started last, 1,075 s
+    # into a run of 1,427 s, while four workers sat idle (the builder's timed run at PR 45).
+    if hasattr(config.option, "loadscopereorder"):
+        config.option.loadscopereorder = False
 
 
 def pytest_collection_modifyitems(items):

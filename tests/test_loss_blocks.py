@@ -73,8 +73,13 @@ def _same_sums(got, want):
     assert float(got["loss_sum"]) == pytest.approx(float(want["loss_sum"]), rel=1e-6)
 
 
-# The new model, and the tied head with its scaling (granite's, one layer: the heads are what differ).
-@pytest.mark.parametrize("name, kwargs", [["brumby_tiny", {}], ["hybrid_lm_tiny", {"num_layers": 1}]], ids=["brumby", "granite"])
+# The untied head and the tied one with its scaling (brumby's and granite's, one layer of each: the heads are what differ).
+HEADS = pytest.mark.parametrize(
+    "name, kwargs", [["brumby_tiny", {"num_layers": 1}], ["hybrid_lm_tiny", {"num_layers": 1}]], ids=["brumby", "granite"]
+)
+
+
+@HEADS
 def test_the_step_and_the_eval_in_blocks_are_the_whole_ones(name, kwargs, monkeypatch, own_gauges):
     model, tx, batch = create_model(name, VOCAB, **kwargs), optax.sgd(0.1, momentum=0.9), _batch_of(name)
     state = _state_of(model, tx, batch)
@@ -83,9 +88,11 @@ def test_the_step_and_the_eval_in_blocks_are_the_whole_ones(name, kwargs, monkey
         whole_eval = jax.jit(steps.make_eval_step(model))(state, batch)
         assert tracing.gauges().get("loss_blocks_per_step") is None  # whole logits set no gauge
         _in_blocks(monkeypatch)
-        blocked_state, blocked = jax.jit(steps.make_train_step(model, tx))(state, batch)
         blocked_eval = jax.jit(steps.make_eval_step(model))(state, batch)
         assert tracing.trace_gauges()["loss_blocks_per_step"] == T // 16
+        assert "loss_grad_blocks_per_step" not in tracing.gauges()  # an eval makes no gradient
+        blocked_state, blocked = jax.jit(steps.make_train_step(model, tx))(state, batch)
+        assert tracing.trace_gauges()["loss_grad_blocks_per_step"] == T // 16
     _same_sums(blocked, whole)
     _same_sums(blocked_eval, whole_eval)
     # One SGD step from the same state: the gradients, every leaf's that the loss reaches.
@@ -124,19 +131,21 @@ def test_the_routed_models_take_the_heads_reduce(name, kwargs, monkeypatch):
 @pytest.mark.parametrize("weighted", [False, True], ids=["next_token", "weighted"])
 def test_the_sums_of_the_blocks_are_the_sums_of_the_whole(weighted):
     """Both kinds of token labels, the block-diffusion pair too, against
-    ``token_loss_sums`` of logits formed whole, and the gradient to ``x``."""
+    ``token_loss_sums`` of logits formed whole, and the gradients to ``x`` and
+    to the kernel, at a cotangent that is not the mean's."""
     rng = np.random.default_rng(0)
     x = jnp.asarray(rng.normal(size=(BATCH, T, 8)), jnp.float32)
     kernel = jnp.asarray(rng.normal(size=(8, VOCAB)), jnp.float32)
     labels = _batch()[1]
     if weighted:
         labels = (labels, jnp.asarray(np.where(labels >= 0, rng.uniform(0.2, 1.0, labels.shape), -1.0), jnp.float32))
-    logits_of = lambda x: jnp.einsum("btd,dv->btv", x, kernel, precision="highest")
-    whole = lambda x: steps.token_loss_sums(logits_of(x), labels)
-    blocked = lambda x: steps.sums_in_blocks(labels, 3)(logits_of, x)
-    np.testing.assert_allclose(blocked(x), whole(x), rtol=1e-6)
-    grad = lambda f: jax.grad(lambda x: f(x)[0])(x)
-    np.testing.assert_allclose(grad(blocked), grad(whole), rtol=1e-4, atol=1e-6)
+    logits_of = lambda x, kernel: jnp.einsum("btd,dv->btv", x, kernel, precision="highest")
+    whole = lambda x, kernel: steps.token_loss_sums(logits_of(x, kernel), labels)
+    blocked = lambda x, kernel: steps.sums_in_blocks(labels, 3)(logits_of, x, kernel)
+    np.testing.assert_allclose(blocked(x, kernel), whole(x, kernel), rtol=1e-6)
+    grad = lambda f: jax.grad(lambda *a: 0.37 * f(*a)[0], argnums=(0, 1))(x, kernel)
+    for got, want in zip(grad(blocked), grad(whole)):
+        np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-6)
 
 
 @pytest.mark.parametrize(
@@ -202,6 +211,54 @@ def test_no_array_of_tokens_by_vocabulary_exists_in_the_new_models_programs(monk
     assert _rows_by_vocabulary(make(), vocab, state, (tokens, targets)) == model.cfg.hidden_size < BATCH * T
 
 
+def _products_by_scan(jaxpr, vocab) -> list[int]:
+    """The ``dot_general``s of ``jaxpr`` that have the vocabulary as an axis,
+    counted by the outermost scan that holds them (a scan that holds none is
+    left out), those in no scan last."""
+
+    def inner(eqn):
+        return list(jax.core.jaxprs_in_params(eqn.params))
+
+    def products(jaxpr):
+        return sum(
+            (eqn.primitive.name == "dot_general" and any(vocab in v.aval.shape for v in [*eqn.invars, *eqn.outvars]))
+            + sum(map(products, inner(eqn)))
+            for eqn in jaxpr.eqns
+        )
+
+    def scans(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "scan":
+                yield sum(map(products, inner(eqn)))
+            else:
+                for j in inner(eqn):
+                    yield from scans(j)
+
+    found = [n for n in scans(jaxpr) if n]
+    return [*found, products(jaxpr) - sum(found)]
+
+
+@pytest.mark.parametrize("program, products", [["train", 3], ["eval", 1]])
+@HEADS
+def test_a_blocks_logits_are_formed_once(name, kwargs, program, products, monkeypatch, own_gauges):
+    """Traced, never compiled: in blocks the train step's products with the
+    vocabulary as an axis are three, in ONE scan over the blocks (the logits,
+    ``d x``, the gradient of the kernel or of the tied table), where a
+    backward scan that rebuilt the logits made them four over two; the eval
+    step's is the one. The train trace says so in a gauge, the eval's does not."""
+    vocab = 4099  # no other size of either model
+    model, tx, batch = create_model(name, vocab, **kwargs), optax.sgd(0.1), _batch_of(name)
+    state = jax.eval_shape(
+        lambda: create_train_state(model, tx, jax.random.PRNGKey(0), batch[0].shape, input_dtype="int32")
+    )
+    _in_blocks(monkeypatch)
+    step = steps.make_train_step(model, tx) if program == "train" else steps.make_eval_step(model)
+    assert _products_by_scan(jax.make_jaxpr(step)(state, batch).jaxpr, vocab) == [products, 0]
+    gauges = tracing.gauges()
+    assert gauges["loss_blocks_per_step"] == T // 16
+    assert gauges.get("loss_grad_blocks_per_step") == (T // 16 if program == "train" else None)
+
+
 _PROGRAMS = """
 import hashlib, json, sys, jax, jax.numpy as jnp, numpy as np, optax
 from turboprune_tpu.data.tokens import block_ordinals
@@ -239,16 +296,28 @@ _CASES = [
 ]
 
 
-@pytest.fixture(scope="module")
-def program_hashes():
-    """In a process of its own, as the hashes were taken: the names a trace
-    gives its functions count what the process traced before."""
+@pytest.fixture(scope="module", autouse=True)
+def lowering(tmp_path_factory):
+    """The eight programs lowered in a process of its own, as the hashes were
+    taken (the names a trace gives its functions count what the process traced
+    before), started with the module's first test: it runs beside the other
+    tests and not after them (71 s of this file's 177 in the driver's run of
+    PR 44's tree, which took 1,434 s of its 1,470)."""
+    out = tmp_path_factory.mktemp("lowering")
     env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=os.pathsep.join(sys.path))
-    out = subprocess.run(
-        [sys.executable, "-c", _PROGRAMS, json.dumps(_CASES)], capture_output=True, text=True, env=env, timeout=600
-    )
-    assert out.returncode == 0, out.stderr[-2000:]
-    return {tuple(line.split()[:2]): line.split()[2] for line in out.stdout.splitlines() if len(line.split()) == 3}
+    with open(out / "stdout", "w") as stdout, open(out / "stderr", "w") as stderr:
+        proc = subprocess.Popen([sys.executable, "-c", _PROGRAMS, json.dumps(_CASES)], stdout=stdout, stderr=stderr, env=env)
+    yield proc, out
+    proc.kill()  # a run that selected none of its readers
+    proc.wait()
+
+
+@pytest.fixture(scope="module")
+def program_hashes(lowering):
+    proc, out = lowering
+    assert proc.wait(timeout=600) == 0, (out / "stderr").read_text()[-2000:]
+    lines = (out / "stdout").read_text().splitlines()
+    return {tuple(line.split()[:2]): line.split()[2] for line in lines if len(line.split()) == 3}
 
 
 @pytest.mark.parametrize(

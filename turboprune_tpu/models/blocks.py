@@ -359,14 +359,17 @@ class SparseMoE(nn.Module):
         return out.astype(dtype).reshape(h32.shape)
 
 
-def head_output(logits_of, x, reduce=None):
+def head_output(logits_of, x, *operands, reduce=None):
     """What every language model's ``__call__`` returns: the logits
-    ``logits_of(x)``; or, where the caller gives a ``reduce``,
-    ``reduce(logits_of, x)`` in their place. ``reduce`` runs ``logits_of`` on
-    as many of ``x``'s tokens at a time as it likes and returns what it makes
-    of them (train/steps.py's loss a block of tokens at a time), so that
-    ``[tokens, vocabulary]`` need never exist whole."""
-    return logits_of(x) if reduce is None else reduce(logits_of, x)
+    ``logits_of(x, *operands)``; or, where the caller gives a ``reduce``,
+    ``reduce(logits_of, x, *operands)`` in their place. ``operands`` are the
+    arrays the head reads beside ``x`` (the untied kernel, the tied table):
+    ``logits_of`` closes over none, so that a ``reduce`` can differentiate
+    with respect to them by a rule of its own. ``reduce`` runs ``logits_of``
+    on as many of ``x``'s tokens at a time as it likes and returns what it
+    makes of them (train/steps.py's loss a block of tokens at a time), so
+    that ``[tokens, vocabulary]`` need never exist whole."""
+    return logits_of(x, *operands) if reduce is None else reduce(logits_of, x, *operands)
 
 
 class Head(nn.Module):
@@ -378,8 +381,7 @@ class Head(nn.Module):
     @nn.compact
     def __call__(self, x, reduce=None):
         kernel = self.param("kernel", nn.initializers.normal(0.02), (x.shape[-1], self.vocab_size))
-        logits_of = lambda x: jnp.einsum(
+        logits_of = lambda x, kernel: jnp.einsum(
             "btd,dv->btv", x, kernel.astype(self.dtype), preferred_element_type=jnp.float32
         )
-        return head_output(logits_of, x, reduce)
-
+        return head_output(logits_of, x, kernel, reduce=reduce)

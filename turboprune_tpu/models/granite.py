@@ -133,10 +133,10 @@ class HybridLM(nn.Module):
             x = block(kind, c, self.dtype, name=f"layers_{i}")(x, seg)
         x = RMSNorm(c.rms_norm_eps, self.dtype, name="final_norm")(x)
         with jax.named_scope("lm_head"):
-            logits_of = lambda x: jnp.einsum(
+            logits_of = lambda x, table: jnp.einsum(
                 "btd,vd->btv", x, table.astype(self.dtype), preferred_element_type=jnp.float32
             ) / c.logits_scaling
-            return head_output(logits_of, x, reduce)
+            return head_output(logits_of, x, table, reduce=reduce)
 
 
 # granite-4.0-h-micro as published (huggingface.co/ibm-granite/

@@ -235,10 +235,10 @@ class Lfm2(nn.Module):
             )(x, seg)
         x = RMSNorm(c.norm_eps, self.dtype, name="final_norm")(x)
         with jax.named_scope("lm_head"):
-            logits_of = lambda x: jnp.einsum(
+            logits_of = lambda x, table: jnp.einsum(
                 "btd,vd->btv", x, table.astype(self.dtype), preferred_element_type=jnp.float32
             )
-            return head_output(logits_of, x, reduce)
+            return head_output(logits_of, x, table, reduce=reduce)
 
 
 # LFM2-8B-A1B as published (huggingface.co/LiquidAI/LFM2-8B-A1B, config.json):

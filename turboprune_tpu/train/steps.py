@@ -27,14 +27,18 @@ one a step has it reads off the labels, never off a model's name.
 **The loss in blocks.** A token batch whose logits would take more than
 ``LOGITS_BYTES`` (tokens x vocabulary x 4, read off the shapes as the step
 is traced) never has them whole: the model is handed ``sums_in_blocks`` as
-its head's ``reduce`` (models/blocks.py's ``Head``) and returns the three
-sums, head and loss run ``LOSS_BLOCK`` tokens at a time under a
-``jax.checkpoint`` (a block's logits are rebuilt in the backward pass), in
-the train step, in the eval step and so in ``evaluate()`` and every probe.
-Smaller batches run as they always did, program for program
-(tests/test_loss_blocks.py). Gauge ``loss_blocks_per_step``: the blocks of
-the newest such trace; a process whose steps all have their logits whole
-never sets it.
+its head's ``reduce`` (models/blocks.py's ``head_output``) and returns the
+three sums, head and loss run ``LOSS_BLOCK`` tokens at a time, in the train
+step, in the eval step and so in ``evaluate()`` and every probe. A block's
+logits are formed once: where the step is differentiated the block that has
+them makes its gradient too (``sums_in_blocks``' own derivative rule), and
+what is kept from the forward pass to the backward pass is the gradient of
+the head's input and of its kernel, which the backward pass would build first
+anyway. Smaller batches run as they always did, program for program
+(tests/test_loss_blocks.py). Gauges ``loss_blocks_per_step``, the blocks of
+the newest such trace, and ``loss_grad_blocks_per_step``, those of them that
+made their gradient with their loss (a train step's; an eval's sets only the
+first); a process whose steps all have their logits whole sets neither.
 """
 
 from __future__ import annotations
@@ -111,6 +115,11 @@ def token_loss_sums(logits: jax.Array, labels):
     return masked_cross_entropy(logits, labels)
 
 
+def token_count(labels) -> jax.Array:
+    """``token_loss_sums``' count, which is of the labels alone."""
+    return jnp.sum((labels[1] if isinstance(labels, tuple) else labels) >= 0).astype(jnp.float32)
+
+
 def loss_blocks(model, labels) -> int:
     """How many blocks of tokens a step's head and loss run in: 0 where the
     logits are formed whole (an image batch; a token batch whose float32
@@ -130,21 +139,69 @@ def loss_blocks(model, labels) -> int:
 def sums_in_blocks(labels, blocks: int) -> Callable:
     """A head's ``reduce`` (models/blocks.py): ``token_loss_sums`` of the
     logits of ``x`` [B, T, D] against ``labels``, ``T / blocks`` tokens of
-    every sequence at a time. Each block is a ``jax.checkpoint``: what a
-    backward pass keeps of it is its three sums."""
+    every sequence at a time, as a function with a derivative rule of its own.
 
-    def reduce(logits_of, x):
-        split = lambda a: jnp.moveaxis(a.reshape(a.shape[0], blocks, -1, *a.shape[2:]), 1, 0)
+    Not differentiated (the eval step), a block makes its logits and its three
+    sums. Differentiated (the train step), a block also makes, of the logits
+    it has in hand, the gradient of its loss sum with respect to its ``x`` and
+    to ``operands`` (the head's kernel or the tied table): the one scan keeps
+    ``d x`` [B, T, D] in ``x``'s dtype and one float32 accumulator an operand.
+    Those are the arrays a backward scan over the blocks would build; the loss
+    is the last of the forward pass and the first of the backward pass, so
+    they are alive no longer for being made early, and no block's logits are
+    formed twice. A block makes its gradient at the cotangent of the mean
+    loss, ``1 / token_count(labels)``, so that what it rounds is what a
+    backward pass would have rounded; the backward pass multiplies by the
+    cotangent it is given over that one, which in the train step is 1.
+    Gauge ``loss_grad_blocks_per_step``: the blocks whose gradient the newest
+    such trace made with their loss."""
+    split = lambda a: jnp.moveaxis(a.reshape(a.shape[0], blocks, -1, *a.shape[2:]), 1, 0)
+    zero = (jnp.zeros((), jnp.float32),) * 3
+    add = lambda sums, new: tuple(a + b for a, b in zip(sums, new))
 
-        @jax.checkpoint
-        def block(sums, inp):
-            x_block, labels_block = inp
+    def reduce(logits_of, x, *operands):
+        def sums_of(x_block, labels_block, *operands):
             with jax.named_scope("loss"):
-                new = token_loss_sums(logits_of(x_block), labels_block)
-            return tuple(a + b for a, b in zip(sums, new)), None
+                return token_loss_sums(logits_of(x_block, *operands), labels_block)
 
-        zero = (jnp.zeros((), jnp.float32),) * 3
-        return jax.lax.scan(block, zero, (split(x), jax.tree.map(split, labels)))[0]
+        @jax.custom_vjp
+        def total(x, labels, *operands):
+            block = lambda sums, inp: (add(sums, sums_of(*inp, *operands)), None)
+            return jax.lax.scan(block, zero, jax.tree.map(split, (x, labels)))[0]
+
+        def total_fwd(x, labels, *operands):
+            tracing.gauge("loss_grad_blocks_per_step", blocks)
+            at = 1.0 / token_count(labels)
+
+            def block(carry, inp):
+                sums, grads = carry
+                x_block, labels_block = inp
+                # The block as an array of its own, not a slice fused into each product that reads
+                # it: XLA then holds it in fast memory for the product with the logits' gradient,
+                # as it did in the backward scan this rule replaced (PERF.md section 6, PR 45).
+                x_block = jax.lax.optimization_barrier(x_block)
+                new, pull = jax.vjp(
+                    lambda x_block, *operands: sums_of(x_block, labels_block, *operands), x_block, *operands
+                )
+                dx, *d_operands = pull((at, *zero[1:]))  # hits and count carry no gradient
+                grads = tuple(g + d.astype(jnp.float32) for g, d in zip(grads, d_operands))
+                return (add(sums, new), grads), dx
+
+            grads = tuple(jnp.zeros(o.shape, jnp.float32) for o in operands)
+            (sums, grads), dx = jax.lax.scan(block, (zero, grads), jax.tree.map(split, (x, labels)))
+            return sums, (jnp.moveaxis(dx, 0, 1).reshape(x.shape), grads, at)
+
+        def total_bwd(kept, cotangents):
+            dx, grads, at = kept
+            g = cotangents[0] / at
+            return (
+                (g * dx).astype(dx.dtype),
+                None,
+                *((g * a).astype(o.dtype) for a, o in zip(grads, operands)),
+            )
+
+        total.defvjp(total_fwd, total_bwd)
+        return total(x, labels, *operands)
 
     return reduce
 
